@@ -30,7 +30,6 @@ A ground-up JAX/XLA/Pallas rebuild of the capability surface of NVIDIA Apex
 
 __version__ = "0.1.0"
 
-from apex_tpu import _compat  # noqa: F401  (jax version shims; must be first)
 from apex_tpu import collectives  # noqa: F401
 from apex_tpu import mesh  # noqa: F401
 

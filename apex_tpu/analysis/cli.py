@@ -3,7 +3,7 @@
 ``python -m apex_tpu.analysis`` (or the ``apex-tpu-lint`` console
 script) with no path arguments scans the production surface — the
 ``apex_tpu/`` package plus the repo-root ``tpu_*.py`` / ``bench*.py``
-drivers — exactly the set ``run_tpu_round.sh`` gates on. Exit status:
+drivers. Exit status:
 
 * 0 — clean (every finding suppressed inline or absorbed by the
   baseline);

@@ -6,7 +6,7 @@ traceable entry points (every ``tpu_aot.kernel_cases()`` program plus
 the serving engine's decode chunk and bucketed admission), builds their
 jaxprs on CPU (``jax.make_jaxpr`` over ``ShapeDtypeStruct`` args — no
 TPU, no compile), ``ir_rules`` checks them (dtype promotion drift, dead
-outputs/scan carries, ineffective donation, large closed-over
+outputs, ineffective donation, large closed-over
 constants, broadcast blowup, effectful primitives in scan bodies,
 compile-key cardinality, minor-dim transposes feeding Pallas), and
 ``ir_report`` maps every finding back to source via ``eqn.source_info``
@@ -17,7 +17,7 @@ Usage::
 
     python -m apex_tpu.analysis --ir              # the whole registry
     python -m apex_tpu.analysis --ir-case NAME    # one entry point
-    python -m apex_tpu.analysis --ir --select ir-dead-scan-carry
+    python -m apex_tpu.analysis --ir --select ir-dead-output
 """
 
 from apex_tpu.analysis.ir.harness import (AnalysisCase, CaseIR,

@@ -131,11 +131,10 @@ def _domain_for(name: str) -> str:
 def _aot_cases(root: Path) -> List[AnalysisCase]:
     if str(root) not in sys.path:
         sys.path.insert(0, str(root))
-    # tpu_aot flips the PROCESS into Mosaic dispatch at import (its own
-    # runs are all-AOT); an in-process lint consumer (the tier-1 suite)
-    # must get its env back — only _force_mosaic's tracing window may
-    # keep the flag
-    with _force_mosaic():
+    from apex_tpu.ops._dispatch import forced_mosaic
+
+    # building the cases traces model inits: stage them like the rest
+    with forced_mosaic():
         import tpu_aot
 
         cases = list(tpu_aot.kernel_cases())
@@ -794,44 +793,16 @@ def analysis_cases(root) -> List[AnalysisCase]:
 # tracing
 # --------------------------------------------------------------------------
 
-class _force_mosaic:
-    """Stage the TPU kernel path during tracing regardless of the host
-    backend (see module docstring); restores the env on exit.
-
-    Exit also clears jax's trace caches: tracing through module-level
-    jit wrappers bakes ``interpret=False`` pallas params into their
-    cached jaxprs, and an in-process consumer (the tier-1 suite)
-    EXECUTING the same op at the same shapes afterwards would reuse the
-    poisoned trace and fail on CPU. Dropping the caches costs a
-    re-trace, never correctness."""
-
-    _KEYS = ("APEX_TPU_FORCE_MOSAIC", "APEX_TPU_FORCE_INTERPRET")
-
-    def __enter__(self):
-        self._old = {k: os.environ.get(k) for k in self._KEYS}
-        os.environ["APEX_TPU_FORCE_MOSAIC"] = "1"
-        os.environ.pop("APEX_TPU_FORCE_INTERPRET", None)
-
-    def __exit__(self, *exc):
-        for k, v in self._old.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-        import jax
-
-        jax.clear_caches()
-        return False
-
-
 def _trace(prog: CaseProgram, args: tuple):
     import contextlib
 
     import jax
 
-    ctx = jax.experimental.enable_x64() if prog.x64 \
+    from apex_tpu.ops._dispatch import forced_mosaic
+
+    ctx = jax.enable_x64(True) if prog.x64 \
         else contextlib.nullcontext()
-    with _force_mosaic(), ctx:
+    with forced_mosaic(), ctx:
         return jax.make_jaxpr(prog.fn)(*args)
 
 

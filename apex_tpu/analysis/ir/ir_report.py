@@ -36,13 +36,9 @@ def eqn_anchor(eqn, root: Path) -> Optional[Tuple[str, int]]:
     """(repo-relative path, line) of the innermost user frame under
     ``root`` for one equation, or None (e.g. jax-internal synthesized
     eqns)."""
-    try:
-        from jax._src import source_info_util as siu
+    from jax._src import source_info_util as siu
 
-        frames = siu.user_frames(eqn.source_info)
-    except Exception:
-        return None
-    for frame in frames:
+    for frame in siu.user_frames(eqn.source_info.traceback):
         rel = _rel_to(root, frame.file_name)
         if rel is not None and frame.start_line:
             return (rel, int(frame.start_line))

@@ -204,7 +204,7 @@ def check_x64_leak(ir: CaseIR) -> Iterator[RawFinding]:
 
 
 # --------------------------------------------------------------------------
-# 3. ir-dead-output / 4. ir-dead-scan-carry
+# 3. ir-dead-output
 # --------------------------------------------------------------------------
 
 #: dead-output flags ONLY these: kernel launches and contractions XLA
@@ -212,7 +212,7 @@ def check_x64_leak(ir: CaseIR) -> Iterator[RawFinding]:
 #: signals a drifted contract. Dead PURE elementwise eqns (a grad-of-
 #: loss primal, a dropped slice) are free for XLA to DCE — flagging
 #: them would bury the real findings in artifacts of how grad stages.
-_EXPENSIVE_PRIMS = {"scan", "while", "cond", "pjit", "closed_call",
+_EXPENSIVE_PRIMS = {"scan", "while", "cond", "jit", "closed_call",
                     "core_call", "remat", "checkpoint", "dot_general",
                     "conv_general_dilated", "custom_jvp_call",
                     "custom_vjp_call", "pallas_call"}
@@ -246,7 +246,7 @@ def _dead_eqns(jaxpr, live_out: Optional[Set[int]] = None
             yield eqn, dead_v
             continue
         # project outer liveness into pjit-like bodies (1:1 outputs)
-        if eqn.primitive.name in ("pjit", "closed_call", "core_call",
+        if eqn.primitive.name in ("jit", "closed_call", "core_call",
                                   "remat", "checkpoint"):
             for sub in _sub_jaxprs(eqn):
                 if len(sub.outvars) != len(eqn.outvars):
@@ -282,52 +282,8 @@ def check_dead_output(ir: CaseIR) -> Iterator[RawFinding]:
                  "reads — dead computation carried in the program")
 
 
-@ir_rule("ir-dead-scan-carry", "warning",
-         "a scan carry component is passed through unread and its "
-         "final value unused — vestigial state copied every step")
-def check_dead_scan_carry(ir: CaseIR) -> Iterator[RawFinding]:
-    for jaxpr in _all_jaxprs(ir.closed.jaxpr):
-        # per-jaxpr use map: vars read by any eqn or returned
-        used: Set[int] = {id(v) for v in jaxpr.outvars}
-        for eqn in jaxpr.eqns:
-            for v in eqn.invars:
-                if _is_var(v):
-                    used.add(id(v))
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name != "scan":
-                continue
-            body = eqn.params["jaxpr"].jaxpr
-            nc = eqn.params["num_consts"]
-            k = eqn.params["num_carry"]
-            body_used: Set[int] = set()
-            for be in body.eqns:
-                for v in be.invars:
-                    if _is_var(v):
-                        body_used.add(id(v))
-            for i in range(k):
-                inv = body.invars[nc + i]
-                outv = body.outvars[i]
-                if outv is not inv:
-                    continue                      # genuinely updated
-                if id(inv) in body_used:
-                    continue                      # read-only state: fine
-                if i < len(body.outvars) \
-                        and body.outvars.count(inv) > 1:
-                    continue                      # aliased elsewhere
-                carried_out = eqn.outvars[i]
-                if not _is_drop(carried_out) and id(carried_out) in used:
-                    continue                      # final value consumed
-                yield RawFinding(
-                    eqn,
-                    f"scan carry component {i} "
-                    f"(shape {tuple(inv.aval.shape)}, {inv.aval.dtype}) "
-                    "is passed through unread and its final value is "
-                    "never consumed — dead state copied every "
-                    "iteration; hoist it out of the carry")
-
-
 # --------------------------------------------------------------------------
-# 5. ir-donation-ineffective
+# 4. ir-donation-ineffective
 # --------------------------------------------------------------------------
 
 @ir_rule("ir-donation-ineffective", "warning",
@@ -358,7 +314,7 @@ def check_donation_ineffective(ir: CaseIR) -> Iterator[RawFinding]:
 
 
 # --------------------------------------------------------------------------
-# 6. ir-large-const-capture
+# 5. ir-large-const-capture
 # --------------------------------------------------------------------------
 
 @ir_rule("ir-large-const-capture", "warning",
@@ -367,7 +323,7 @@ def check_donation_ineffective(ir: CaseIR) -> Iterator[RawFinding]:
          "compile-cache entry")
 def check_large_const(ir: CaseIR) -> Iterator[RawFinding]:
     for const in ir.closed.consts:
-        nb = int(getattr(const, "nbytes", 0) or 0)
+        nb = _nbytes(const)
         if nb >= CONST_BYTES:
             yield RawFinding(
                 None,
@@ -379,7 +335,7 @@ def check_large_const(ir: CaseIR) -> Iterator[RawFinding]:
 
 
 # --------------------------------------------------------------------------
-# 7. ir-broadcast-blowup
+# 6. ir-broadcast-blowup
 # --------------------------------------------------------------------------
 
 @ir_rule("ir-broadcast-blowup", "warning",
@@ -406,7 +362,7 @@ def check_broadcast_blowup(ir: CaseIR) -> Iterator[RawFinding]:
 
 
 # --------------------------------------------------------------------------
-# 8. ir-effectful-in-scan
+# 7. ir-effectful-in-scan
 # --------------------------------------------------------------------------
 
 @ir_rule("ir-effectful-in-scan", "warning",
@@ -427,7 +383,7 @@ def check_effectful_in_scan(ir: CaseIR) -> Iterator[RawFinding]:
         name = eqn.primitive.name
         if "callback" in name or name == "debug_print" \
                 or (host_effects(eqn)
-                    and name not in ("scan", "while", "cond", "pjit")):
+                    and name not in ("scan", "while", "cond", "jit")):
             yield RawFinding(
                 eqn,
                 f"`{name}` executes inside a scan/while body: one host "
@@ -437,7 +393,7 @@ def check_effectful_in_scan(ir: CaseIR) -> Iterator[RawFinding]:
 
 
 # --------------------------------------------------------------------------
-# 9. ir-compile-key-cardinality
+# 8. ir-compile-key-cardinality
 # --------------------------------------------------------------------------
 
 @ir_rule("ir-compile-key-cardinality", "error",
@@ -464,7 +420,7 @@ def check_compile_cardinality(ir: CaseIR) -> Iterator[RawFinding]:
 
 
 # --------------------------------------------------------------------------
-# 10. ir-transpose-heavy-layout
+# 9. ir-transpose-heavy-layout
 # --------------------------------------------------------------------------
 
 @ir_rule("ir-transpose-heavy-layout", "warning",

@@ -28,7 +28,7 @@ path so "registered for lint" means "covered by the fit proof"):
   compile.
 
 - **sharding contracts** (:class:`ShardMapInfo`): every ``shard_map``
-  equation's mesh axis sizes + per-operand ``in_names``/``out_names``,
+  equation's mesh axis sizes + per-operand ``in_specs``/``out_specs``,
   aligned positionally with the case's argument tree paths so rules can
   talk about ``cache/layers/0/k_scales`` rather than ``invar 17``.
 
@@ -96,7 +96,7 @@ def unwrap_trivial(jaxpr):
     program. Stops at the first level that has real structure."""
     depth = 0
     while depth < 8 and len(jaxpr.eqns) == 1 and \
-            jaxpr.eqns[0].primitive.name in ("pjit", "closed_call",
+            jaxpr.eqns[0].primitive.name in ("jit", "closed_call",
                                              "custom_jvp_call",
                                              "custom_vjp_call",
                                              "remat", "checkpoint"):
@@ -131,34 +131,34 @@ class ShardMapInfo:
             if pos < len(self.out_names) else {}
 
 
+def _spec_names(spec) -> Dict[int, Tuple[str, ...]]:
+    """``PartitionSpec`` -> ``{dim: (axis names...)}`` over the dims it
+    shards."""
+    return {dim: entry if isinstance(entry, tuple) else (entry,)
+            for dim, entry in enumerate(spec) if entry is not None}
+
+
 def shard_map_infos(closed) -> List[ShardMapInfo]:
     out: List[ShardMapInfo] = []
     for eqn in iter_eqns(unwrap_trivial(closed.jaxpr)):
         if eqn.primitive.name != "shard_map":
             continue
-        mesh = eqn.params.get("mesh")
-        try:
-            mesh_axes = {str(k): int(v) for k, v in dict(mesh.shape).items()}
-        except Exception:
-            mesh_axes = {}
-        body = eqn.params.get("jaxpr")
-        body = getattr(body, "jaxpr", body)
+        mesh_axes = {str(k): int(v)
+                     for k, v in eqn.params["mesh"].shape.items()}
+        body = eqn.params["jaxpr"]
         out.append(ShardMapInfo(
             eqn=eqn, mesh_axes=mesh_axes,
-            in_names=tuple(eqn.params.get("in_names", ())),
-            out_names=tuple(eqn.params.get("out_names", ())),
-            body=body))
+            in_names=tuple(map(_spec_names, eqn.params["in_specs"])),
+            out_names=tuple(map(_spec_names, eqn.params["out_specs"])),
+            body=getattr(body, "jaxpr", body)))
     return out
 
 
-def arg_leaf_paths(prog) -> Optional[List[Tuple[str, object, int]]]:
+def arg_leaf_paths(prog) -> List[Tuple[str, object, int]]:
     """Flatten the case's argument tuple to ``(path, aval, arg_index)``
-    leaves in jaxpr-invar order (``make_jaxpr`` flattens positionally).
-    None when jax is too old to report paths."""
-    try:
-        import jax
-    except Exception:
-        return None
+    leaves in jaxpr-invar order (``make_jaxpr`` flattens positionally)."""
+    import jax
+
     leaves: List[Tuple[str, object, int]] = []
     for i, arg in enumerate(prog.args):
         flat = jax.tree_util.tree_flatten_with_path(arg)[0]
@@ -194,7 +194,7 @@ def _scan_carry_extra(eqn) -> int:
 _INPLACE_PRIMS = frozenset({
     "scatter", "scatter-add", "scatter-mul", "scatter-min",
     "scatter-max", "dynamic_update_slice", "scan", "while", "select_n",
-    "copy", "pjit", "closed_call",
+    "copy", "jit", "closed_call",
 })
 
 
@@ -286,18 +286,6 @@ class VmemCall:
     grid: Tuple[int, ...]
 
 
-def _block_dims(block_shape) -> Tuple[int, ...]:
-    # grid-mapped dims appear as pallas' Mapped sentinel (not an int):
-    # the kernel sees them squeezed, i.e. extent 1
-    dims = []
-    for d in block_shape:
-        try:
-            dims.append(max(int(d), 1))
-        except (TypeError, ValueError):
-            dims.append(1)
-    return tuple(dims)
-
-
 def vmem_calls(closed) -> List[VmemCall]:
     out: List[VmemCall] = []
     for eqn in iter_eqns(unwrap_trivial(closed.jaxpr)):
@@ -312,18 +300,16 @@ def vmem_calls(closed) -> List[VmemCall]:
             grid = ()                      # dynamic grid: size unknown
         total = 0
         n_blocks = 0
-        for bm in getattr(gm, "block_mappings", ()):
-            sds = getattr(bm, "array_shape_dtype", None)
-            dtype = getattr(sds, "dtype", None)
-            if dtype is None:
-                continue
-            total += tiled_padded_bytes(
-                _block_dims(getattr(bm, "block_shape", ())), dtype)
+        for bm in gm.block_mappings:
+            # the VMEM block as the kernel sees it: grid-mapped
+            # (squeezed) dims are already dropped from its shape
+            block = bm.block_aval
+            total += tiled_padded_bytes(tuple(block.shape), block.dtype)
             n_blocks += 1
         buffering = 2 if any(g > 1 for g in grid) else 1
-        name = str(eqn.params.get("name_and_src_info",
-                                  eqn.params.get("name", "<kernel>")))
-        out.append(VmemCall(eqn=eqn, kernel_name=name.split(" ")[0],
+        name = eqn.params["name"] \
+            or eqn.params["jaxpr"].debug_info.func_name
+        out.append(VmemCall(eqn=eqn, kernel_name=name,
                             est_bytes=total * buffering,
                             buffering=buffering, n_blocks=n_blocks,
                             grid=grid))
@@ -359,7 +345,7 @@ class MemEstimate:
     boundary: List[BoundaryArray]
     vmem: List[VmemCall]
     shard_maps: List[ShardMapInfo]
-    arg_leaves: Optional[List[Tuple[str, object, int]]]
+    arg_leaves: List[Tuple[str, object, int]]
     notes: List[str]
 
 
@@ -410,7 +396,7 @@ def estimate_case(ir) -> MemEstimate:
                      "(per-chip bytes)")
 
     def _label(kind: str, idx: int) -> str:
-        if kind == "in" and leaves is not None and idx < len(leaves) \
+        if kind == "in" and idx < len(leaves) \
                 and len(leaves) == len(jaxpr.invars):
             return leaves[idx][0]
         return f"{kind}[{idx}]"

@@ -460,7 +460,6 @@ def decode_loop(step_apply, prefill_logits, cache, max_new_tokens: int, *,
     if max_new_tokens > 1:
         # without an eos the `done` carry is vestigial (never read) —
         # kept so the scan signature is identical across eos modes
-        # tpu-lint: disable=ir-dead-scan-carry -- one (b,) bool per step
         _, rest = lax.scan(step, (cache, tok0, done0),
                            jnp.arange(1, max_new_tokens))
         return jnp.concatenate([tok0[:, None], rest.T], axis=1)

@@ -23,8 +23,8 @@ Performance attribution (PR 8) adds three more, CLI-first:
 - ``compile_watch`` — :class:`CompileWatcher`: jit recompile /
   trace-cache-miss counters keyed by function name, with the serving
   frontend's recompile-storm warning built on top.
-- ``ledger`` — the persistent perf ledger + regression gate
-  (``python -m apex_tpu.obs.ledger --check``, ``PERF_LEDGER.jsonl``).
+- ``ledger`` — the persistent cost ledger + regression gate
+  (``python -m apex_tpu.obs.ledger --check``, ``COST_LEDGER.jsonl``).
 
 The fleet plane (``fleet``, docs/observability.md "Fleet plane") spans
 processes: process-independent trace ids stitched across replica

@@ -15,15 +15,9 @@ PR 4 instrument registry:
   then also compile).
 
 Mechanism: ``jax.monitoring.register_event_duration_secs_listener``
-subscribes to jax's own ``/jax/core/compile/...`` duration events. Those
-events carry no function name, so the watcher also wraps
-``jax._src.dispatch.log_elapsed_time`` (the context manager every
-compile/trace timer runs under) purely to capture ``fun_name`` into a
-thread-local — the listener reads it at record time. When this jax
-version has no ``jax.monitoring`` (or the internal timer moved), the
-wrapper alone times the lowering and records directly — same
-instruments, degraded to wrapper-measured durations; if neither hook
-exists the watcher is inert (counts stay 0) rather than broken.
+subscribes to jax's own ``/jax/core/compile/...`` duration events; each
+carries the jitted function's name as its ``fun_name`` keyword, which
+keys the instruments.
 
 One process-wide watcher (:func:`watcher`) is installed lazily on first
 use — the serving frontend snapshots its counters per run and raises a
@@ -42,10 +36,10 @@ process-level warning that happened to be noticed by this frontend.
 
 from __future__ import annotations
 
-import contextlib
 import threading
-import time
 from typing import Dict, Optional, Tuple
+
+from jax import monitoring
 
 from apex_tpu.utils import metrics
 
@@ -75,49 +69,21 @@ class CompileWatcher:
         self._compiles: Dict[str, int] = {}
         self._trace_misses: Dict[str, int] = {}
         self._installed = False
-        self._listener_active = False
-        self._orig_log_elapsed = None
-        self._names = threading.local()
-
-    # -- name capture (thread-local stack) -----------------------------------
-
-    def _current_name(self) -> str:
-        stack = getattr(self._names, "stack", None)
-        return stack[-1] if stack else _UNKNOWN
-
-    @contextlib.contextmanager
-    def _wrapped_log_elapsed(self, fmt, fun_name, event=None, **kw):
-        stack = getattr(self._names, "stack", None)
-        if stack is None:
-            stack = self._names.stack = []
-        stack.append(str(fun_name))
-        t0 = time.perf_counter()
-        try:
-            with self._orig_log_elapsed(fmt, fun_name, event=event, **kw):
-                yield
-        finally:
-            # fallback mode: no monitoring listener delivers durations,
-            # so the wrapper itself times the lowering window
-            if not self._listener_active and event is not None:
-                self._record(event, time.perf_counter() - t0)
-            stack.pop()
 
     # -- recording -----------------------------------------------------------
 
     # the listener runs synchronously inside jax's compile path on
     # arbitrary threads; it only updates host-side counters
     # tpu-lint: host-boundary -- monitoring callback, never traced
-    def _on_duration(self, event, duration, **kwargs) -> None:
-        self._record(event, duration)
-
-    def _record(self, event: str, duration_s: float) -> None:
-        name = self._current_name()
+    def _on_duration(self, event, duration, *, fun_name=_UNKNOWN,
+                     **kwargs) -> None:
+        name = str(fun_name)
         if event == _COMPILE_EVENT:
             with self._lock:
                 self._compiles[name] = self._compiles.get(name, 0) + 1
             metrics.counter("jit.compiles", labels={"fn": name}).inc()
             metrics.histogram("jit.compile_ms", labels={"fn": name}) \
-                .observe(duration_s * 1e3)
+                .observe(duration * 1e3)
         elif event == _TRACE_EVENT:
             with self._lock:
                 self._trace_misses[name] = \
@@ -128,44 +94,21 @@ class CompileWatcher:
     # -- install / uninstall -------------------------------------------------
 
     def install(self) -> "CompileWatcher":
-        """Idempotently hook jax. Safe to call from any thread."""
+        """Idempotently subscribe to jax. Safe to call from any thread."""
         with self._lock:
             if self._installed:
                 return self
             self._installed = True
-        try:
-            from jax import monitoring
-            monitoring.register_event_duration_secs_listener(
-                self._on_duration)
-            self._listener_active = True
-        except Exception:       # noqa: BLE001 — no monitoring: fallback
-            self._listener_active = False
-        try:
-            from jax._src import dispatch as _dispatch
-            self._orig_log_elapsed = _dispatch.log_elapsed_time
-            _dispatch.log_elapsed_time = self._wrapped_log_elapsed
-        except Exception:       # noqa: BLE001 — names degrade to unknown
-            self._orig_log_elapsed = None
+        monitoring.register_event_duration_secs_listener(self._on_duration)
         return self
 
     def uninstall(self) -> None:
-        """Remove the hooks (tests); counts/instruments are kept."""
+        """Unsubscribe (tests); counts/instruments are kept."""
         with self._lock:
             if not self._installed:
                 return
             self._installed = False
-        if self._listener_active:
-            try:
-                from jax._src import monitoring as _monitoring
-                _monitoring._unregister_event_duration_listener_by_callback(
-                    self._on_duration)
-            except Exception:   # noqa: BLE001 — listener list unchanged
-                pass
-            self._listener_active = False
-        if self._orig_log_elapsed is not None:
-            from jax._src import dispatch as _dispatch
-            _dispatch.log_elapsed_time = self._orig_log_elapsed
-            self._orig_log_elapsed = None
+        monitoring.unregister_event_duration_listener(self._on_duration)
 
     # -- reads ---------------------------------------------------------------
 

@@ -11,8 +11,7 @@ touches a device. Three transports:
   deterministic for a given registry state (the golden-file test pins
   it).
 - :func:`json_snapshot` / :func:`write_snapshot` — the full registry as
-  one JSON document (CI artifacts: ``run_tpu_round.sh`` banks one per
-  round next to the bench JSON).
+  one JSON document (a CI artifact next to the bench JSON).
 - :func:`serve` — optional stdlib ``http.server`` endpoint exposing
   ``/metrics`` (Prometheus), ``/metrics.json``, ``/healthz`` (liveness:
   pump-alive + queue depth of the frontend passed via ``serve(...,

@@ -9,6 +9,7 @@ run-everywhere kernels.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 
@@ -23,17 +24,43 @@ def _backend() -> str:
 def interpret() -> bool:
     """True when pallas_call must run in interpreter mode (non-TPU backend).
 
-    ``APEX_TPU_FORCE_MOSAIC=1`` forces the Mosaic path even when the default
-    backend is CPU — used by the offline AOT evidence tier (``tpu_aot.py``),
-    which lowers kernels against a device-less TPU *topology*
-    (``jax.experimental.topologies``) where ``jax.default_backend()`` still
-    reports the host platform.
+    ``APEX_TPU_FORCE_MOSAIC=1`` (see :func:`forced_mosaic`) forces the
+    Mosaic path even when the default backend is CPU — used by the offline
+    AOT sweep (``tpu_aot.py``), which lowers kernels against a described TPU
+    *topology* (``jax.experimental.topologies``) where
+    ``jax.default_backend()`` still reports the host platform.
     """
     if os.environ.get("APEX_TPU_FORCE_INTERPRET") == "1":
         return True
     if os.environ.get("APEX_TPU_FORCE_MOSAIC") == "1":
         return False
     return _backend() != "tpu"
+
+
+@contextlib.contextmanager
+def forced_mosaic():
+    """Stage the Mosaic kernel path although the default backend is the
+    CPU, for the duration only (the lint/cost tracers and the AOT compiles
+    for a described topology); restores the environment on exit.
+
+    Exit also clears jax's trace caches: tracing through module-level jit
+    wrappers bakes ``interpret=False`` into their cached jaxprs, and code
+    EXECUTING the same op at the same shapes afterwards in this process
+    would reuse the poisoned trace and fail on the CPU. Dropping the caches
+    costs a re-trace, never correctness."""
+    keys = ("APEX_TPU_FORCE_MOSAIC", "APEX_TPU_FORCE_INTERPRET")
+    old = {k: os.environ.get(k) for k in keys}
+    os.environ["APEX_TPU_FORCE_MOSAIC"] = "1"
+    os.environ.pop("APEX_TPU_FORCE_INTERPRET", None)
+    try:
+        yield
+    finally:
+        for k, v in old.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+        jax.clear_caches()
 
 
 def pallas_call(kernel, *, out_shape, **kw):
@@ -53,20 +80,17 @@ def pallas_call(kernel, *, out_shape, **kw):
     def call(*args):
         vma = frozenset()
         for a in jax.tree.leaves(args):
-            vma = vma | getattr(jax.typeof(a), "vma", frozenset())
+            vma = vma | jax.typeof(a).vma
 
         def lift(a):
             # align every input to the union vma (a replicated operand next
             # to a varying one trips "varying manual axes must match" inside
             # the kernel body)
-            missing = vma - getattr(jax.typeof(a), "vma", frozenset())
+            missing = vma - jax.typeof(a).vma
             return lax.pcast(a, tuple(missing), to="varying") if missing else a
 
         def stamp(s):
-            # empty vma: pass s through untouched (also keeps older jax,
-            # whose ShapeDtypeStruct has no vma kwarg, working — there the
-            # union is always empty)
-            if isinstance(s, jax.ShapeDtypeStruct) and vma:
+            if isinstance(s, jax.ShapeDtypeStruct):
                 return jax.ShapeDtypeStruct(s.shape, s.dtype, vma=vma)
             return s
 

@@ -61,7 +61,7 @@ def _block_table():
         try:
             with open(path) as f:
                 _BLOCK_TABLE = {k: tuple(v) for k, v in json.load(f).items()}
-        except Exception:
+        except FileNotFoundError:
             _BLOCK_TABLE = {}
     return _BLOCK_TABLE
 
@@ -79,102 +79,23 @@ def _block_sizes(sq: int, sk: int, block_q: Optional[int],
     return bq, bk
 
 
-#: the (b, h, s, d) shapes a tight-head-dim proof must have covered — the
-#: autotune candidate set (tpu_autotune.SHAPES mirrors this) plus the
-#: on-chip parity test's shape. The marker records the set it proved;
-#: changing this list (new flagship shapes) deliberately invalidates old
-#: markers.
-TIGHT_PROOF_SHAPES = ((2, 8, 512, 64), (8, 16, 512, 64),
-                      (4, 16, 1024, 64), (2, 16, 2048, 64))
-
-
-def _git_rev():
-    """HEAD revision of the checkout this module runs from, with a
-    ``-dirty`` suffix when the tree has uncommitted changes (a proof run
-    against edited-but-uncommitted kernel code must not validate for the
-    clean tree at the same HEAD, or vice versa). None when git metadata
-    is unavailable — pip installs, stripped archives."""
-    import os
-    import subprocess
-
-    cwd = os.path.dirname(os.path.abspath(__file__))
-    try:
-        out = subprocess.run(["git", "rev-parse", "HEAD"], cwd=cwd,
-                             capture_output=True, text=True, timeout=10)
-        rev = out.stdout.strip()
-        if not rev:
-            return None
-        # tracked files only: the marker itself (and round artifacts like
-        # TPU_TESTS_*.jsonl) are untracked, and counting them would flip
-        # every post-proof read to -dirty, self-invalidating the marker
-        status = subprocess.run(
-            ["git", "status", "--porcelain", "--untracked-files=no"],
-            cwd=cwd, capture_output=True, text=True, timeout=10)
-        return rev + ("-dirty" if status.stdout.strip() else "")
-    except Exception:
-        return None
-
-
-# Read ONCE at import: the value participates in traced shapes, and jit
-# caches are not keyed on env vars — a mid-process flip would silently keep
-# serving the previously-compiled layout. Set the env before importing
-# apex_tpu (tests monkeypatch this constant + jax.clear_caches()).
-#
-# Default resolution (r5 pre-staged flip): env var wins when set; otherwise
-# the layout turns ON once on-chip proof exists — ``_flash_tight_ok.json``,
-# written by run_tpu_round.sh only after the on-chip parity test
-# (test_flash_attention_tight_head_dim) passed AND the autotuner timed the
-# tight layout faster than the 128-padded default on the real chip. The
-# compile half of the gate is already discharged offline (AOT_r05.json:
-# flash_tight_headdim_* compile to tpu_custom_call on the v5e topology).
-#
-# Staleness guard (ADVICE r5): the marker is keyed to the git revision and
-# the shape set it proved — a marker written at another revision (stale
-# proof surviving a flash-kernel change, or a fresh clone carrying someone
-# else's artifact) or for a different TIGHT_PROOF_SHAPES is IGNORED.
-def _tight_default() -> bool:
-    import json
-    import os
-
-    env = os.environ.get("APEX_TPU_FLASH_TIGHT_HEADDIM")
-    if env is not None:
-        return env == "1"
-    try:
-        with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                               "_flash_tight_ok.json")) as f:
-            marker = json.load(f)
-        if not marker.get("ok"):
-            return False
-        rev = _git_rev()
-        if rev is None or marker.get("rev") != rev:
-            return False
-        # a proof from a dirty tree names no reproducible code state —
-        # dirtiness is binary, so "same dirty rev" doesn't mean same
-        # kernel; only clean-tree proofs count
-        if rev.endswith("-dirty"):
-            return False
-        if marker.get("shapes") != [list(s) for s in TIGHT_PROOF_SHAPES]:
-            return False
-        return True
-    except Exception:
-        return False
-
-
-_TIGHT_HEADDIM = _tight_default()
+#: keep a sublane-aligned head dim (64 for BERT/GPT-2 heads) unpadded
+#: instead of rounding it up to 128 lanes. It participates in traced shapes
+#: and jit caches are not keyed on it, so it is a constant, not a switch:
+#: the tests and tpu_autotune.py that try the other layout set it and call
+#: ``jax.clear_caches()``. Which layout is faster on the chip is not
+#: measured yet.
+_TIGHT_HEADDIM = False
 
 
 def _head_pad(d: int) -> int:
     """Padded head-dim for the kernel blocks.
 
     Default: round up to a 128-lane multiple — always legal. With
-    ``APEX_TPU_FLASH_TIGHT_HEADDIM=1`` (read at import, see
-    ``_TIGHT_HEADDIM``) a sublane-aligned d (64 for BERT/GPT-2 heads) is
-    kept as-is: the block's minor dim then equals the full array dim,
-    which Mosaic's (8, 128)-or-full-dim rule permits, and the QK^T/PV
-    contractions stop wasting half their MXU work on zero padding. Gated
-    off by default until the on-chip suite
-    (tests/test_real_tpu_kernels.py::test_flash_attention_tight_head_dim)
-    has proven the layout compiles on the target chip generation.
+    ``_TIGHT_HEADDIM`` a sublane-aligned d is kept as-is: the block's minor
+    dim then equals the full array dim, which Mosaic's (8, 128)-or-full-dim
+    rule permits, and the QK^T/PV contractions stop spending half their MXU
+    work on zero padding.
     """
     if d % 128 == 0:
         return d

@@ -83,7 +83,7 @@ def flatten(tree, spec: FlatSpec, dtype=jnp.float32) -> jax.Array:
     bitcast under TPU tiled layouts, and with an odd ``total_rows`` the
     backend lowers it through a relayout whose intermediate allocates
     ~64x the buffer (observed on-chip: an f32[N/2, 2] relayout tile-padded
-    2->128 lanes = 86 GB for BERT-Large; TPU_TESTS_r03.log). Row-space
+    2->128 lanes = 86 GB for BERT-Large, round 3). Row-space
     concat keeps every reshape leaf-local.
     """
     leaves = jax.tree.leaves(tree)

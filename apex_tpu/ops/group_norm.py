@@ -67,7 +67,7 @@ def _gn_fwd_kernel(x_ref, w_ref, b_ref, y_ref, mean_ref, rstd_ref,
     y_ref[0] = y.astype(y_ref.dtype)
     # stats ride in ONE whole-array SMEM block (Mosaic rejects (1, 1)
     # grid-blocked outputs: block dims must be (8, 128)-divisible or equal
-    # the array's — TPU_TESTS_r03.log); each step writes its own row
+    # the array's — seen on the chip in round 3); each step writes its own row
     i = pl.program_id(0)
     mean_ref[i, 0] = mean
     rstd_ref[i, 0] = rstd

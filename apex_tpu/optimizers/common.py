@@ -23,6 +23,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from apex_tpu.ops import flat_buffer
 from apex_tpu.ops.flat_buffer import LANE, FlatSpec, build_spec
@@ -204,6 +205,16 @@ class FusedOptimizerBase:
                 params = flat_buffer.unflatten(new_master, spec, dtypes=out_dtypes)
                 return params, new_master, new_state, new_step, scaler_state
 
+            sharding = self.master.sharding
+            if (len(sharding.device_set) > 1
+                    and sharding.is_fully_replicated):
+                # replicated state on a multi-device mesh (data
+                # parallelism): Mosaic kernels cannot be partitioned
+                # automatically, so every device runs the whole update
+                # under shard_map
+                _pure = jax.shard_map(_pure, mesh=sharding.mesh,
+                                      in_specs=P(), out_specs=P(),
+                                      check_vma=False)
             self._jit_step = jax.jit(_pure, donate_argnums=(1, 2))
 
         hyper = {k: jnp.asarray(v, jnp.float32)

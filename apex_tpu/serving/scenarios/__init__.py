@@ -32,9 +32,9 @@ makes workloads DECLARATIVE, SEEDED, and REPLAYABLE:
 
 CLI: ``python -m apex_tpu.serving.scenarios --list`` /
 ``--scenario NAME [--scenario NAME ...] --json OUT --seed N [--check]``
-(also installed as ``apex-tpu-scenarios``). ``run_tpu_round.sh`` runs a
-two-scenario smoke per round, banking ``SCENARIOS_<tag>.json`` whose
-``scenario.<name>.*`` SLO fields the perf ledger band-gates.
+(also installed as ``apex-tpu-scenarios``). The ``scenario.<name>.*``
+SLO fields of a ``--json`` document are what the cost ledger band-gates
+(``obs.ledger --bench``).
 
 Docs: docs/scenarios.md (spec format, seeding contract, catalog, report
 schema, extension guide).

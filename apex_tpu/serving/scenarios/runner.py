@@ -48,8 +48,8 @@ __all__ = ["EngineSpec", "ScenarioSpec", "ScenarioResult", "MODELS",
            "trace_requests", "replay", "run_scenario"]
 
 #: scenario model registry: tiny CPU-fast configs (the scenario layer is
-#: a workload/SLO harness, not a throughput bench — run_tpu_round's
-#: on-chip numbers come from tpu_decode_bench.py at real sizes).
+#: a workload/SLO harness, not a throughput bench — on-chip numbers
+#: come from tpu_decode_bench.py at real sizes).
 #: ``gpt2-small`` exists for the bench's full-size trace materialization
 #: (vocab/position bounds); don't replay it on CPU.
 MODELS = ("gpt2-tiny", "llama-tiny", "llama-tiny-windowed",

@@ -752,7 +752,6 @@ class PagedDecodeEngine:
         def step(cache, variables, tok, done, n_left, req_keys, samp_i):
             # greedy mode never reads req_keys; the carry layout stays
             # identical across greedy/sampled so both share one step
-            # tpu-lint: disable=ir-dead-scan-carry -- (slots, 2) u32/step
             (cache, tok, done, n_left, _, samp_i), toks = lax.scan(
                 functools.partial(one_step, variables),
                 (cache, tok, done, n_left, req_keys, samp_i),

@@ -110,10 +110,7 @@ def abstract_tp_mesh(tp: int, axis_name: str = MODEL_AXIS):
     with any device count — no real mesh required)."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(((axis_name, tp),))
-    except TypeError:       # newer jax: AbstractMesh(shape, axis_names)
-        return AbstractMesh((tp,), (axis_name,))
+    return AbstractMesh((tp,), (axis_name,))
 
 
 # --------------------------------------------------------------------------

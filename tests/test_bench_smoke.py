@@ -1,10 +1,7 @@
-"""bench.py smoke: the measurement path must shard over every local device.
-
-VERDICT r2 weakness #4: throughput divided by n_chips while the step ran on
-one device. This runs bench.py as a subprocess on an 8-virtual-CPU-device
-platform with the tiny BERT config and asserts the emitted JSON proves the
-batch was split 8 ways (n_data_shards == n_chips == 8) with a nonzero
-throughput — i.e. per-chip numbers come from a genuinely sharded step.
+"""The bench scripts off the chip: bench.py must refuse a device whose peak
+it does not know (a CPU included) instead of inventing one, and
+tpu_decode_bench.py's CPU mechanics check must stamp every record with the
+device it ran on.
 """
 
 import json
@@ -19,32 +16,15 @@ pytestmark = pytest.mark.slow
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_bench_sharded_over_8_cpu_devices():
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
-    env.update({
-        "XLA_FLAGS": (env.get("XLA_FLAGS", "") +
-                      " --xla_force_host_platform_device_count=8").strip(),
-        "APEX_TPU_BENCH_PLATFORM": "cpu",
-        "APEX_TPU_BENCH_CONFIG": "tiny",
-        "APEX_TPU_BENCH_BATCH": "2",      # per chip -> global batch 16
-        "APEX_TPU_BENCH_SEQ": "64",
-        "APEX_TPU_BENCH_STEPS": "2",
-        "APEX_TPU_BENCH_RETRIES": "1",
-        "APEX_TPU_BENCH_COMPILE_RETRIES": "1",
-        "APEX_TPU_BENCH_INIT_TIMEOUT": "120",
-    })
+def test_bench_refuses_a_device_without_a_known_peak():
+    env = dict(os.environ, JAX_PLATFORMS="cpu", APEX_TPU_BENCH_STEPS="1")
     r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
                        capture_output=True, text=True, timeout=600, env=env,
                        cwd=REPO)
-    line = r.stdout.strip().splitlines()[-1]
-    rec = json.loads(line)
-    assert "error" not in rec, f"bench failed: {rec}\nstderr: {r.stderr[-2000:]}"
-    assert rec["n_chips"] == 8
-    assert rec["n_data_shards"] == 8, (
-        "batch not sharded over the device mesh — per-chip throughput would "
-        f"be fictional: {rec}")
-    assert rec["value"] > 0
+    assert r.returncode != 0
+    assert "no peak FLOP/s known for device kind" in r.stderr
+    assert not [line for line in r.stdout.splitlines()
+                if line.startswith("{")], "a record was printed on the CPU"
 
 
 def test_decode_bench_smoke_emits_json(tmp_path):
@@ -58,8 +38,7 @@ def test_decode_bench_smoke_emits_json(tmp_path):
     metrics snapshot artifact lands where APEX_TPU_METRICS_OUT points."""
     env = dict(os.environ)
     env["APEX_TPU_DECODE_SMOKE"] = "1"
-    # the tp=2 section needs >= 2 devices; don't rely on conftest's
-    # env mutation having taken the XLA_FLAGS fallback path
+    # the tp=2 section needs >= 2 devices
     if "host_platform_device_count" not in env.get("XLA_FLAGS", ""):
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                             + " --xla_force_host_platform_device_count=8"
@@ -76,6 +55,12 @@ def test_decode_bench_smoke_emits_json(tmp_path):
         if line.startswith("{"):
             rec = json.loads(line)
             recs[rec["metric"]] = rec
+
+    # every record names the device it was taken on: a CPU smoke record
+    # cannot pass for a chip measurement
+    for rec in recs.values():
+        assert rec["platform"] == "cpu" and rec["n_devices"] == 8
+        assert rec["device_kind"]
 
     rec = recs["gpt2_decode_tokens_per_sec_per_chip"]
     assert rec["value"] > 0
@@ -137,7 +122,6 @@ def test_decode_bench_smoke_emits_json(tmp_path):
     # asserted inside the bench itself — be greedy token-identical to
     # the single-chip paged engine on the same workload
     tp = recs["gpt2_tp2_paged_decode_tokens_per_sec_per_chip"]
-    assert "skipped" not in tp, tp
     assert tp["value"] > 0
     assert tp["tp_world"] == 2
     assert tp["gpt2_tp2_paged_decode_ttft_ms_p50"] > 0
@@ -225,7 +209,7 @@ def test_decode_bench_smoke_emits_json(tmp_path):
     assert cp["chunked_prefills"] >= 1
     assert cp["prefill_chunks"] > cp["chunked_prefills"]
 
-    # the run_tpu_round.sh metrics artifact: a strict-JSON registry
+    # the APEX_TPU_METRICS_OUT artifact: a strict-JSON registry
     # snapshot holding the serving histograms
     with open(snap_path) as f:
         snap = json.load(f)

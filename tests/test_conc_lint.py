@@ -10,7 +10,7 @@ Mirrors the PR 3/5 load-bearing pattern for the third tier, per ISSUE 7:
    ``with self._lock:`` fires ``conc-unguarded-shared-field``, and an
    inverted acquisition order fires ``conc-lock-order-cycle``;
 4. end-to-end — ``--conc`` over the repo itself exits 0 at HEAD: the
-   tier-1 twin of the ``run_tpu_round.sh`` conc gate.
+   tier-1 twin of the ``--conc`` CI gate.
 """
 
 import json

@@ -13,7 +13,7 @@ Mirrors the PR 7 load-bearing pattern for the fifth tier, per ISSUE 20:
    gauge, dropping one SSE frame kind from the client parsers, and
    stripping a schema pin each light exactly one rule;
 4. end-to-end — ``--contract`` over the repo itself exits 0 at HEAD:
-   the tier-1 twin of the ``run_tpu_round.sh`` contract gate.
+   the tier-1 twin of the ``--contract`` CI gate.
 """
 
 import json

@@ -11,7 +11,7 @@ Mirrors the PR 3 load-bearing pattern one layer down, per ISSUE 5:
    (host-sync through an imported helper, imported donated wrappers,
    the host-boundary pragma);
 4. end-to-end — ``--ir`` over the repo itself exits 0 at HEAD: the
-   tier-1 twin of the ``run_tpu_round.sh`` IR gate.
+   tier-1 twin of the ``--ir`` CI gate.
 """
 
 import os
@@ -100,26 +100,6 @@ def _dead_output_good():
     def f(a, b):
         return a @ b
     return CaseProgram(fn=f, args=(_sds((256, 256)), _sds((256, 256))))
-
-
-def _dead_carry_bad():
-    def f(x, vestigial):
-        def body(carry, _):
-            a, d = carry
-            return (a + 1.0, d), a.sum()
-        (_, _), ys = lax.scan(body, (x, vestigial), None, length=3)
-        return ys
-    return CaseProgram(fn=f, args=(_sds((8, 128)), _sds((4,))))
-
-
-def _dead_carry_good():
-    def f(x, offset):
-        def body(carry, _):
-            a, d = carry
-            return (a + d.sum(), d), a.sum()    # read-only state: fine
-        (_, _), ys = lax.scan(body, (x, offset), None, length=3)
-        return ys
-    return CaseProgram(fn=f, args=(_sds((8, 128)), _sds((4,))))
 
 
 def _donation_bad():
@@ -225,7 +205,6 @@ IR_FIXTURES = {
     "ir-dtype-promotion-drift": (_promotion_bad, _promotion_good),
     "ir-x64-leak": (_x64_bad, _x64_good),
     "ir-dead-output": (_dead_output_bad, _dead_output_good),
-    "ir-dead-scan-carry": (_dead_carry_bad, _dead_carry_good),
     "ir-donation-ineffective": (_donation_bad, _donation_good),
     "ir-large-const-capture": (_const_bad, _const_good),
     "ir-broadcast-blowup": (_blowup_bad, _blowup_good),

@@ -12,7 +12,7 @@ the fourth tier, per ISSUE 18:
    HBM budget makes the fit proof fail (and between the two peaks, the
    scan-carry rule — the two HBM rules are disjoint by construction);
 4. end-to-end — ``--mem`` over the repo itself exits 0 at HEAD: the
-   tier-1 twin of the ``run_tpu_round.sh`` mem gate.
+   tier-1 twin of the ``--mem`` CI gate.
 """
 
 import dataclasses
@@ -557,9 +557,8 @@ def test_mem_diff_base_rev_without_tier_is_empty(capsys):
 @pytest.mark.slow
 def test_repo_mem_is_clean_at_head(capsys):
     """The full-registry mem gate (~85 s: every case re-traced). Slow
-    tier to hold the tier-1 verify wall; run_tpu_round.sh runs the same
-    gate on every round, and test_mem_gate_case_is_clean_at_head below
-    is the fast tier-1 twin."""
+    tier to hold the tier-1 verify wall;
+    test_mem_gate_case_is_clean_at_head below is the fast tier-1 twin."""
     rc = cli.main(["--root", REPO, "--mem"])
     out = capsys.readouterr().out
     assert rc == 0, f"tpu-lint --mem found new issues in the repo:\n{out}"

@@ -1,13 +1,12 @@
-"""Perf ledger + regression gate (apex_tpu/obs/ledger.py).
+"""Cost ledger + regression gate (apex_tpu/obs/ledger.py).
 
 Unit tier (synthetic metrics, no tracing): append/load round trips,
 seeding from the driver's BENCH wrapper artifacts, and the check
 semantics — deterministic ``cost.*`` metrics gate EXACTLY, wall-time
 metrics gate direction-aware inside a band, informational counters never
-gate. Acceptance tier: the committed ``PERF_LEDGER.jsonl`` has the
-seeded history plus a HEAD entry, and ``--check`` against HEAD's
-freshly computed cost report exits 0 (a perturbed ledger exits 1) —
-run as a subprocess exactly like the ``run_tpu_round.sh`` gate.
+gate. Acceptance tier: the committed ``COST_LEDGER.jsonl`` holds HEAD's
+cost entry, and ``--check`` against HEAD's freshly computed cost report
+exits 0 (a perturbed ledger exits 1) — run as a subprocess, as CI does.
 """
 
 import json
@@ -61,10 +60,10 @@ def test_bench_metrics_from_wrapper_and_jsonl(tmp_path):
     wrapper.write_text(json.dumps({
         "n": 3, "rc": 0, "tail": "...",
         "parsed": {"metric": "bert_tokens_per_sec", "value": 123.4,
-                   "error": "tunnel down"}}))
+                   "error": "backend down"}}))
     m, meta = ledger.bench_metrics_from_file(wrapper)
     assert m == {"bert_tokens_per_sec": 123.4}
-    assert meta["errors"] == ["tunnel down"]
+    assert meta["errors"] == ["backend down"]
     # the DECODE_*.json JSONL-of-records shape
     decode = tmp_path / "DECODE_r06.json"
     decode.write_text(
@@ -169,7 +168,7 @@ def test_check_uses_most_recent_value_per_metric():
     regs = ledger.check({"cost.a": 1.0}, entries)
     assert regs and "new" in regs[0].baseline_tag
     # a bench metric keeps gating even after many cost-only rounds
-    # appended on top (the dead-tunnel cadence) — baselines are
+    # appended on top — baselines are
     # per-metric most-recent, not a fixed entry window
     entries = [_entry({"ttft_ms_p95": 50.0}, kind="bench", tag="bench")]
     entries += [_entry({"cost.a": 1.0}, tag=f"r{i}") for i in range(10)]
@@ -178,7 +177,7 @@ def test_check_uses_most_recent_value_per_metric():
 
 
 # --------------------------------------------------------------------------
-# CLI + acceptance (subprocess, like the run_tpu_round.sh gate)
+# CLI + acceptance (subprocess, like the CI gate)
 # --------------------------------------------------------------------------
 
 def _run_ledger(*args, env_extra=None):
@@ -192,13 +191,12 @@ def _run_ledger(*args, env_extra=None):
 
 
 def test_committed_ledger_has_history_and_head_entry():
-    """Acceptance: PERF_LEDGER.jsonl exists with >= 2 entries — the
-    seeded (empty-trajectory) history plus HEAD's cost entry."""
+    """Acceptance: COST_LEDGER.jsonl exists and holds HEAD's cost entry —
+    under its own name, never the driver's PERF_LEDGER.jsonl."""
+    assert ledger.LEDGER_NAME == "COST_LEDGER.jsonl"
     entries = ledger.load(os.path.join(REPO, ledger.LEDGER_NAME))
-    assert len(entries) >= 2
-    kinds = {e["kind"] for e in entries}
-    assert "seed" in kinds and "cost" in kinds
-    head = [e for e in entries if e["kind"] == "cost"][-1]
+    assert entries and {e["kind"] for e in entries} == {"cost"}
+    head = entries[-1]
     assert any(k.startswith("cost.case.") for k in head["metrics"])
     assert "cost.decode.weight_fraction" in head["metrics"]
 
@@ -232,11 +230,11 @@ def test_cli_check_exit_codes_synthetic(tmp_path, capsys):
 def test_check_clean_at_head_and_perturbed_trips(tmp_path):
     """Acceptance: a clean --check at HEAD exits 0; a seeded regression
     (perturbed last entry) exits nonzero. Runs the real CLI so the
-    gate's environment is exactly what run_tpu_round.sh executes.
+    gate's environment is exactly what CI executes.
 
     If this fails after an intentional kernel/model change, the cost
     metrics moved: run  python -m apex_tpu.obs.ledger --append --tag
-    <tag>  and commit the updated PERF_LEDGER.jsonl (the perf delta
+    <tag>  and commit the updated COST_LEDGER.jsonl (the cost delta
     then shows up as a reviewable line in the PR)."""
     costs_json = tmp_path / "costs.json"
     r = subprocess.run(

@@ -8,8 +8,7 @@ compiles every Pallas kernel at the flagship benchmark's shapes
 (seq 512, hidden 1024, vocab 30528, BERT-Large-sized flat buffers),
 asserting parity against pure-jnp references computed on the same chip.
 
-    APEX_TPU_REAL=1 python -m pytest tests/test_real_tpu_kernels.py -v \
-        2>&1 | tee TPU_TESTS_r02.log
+    APEX_TPU_REAL=1 python -m pytest tests/test_real_tpu_kernels.py -v
 """
 
 import functools

@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from apex_tpu.ops import flash_attention, flash_attention_with_lse, ring_attention
@@ -27,10 +26,10 @@ def cp_mesh(cp):
 def ring_sharded(q, k, v, cp, causal):
     mesh = cp_mesh(cp)
     spec = P(None, None, "context", None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name="context", causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     return fn(q, k, v)
 
 
@@ -119,10 +118,10 @@ def zigzag_sharded(q, k, v, cp, **kw):
 
     mesh = cp_mesh(cp)
     spec = P(None, None, "context", None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention_zigzag, axis_name="context", **kw),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     return fn(q, k, v)
 
 
@@ -331,11 +330,11 @@ def test_ring_dropout_matches_single_device(rng):
                           dropout_seed=11)
     mesh = cp_mesh(cp)
     spec = P(None, None, "context", None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name="context", causal=True,
                           dropout_rate=0.3, dropout_seed=11),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     out = fn(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
@@ -387,11 +386,11 @@ def test_ring_sliding_window_matches_single_device(rng, cp, window):
 
     mesh = cp_mesh(cp)
     spec = P(None, None, "context", None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name="context", causal=True,
                           window=window),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     out = fn(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
@@ -408,11 +407,11 @@ def test_ring_sliding_window_grads_match(rng):
 
     mesh = cp_mesh(cp)
     spec = P(None, None, "context", None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name="context", causal=True,
                           window=window),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
 
     gr = jax.grad(lambda q, k, v: jnp.sum(
         flash_attention(q, k, v, causal=True, window=window) ** 2),

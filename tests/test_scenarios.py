@@ -653,8 +653,7 @@ def test_cli_json_document_and_ledger_extraction(tmp_path):
 def test_cli_http_flag_drives_the_wire(tmp_path):
     """--http forces EngineSpec(http=True) on any catalog entry: the
     replay goes over real localhost SSE and the banked document grows
-    the pinned http block — the flag CI's HTTP smoke
-    (run_tpu_round.sh, HTTP_<tag>.json) is built on."""
+    the pinned http block — the flag CI's HTTP smoke is built on."""
     from apex_tpu.serving.scenarios.__main__ import main
 
     out = tmp_path / "http.json"

@@ -9,7 +9,7 @@ Three layers, matching ISSUE 3's acceptance criteria:
 2. machinery — inline suppressions, the baseline workflow, the JSON
    format, exit codes, the AOT case-drift project rule.
 3. end-to-end — the repo itself is clean at the current baseline: the
-   tier-1 twin of the ``run_tpu_round.sh`` fail-fast gate.
+   tier-1 twin of the CI fail-fast gate.
 """
 
 import json
@@ -414,7 +414,7 @@ def test_aot_case_drift_clean_when_in_sync(tmp_path):
 
 
 # --------------------------------------------------------------------------
-# end-to-end: the repo itself is clean (the run_tpu_round.sh gate, tier-1)
+# end-to-end: the repo itself is clean (the CI gate, tier-1)
 # --------------------------------------------------------------------------
 
 def test_repo_is_clean_at_current_baseline(capsys):
