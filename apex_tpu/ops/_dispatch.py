@@ -63,8 +63,27 @@ def forced_mosaic():
         jax.clear_caches()
 
 
-def pallas_call(kernel, *, out_shape, **kw):
-    """``pl.pallas_call`` that propagates varying-manual-axes (vma).
+#: every kernel this package launches, by the label its ``pallas_call``
+#: carries into the compiled program: ``metadata={"kernel": <label>}``
+#: lands in the custom call's own HLO text as
+#: ``kernel_metadata={"kernel":"<label>"}``, which is the op's name on the
+#: device trace's ``XLA Ops`` line (docs/observability.md "Kernel
+#: labels"). ``name=`` is NOT used: it enters the name stack and would
+#: rename the op (``%attention.N`` -> ``%flash_fwd.N``) under readers that
+#: find kernels by their flax module. A closed set — a new kernel adds its
+#: label here first.
+KERNEL_LABELS = (
+    "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_attention",
+    "layer_norm_fwd", "layer_norm_bwd", "xentropy_fwd", "xentropy_bwd",
+    "l2norm", "lamb_phase1", "lamb_phase2", "adam", "sgd", "novograd",
+    "scale", "group_norm_fwd", "group_norm_bwd", "scaled_softmax_fwd",
+    "scaled_softmax_bwd", "dequant_matmul")
+
+
+def pallas_call(fn, *, kernel: str, out_shape, **kw):
+    """``pl.pallas_call`` of the kernel body ``fn`` that names the kernel
+    (``kernel``, one of :data:`KERNEL_LABELS`; an unknown label raises
+    here, at trace time) and propagates varying-manual-axes (vma).
 
     Inside ``shard_map(check_vma=True)`` a pallas_call must declare how its
     outputs vary over mesh axes; the correct answer for our elementwise/
@@ -76,6 +95,11 @@ def pallas_call(kernel, *, out_shape, **kw):
     from jax.experimental import pallas as pl
 
     from jax import lax
+
+    if kernel not in KERNEL_LABELS:
+        raise ValueError(
+            f"pallas_call: unknown kernel label {kernel!r}; add it to "
+            f"apex_tpu.ops._dispatch.KERNEL_LABELS (has {KERNEL_LABELS})")
 
     def call(*args):
         vma = frozenset()
@@ -96,7 +120,8 @@ def pallas_call(kernel, *, out_shape, **kw):
 
         args = jax.tree.map(lift, args)
         os_ = jax.tree.map(stamp, out_shape)
-        return pl.pallas_call(kernel, out_shape=os_, **kw)(*args)
+        return pl.pallas_call(fn, out_shape=os_,
+                              metadata={"kernel": kernel}, **kw)(*args)
 
     return call
 

@@ -415,6 +415,7 @@ def _fa_fwd(q, k, v, bias, q_seg, kv_seg, seed, scale, causal, dropout_rate,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
+        kernel="flash_fwd",
         interpret=_INTERPRET(),
     )(*args)
     return o[:, :, :q_len, :d], lse[:, :, :q_len, 0]
@@ -686,6 +687,7 @@ def _fa_bwd_impl(q, k, v, bias, q_seg, kv_seg, seed, scale, causal,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
+        kernel="flash_bwd_dq",
         interpret=_INTERPRET(),
     )(*base_args)[0]
 
@@ -721,6 +723,7 @@ def _fa_bwd_impl(q, k, v, bias, q_seg, kv_seg, seed, scale, causal,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
+        kernel="flash_bwd_dkv",
         interpret=_INTERPRET(),
     )(*base_args)
 

@@ -139,6 +139,7 @@ def _gn_fwd(x, weight, bias, num_groups, eps, act):
             jax.ShapeDtypeStruct((n * g, 1), jnp.float32),
             jax.ShapeDtypeStruct((n * g, 1), jnp.float32),
         ],
+        kernel="group_norm_fwd",
         interpret=_INTERPRET(),
     )(x_slab, w_slab, b_slab)
     y = y_slab.reshape(n, g, hw, cg).transpose(0, 2, 1, 3).reshape(n, h, w_, c)
@@ -238,6 +239,7 @@ def _gn_bwd(num_groups, eps, act, res, dy):
             jax.ShapeDtypeStruct((n * g, 1, cg), jnp.float32),
             jax.ShapeDtypeStruct((n * g, 1, cg), jnp.float32),
         ],
+        kernel="group_norm_bwd",
         interpret=_INTERPRET(),
     )(x_slab, dy_slab, w_slab, b_slab,
       mean.reshape(n * g, 1), rstd.reshape(n * g, 1))

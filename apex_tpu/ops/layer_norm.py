@@ -122,6 +122,7 @@ def _ln_fwd(x2d, weight, bias, eps, rms):
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
+        kernel="layer_norm_fwd",
         interpret=_INTERPRET(),
     )(*args)
     return y, mean, rstd
@@ -244,6 +245,7 @@ def _ln_bwd(dy2d, saved, weight, bias, eps, rms, memory_efficient):
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        kernel="layer_norm_bwd",
         interpret=_INTERPRET(),
     )(*args)
     dx = outs[0]

@@ -142,6 +142,7 @@ def segment_stats(a, seg_rows, num_segments: int, b: Optional[jax.Array] = None)
         in_specs=in_specs,
         out_specs=pl.BlockSpec((_STAT_ROWS, s_pad), lambda i: (0, 0), memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((_STAT_ROWS, s_pad), jnp.float32),
+        kernel="l2norm",
         interpret=_INTERPRET(),
     )(*args)
 
@@ -258,6 +259,7 @@ def adam_update(g, p, m, v, *, beta1, beta2, eps, weight_decay, lr, step,
         out_specs=[_buf_spec(blk)] * 3,
         out_shape=[jax.ShapeDtypeStruct(p.shape, jnp.float32)] * 3,  # tpu-lint: disable=pallas-dtype-drift -- fp32 master params/state by contract
         input_output_aliases=aliases,
+        kernel="adam",
         interpret=_INTERPRET(),
     )(*args)
 
@@ -316,6 +318,7 @@ def sgd_update(g, p, m, *, lr, momentum=0.0, dampening=0.0, weight_decay=0.0,
         out_specs=[_buf_spec(blk)] * 2,
         out_shape=[jax.ShapeDtypeStruct(p.shape, jnp.float32)] * 2,  # tpu-lint: disable=pallas-dtype-drift -- fp32 master params/momentum by contract
         input_output_aliases={2: 0, 3: 1},
+        kernel="sgd",
         interpret=_INTERPRET(),
     )(hp, g, p, m)
 
@@ -447,6 +450,7 @@ def lamb_update(g, p, m, v, seg_rows, num_segments, *, beta1, beta2, eps,
         out_shape=[jax.ShapeDtypeStruct(p.shape, jnp.float32)] * 3  # tpu-lint: disable=pallas-dtype-drift -- fp32 master params/state by contract
         + [jax.ShapeDtypeStruct((_STAT_ROWS, s_pad), jnp.float32)],
         input_output_aliases={3: 1, 4: 2},
+        kernel="lamb_phase1",
         interpret=_INTERPRET(),
     )(hp1, g, p, m, v, seg2d, wd_mat)
 
@@ -474,6 +478,7 @@ def lamb_update(g, p, m, v, seg_rows, num_segments, *, beta1, beta2, eps,
         out_specs=_buf_spec(blk),
         out_shape=jax.ShapeDtypeStruct(p.shape, jnp.float32),  # tpu-lint: disable=pallas-dtype-drift -- fp32 master params by contract
         input_output_aliases={2: 0},
+        kernel="lamb_phase2",
         interpret=_INTERPRET(),
     )(hp2, u, p, ratio_mat, seg2d)
     return p_new, m, v
@@ -548,6 +553,7 @@ def novograd_update(g, p, m, v_per_tensor, seg_rows, num_segments, *, beta1, bet
         out_specs=[_buf_spec(blk)] * 2,
         out_shape=[jax.ShapeDtypeStruct(p.shape, jnp.float32)] * 2,  # tpu-lint: disable=pallas-dtype-drift -- fp32 master params/momentum by contract
         input_output_aliases={2: 0, 3: 1},
+        kernel="novograd",
         interpret=_INTERPRET(),
     )(hp, g, p, m, vden_mat, seg_rows.reshape(-1, 1))
     v_out = jnp.where(noop_s > 0.0, v_per_tensor, v_new)
@@ -573,5 +579,6 @@ def multi_tensor_scale(x, scale):
         in_specs=[_smem_spec(1), _buf_spec(blk)],
         out_specs=_buf_spec(blk),
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),  # tpu-lint: disable=pallas-dtype-drift -- amp unscale emits fp32 master grads
+        kernel="scale",
         interpret=_INTERPRET(),
     )(hp, x)

@@ -321,6 +321,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        kernel="paged_attention",
         interpret=_INTERPRET(),
     )(*operands)
     return (out.reshape(b, kv, s_q, rep, d).transpose(0, 1, 3, 2, 4)

@@ -370,6 +370,7 @@ def fused_dequant_matmul(x, qw, scale):
         in_specs=[pl.BlockSpec((m_pad, n_in), lambda j: (0, 0)),
                   w_spec, s_spec],
         out_specs=pl.BlockSpec((m_pad, bo), lambda j: (0, j)),
+        kernel="dequant_matmul",
         interpret=interpret(),
     )(x2, qw, s2.astype(jnp.float32))
     return y[:m].reshape(*lead, out).astype(x.dtype)

@@ -101,6 +101,7 @@ def _softmax_fwd(x, mask, scale, causal):
                                lambda b, h, i: (b, h, i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(xp.shape, x.dtype),
+        kernel="scaled_softmax_fwd",
         interpret=_INTERPRET(),
     )(*args)
     return y[:, :, :sq, :sk]
@@ -122,6 +123,7 @@ def _softmax_bwd_impl(y, dy, scale):
         in_specs=[spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(yp.shape, dy.dtype),
+        kernel="scaled_softmax_bwd",
         interpret=_INTERPRET(),
     )(yp, dyp)
     return dx[:, :, :sq, :sk]

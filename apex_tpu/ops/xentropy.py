@@ -102,6 +102,7 @@ def _xent_fwd_call(logits2d, labels, smoothing, padding_idx):
             jax.ShapeDtypeStruct((r_pad, 1), jnp.float32),
             jax.ShapeDtypeStruct((r_pad, 1), jnp.float32),
         ],
+        kernel="xentropy_fwd",
         interpret=_INTERPRET(),
     )(xp, lp)
     return loss[:rows, 0], lse[:rows, 0]
@@ -130,6 +131,7 @@ def _xent_bwd_call(logits2d, labels, lse, dy, smoothing, padding_idx):
         in_specs=[x_spec, s_spec, s_spec, s_spec],
         out_specs=x_spec,
         out_shape=jax.ShapeDtypeStruct(xp.shape, logits2d.dtype),
+        kernel="xentropy_bwd",
         interpret=_INTERPRET(),
     )(xp, lp, lsep, dyp)
     return dx[:rows, :vocab]

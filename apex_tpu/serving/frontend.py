@@ -59,6 +59,7 @@ replicated values, and nothing in this module knows the chip count
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import queue as _queue
 import threading
@@ -119,12 +120,19 @@ class ServingError(RuntimeError):
 #: cumulative distributions in the engine-labeled histograms):
 #: ``dispatch_ready_ms`` = device wall time of one decode chunk from
 #: dispatch to the host observing its tokens, ``host_work_ms`` = the
-#: host side of one pump iteration NET of time blocked on the device,
-#: ``bubble_ms`` = device idle between a chunk completing and the next
-#: dispatch — the direct measurement of whether the double-buffered
-#: host work is actually hidden (docs/frontend.md)
+#: host side of one pump iteration NET of time blocked on the device
+#: (every ``pump:wait_device`` phase: the chunk harvest AND an
+#: admission's first-token sync), ``bubble_ms`` = device idle between a
+#: chunk completing and the next dispatch — the direct measurement of
+#: whether the double-buffered host work is actually hidden
+#: (docs/frontend.md)
 _PUMP_SERIES = ("pump.dispatch_ready_ms", "pump.host_work_ms",
                 "pump.bubble_ms")
+
+#: pump phases whose host seconds feed a ``serving.*`` counter on exit
+#: (every phase is a ``pump:<name>`` span in an xprof capture)
+_PHASE_COUNTERS = {"admission": "pump_admission_seconds",
+                   "wait_device": "pump_blocked_seconds"}
 
 
 class StreamHandle:
@@ -292,7 +300,7 @@ class _Entry:
                  "seg_tokens", "nodes", "n_private", "joined",
                  "first_token_seen", "tpot_slo", "deadline_missed",
                  "win_dropped", "prefilling", "pf_pos", "pf_key",
-                 "pf_samp0")
+                 "pf_samp0", "t_enqueue", "t_admit")
 
     def __init__(self, idx, handle, prompt, total_new, priority,
                  deadline_at, arrival, seq):
@@ -319,6 +327,8 @@ class _Entry:
         self.pf_pos = 0                  # prompt tokens fed so far
         self.pf_key = None               # req_key held until decode joins
         self.pf_samp0 = 0
+        self.t_enqueue = 0.0             # the tracer's enqueue instant
+        self.t_admit: Optional[float] = None     # its FIRST admit instant
 
     @property
     def s0(self) -> int:
@@ -429,6 +439,12 @@ class ServingFrontend:
         self._bubble = metrics.gauge("pump.bubble_ms", labels=labels)
         self._last_ready: Optional[float] = None
         self._wait_s = 0.0
+        # bytes of K and V one context token costs across all layers and
+        # chips, as the pool holds them (feeds serving.kv_bytes_attended)
+        self._kv_token_bytes = (
+            kv_pool.page_bytes(engine.cfg, engine.page_size,
+                               kv_dtype=engine.kv_dtype)
+            * int(getattr(engine, "tp_world", 1)) / engine.page_size)
         # TPOT-SLO burn rate: (time, missed) per SLO-carrying retirement
         # inside the policy's rolling window (pump-confined state)
         self._slo_window: deque = deque()
@@ -489,12 +505,11 @@ class ServingFrontend:
         # spans on it
         trace_id = request.trace_id if request.trace_id is not None \
             else fleet.mint_trace_id()
-        self.tracer.event(idx, "enqueue",
-                          prompt_tokens=int(prompt.shape[0]),
-                          max_new_tokens=request.max_new_tokens,
-                          priority=request.priority,
-                          deadline_ms=request.deadline_ms,
-                          trace_id=trace_id)
+        entry.t_enqueue = self.tracer.event(
+            idx, "enqueue", prompt_tokens=int(prompt.shape[0]),
+            max_new_tokens=request.max_new_tokens,
+            priority=request.priority, deadline_ms=request.deadline_ms,
+            trace_id=trace_id).t_start
         with self._ingest_lock:
             # re-check under the lock: a pump failure drains the ingest
             # queue under this lock, so an entry either lands before the
@@ -627,30 +642,39 @@ class ServingFrontend:
             # already completed: either nothing was in flight (the last
             # chunk's completion time is in _last_ready), or the chunk
             # still nominally in flight was materialized early by an
-            # admission's pool read. The gap from that completion to
-            # this dispatch is the pipeline bubble the double-buffering
-            # exists to hide — pay attention when it grows.
-            idle_since = prev.t_done if prev is not None \
-                else self._last_ready
-            self._dispatch()
+            # admission's pool read — and then the device went on to run
+            # that admission's prefill, so it is idle from the
+            # first-token sync (_last_ready moves there), not from the
+            # chunk's end. The gap from that instant to this dispatch is
+            # the pipeline bubble the double-buffering exists to hide —
+            # pay attention when it grows.
+            idle_since = self._last_ready \
+                if prev is None or prev.t_done is not None else None
+            with self._phase("dispatch"):
+                self._dispatch()
             if idle_since is not None:
                 bubble_ms = max(0.0,
                                 (self._inflight.t0 - idle_since) * 1e3)
                 self._bubble.set(bubble_ms)
                 self._per_run["pump.bubble_ms"].append(bubble_ms)
+                self._C["pump_bubble_seconds"].inc(bubble_ms * 1e-3)
                 self._last_ready = None
         if eng.host_tier is not None:
             # demote copies dispatched at earlier boundaries ride the
             # double-buffered host-work slot: the next chunk is already
             # in flight above, so converting the gathered tiles to host
             # entries here overlaps the device, not the pipeline
-            eng.host_tier.drain()
+            with self._phase("housekeeping"):
+                eng.host_tier.drain()
         if prev is not None:
-            self._harvest(prev)
-        self._backpressure_spill()
-        self._drop_window_pages()
-        self._advance_prefills()
-        admitted = self._admission()
+            with self._phase("harvest"):
+                self._harvest(prev)
+        with self._phase("housekeeping"):
+            self._backpressure_spill()
+            self._drop_window_pages()
+        with self._phase("admission"):
+            self._advance_prefills()
+            admitted = self._admission()
         if (any(not self._bp_held(e) for e in self._pending)
                 and not self._active and self._inflight is None
                 and not admitted):
@@ -659,17 +683,21 @@ class ServingFrontend:
                 "even with every slot vacant and every evictable cached "
                 "page evicted (pool too small for its page demand?)")
         if self._pool_dirty:
-            kv_pool.observe_pool(eng.cache, labels=eng.obs_labels)
+            with self._phase("housekeeping"):
+                kv_pool.observe_pool(eng.cache, labels=eng.obs_labels)
             self._pool_dirty = False
         self._qdepth.set(len(self._pending))
         if prev is not None or admitted:
             # host cost of this iteration net of time blocked on the
-            # device — with the chunk in flight, this is the work the
-            # pipeline hides (bubble_ms above is what leaked through)
+            # device (_wait_s: every pump:wait_device phase) — with the
+            # chunk in flight, this is the work the pipeline hides
+            # (bubble_ms above is what leaked through)
             host_ms = max(0.0, (self.clock() - t_iter0 - self._wait_s)
                           * 1e3)
             self._host_H.observe(host_ms)
             self._per_run["pump.host_work_ms"].append(host_ms)
+            self._C["pump_iterations"].inc()
+            self._C["pump_host_seconds"].inc(host_ms * 1e-3)
         self._check_compile_storm()
         # a pending entry held by backpressure does not count as live
         # work: the pump has nothing to do for it until its consumer
@@ -681,6 +709,43 @@ class ServingFrontend:
         if not alive:
             self._last_ready = None      # idle gaps are not bubbles
         return alive
+
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """One pump phase: a ``pump:<name>`` host span on the profiler's
+        clock (beside the device line in an xprof capture), whose host
+        seconds feed ``_PHASE_COUNTERS[name]`` on exit. Phases are flat
+        except ``wait_device`` — every host block on a device value —
+        which nests inside the others: it sums into ``_wait_s``, and an
+        enclosing phase's seconds are NET of it."""
+        counter = _PHASE_COUNTERS.get(name)
+        with jax.profiler.TraceAnnotation("pump:" + name):
+            t0, waited0 = self.clock(), self._wait_s
+            try:
+                yield
+            finally:
+                seconds = self.clock() - t0
+                if name == "wait_device":
+                    self._wait_s += seconds
+                else:
+                    seconds -= self._wait_s - waited0
+                if counter is not None:
+                    self._C[counter].inc(max(0.0, seconds))
+
+    def _await(self, value) -> np.ndarray:
+        """Block for a device value, as a ``pump:wait_device`` phase."""
+        with self._phase("wait_device"):
+            return np.asarray(value)
+
+    def _await_first_token(self, tok) -> int:
+        """Block for an admission's sampled token. The chunk in flight
+        was stamped before the admission program was queued, so what is
+        left to wait for is the prefill — the last thing on the stream:
+        the device idles from here (``_last_ready``), not from the
+        chunk's end."""
+        tok0 = int(self._await(tok))
+        self._last_ready = self.clock()
+        return tok0
 
     # tpu-lint: host-boundary -- synchronous drive of the pump loop
     def drain(self) -> None:
@@ -838,10 +903,13 @@ class ServingFrontend:
     def _dispatch(self) -> None:
         eng = self.engine
         self._chunk += 1
-        busy = sum(1 for e in self._active.values()
-                   if e.joined <= self._chunk)
-        self._C["busy_slot_steps"].inc(busy * eng.sync_every)
+        decoding = [e for e in self._active.values()
+                    if e.joined <= self._chunk]
+        self._C["busy_slot_steps"].inc(len(decoding) * eng.sync_every)
         self._C["decode_steps"].inc(eng.sync_every)
+        self._C["kv_bytes_attended"].inc(
+            sum(self._tokens_attended(e) for e in decoding)
+            * self._kv_token_bytes)
         t0 = self.clock()
         if eng.draft_len:
             # speculative chunk: the payload is (target predictions,
@@ -861,6 +929,23 @@ class ServingFrontend:
         self.peak_slots = max(self.peak_slots, len(self._active))
         self._occ.set(len(self._active))
 
+    def _tokens_attended(self, entry: _Entry) -> int:
+        """Context tokens the chunk being dispatched attends for one
+        decoding slot, summed over its LIVE steps: step ``j`` of a slot
+        that has run ``ran`` steps since it joined reads the K/V of
+        ``s0 + ran + j + 1`` tokens (its own included), banded to the
+        window; steps past the token budget are frozen and read nothing
+        the algorithm needs. What the algorithm must read — the kernel's
+        grid may touch more. (A speculative chunk is counted one verify
+        round per step at the least context the round can have.)"""
+        eng = self.engine
+        ran = (self._chunk - entry.joined) * eng.sync_every
+        live = min(eng.sync_every, max(entry.seg_new - 1 - ran, 0))
+        first = entry.s0 + ran + 1
+        if eng.window is not None:
+            return sum(min(eng.window, first + j) for j in range(live))
+        return live * first + live * (live - 1) // 2
+
     def _materialize(self, chunk: _Chunk) -> np.ndarray:
         """Block for the chunk's tokens (overlapping whatever device
         work was queued after it) and stamp its completion time, once —
@@ -868,13 +953,12 @@ class ServingFrontend:
         done (harvest, or an admission's pool read) fixes the
         measurement before unrelated host work can inflate it."""
         if chunk.toks_np is None:
-            t_enter = self.clock()
-            chunk.toks_np = (tuple(np.asarray(t) for t in chunk.toks)
-                             if isinstance(chunk.toks, tuple)
-                             else np.asarray(chunk.toks))
-            chunk.t_done = self.clock()
             # the blocked span counts as device wait, not host work
-            self._wait_s += chunk.t_done - t_enter
+            with self._phase("wait_device"):
+                chunk.toks_np = (tuple(np.asarray(t) for t in chunk.toks)
+                                 if isinstance(chunk.toks, tuple)
+                                 else np.asarray(chunk.toks))
+                chunk.t_done = self.clock()
             self._last_ready = chunk.t_done
         return chunk.toks_np
 
@@ -1005,7 +1089,9 @@ class ServingFrontend:
             seq = np.concatenate(
                 [entry.prompt, np.asarray(entry.seg_tokens[:-1],
                                           np.int32)])
-        row = np.asarray(eng.cache["block_tables"][slot])
+        # the read waits for whatever produces ``eng.cache`` — with a chunk
+        # in flight, for that whole chunk
+        row = self._await(eng.cache["block_tables"][slot])
         keep = eng.prefix.release_and_insert(seq, written, entry.nodes, row)
         eng.cache = eng._release_jit(eng.cache, jnp.int32(slot),
                                      jnp.asarray(keep))
@@ -1230,7 +1316,7 @@ class ServingFrontend:
         # chunk first (same discipline as the admission's free read)
         if self._inflight is not None:
             self._materialize(self._inflight)
-        free = int(kv_pool.free_page_count(eng.cache))
+        free = int(self._await(kv_pool.free_page_count(eng.cache)))
         need_after = kv_pool.pages_for(s0 + entry.seg_new, ps) - target
         if free < h + need_after:
             # the tier swap: in a thrashing pool the stack is never
@@ -1266,7 +1352,7 @@ class ServingFrontend:
         # destinations: the top h free-stack entries, host-read in the
         # same pop order alloc_slot uses — promote_pages decrements
         # free_top by exactly these pages
-        stack = np.asarray(eng.cache["free_stack"])
+        stack = self._await(eng.cache["free_stack"])
         page_ids = stack[free - h:free][::-1].astype(np.int32)
         C = kv_pool.HOST_COPY_CHUNK
         t0 = self.clock()
@@ -1279,7 +1365,7 @@ class ServingFrontend:
                 _stack_tiles(payloads[i:i + n_g], C))
         # block on the promoted pool's scalar: the measured span is the
         # host->device copy the admission program would wait on anyway
-        np.asarray(eng.cache["free_top"])
+        self._await(eng.cache["free_top"])
         tier.observe_promote_ms((self.clock() - t0) * 1e3)
         for i in range(h):
             nodes.append(eng.prefix.insert_promoted(nodes, keys[i],
@@ -1326,7 +1412,7 @@ class ServingFrontend:
         # decode_step_ms never charges admission work to the chunk
         if self._inflight is not None:
             self._materialize(self._inflight)
-        free = int(kv_pool.free_page_count(eng.cache))
+        free = int(self._await(kv_pool.free_page_count(eng.cache)))
         if free < need and eng.prefix is not None:
             victims: List[tuple] = []
             sink = ((lambda path, page: victims.append((path, page)))
@@ -1349,7 +1435,7 @@ class ServingFrontend:
             eng._defrag_now()
             self._C["defrag_runs"].inc()
             eng.events.emit("defrag", request=idx)
-            free = int(kv_pool.free_page_count(eng.cache))
+            free = int(self._await(kv_pool.free_page_count(eng.cache)))
         if free < need:
             if nodes:
                 eng.prefix.release(nodes)
@@ -1364,7 +1450,13 @@ class ServingFrontend:
             self._C["resumes"].inc()
             eng.events.emit("resume", request=idx, slot=slot,
                             cached_pages=m)
-        tr.event(idx, "admit", slot=slot, free_pages=free, cached_pages=m)
+        t_admit = tr.event(idx, "admit", slot=slot, free_pages=free,
+                           cached_pages=m).t_start
+        if entry.t_admit is None:
+            # first admission only, as lifecycle() anchors queue_wait_ms
+            entry.t_admit = t_admit
+            self._C["queue_wait_seconds"].inc(
+                max(0.0, t_admit - entry.t_enqueue))
         req_key = jax.random.fold_in(eng.rng, idx)
         samp0 = len(entry.prev)          # resume continues the key stream
         # chunked prefill (docs/frontend.md): instead of one monolithic
@@ -1443,18 +1535,8 @@ class ServingFrontend:
                     eng.cache, eng.variables, jnp.asarray(ids),
                     jnp.int32(s0), jnp.int32(slot), jnp.asarray(row),
                     jnp.int32(need), req_key, jnp.int32(samp0))
-            tok0 = int(tok0)
-        if not entry.first_token_seen:
-            entry.first_token_seen = True
-            tr.event(idx, "first_token", slot=slot)
-            # the TTFT SLO check, exactly once per request — a resume's
-            # re-admission never re-counts
-            if (entry.deadline_at is not None
-                    and self.clock() > entry.deadline_at):
-                entry.deadline_missed = True
-                self._C["deadline_misses"].inc()
-                tr.event(idx, "deadline_miss")
-                eng.events.emit("deadline_miss", request=idx)
+            tok0 = self._await_first_token(tok0)
+        self._first_token(entry, slot)
         tr.begin(idx, "decode", slot=slot)
         self._C["admitted"].inc()
         self._C["prefill_tokens_total"].inc(s0)
@@ -1517,7 +1599,27 @@ class ServingFrontend:
             # decode_step_ms never charges prefill work to it
             if self._inflight is not None:
                 self._materialize(self._inflight)
-            self._finish_prefill(slot, entry, int(tok))
+            self._finish_prefill(slot, entry,
+                                 self._await_first_token(tok))
+
+    def _first_token(self, entry: _Entry, slot: int) -> None:
+        """The ``first_token`` instant and what hangs on it, exactly once
+        per request — a resume's re-admission never re-counts."""
+        if entry.first_token_seen:
+            return
+        entry.first_token_seen = True
+        tr, idx = self.tracer, entry.idx
+        t_first = tr.event(idx, "first_token", slot=slot).t_start
+        # admit -> first token: the device's queue plus the prefill
+        self._C["first_token_wait_seconds"].inc(
+            max(0.0, t_first - entry.t_admit))
+        # the TTFT SLO check
+        if (entry.deadline_at is not None
+                and self.clock() > entry.deadline_at):
+            entry.deadline_missed = True
+            self._C["deadline_misses"].inc()
+            tr.event(idx, "deadline_miss")
+            self.engine.events.emit("deadline_miss", request=idx)
 
     def _finish_prefill(self, slot: int, entry: _Entry, tok0: int) -> None:
         """The prompt's final chunk landed: sample arrived (``tok0`` off
@@ -1528,15 +1630,7 @@ class ServingFrontend:
         idx = entry.idx
         entry.prefilling = False
         tr.end(idx, "prefill")
-        if not entry.first_token_seen:
-            entry.first_token_seen = True
-            tr.event(idx, "first_token", slot=slot)
-            if (entry.deadline_at is not None
-                    and self.clock() > entry.deadline_at):
-                entry.deadline_missed = True
-                self._C["deadline_misses"].inc()
-                tr.event(idx, "deadline_miss")
-                eng.events.emit("deadline_miss", request=idx)
+        self._first_token(entry, slot)
         tr.begin(idx, "decode", slot=slot)
         entry.seg_tokens = [tok0]
         entry.joined = self._chunk + 1

@@ -750,3 +750,163 @@ def test_concurrent_submit_cancel_stress(rng):
     # the cached pages are all refcount-0 (no dangling prefix refs)
     assert int(np.asarray(engine.cache["page_ref"]).sum()) == 0
     assert fe.stats()["retired"] == n_threads * n_req
+
+
+# --------------------------------------------------------------------------
+# the pump's own account (docs/frontend.md "Measuring the pump")
+# --------------------------------------------------------------------------
+
+class _TickClock:
+    """A millisecond passes at every read; ``jump`` adds more."""
+
+    def __init__(self):
+        self.t = 0.0
+        self.reads = []
+
+    def __call__(self):
+        self.t += 1e-3
+        self.reads.append(self.t)
+        return self.t
+
+    def jump(self, seconds):
+        self.t += seconds
+
+
+class _SlowValue:
+    """A device value whose read takes ``seconds`` of the fake clock."""
+
+    def __init__(self, value, clock, seconds):
+        self.value, self.clock, self.seconds = value, clock, seconds
+
+    def __array__(self, dtype=None, copy=None):
+        self.clock.jump(self.seconds)
+        return np.asarray(self.value, dtype)
+
+
+def _clocked_frontend(num_slots=2, **kw):
+    from apex_tpu.obs.spans import SpanTracer
+
+    cfg, model, v = _model()
+    engine = PagedDecodeEngine(model, v, num_slots=num_slots, page_size=8,
+                               sync_every=2, **kw)
+    clk = _TickClock()
+    fe = ServingFrontend(engine, clock=clk, tracer=SpanTracer(clock=clk))
+    return cfg, fe, clk
+
+
+def _requests(rng, cfg, shapes):
+    return [Request(prompt=rng.integers(0, cfg.vocab_size, (s0,)
+                                        ).astype(np.int32),
+                    max_new_tokens=n) for s0, n in shapes]
+
+
+def test_pump_host_plus_blocked_is_the_iterations_wall_time(rng):
+    """Every second of an iteration that did work is either host work or
+    a wait for the device: the two counters sum to the iterations' wall
+    time by the pump's own clock, and the admission's share is part of
+    the host's."""
+    cfg, fe, clk = _clocked_frontend()
+    for i, r in enumerate(_requests(rng, cfg, [(9, 6)] * 4)):
+        fe.submit(r, request_id=i)
+    wall = accounted = 0.0
+    iterations = 0
+    alive = True
+    while alive:
+        before, first_read = fe.counter_deltas(), len(clk.reads)
+        alive = fe.pump()
+        after = fe.counter_deltas()
+        if after["pump_iterations"] == before["pump_iterations"]:
+            continue                     # neither harvested nor admitted
+        iterations += 1
+        # the pump reads its clock first (t_iter0) and, in an iteration
+        # it counts, last where it observes host_work_ms
+        wall += clk.reads[-1] - clk.reads[first_read]
+        accounted += sum(after[k] - before[k] for k in
+                         ("pump_host_seconds", "pump_blocked_seconds"))
+    d = fe.counter_deltas()
+    assert iterations == d["pump_iterations"] > 2
+    assert accounted == pytest.approx(wall, rel=1e-9)
+    assert d["pump_blocked_seconds"] > 0.0
+    assert 0.0 < d["pump_admission_seconds"] < d["pump_host_seconds"]
+    # one measurement feeds the histogram's series and the counter
+    assert d["pump_host_seconds"] * 1e3 == pytest.approx(
+        sum(fe._per_run["pump.host_work_ms"]))
+    assert d["pump_bubble_seconds"] * 1e3 == pytest.approx(
+        sum(fe._per_run["pump.bubble_ms"]))
+
+
+def test_an_admission_that_blocks_is_device_wait_not_host_work(rng):
+    """The first-token sync of an admission waits for the prefill on the
+    device: it belongs to ``pump_blocked_seconds``, and neither
+    ``pump.host_work_ms`` nor the admission's host seconds may hold it."""
+    cfg, fe, clk = _clocked_frontend(num_slots=1)
+    program = fe.admission_program
+
+    def slow_admission(s0):
+        admit, bucket = program(s0)
+
+        def run(*args):
+            cache, tok0 = admit(*args)
+            return cache, _SlowValue(tok0, clk, 5.0)
+
+        return run, bucket
+
+    fe.admission_program = slow_admission
+    [req] = _requests(rng, cfg, [(9, 4)])
+    handle = fe.submit(req, request_id=0)
+    fe.drain()
+    assert handle.result(timeout=0).shape[0] == 4
+    d = fe.counter_deltas()
+    assert d["pump_blocked_seconds"] >= 5.0
+    assert d["pump_host_seconds"] < 1.0
+    assert d["pump_admission_seconds"] < 1.0
+    assert fe.stats()["pump.host_work_ms_p95"] < 1000.0
+    # the same five seconds lie between admit and the first token
+    assert d["first_token_wait_seconds"] >= 5.0 > d["queue_wait_seconds"]
+    assert fe.tracer.lifecycle(0)["ttft_ms"] == pytest.approx(
+        (d["queue_wait_seconds"] + d["first_token_wait_seconds"]) * 1e3)
+
+
+def test_the_two_waits_of_ttft_sum_to_the_lifecycles(rng):
+    """enqueue -> admit and admit -> first token, summed over requests,
+    are the lifecycles' ``queue_wait_ms`` and ``ttft_ms`` (four requests
+    over two slots, so two of them queue)."""
+    cfg, fe, clk = _clocked_frontend()
+    for i, r in enumerate(_requests(rng, cfg, [(9, 6), (12, 4), (9, 5),
+                                               (17, 3)])):
+        fe.submit(r, request_id=i)
+    fe.drain()
+    lives = [fe.tracer.lifecycle(i) for i in range(4)]
+    d = fe.counter_deltas()
+    assert d["admitted"] == 4
+    assert d["queue_wait_seconds"] * 1e3 == pytest.approx(
+        sum(life["queue_wait_ms"] for life in lives))
+    assert (d["queue_wait_seconds"] + d["first_token_wait_seconds"]) * 1e3 \
+        == pytest.approx(sum(life["ttft_ms"] for life in lives))
+    assert d["first_token_wait_seconds"] > 0.0
+    assert max(life["queue_wait_ms"] for life in lives) > \
+        2 * min(life["queue_wait_ms"] for life in lives)
+
+
+def test_kv_bytes_attended_equals_the_hand_count(rng):
+    """Two requests of known lengths: a request of ``s0`` prompt tokens
+    and ``n`` new ones takes ``n - 1`` decode steps, and step ``i``
+    attends ``s0 + i`` tokens; each costs its K and V in every layer."""
+    from apex_tpu.serving import kv_pool
+
+    cfg, fe, _ = _clocked_frontend()
+    shapes = [(9, 6), (12, 4)]
+    for i, r in enumerate(_requests(rng, cfg, shapes)):
+        fe.submit(r, request_id=i)
+    fe.drain()
+    tokens = sum((n - 1) * s0 + (n - 1) * n // 2 for s0, n in shapes)
+    assert tokens == 60 + 42
+    layers = fe.engine.cache["layers"]
+    pool_tokens = layers[0]["k_pages"].shape[0] * fe.engine.page_size
+    token_bytes = sum(lc["k_pages"].nbytes + lc["v_pages"].nbytes
+                      for lc in layers) / pool_tokens
+    assert token_bytes == kv_pool.page_bytes(cfg, 8) / 8
+    d = fe.counter_deltas()
+    assert d["kv_bytes_attended"] == tokens * token_bytes
+    # frozen steps of the last chunks are counted busy but attend nothing
+    assert d["busy_slot_steps"] >= sum(n - 1 for _, n in shapes)

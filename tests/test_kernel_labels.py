@@ -1,0 +1,183 @@
+"""Kernel labels: every Pallas kernel names itself where it is launched
+(``ops/_dispatch.pallas_call(..., kernel=<label>)``) and the label reaches
+the program's text, where the device trace's ``XLA Ops`` line and the
+benchmark's ``kernel_ms`` reader find it.  Also pins the names of the jitted
+functions that the benchmark finds whole programs by."""
+
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from apex_tpu.ops import _dispatch
+
+f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+
+
+def _sds(shape, dtype=f32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+@functools.cache
+def _cases():
+    """label -> (function that reaches the kernel through a public op,
+    argument structs), at tiny kernel-eligible shapes."""
+    from apex_tpu.ops import (flash_attention, flat_buffer, optim_kernels,
+                              paged_attention, softmax_cross_entropy)
+    from apex_tpu.ops.group_norm import group_norm_nhwc
+    from apex_tpu.ops.layer_norm import layer_norm
+    from apex_tpu.ops.quant import fused_dequant_matmul
+    from apex_tpu.ops.scaled_softmax import scaled_softmax
+
+    def sq_sum(fn):
+        return lambda *a: jnp.sum(fn(*a).astype(f32) ** 2)
+
+    ln_args = [_sds((16, 256)), _sds((256,)), _sds((256,))]
+    qkv = [_sds((1, 2, 128, 64), bf16)] * 3
+    xent = [_sds((16, 512)), _sds((16,), i32)]
+    gn = functools.partial(group_norm_nhwc, num_groups=2, eps=1e-5,
+                           act="silu")
+    gn_args = [_sds((1, 4, 4, 256)), _sds((256,)), _sds((256,))]
+    soft = functools.partial(scaled_softmax, scale=0.5)
+    soft_args = [_sds((1, 2, 16, 128), bf16)]
+
+    spec = flat_buffer.build_spec({"w": _sds((64, 128)), "b": _sds((128,))})
+    seg = np.asarray(spec.segment_rows())
+    buf = _sds((spec.total_rows, flat_buffer.LANE))
+    hp = dict(beta1=0.9, beta2=0.999, eps=1e-6, weight_decay=0.01, lr=1e-3,
+              step=1)
+
+    def lamb(g, p, m, v):
+        return optim_kernels.lamb_update(g, p, m, v, jnp.asarray(seg),
+                                         spec.num_tensors, **hp)
+
+    def novograd(g, p, m, v):
+        return optim_kernels.novograd_update(
+            g, p, m, v, jnp.asarray(seg), spec.num_tensors, **hp)
+
+    pages = _sds((9, 2, 16, 64), bf16)
+    return {
+        "flash_fwd": (flash_attention, qkv),
+        "flash_bwd_dq": (jax.grad(sq_sum(flash_attention), (0, 1, 2)), qkv),
+        "flash_bwd_dkv": (jax.grad(sq_sum(flash_attention), (0, 1, 2)), qkv),
+        "paged_attention": (paged_attention, [
+            _sds((2, 4, 1, 64), bf16), pages, pages, _sds((2, 4), i32),
+            _sds((2,), i32)]),
+        "layer_norm_fwd": (layer_norm, ln_args),
+        "layer_norm_bwd": (jax.grad(sq_sum(layer_norm), (0, 1, 2)), ln_args),
+        "xentropy_fwd": (softmax_cross_entropy, xent),
+        "xentropy_bwd": (jax.grad(
+            lambda x, y: softmax_cross_entropy(x, y).sum()), xent),
+        "l2norm": (lambda g: optim_kernels.global_grad_norm_and_finite(
+            g, jnp.asarray(seg), spec.num_tensors)[0], [buf]),
+        "lamb_phase1": (lamb, [buf] * 4),
+        "lamb_phase2": (lamb, [buf] * 4),
+        "adam": (functools.partial(optim_kernels.adam_update, **hp),
+                 [buf] * 4),
+        "sgd": (functools.partial(optim_kernels.sgd_update, lr=0.1,
+                                  momentum=0.9), [buf] * 3),
+        "novograd": (novograd, [buf] * 3 + [_sds((spec.num_tensors,))]),
+        "scale": (functools.partial(optim_kernels.multi_tensor_scale,
+                                    scale=0.5), [buf]),
+        "group_norm_fwd": (gn, gn_args),
+        "group_norm_bwd": (jax.grad(sq_sum(gn), (0, 1, 2)), gn_args),
+        "scaled_softmax_fwd": (soft, soft_args),
+        "scaled_softmax_bwd": (jax.grad(sq_sum(soft)), soft_args),
+        "dequant_matmul": (fused_dequant_matmul, [
+            _sds((8, 256), bf16), _sds((128, 256), jnp.int8),
+            _sds((128,))]),
+    }
+
+
+def test_the_cases_cover_the_closed_set():
+    assert set(_cases()) == set(_dispatch.KERNEL_LABELS)
+    assert len(set(_dispatch.KERNEL_LABELS)) == len(_dispatch.KERNEL_LABELS)
+
+
+@pytest.mark.parametrize("label", _dispatch.KERNEL_LABELS)
+def test_label_reaches_the_lowered_program(label):
+    """``metadata={"kernel": label}`` lands on the Mosaic custom call as
+    ``kernel_metadata``; the benchmark's pattern finds it there."""
+    fn, args = _cases()[label]
+    # staged through Mosaic for the trace only; the exit clears jax's
+    # trace caches, so nothing here leaks into tests that execute
+    with _dispatch.forced_mosaic():
+        text = jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+    # MLIR escapes the JSON's quotes and newlines as \22 and \0A
+    found = [blob.replace("\\22", '"').replace("\\0A", "\n") for blob in
+             re.findall(r'kernel_metadata = "([^"]*)"', text)]
+    assert found, f"no kernel_metadata in the program of {label}"
+    labels = {m.group(1) for blob in found
+              for m in re.finditer(r"kernel\W{1,8}(\w+)", blob)}
+    assert label in labels
+    assert labels <= set(_dispatch.KERNEL_LABELS)
+    # the name stack is left alone: no scope is named after the label
+    assert f"/{label}/" not in text
+
+
+def test_pallas_call_refuses_a_missing_or_unknown_label():
+    out = jax.ShapeDtypeStruct((8, 128), f32)
+
+    def body(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
+
+    with pytest.raises(TypeError, match="kernel"):
+        _dispatch.pallas_call(body, out_shape=out, interpret=True)
+    with pytest.raises(ValueError, match="unknown kernel label 'copy'"):
+        _dispatch.pallas_call(body, kernel="copy", out_shape=out,
+                              interpret=True)
+    x = jnp.ones((8, 128), f32)
+    y = _dispatch.pallas_call(body, kernel="scale", out_shape=out,
+                              interpret=True)(x)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
+
+
+# -- the names the benchmark finds programs by ---------------------------------
+#
+# ``XLA Modules`` names a program ``jit_<function name>``; five per-layer
+# metrics match ``^jit_loss_fn``, ``^jit__pure``, ``^jit_admit``,
+# ``^jit_step`` (benchmark/layer_metrics/*.json).  A rename empties them
+# silently, so it has to fail here first.
+
+def _tiny_engine():
+    from apex_tpu.models.gpt import GPTModel, gpt_tiny_config
+    from apex_tpu.serving import PagedDecodeEngine
+
+    cfg = gpt_tiny_config()
+    model = GPTModel(cfg)
+    variables = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                               _sds((1, 8), i32))
+    variables = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                             variables)
+    return PagedDecodeEngine(model, variables, num_slots=2, page_size=8,
+                             sync_every=2)
+
+
+def _grad_step_name():
+    from apex_tpu.models import BertForPreTraining, make_pretrain_step
+    from apex_tpu.models.bert import bert_tiny_config
+
+    return make_pretrain_step(BertForPreTraining(bert_tiny_config())).__name__
+
+
+def _optimizer_step_name():
+    from apex_tpu.optimizers import FusedLAMB
+
+    params = {"w": jnp.ones((8, 128), f32)}
+    opt = FusedLAMB(params, lr=1e-3)
+    opt.step({"w": jnp.ones((8, 128), f32)})
+    return opt._jit_step.__name__
+
+
+@pytest.mark.parametrize("want,name_of", [
+    ("loss_fn", _grad_step_name),
+    ("_pure", _optimizer_step_name),
+    ("admit", lambda: _tiny_engine()._admit_fn(16).__name__),
+    ("step", lambda: _tiny_engine()._step_fn().__name__),
+])
+def test_jitted_function_names_the_benchmark_depends_on(want, name_of):
+    assert name_of() == want
