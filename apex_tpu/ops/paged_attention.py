@@ -10,32 +10,57 @@ retires, and admission never reshapes anything.
 This kernel computes GQA attention for a small static block of ``s``
 decode queries per slot (``s=1`` is plain decode; ``s=k`` verifies a
 speculative draft chunk in one pass; ``s``-sized chunks carry interleaved
-prefill) directly against the page pool. The block table rides in as a
-SCALAR-PREFETCH operand (``pltpu.PrefetchScalarGridSpec``) so the k/v
-BlockSpec index maps resolve the physical page for grid step ``j`` —
-``block_tables[b, j]`` — before the body runs: each (page_size, d) page
-tile is DMA'd HBM->VMEM exactly once, and the gather never materializes a
-contiguous copy of the sequence. Online softmax (m, l, acc) carries across
-the sequential page axis exactly like flash_attention's k-block axis; fp32
-scores and accumulation (same numerics contract). The ``s`` queries of a
-slot occupy positions ``lengths[b] - s + i`` (``i`` in ``0..s-1``), so the
-causal/window mask is a per-query-position band — the grid, the page
-skip, and the softmax carry are untouched by the generalization.
+prefill) directly against the page pool. The ``s`` queries of a slot
+occupy positions ``lengths[b] - s + i`` (``i`` in ``0..s-1``), so the
+causal/window mask is a per-query-position band.
 
-Layout: the pool is ``(num_pages, kv_heads, page_size, head_dim)`` — the
-page tile's minor two dims are then ``(page_size, head_dim)``, which
-satisfies Mosaic's (sublane, lane)-or-full-dim block rule for
-``page_size`` a sublane multiple and the usual head dims (64 = full minor
-dim, 128 = lane multiple). GQA queries reshape to ``(b, kv, rep, d)`` and
-contract against the UNexpanded kv-head pages (``rep`` = full dim), the
-same no-repeat discipline as flash_attention and cached_attention.
+The tile: ONE GRID STEP SERVES ALL KV HEADS OF ONE SLOT OVER A BLOCK OF
+CONSECUTIVE PAGES. The grid is ``(batch, kv // heads, cdiv(max_pages,
+pages))`` with ``pages`` and ``heads`` derived from the shapes alone
+(:func:`_tile`: as many pages as hold 128 tokens for every kv head,
+cut down only where the K and V buffers would pass a VMEM budget). The
+step's K and V are ``pages`` operands a tensor, each one whole page
+``(1, heads, page_size, d)`` of the pool as it lies in HBM — the layout
+keeps a page's heads contiguous, so nothing outside the kernel changes
+to fetch a page whole. At GPT-2 large (16 slots, 20 heads of 64,
+64-page tables) that is 128 grid steps of 16 pages of 40 KB a tensor
+where a one-page one-head tile took 20 480 steps of 2 KB and spent its
+time on the steps, never the bytes (PERF.md, PR 28).
+
+The page operands' index maps read a SCALAR-PREFETCH table
+(``pltpu.PrefetchScalarGridSpec``) of physical pages that the wrapper
+resolves once per call: entry ``e`` of slot ``b`` is ``block_tables[b,
+clip(e, first_live(b), last_live(b))]``. A dead entry — past the
+sequence end, below the sliding-window band, past the table where
+``max_pages`` is no multiple of ``pages`` — so repeats a live one: what
+the table holds there is never read, and a step whose entries all
+repeat the step before moves nothing (the pipeline skips a block whose
+index did not change). Dead blocks skip their body as well; inside a
+live block the position band masks whatever the clamp repeats. Resolving
+the clamp outside keeps an index map to one SMEM load: the scalar core
+evaluates ``2*pages + 2`` of them every grid step, and at this tile that
+walk, not the DMA or the dots, is most of a call.
+
+Online softmax ``(m, l, acc)`` carries across the sequential page-block
+axis exactly like flash_attention's k-block axis, shaped for the step's
+heads; fp32 scores and accumulation (same numerics contract). Scores are
+one batched contraction over the head axis, ``(heads, s*rep, d) .
+(heads, pages*page_size, d)``: on the MXU at every ``s*rep``, since at
+``s*rep = 1`` the live step already costs little more than a dead one.
+
+Layout: the pool is ``(num_pages, kv_heads, page_size, head_dim)`` — a
+page operand's minor two dims are the array's own ``(page_size,
+head_dim)``, legal under Mosaic's block rule at every page size that is
+a sublane multiple. GQA queries reshape to ``(b, kv, s*rep, d)`` and
+contract against the UNexpanded kv-head pages, the same no-repeat
+discipline as flash_attention and cached_attention.
 
 Off-TPU the kernel runs through the Pallas interpreter
 (``ops/_dispatch.interpret``), so CPU tests cover the real kernel code.
 
 Tensor parallelism (``serving/tp.py``, docs/tp_serving.md): the kernel
-is TP-native by shape, not by flag. Heads never interact — the grid's
-``kv_head`` axis is embarrassingly parallel — so inside ``shard_map``
+is TP-native by shape, not by flag. Heads never interact — the batched
+contraction's head axis is embarrassingly parallel — so inside ``shard_map``
 with the pool sharded along its kv-head axis, each chip calls this
 kernel on its LOCAL ``(num_pages, kv_heads/tp, page_size, d)`` shard
 with its local query heads and the REPLICATED block tables / lengths:
@@ -61,20 +86,76 @@ from apex_tpu.ops.flash_attention import DEFAULT_MASK_VALUE
 
 _INTERPRET = _dispatch.interpret
 
+#: context tokens one grid step attends: one 128-lane tile of scores
+_STEP_TOKENS = 128
+#: VMEM the K and V page buffers of one grid step may take — both
+#: tensors, double-buffered by the pipeline, tile padding included (a
+#: d=64 page pads to 128 lanes). Half of Mosaic's 16 MiB scoped stack;
+#: the rest holds q, the (m, l, acc) carry and the step's f32 scores
+_KV_VMEM_BUDGET = 8 * 1024 * 1024
 
-def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest, scale,
-                  page_size, max_pages, s_q, rep, window=None,
-                  quantized=False):
+
+@functools.cache
+def _tile(kv: int, page_size: int, d: int, dtype, max_pages: int):
+    """``(pages, heads)`` of one grid step, from the shapes alone: as
+    many consecutive table entries as fill :data:`_STEP_TOKENS` (never
+    more than the table has) for all ``kv`` heads; where that overflows
+    :data:`_KV_VMEM_BUDGET` the page block halves first, then the heads
+    split into the largest divisor of ``kv`` that fits."""
+    item = jnp.dtype(dtype).itemsize
+    page_vmem = (4 * _dispatch.round_up(page_size, 32 // item)
+                 * _dispatch.round_up(d, 128) * item)   # per kv head
+    pages = max(1, min(max_pages, _STEP_TOKENS // page_size))
+    while pages > 1 and pages * kv * page_vmem > _KV_VMEM_BUDGET:
+        pages //= 2
+    heads = next(h for h in range(kv, 0, -1)
+                 if kv % h == 0 and (h == 1 or pages * h * page_vmem
+                                     <= _KV_VMEM_BUDGET))
+    return pages, heads
+
+
+def _live_pages(length, page_size: int, s_q: int, window, maximum=max):
+    """``(first, last)`` table entries of a slot that hold a position
+    some query of the block attends (``first == last == 0`` for an empty
+    slot). Pure arithmetic: the wrapper evaluates it on the traced
+    lengths (``maximum=jnp.maximum``), the serving host on ints."""
+    last = maximum(_dispatch.cdiv(length, page_size) - 1, 0)
+    if window is None:
+        return 0, last
+    # the earliest query sits at length - s_q and attends down to
+    # length - s_q - window + 1; pages wholly below that are dead for
+    # every query of the block and every later step
+    return maximum(length - s_q - window + 1, 0) // page_size, last
+
+
+def pages_fetched(length: int, *, kv_heads: int, page_size: int,
+                  head_dim: int, dtype, max_pages: int, s_q: int = 1,
+                  window: Optional[int] = None) -> int:
+    """Pages of K (and as many of V) one call DMAs for a slot of
+    ``length`` positions: the kernel fetches by block, so every block
+    that holds a live page counts whole (feeds
+    ``serving.kv_bytes_fetched``; per kv-head block the same count of
+    narrower pages)."""
+    pages, _ = _tile(kv_heads, page_size, head_dim, dtype, max_pages)
+    first, last = _live_pages(length, page_size, s_q, window)
+    return (last // pages - first // pages + 1) * pages
+
+
+def _paged_kernel(phys_ref, len_ref, q_ref, *rest, scale, page_size, pages,
+                  s_q, rep, window=None, quantized=False):
+    k_refs, v_refs, rest = rest[:pages], rest[pages:2 * pages], \
+        rest[2 * pages:]
     if quantized:
-        # two extra scalar operands: this page's per-kv-head symmetric
-        # dequant scales, prefetched by the same bt[b, j] index map as
-        # the page tiles (docs/serving.md "Quantized KV pages")
+        # two extra operands: the per-token dequant scales of this
+        # step's pages, gathered through the same clamped table entries
+        # as the page tiles (docs/serving.md "Quantized KV pages")
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
         ks_ref = vs_ref = None
         o_ref, acc_ref, m_ref, l_ref = rest
     b = pl.program_id(0)
     j = pl.program_id(2)
+    block = pages * page_size
 
     @pl.when(j == 0)
     def _init():
@@ -84,74 +165,76 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest, scale,
 
     seq_len = len_ref[b]
 
-    # page j holds absolute positions [j*ps, (j+1)*ps): dead pages (at or
-    # past the sequence end) skip both their FLOPs and their accumulator
-    # update; their DMA fetched whatever page id the table holds (0 = the
-    # reserved null page) — never read, so never wrong
-    page_live = j * page_size < seq_len
+    # block j holds absolute positions [j*block, (j+1)*block): dead
+    # blocks (at or past the sequence end) skip their FLOPs and their
+    # accumulator update, and the clamped index maps give them the table
+    # entry the step before already holds, so the pipeline fetches
+    # nothing for them either
+    block_live = j * block < seq_len
     if window is not None:
         # sliding-window band: query i of the block sits at position
-        # seq_len - s_q + i and attends (pos_i - window, pos_i]. A page
+        # seq_len - s_q + i and attends (pos_i - window, pos_i]. A block
         # whose LAST position is at or below the EARLIEST query's band
         # floor (seq_len - s_q) - window is dead for every query in the
         # block and every later step (the band only moves forward) — the
         # serving engine drops such pages from the block table entirely
-        # (kv_pool.drop_slot_pages), and this gate skips whatever the
+        # (kv_pool.drop_slot_pages), and the clamp never reads what a
         # dropped entry now points at (the null page)
-        page_live = jnp.logical_and(
-            page_live, (j + 1) * page_size + window + s_q - 1 > seq_len)
+        block_live = jnp.logical_and(
+            block_live, (j + 1) * block + window + s_q - 1 > seq_len)
 
-    @pl.when(page_live)
+    @pl.when(block_live)
     def _body():
-        q = q_ref[0, 0]                                   # (s_q*rep, d)
-        k = k_ref[0, 0]                                   # (ps, d)
+        q = q_ref[0]                                  # (heads, s_q*rep, d)
+        # the step's pages side by side: (heads, block, d). Quantized
+        # pages widen one by one first, so the concatenation is of whole
+        # tiles of q's dtype. int8 (<=127) and e4m3 (<=448) values are
+        # exact in bf16/f32, and the f32 pool never exists: dequant is a
+        # fold of the scales, the k-scale into the scores (q.k * sk ==
+        # q.(k*sk)), the v-scale into p before the value dot
+        k = jnp.concatenate(
+            [r[0].astype(q.dtype) if quantized else r[0] for r in k_refs],
+            axis=1)
+        s = jnp.einsum("hqd,htd->hqt", q, k,
+                       preferred_element_type=jnp.float32) * scale
         if quantized:
-            # dequant is a SCALAR fold, never a widened tensor: the
-            # page's k-scale rides the score multiply (q.k * sk == q.
-            # (k*sk)), the v-scale rides p before the value dot — the
-            # narrow page is cast in VMEM, the f32 pool never exists.
-            # int8 (<=127) and e4m3 (<=448) values are exact in bf16/f32
-            k = k.astype(q.dtype)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (s_q*rep, ps)
-        if quantized:
-            # keep the scale a (1, 1) array and broadcast — extracting a
-            # true scalar from a VMEM tile is an unsupported shape cast
-            s = s * ks_ref[0, 0]
-        pos = lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * page_size
+            s = s * ks_ref[0, 0]                      # (heads, 1, block)
+        pos = lax.broadcasted_iota(jnp.int32, s.shape, 2) + j * block
         # rows are position-major: row r is query position seq_len - s_q
         # + r // rep (each query's rep GQA heads are adjacent rows)
         qpos = (seq_len - s_q
-                + lax.broadcasted_iota(jnp.int32, s.shape, 0) // rep)
+                + lax.broadcasted_iota(jnp.int32, s.shape, 1) // rep)
+        # also masks what a clamped entry repeats: a position past the
+        # sequence end is past every query
         live = pos <= qpos
         if window is not None:
-            # positions inside a live page but below a query's band
+            # positions inside a live block but below a query's band
             # floor mask out — exactly cached_attention_rolling's band,
             # per query position
             live = jnp.logical_and(live, pos > qpos - window)
         s = jnp.where(live, s, DEFAULT_MASK_VALUE)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         p = jnp.where(live, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
         m_ref[...] = m_new
-        v = v_ref[0, 0]
         if quantized:
-            p_in, v_in = p * vs_ref[0, 0], v.astype(jnp.float32)
+            p_in = p * vs_ref[0, 0]
+            v = jnp.concatenate([r[0].astype(jnp.float32) for r in v_refs],
+                                axis=1)
         else:
-            p_in, v_in = p.astype(v.dtype), v
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p_in, v_in, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            v = jnp.concatenate([r[0] for r in v_refs], axis=1)
+            p_in = p.astype(v.dtype)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
+            "hqt,htd->hqd", p_in, v, preferred_element_type=jnp.float32)
 
-    @pl.when(j == max_pages - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         l = l_ref[...]
         # a zero-length slot (idle serving slot) outputs exactly 0
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
 def _validate(q, k_pages, v_pages, block_tables, lengths, window=None,
@@ -227,7 +310,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         physical page holding slot ``b``'s positions
         ``[j*page_size, (j+1)*page_size)``. Entries past a sequence's
         allocation must hold a VALID page id (the pool reserves page 0 as
-        a null page) — they are fetched by the pipeline but never read.
+        a null page) — the kernel clamps them away and never reads them.
       lengths: int32 ``(batch,)`` — valid positions per slot INCLUDING
         all ``s`` current tokens (their K/V must already be written to
         the pool). Length 0 (idle slot) outputs exactly 0; a slot whose
@@ -240,18 +323,17 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         ``cached_attention``/``cached_attention_rolling`` mask applied
         per query position, so a windowed model's paged decode is
         token-identical to its contiguous/rolling decode. Pages fully
-        below every query's band skip their FLOPs (and may be dropped
+        below every query's band are never fetched (and may be dropped
         from the block table entirely — the serving engine's
         O(window)-HBM trick, ``kv_pool.drop_slot_pages``).
       k_scales / v_scales: f32 ``(num_pages, kv_heads)`` per-page,
         per-kv-head symmetric dequant scales of a QUANTIZED pool
         (int8 / fp8 e4m3 pages, ``kv_pool.init_paged_cache(kv_dtype=)``)
         — ``true_k[p, h] = k_pages[p, h].astype(f32) * k_scales[p, h]``.
-        Both or neither. The kernel prefetches each page's two scalars
-        through the same ``bt[b, j]`` index map as the page tiles and
-        folds them into the score / value dots, so the dequantized pool
-        is never materialized. Under TP they shard along the kv-head
-        axis with the pages.
+        Both or neither. They are gathered through the same clamped
+        table entries as the page operands and folded into the score /
+        value dots, so the dequantized pool is never materialized. Under
+        TP they shard along the kv-head axis with the pages.
 
     Returns ``(batch, heads, s, head_dim)`` in ``q.dtype``.
     """
@@ -261,63 +343,77 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     num_pages, kv, page_size, d = k_pages.shape
     b, h, s_q = q.shape[0], q.shape[1], q.shape[2]
     rep = h // kv
+    rows = s_q * rep
     max_pages = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
+    pages, heads = _tile(kv, page_size, d, k_pages.dtype, max_pages)
+    n_blocks = _dispatch.cdiv(max_pages, pages)
 
     # position-major row layout: row i*rep + r is query position i of
     # GQA group-member r, so the kernel recovers the position as
-    # row // rep with the group's rows adjacent (one contraction for
-    # all s*rep rows against the page tile — same dot shape as s=1,
-    # just taller)
+    # row // rep with the group's rows adjacent (one contraction per kv
+    # head for all s*rep rows against the step's pages)
     qr = (q.reshape(b, kv, rep, s_q, d).transpose(0, 1, 3, 2, 4)
-          .reshape(b, kv, s_q * rep, d))
-    bt = block_tables.astype(jnp.int32)
+          .reshape(b, kv, rows, d))
     ln = lengths.astype(jnp.int32)
+    # the physical page of every table entry a grid step names, each
+    # entry clamped into its slot's live pages first: a dead entry (past
+    # the end, below the band, or past the table where max_pages is no
+    # multiple of the page block) repeats a live one, so whatever it
+    # holds is never read, and a step whose entries all repeat the step
+    # before fetches nothing. Resolved here, once per call, so that an
+    # index map is one SMEM load: the scalar core walks 2*pages + 2 of
+    # them every grid step
+    first, last = _live_pages(ln, page_size, s_q, window, jnp.maximum)
+    entries = jnp.clip(
+        jnp.arange(n_blocks * pages, dtype=jnp.int32)[None, :],
+        jnp.asarray(first, jnp.int32)[..., None],
+        jnp.minimum(last, max_pages - 1)[:, None])
+    phys = jnp.take_along_axis(block_tables.astype(jnp.int32), entries,
+                               axis=1)              # (b, n_blocks*pages)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, s_q * rep, d),
-                     lambda b, h, j, bt, ln: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, d),
-                     lambda b, h, j, bt, ln: (bt[b, j], h, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, d),
-                     lambda b, h, j, bt, ln: (bt[b, j], h, 0, 0)),
-    ]
-    operands = [bt, ln, qr, k_pages, v_pages]
+    def page_spec(i):
+        return pl.BlockSpec(
+            (1, heads, page_size, d),
+            lambda b, g, j, phys, ln: (phys[b, j * pages + i], g, 0, 0))
+
+    q_spec = pl.BlockSpec((1, heads, rows, d),
+                          lambda b, g, j, phys, ln: (b, g, 0, 0))
+    in_specs = [q_spec] + [page_spec(i) for i in range(pages)] * 2
+    operands = [phys, ln, qr] + [k_pages] * pages + [v_pages] * pages
     if quantized:
-        # one scalar scale block per (page, kv_head) grid step, resolved
-        # by the SAME scalar-prefetched bt[b, j] map as the page tiles.
-        # The (pages, kv) array is viewed as (pages, kv, 1, 1) so the
-        # block's last two dims EQUAL the array's — the only legal shape
-        # for a sub-(8, 128) VMEM block under Mosaic's tiling rules
-        # (same trick as the upstream quantized paged-attention kernels)
-        in_specs += [
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda b, h, j, bt, ln: (bt[b, j], h, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda b, h, j, bt, ln: (bt[b, j], h, 0, 0)),
-        ]
-        operands += [k_scales.astype(jnp.float32)[:, :, None, None],
-                     v_scales.astype(jnp.float32)[:, :, None, None]]
+        # the (num_pages, kv) scales, gathered through the same clamped
+        # entries and spread over each page's positions:
+        # (b, n_blocks, kv, 1, block) f32, one (heads, 1, block) tile a
+        # step that broadcasts over the rows
+        def per_token(scales):
+            sc = jnp.take(scales.astype(jnp.float32), phys, axis=0)
+            sc = sc.reshape(b, n_blocks, pages, kv).transpose(0, 1, 3, 2)
+            return jnp.repeat(sc, page_size, axis=3)[:, :, :, None]
+
+        in_specs += [pl.BlockSpec(
+            (1, 1, heads, 1, pages * page_size),
+            lambda b, g, j, phys, ln: (b, j, g, 0, 0))] * 2
+        operands += [per_token(k_scales), per_token(v_scales)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kv, max_pages),
+        grid=(b, kv // heads, n_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, s_q * rep, d),
-                               lambda b, h, j, bt, ln: (b, h, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((s_q * rep, d), jnp.float32),
-            pltpu.VMEM((s_q * rep, 1), jnp.float32),
-            pltpu.VMEM((s_q * rep, 1), jnp.float32),
+            pltpu.VMEM((heads, rows, d), jnp.float32),
+            pltpu.VMEM((heads, rows, 1), jnp.float32),
+            pltpu.VMEM((heads, rows, 1), jnp.float32),
         ],
     )
     out = _dispatch.pallas_call(
         functools.partial(_paged_kernel, scale=float(scale),
-                          page_size=page_size, max_pages=max_pages,
+                          page_size=page_size, pages=pages,
                           s_q=s_q, rep=rep, window=window,
                           quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kv, s_q * rep, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kv, rows, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),

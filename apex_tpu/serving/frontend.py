@@ -60,6 +60,7 @@ replicated values, and nothing in this module knows the chip count
 from __future__ import annotations
 
 import contextlib
+import functools
 import itertools
 import queue as _queue
 import threading
@@ -75,6 +76,7 @@ from apex_tpu.obs import compile_watch
 from apex_tpu.obs import fleet
 from apex_tpu.obs.spans import SpanTracer
 from apex_tpu.ops._dispatch import round_up
+from apex_tpu.ops.paged_attention import pages_fetched
 from apex_tpu.serving import kv_pool
 from apex_tpu.serving.policy import PriorityDeadlinePolicy
 from apex_tpu.serving.scheduler import (_RUN_COUNTERS, _RUN_HISTOGRAMS,
@@ -441,10 +443,21 @@ class ServingFrontend:
         self._wait_s = 0.0
         # bytes of K and V one context token costs across all layers and
         # chips, as the pool holds them (feeds serving.kv_bytes_attended)
+        tp = int(getattr(engine, "tp_world", 1))
         self._kv_token_bytes = (
             kv_pool.page_bytes(engine.cfg, engine.page_size,
                                kv_dtype=engine.kv_dtype)
-            * int(getattr(engine, "tp_world", 1)) / engine.page_size)
+            * tp / engine.page_size)
+        # pages the decode kernel's DMAs move for a slot of a given
+        # length, as each chip's call tiles its local heads (feeds
+        # serving.kv_bytes_fetched)
+        pool = engine.cache["layers"][0]["k_pages"]
+        self._pages_fetched = functools.partial(
+            pages_fetched,
+            kv_heads=pool.shape[1] // tp, page_size=engine.page_size,
+            head_dim=pool.shape[3], dtype=pool.dtype,
+            max_pages=engine.cache["block_tables"].shape[1],
+            s_q=engine.draft_len + 1, window=engine.window)
         # TPOT-SLO burn rate: (time, missed) per SLO-carrying retirement
         # inside the policy's rolling window (pump-confined state)
         self._slo_window: deque = deque()
@@ -910,6 +923,9 @@ class ServingFrontend:
         self._C["kv_bytes_attended"].inc(
             sum(self._tokens_attended(e) for e in decoding)
             * self._kv_token_bytes)
+        self._C["kv_bytes_fetched"].inc(
+            sum(self._pages_moved(e) for e in decoding)
+            * self._kv_token_bytes * eng.page_size)
         t0 = self.clock()
         if eng.draft_len:
             # speculative chunk: the payload is (target predictions,
@@ -945,6 +961,20 @@ class ServingFrontend:
         if eng.window is not None:
             return sum(min(eng.window, first + j) for j in range(live))
         return live * first + live * (live - 1) // 2
+
+    def _pages_moved(self, entry: _Entry) -> int:
+        """Pages the decode kernel fetches for one decoding slot over
+        the chunk being dispatched, summed over ALL its steps: the
+        kernel moves whole page blocks (``ops.paged_attention.
+        pages_fetched``), and a frozen step still runs the forward at
+        the slot's last length. Over ``_tokens_attended`` x page_size
+        this is the rounding the tile costs."""
+        eng = self.engine
+        ran = (self._chunk - entry.joined) * eng.sync_every
+        return sum(
+            self._pages_fetched(
+                entry.s0 + min(ran + j, max(entry.seg_new - 1, 0)) + 1)
+            for j in range(eng.sync_every))
 
     def _materialize(self, chunk: _Chunk) -> np.ndarray:
         """Block for the chunk's tokens (overlapping whatever device

@@ -93,11 +93,13 @@ _RUN_COUNTERS = ("admitted", "retired", "decode_steps", "busy_slot_steps",
                  # the pump's own account, as counters of host seconds
                  # (docs/frontend.md "Measuring the pump"), the two
                  # halves of TTFT summed over requests, and the K/V bytes
-                 # the decode steps' attention must read
+                 # the decode steps' attention must read and the bytes
+                 # the kernel's page blocks move for them
                  "pump_iterations", "pump_host_seconds",
                  "pump_admission_seconds", "pump_blocked_seconds",
                  "pump_bubble_seconds", "queue_wait_seconds",
-                 "first_token_wait_seconds", "kv_bytes_attended")
+                 "first_token_wait_seconds", "kv_bytes_attended",
+                 "kv_bytes_fetched")
 
 #: per-request latency histograms (``serving.<name>``, log-bucketed ms)
 _RUN_HISTOGRAMS = ("ttft_ms", "tpot_ms", "queue_wait_ms", "decode_step_ms")

@@ -910,3 +910,22 @@ def test_kv_bytes_attended_equals_the_hand_count(rng):
     assert d["kv_bytes_attended"] == tokens * token_bytes
     # frozen steps of the last chunks are counted busy but attend nothing
     assert d["busy_slot_steps"] >= sum(n - 1 for _, n in shapes)
+
+
+def test_kv_bytes_fetched_counts_whole_page_blocks(rng):
+    """The kernel moves page blocks whole, in every step a slot is
+    decoding in, frozen ones too: at this engine's sizes the table is one
+    block of 16 pages, so each busy slot-step fetches 16 pages of K and V
+    in every layer; what is attended is the part that is no rounding
+    (the blocks' own arithmetic: tests/test_paged_attention.py)."""
+    from apex_tpu.serving import kv_pool
+
+    cfg, fe, _ = _clocked_frontend()
+    for i, r in enumerate(_requests(rng, cfg, [(9, 6), (12, 4)])):
+        fe.submit(r, request_id=i)
+    fe.drain()
+    assert fe._pages_fetched(1) == fe._pages_fetched(128) == 16
+    d = fe.counter_deltas()
+    assert d["kv_bytes_fetched"] == d["busy_slot_steps"] * 16 * \
+        kv_pool.page_bytes(cfg, fe.engine.page_size)
+    assert 0 < d["kv_bytes_attended"] < d["kv_bytes_fetched"]

@@ -24,7 +24,9 @@ def _sds(shape, dtype=f32):
 @functools.cache
 def _cases():
     """label -> (function that reaches the kernel through a public op,
-    argument structs), at tiny kernel-eligible shapes."""
+    argument structs), at tiny kernel-eligible shapes; ``label@cell`` is
+    the same kernel at the shape a benchmark cell calls it with, so a
+    block shape that Mosaic's rules refuse fails here first."""
     from apex_tpu.ops import (flash_attention, flat_buffer, optim_kernels,
                               paged_attention, softmax_cross_entropy)
     from apex_tpu.ops.group_norm import group_norm_nhwc
@@ -66,6 +68,11 @@ def _cases():
         "paged_attention": (paged_attention, [
             _sds((2, 4, 1, 64), bf16), pages, pages, _sds((2, 4), i32),
             _sds((2,), i32)]),
+        # GPT-2 large: 16 slots, 20 heads of 64, 64-page tables, 2 GiB pool
+        "paged_attention@gpt2-large.chat-closed16": (paged_attention, [
+            _sds((16, 20, 1, 64), bf16), _sds((729, 20, 16, 64), bf16),
+            _sds((729, 20, 16, 64), bf16), _sds((16, 64), i32),
+            _sds((16,), i32)]),
         "layer_norm_fwd": (layer_norm, ln_args),
         "layer_norm_bwd": (jax.grad(sq_sum(layer_norm), (0, 1, 2)), ln_args),
         "xentropy_fwd": (softmax_cross_entropy, xent),
@@ -93,15 +100,18 @@ def _cases():
 
 
 def test_the_cases_cover_the_closed_set():
-    assert set(_cases()) == set(_dispatch.KERNEL_LABELS)
+    assert {c.partition("@")[0] for c in _cases()} == \
+        set(_dispatch.KERNEL_LABELS)
     assert len(set(_dispatch.KERNEL_LABELS)) == len(_dispatch.KERNEL_LABELS)
 
 
-@pytest.mark.parametrize("label", _dispatch.KERNEL_LABELS)
-def test_label_reaches_the_lowered_program(label):
+@pytest.mark.parametrize("case", _dispatch.KERNEL_LABELS + (
+    "paged_attention@gpt2-large.chat-closed16",))
+def test_label_reaches_the_lowered_program(case):
     """``metadata={"kernel": label}`` lands on the Mosaic custom call as
     ``kernel_metadata``; the benchmark's pattern finds it there."""
-    fn, args = _cases()[label]
+    fn, args = _cases()[case]
+    label = case.partition("@")[0]
     # staged through Mosaic for the trace only; the exit clears jax's
     # trace caches, so nothing here leaks into tests that execute
     with _dispatch.forced_mosaic():

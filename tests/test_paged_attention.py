@@ -265,3 +265,183 @@ def test_query_block_windowed_matches_reference(rng):
     ref = np.asarray(paged_attention_reference(q, k_pages, v_pages, bt,
                                                lens, window=W))
     np.testing.assert_allclose(out, ref, **TOL)
+
+
+# --------------------------------------------------------------------------
+# the page-block tile (ISSUE 28): one grid step serves all kv heads of a
+# slot over a block of consecutive table entries (128 tokens: 16 pages of
+# 8, 8 pages of 16), bounded by the slot's live pages
+# --------------------------------------------------------------------------
+
+def _scattered_tables(rng, lens, max_pages, num_pages, ps, dead="null"):
+    """Each slot's live entries hold pages of its own (scrambled); its
+    dead entries hold the null page 0, or — ``dead="stolen"`` — live
+    pages of the OTHER slots, which the kernel must never read as this
+    slot's."""
+    b = len(lens)
+    free = list(rng.permutation(np.arange(1, num_pages)))
+    bt = np.zeros((b, max_pages), np.int32)
+    for i, n in enumerate(lens):
+        for j in range(-(-int(n) // ps)):
+            bt[i, j] = free.pop()
+    if dead == "stolen":
+        live = [p for p in bt.reshape(-1) if p]
+        for i, n in enumerate(lens):
+            mine = set(bt[i, :-(-int(n) // ps)])
+            others = [p for p in live if p not in mine]
+            for j in range(-(-int(n) // ps), max_pages):
+                bt[i, j] = others[(i + j) % len(others)]
+    return jnp.asarray(bt)
+
+
+_BLOCK_CASES = {
+    # the serving cell's call: GPT-2 large, 16 slots, 64-page tables
+    "cell_shape_bf16": dict(kv=20, rep=1, d=64, ps=16, mp=64,
+                            lens=[110, 180, 200, 290, 300, 420, 450, 560,
+                                  600, 800, 150, 260, 330, 480, 130, 1024],
+                            dtype=jnp.bfloat16),
+    # 20 entries against blocks of 16: the second block's tail entries
+    # lie past the table (clamped index, masked positions)
+    "max_pages_no_multiple_of_the_block": dict(
+        kv=2, rep=2, d=16, ps=8, mp=20, lens=[160, 129, 100, 7]),
+    "lengths_at_the_block_edges": dict(
+        kv=2, rep=1, d=16, ps=8, mp=32, lens=[0, 1, 127, 128, 129, 256]),
+    "slots_end_in_different_blocks": dict(
+        kv=2, rep=2, d=16, ps=8, mp=48, lens=[5, 384, 130, 250, 0, 300]),
+    "dead_entries_hold_other_slots_pages": dict(
+        kv=2, rep=1, d=16, ps=8, mp=32, lens=[3, 128, 131, 200],
+        dead="stolen"),
+    "query_block_straddles_a_block_edge": dict(
+        kv=2, rep=2, d=16, ps=8, mp=32, s_q=4, lens=[130, 129, 128, 131, 2]),
+    "window_across_blocks": dict(
+        kv=2, rep=1, d=16, ps=8, mp=40, window=37,
+        lens=[300, 165, 128, 36, 1]),
+    "window_query_block_across_blocks": dict(
+        kv=2, rep=2, d=16, ps=8, mp=40, window=130, s_q=3,
+        lens=[300, 258, 131, 3]),
+    "tp_local_heads_kv5": dict(kv=5, rep=1, d=64, ps=16, mp=24,
+                               lens=[383, 129, 16, 300],
+                               dtype=jnp.bfloat16),
+    "gqa_rep4_d128": dict(kv=2, rep=4, d=128, ps=16, mp=20,
+                          lens=[320, 17, 128, 250]),
+}
+
+
+@pytest.mark.parametrize("name", list(_BLOCK_CASES))
+def test_page_block_tile_matches_reference(rng, name):
+    c = dict(_BLOCK_CASES[name])
+    kv, rep, d, ps, mp = c["kv"], c["rep"], c["d"], c["ps"], c["mp"]
+    dtype = c.get("dtype", jnp.float32)
+    s_q, window = c.get("s_q", 1), c.get("window")
+    lens = c["lens"]
+    b = len(lens)
+    P = 2 + sum(-(-n // ps) for n in lens)
+    k_pages, v_pages = _pool(rng, P, kv, ps, d, dtype)
+    q = jnp.asarray(rng.standard_normal((b, kv * rep, s_q, d)), dtype)
+    bt = _scattered_tables(rng, lens, mp, P, ps, c.get("dead", "null"))
+    ln = jnp.asarray(lens, jnp.int32)
+    out = np.asarray(paged_attention(q, k_pages, v_pages, bt, ln,
+                                     window=window), np.float32)
+    ref = np.asarray(paged_attention_reference(
+        q, k_pages, v_pages, bt, ln, window=window), np.float32)
+    tol = TOL if dtype == jnp.float32 else dict(rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(out, ref, **tol)
+    for i, n in enumerate(lens):
+        if n == 0:
+            assert (out[i] == 0).all()   # an idle slot stays exactly zero
+
+
+@pytest.mark.parametrize("window", [None, 37])
+def test_page_block_tile_never_reads_a_dead_entry(rng, window):
+    """Dead entries are clamped away, not masked after the read: NaN in
+    the null page, and in every page a dead entry names, leaves the
+    result as it was — below the band, past the end, in a live block's
+    tail and in wholly dead blocks alike."""
+    kv, d, ps, mp = 2, 16, 8, 40
+    lens = [300, 165, 128, 36, 1, 0]
+    b = len(lens)
+    P = 3 + sum(-(-n // ps) for n in lens)
+    k_pages, v_pages = _pool(rng, P, kv, ps, d)
+    q = jnp.asarray(rng.standard_normal((b, kv, 1, d)), jnp.float32)
+    bt = np.array(_scattered_tables(rng, lens, mp, P, ps))
+    ln = jnp.asarray(lens, jnp.int32)
+    want = np.asarray(paged_attention(q, k_pages, v_pages, jnp.asarray(bt),
+                                      ln, window=window))
+    poison = min(set(range(1, P)) - set(bt.reshape(-1).tolist()))  # unowned
+    for i, n in enumerate(lens):
+        first = max(n - 1 - window + 1, 0) // ps if window else 0
+        bt[i, -(-n // ps):] = poison     # past the end
+        bt[i, :first] = 0                # dropped below the band
+    k_bad = k_pages.at[0].set(jnp.nan).at[poison].set(jnp.nan)
+    v_bad = v_pages.at[0].set(jnp.nan).at[poison].set(jnp.nan)
+    out = np.asarray(paged_attention(q, k_bad, v_bad, jnp.asarray(bt), ln,
+                                     window=window))
+    np.testing.assert_array_equal(out, want)
+
+
+@pytest.mark.parametrize("kv_dtype", ["int8", "fp8"])
+def test_page_block_tile_quantized_pool(rng, kv_dtype):
+    """A quantized pool across block edges: the per-page per-head scales
+    ride the same clamped entries as the pages (a NaN scale on the null
+    page is never gathered into a live position's arithmetic)."""
+    kv, rep, d, ps, mp = 2, 2, 16, 8, 40
+    lens = [300, 129, 128, 9, 0]
+    b = len(lens)
+    P = 2 + sum(-(-n // ps) for n in lens)
+    kf, vf = _pool(rng, P, kv, ps, d)
+    if kv_dtype == "int8":
+        qmax, dt = 127.0, jnp.int8
+        narrow = lambda x: jnp.round(x).astype(dt)
+    else:
+        qmax, dt = 448.0, jnp.float8_e4m3fn
+        narrow = lambda x: x.astype(dt)
+    ks = jnp.max(jnp.abs(kf), axis=(2, 3)) / qmax
+    vs = jnp.max(jnp.abs(vf), axis=(2, 3)) / qmax
+    k_pages = narrow(kf / ks[:, :, None, None])
+    v_pages = narrow(vf / vs[:, :, None, None])
+    q = jnp.asarray(rng.standard_normal((b, kv * rep, 1, d)), jnp.float32)
+    bt = _scattered_tables(rng, lens, mp, P, ps)
+    ln = jnp.asarray(lens, jnp.int32)
+    out = np.asarray(paged_attention(q, k_pages, v_pages, bt, ln,
+                                     k_scales=ks, v_scales=vs))
+    ref = np.asarray(paged_attention_reference(q, k_pages, v_pages, bt, ln,
+                                               k_scales=ks, v_scales=vs))
+    np.testing.assert_allclose(out, ref, **TOL)
+    assert (out[4] == 0).all()
+    bad = np.asarray(paged_attention(
+        q, k_pages, v_pages, bt, ln, k_scales=ks.at[0].set(jnp.nan),
+        v_scales=vs.at[0].set(jnp.nan)))
+    np.testing.assert_array_equal(bad, out)
+
+
+@pytest.mark.parametrize("length,window,s_q,want", [
+    (0, None, 1, 8), (1, None, 1, 8), (128, None, 1, 8), (129, None, 1, 16),
+    (450, None, 1, 32), (1024, None, 1, 64),
+    # band of 100 under position 449: pages 21..28, blocks 2 and 3
+    (450, 100, 1, 16),
+    # the earliest of 4 queries reaches one page further down
+    (261, 5, 1, 8), (261, 5, 4, 16),
+])
+def test_pages_fetched_counts_whole_live_blocks(length, window, s_q, want):
+    """What ``serving.kv_bytes_fetched`` charges a slot: every block of
+    8 pages (the cell's tile: page 16, 20 heads of 64, bf16) that holds
+    a live page, whole."""
+    from apex_tpu.ops.paged_attention import pages_fetched
+
+    assert pages_fetched(length, kv_heads=20, page_size=16, head_dim=64,
+                         dtype=jnp.bfloat16, max_pages=64, s_q=s_q,
+                         window=window) == want
+
+
+@pytest.mark.parametrize("kv,ps,d,dtype,mp,want", [
+    (20, 16, 64, jnp.bfloat16, 64, (8, 20)),      # the serving cell
+    (5, 16, 64, jnp.bfloat16, 64, (8, 5)),        # its tp=4 shard
+    (12, 16, 64, jnp.int8, 32, (8, 12)),          # quantized gpt2-small
+    (2, 8, 16, jnp.float32, 4, (4, 2)),           # table shorter than a block
+    (8, 16, 128, jnp.bfloat16, 512, (8, 8)),      # llama GQA
+    (64, 64, 256, jnp.float32, 64, (1, 32)),      # VMEM forces a head block
+])
+def test_tile_is_derived_from_the_shapes(kv, ps, d, dtype, mp, want):
+    from apex_tpu.ops.paged_attention import _tile
+
+    assert _tile(kv, ps, d, dtype, mp) == want

@@ -296,6 +296,15 @@ def kernel_cases():
             _sds((513, 12, 16, 64), bf16), _sds((8, 32), i32),
             _sds((8,), i32)])
 
+    # -- the benchmark's serving cell (gpt2-large.chat-closed16): 16 slots,
+    # 20 heads of 64, 64-page tables over the 2 GiB pool; one grid step
+    # takes all 20 heads of 8 pages, so 16 page operands of
+    # (1, 20, 16, 64) a tensor ride one call
+    yield ("paged_attention_gpt2l_cell", paged_attention,
+           [_sds((16, 20, 1, 64), bf16), _sds((729, 20, 16, 64), bf16),
+            _sds((729, 20, 16, 64), bf16), _sds((16, 64), i32),
+            _sds((16,), i32)])
+
     # -- the s>1 query-block generalization (ISSUE 13): the speculative
     # verify step reads a 4-token block (draft_len 3 + 1 pending) per
     # slot through the SAME kernel — the per-row causal band
