@@ -6,7 +6,8 @@ difference) for the first gradient as the optimizer got it and for the
 parameters' change after the followed steps, each measured against the
 reference's norm of that leaf or of the median leaf, whichever is larger;
 for the gradient also the 95th-percentile leaf's gap, the number that
-separates bfloat16 from the float8 control.
+separates bfloat16 from the float8 control at 8 rows a step, and for the
+change the median moving leaf's gap, which separates them at 32 rows.
 Leaves whose reference gradient is under a thousandth of the median leaf's
 move under Adam by round-off alone and are left out of the change.
 
@@ -77,6 +78,8 @@ def train_numbers(program: dict, reference: dict) -> Dict[str, float]:
         program["change_norms"], reference["change_norms"], moving)
     out["grad_norm_p95_gap"] = quantile_leaf_gap(
         program["grad_norms"], reference["grad_norms"], 0.95)
+    out["change_norm_p50_gap"] = quantile_leaf_gap(
+        program["change_norms"], reference["change_norms"], 0.5, moving)
     return out
 
 

@@ -79,6 +79,19 @@ def enable_compile_cache() -> str:
     return where
 
 
+class Phases(dict):
+    """Seconds of a run's phases by the host's clock, for its notes:
+    ``mark(name)`` closes the phase that began at the last mark."""
+
+    def __init__(self):
+        super().__init__()
+        self._t = time.perf_counter()
+
+    def mark(self, name: str) -> None:
+        now = time.perf_counter()
+        self[name], self._t = round(now - self._t, 3), now
+
+
 def annotate(name: str):
     """A host span in the profiler's trace, ``bench:<name>``."""
     import jax

@@ -35,9 +35,10 @@ def find_xplane(trace_dir: str) -> str:
     return found[-1]
 
 
-def load(path: str, *, host_prefix: str = "bench:") -> Trace:
+def load(path: str, *, host_prefix=("bench:", "pump:")) -> Trace:
     """Device planes whole; of the host plane only the spans whose name
-    starts with ``host_prefix`` (the benchmark's own annotations)."""
+    starts with one of ``host_prefix``: the benchmark's own annotations and
+    the phases of the serving pump."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
@@ -113,12 +114,21 @@ def op_seconds(trace: Trace, pattern: str) -> Tuple[float, int]:
 
 
 _OPCODE = re.compile(r"\s([a-z][a-z\-]*)\(")
+# ``kernel_metadata={"kernel":"flash_fwd"}`` in a labelled kernel's text (the
+# quotes may come escaped); ``kernel_metadata`` itself does not match
+_KERNEL_LABEL = re.compile(r"kernel\W{1,8}([A-Za-z0-9_]+)")
 
 
 def short_name(op: str) -> str:
     """An op's name on the v5e is its whole HLO instruction, thousands of
-    characters for a concatenate: keep "<result name without its number>
-    <opcode>", e.g. ``attention custom-call``, ``fusion fusion``."""
+    characters for a concatenate: keep the kernel's label where the text
+    carries one (``flash_fwd``, whatever module the result is named after),
+    else "<result name without its number> <opcode>", e.g. ``fusion
+    fusion``, ``attention custom-call`` (a kernel from before the labels,
+    or a name cut short of its label)."""
+    label = _KERNEL_LABEL.search(op)
+    if label:
+        return label.group(1)
     head, sep, rest = op.partition(" = ")
     if not sep:
         return op[:80]

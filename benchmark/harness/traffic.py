@@ -130,6 +130,15 @@ class ServeTraffic:
                 index += 1
 
 
+def train_hyper(mix: dict) -> dict:
+    """The optimizer's hyper-parameters of a training mix, by the names the
+    plain reference's LAMB step reads."""
+    return {"lr": mix["lr"], "beta1": mix["betas"][0],
+            "beta2": mix["betas"][1], "eps": mix["eps"],
+            "weight_decay": mix["weight_decay"],
+            "max_grad_norm": mix["max_grad_norm"]}
+
+
 def train_batches(mix: dict, vocab: int, type_vocab: int, seed: int,
                   count: int) -> List[Dict[str, np.ndarray]]:
     """``count`` host batches of one training mix; every row differs.

@@ -22,6 +22,7 @@ sys.path.insert(0, ROOT)
 
 def main(cell_name: str, seconds: float, n_program: int,
          n_control: int) -> None:
+    from benchmark import families
     from benchmark import run as bench_run
     from benchmark.harness import runtime
     from benchmark.runners import serve
@@ -44,8 +45,8 @@ def main(cell_name: str, seconds: float, n_program: int,
                 "run_s": time.perf_counter() - t0}
         if n < n_control:
             t1 = time.perf_counter()
-            line["control_fp8"] = serve.judge(cell.config, seed,
-                                              ran["samples"], "fp8")
+            line["control_fp8"] = families.load(cell.config).judge(
+                cell.config, seed, ran["samples"], "fp8")
             line["control_s"] = time.perf_counter() - t1
         print(json.dumps(line), flush=True)
         out.write(json.dumps(line) + "\n")

@@ -1,12 +1,14 @@
 #!/usr/bin/env python3
-"""Readings for the training cell's limits, in one process on the chip.
+"""Readings for a training cell's limits, in one process on the cell's chips.
 
 For each seed: the program's first steps against the reference (the lower
 reading), then in the reference's own place the control (float8 matmul
-inputs) and the fault "half of the batch left out" (the upper readings).
-One JSON line per seed on standard output and in ``chiprun_out/``.
+inputs) and the fault "half of the batch left out", and on a cell of several
+chips the fault "the exchange between chips left out" planted in the program
+(the upper readings).  One JSON line per seed on standard output and in
+``chiprun_out/``.
 
-    python benchmark/proof/train_readings.py <cell> <seeds for the program> <seeds for control and fault>
+    python benchmark/proof/train_readings.py <cell> <seeds for the program> <seeds for control and faults> [first seed]
 """
 
 import gc
@@ -20,50 +22,68 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path.insert(0, ROOT)
 
 
-def main(cell_name: str, n_program: int, n_upper: int) -> None:
-    import jax
-
+def main(cell_name: str, n_program: int, n_upper: int,
+         first_seed: int = 4_100_000_000) -> None:
+    from apex_tpu.parallel import DistributedDataParallel
+    from benchmark import families
     from benchmark import run as bench_run
-    from benchmark.harness import compare, runtime, traffic
+    from benchmark.harness import runtime
     from benchmark.runners import train
 
-    runtime.require_tpu(1)
-    runtime.enable_compile_cache()
     cell = bench_run.Cell.load(cell_name)
+    devices = runtime.require_tpu(cell.chips)
+    runtime.enable_compile_cache()
     cfg, mix = cell.config, cell.mix
+    family = families.load(cfg)
+    beta1 = mix["betas"][0]
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    out = open(os.path.join(ROOT, "chiprun_out", "train_readings.jsonl"), "w")
-    grad_step = None
+    out = open(os.path.join(ROOT, "chiprun_out",
+                            cell_name + ".readings.jsonl"), "w")
+    grad_step, alone_step = None, None
+    exchange = DistributedDataParallel.allreduce_gradients
     for n in range(n_program):
-        seed = 4_100_000_000 + 7919 * n
+        seed = first_seed + 7919 * n
         t0 = time.perf_counter()
-        batches = traffic.train_batches(mix, cfg["held_vocab"],
-                                        cfg["type_vocab_size"], seed,
-                                        train.FOLLOWED)
-        prog = train.Program(cfg, mix, seed, grad_step)
+        batches = family.batches(cfg, mix, seed, train.FOLLOWED)
+        prog = train.Program(cfg, mix, seed, devices, grad_step)
         grad_step = prog.grad_step
-        seen = train.first_steps(prog, batches, mix["betas"][0])
+        seen = train.first_steps(prog, batches, beta1)
         del prog
         gc.collect()
+        alone = None
+        if n < n_upper and cell.chips > 1:
+            # every chip steps on its own gradient
+            DistributedDataParallel.allreduce_gradients = \
+                lambda self, grads: grads
+            try:
+                prog = train.Program(cfg, mix, seed, devices, alone_step)
+                alone_step = prog.grad_step
+                alone = train.first_steps(prog, batches, beta1)
+            finally:
+                DistributedDataParallel.allreduce_gradients = exchange
+            del prog
+            gc.collect()
         t1 = time.perf_counter()
-        ref = train.follow_reference(cfg, mix, seed, batches)
+        ref = family.follow(cfg, mix, seed, batches)
         t2 = time.perf_counter()
-        line = {"seed": seed, "program": compare.train_numbers(seen, ref),
+        line = {"seed": seed, "program": train.numbers_of(seen, ref),
                 "program_s": t1 - t0, "reference_s": t2 - t1,
                 "losses": ref["losses"],
                 "leaves": {"reference": [ref["grad_norms"],
                                          ref["change_norms"]],
                            "program": [seen["grad_norms"],
                                        seen["change_norms"]]}}
+        if alone is not None:
+            line["fault_no_exchange"] = train.numbers_of(alone, ref)
         if n < n_upper:
-            control = train.follow_reference(cfg, mix, seed, batches, "fp8")
-            line["control_fp8"] = compare.train_numbers(control, ref)
+            control = family.follow(cfg, mix, seed, batches, "fp8")
+            line["control_fp8"] = train.numbers_of(control, ref)
             line["leaves"]["control_fp8"] = [control["grad_norms"],
                                              control["change_norms"]]
             half = [{k: v[:v.shape[0] // 2] for k, v in b.items()}
                     for b in batches]
-            halved = train.follow_reference(cfg, mix, seed, half)
-            line["fault_half_batch"] = compare.train_numbers(halved, ref)
+            halved = family.follow(cfg, mix, seed, half)
+            line["fault_half_batch"] = train.numbers_of(halved, ref)
             line["leaves"]["fault_half_batch"] = [halved["grad_norms"],
                                                   halved["change_norms"]]
             line["upper_s"] = time.perf_counter() - t2
@@ -75,4 +95,5 @@ def main(cell_name: str, n_program: int, n_upper: int) -> None:
 
 
 if __name__ == "__main__":
-    main(sys.argv[1], int(sys.argv[2]), int(sys.argv[3]))
+    main(sys.argv[1], int(sys.argv[2]), int(sys.argv[3]),
+         *(int(a) for a in sys.argv[4:5]))

@@ -178,10 +178,32 @@ def _programs(cfg_items: tuple, hp_items: tuple, precision: str):
     return grad, update
 
 
-def train(params, batches, cfg: dict, hp: dict, precision: str = "float32"):
+_add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b), donate_argnums=0)
+
+
+def _blockwise(grad, params, batch, block_rows: int):
+    """Loss and gradient of ``batch`` as the mean over its blocks of
+    ``block_rows`` rows, one block on the device at a time.  Equal to the
+    whole batch's where every row predicts as many positions (the MLM loss
+    is a mean over predicted positions, the NSP loss over rows)."""
+    rows = batch["input_ids"].shape[0]
+    if rows % block_rows:
+        raise ValueError(f"{rows} rows do not split into blocks of "
+                         f"{block_rows}")
+    total = None
+    for start in range(0, rows, block_rows):
+        part = grad(params, {k: v[start:start + block_rows]
+                             for k, v in batch.items()})
+        total = part if total is None else _add(total, part)
+    return jax.tree.map(lambda x: x * (block_rows / rows), total)
+
+
+def train(params, batches, cfg: dict, hp: dict, precision: str = "float32",
+          block_rows: int = None):
     """Follow ``len(batches)`` steps from ``params``.  Returns the losses,
     the per-leaf norms of the first (clipped) gradient and of the
-    parameters' change after the last step."""
+    parameters' change after the last step.  With ``block_rows`` a batch's
+    gradient is accumulated over blocks of that many rows."""
     grad, update = _programs(tuple((k, cfg[k]) for k in _KEYS),
                              tuple(sorted(hp.items())), precision)
     start = params
@@ -189,7 +211,10 @@ def train(params, batches, cfg: dict, hp: dict, precision: str = "float32"):
     v = {k: jnp.zeros_like(x) for k, x in params.items()}
     losses, first = [], None
     for n, batch in enumerate(batches, start=1):
-        loss, grads = grad(params, batch)
+        if block_rows and block_rows < batch["input_ids"].shape[0]:
+            loss, grads = _blockwise(grad, params, batch, block_rows)
+        else:
+            loss, grads = grad(params, batch)
         params, m, v, used = update(params, grads, m, v, step=n)
         losses.append(float(loss))
         if first is None:

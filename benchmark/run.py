@@ -146,9 +146,7 @@ def result_line(cell: Cell, ctx: Context, ran: dict,
             device["window_s"] = reading["window_s"]
             line["breakdown"] = {
                 "device_ops": trace_reduce.top_ops(trace, 10),
-                "idle_gaps": trace_reduce.idle_gaps(
-                    trace, 10, unattributed=reading.get("unattributed",
-                                                        "unattributed"))}
+                "idle_gaps": trace_reduce.idle_gaps(trace, 10)}
     else:
         values = dict(ran["metrics"], setup_s=ctx.setup_s)
         line["metrics"] = {

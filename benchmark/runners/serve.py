@@ -20,43 +20,28 @@ from typing import List
 
 import numpy as np
 
+from benchmark import families
 from benchmark.harness import flops, runtime, traffic, weights
 from benchmark.harness.stats import RequestTimes, serve_metrics
-from benchmark.references import gpt2 as reference
 
 CLOSE_S = 60.0          # how long cancelling what is in flight may take
 
 
-def program_config(cfg: dict):
-    import jax.numpy as jnp
-
-    from apex_tpu.models.gpt import GPTConfig
-
-    return GPTConfig(
-        vocab_size=cfg["held_vocab"], hidden_size=cfg["n_embd"],
-        num_layers=cfg["n_layer"], num_heads=cfg["n_head"],
-        max_position_embeddings=cfg["n_positions"],
-        layernorm_eps=cfg["layer_norm_epsilon"],
-        dtype=jnp.dtype(cfg["compute_dtype"]),
-        param_dtype=jnp.dtype(cfg["param_dtype"]))
-
-
 def build_engine(cfg: dict, mix: dict, seed: int):
-    """The model with weights from the seed and the engine of the mix."""
+    """The family's model with weights from the seed and the engine of the
+    mix."""
     import jax
     import jax.numpy as jnp
 
-    from apex_tpu.models.gpt import GPTModel
-    from apex_tpu.serving import PagedDecodeEngine, kv_pool
+    from apex_tpu.serving import PagedDecodeEngine
 
-    pcfg = program_config(cfg)
-    model = GPTModel(pcfg)
+    family = families.load(cfg)
+    model = family.model(cfg)
     like = jax.eval_shape(model.init, jax.random.PRNGKey(0),
                           jax.ShapeDtypeStruct((1, 8), jnp.int32))
     variables = {"params": weights.make_like(like["params"], seed)}
     eng = mix["engine"]
-    pages = 1 + eng["pool_bytes"] // kv_pool.page_bytes(pcfg,
-                                                        eng["page_size"])
+    pages = 1 + eng["pool_bytes"] // family.page_bytes(cfg, eng["page_size"])
     return PagedDecodeEngine(
         model, variables, num_slots=eng["num_slots"],
         page_size=eng["page_size"], num_pages=pages,
@@ -204,45 +189,28 @@ def sample_finished(client: Client, t0: float, t1: float, count: int,
     return [(done[i][1], done[i][2]) for i in picked]
 
 
-def judge(cfg: dict, seed: int, samples: List[tuple],
-          precision: str = "float32") -> dict:
-    import jax
-
-    t0 = time.perf_counter()
-    params = weights.make_weights(reference.param_table(cfg), seed)
-    jax.block_until_ready(params)
-    t1 = time.perf_counter()
-    out = reference.widest_gap(params, samples, cfg, precision=precision)
-    out["weights_s"], out["judge_s"] = t1 - t0, time.perf_counter() - t1
-    return out
-
-
 def run(ctx) -> dict:
     """One run of a serving cell; ``ctx`` is ``run.Context``."""
     from apex_tpu.serving import ServingFrontend
 
     cfg, mix, seed = ctx.config, ctx.mix, ctx.seed
+    family = families.load(cfg)
     arrival = mix["arrival"]
-    phases, t_phase = {}, time.perf_counter()
-
-    def phase(name: str) -> None:
-        nonlocal t_phase
-        now = time.perf_counter()
-        phases[name], t_phase = now - t_phase, now
+    phases = runtime.Phases()
 
     engine = build_engine(cfg, mix, seed)
     weight_bytes = flops.tree_bytes(engine.variables)
     frontend = ServingFrontend(engine)
     frontend.start()
-    phase("engine_s")
+    phases.mark("engine_s")
     try:
         client = Client(frontend, traffic.ServeTraffic(
-            mix, cfg["vocab_size"], seed))
+            mix, family.drawn_vocab(cfg), seed))
         warm_requests = warm_up(client, mix)
-        phase("warm_up_s")
+        phases.mark("warm_up_s")
         t_ramp = time.perf_counter()
         client.drive(arrival, t_ramp, t_ramp + mix["ramp_s"], started=False)
-        phase("ramp_s")
+        phases.mark("ramp_s")
 
         compiles0 = ctx.compiles.count
         ctx.window_opens()
@@ -268,29 +236,24 @@ def run(ctx) -> dict:
         # requests still in flight are cancelled, not failed: nothing of
         # theirs was due; one that failed inside the window is counted
         frontend.shutdown(CLOSE_S, mode="cancel")
-    phase("window_and_close_s")
+    phases.mark("window_and_close_s")
     measured = serve_metrics(client.records, t0, t1)
 
     # the engine and its pool leave the device before the reference runs
     del frontend, engine, client
     gc.collect()
-    judged = judge(cfg, seed, samples) if samples else \
+    judged = family.judge(cfg, seed, samples) if samples else \
         {"gap": float("inf"), "where": None, "tokens": 0}
-    phase("reference_s")
+    phases.mark("reference_s")
 
     numbers = {"served_logit_gap": judged["gap"],
                "failed_requests": float(measured["failed"])}
     reading = {
-        # the benchmark's spans end at submit: what the pump does in a gap
-        # is not named until the program carries spans of its own
-        "unattributed": "pump, unattributed",
         "weight_bytes": weight_bytes,
         "num_slots": mix["engine"]["num_slots"],
         "sync_every": mix["engine"]["sync_every"],
         "client": measured,
-        "forward_flops_per_token": flops.gpt_forward_flops_per_token(
-            hidden=cfg["n_embd"], layers=cfg["n_layer"],
-            vocab=cfg["held_vocab"]),
+        "forward_flops_per_token": family.forward_flops_per_token(cfg),
     }
     if traced is not None:
         reading.update(trace=traced.trace, window_s=traced.window_s,
@@ -315,7 +278,7 @@ def run(ctx) -> dict:
                   "judged_where": judged["where"],
                   "reference_weights_s": judged.get("weights_s"),
                   "reference_judge_s": judged.get("judge_s"),
-                  "phases": {k: round(v, 3) for k, v in phases.items()},
+                  "phases": dict(phases),
                   "lifetime_counters": {k: lifetime[k] for k in (
                       "decode_steps", "busy_slot_steps", "admitted",
                       "retired", "prefix_hits", "evicted_pages",

@@ -1,5 +1,15 @@
-"""Runner of the training cells: ``make_pretrain_step`` + ``FusedLAMB.step``
-as ``chip_smoke.train_loop`` drives them, one chip.
+"""Runner of the training cells: the family's grad step + ``FusedLAMB.step``
+as ``chip_smoke.train_loop`` drives them, on the cell's chips.
+
+The model is the configuration's family (``benchmark/families/``); the mesh
+is the cell's ``chips``.  On one chip the step is the family's jitted grad
+step and the optimizer's.  On several it is the data-parallel step of
+``chip_smoke.train_loop(mesh=...)``: a ``shard_map`` over ``data`` of the
+grad step, the loss averaged and the gradients exchanged by
+``DistributedDataParallel.allreduce_gradients``, parameters and optimizer
+state replicated, each host batch ``device_put`` split over ``data`` (plain
+``jit`` over a sharded batch will not do: Mosaic kernels are not partitioned
+automatically).
 
 Set-up builds one object (the compiled step with its optimizer state),
 drives it from the seed through its first ``FOLLOWED`` steps by the window's
@@ -16,66 +26,72 @@ from typing import Dict, List
 
 import numpy as np
 
-from benchmark.harness import compare, flops, runtime, traffic, weights
-from benchmark.references import bert as reference
+from benchmark import families
+from benchmark.harness import compare, runtime, traffic, weights
 
 FOLLOWED = 3            # steps the reference follows
 BLOCK_EVERY = 4         # steps between two reads of the clock
 POOL = 16               # host batches made from the seed, fed in turn
 
 
-def program_config(cfg: dict):
-    """The program's ``BertConfig`` for a configuration file."""
-    import jax.numpy as jnp
+def data_parallel(grad_step, model, mesh):
+    """The grad step over ``mesh``: every chip takes its rows of the batch,
+    the loss is averaged and the gradients exchanged.  The function keeps
+    the one-chip step's name, so that the trace's ``XLA Modules`` line reads
+    ``jit_loss_fn`` on any number of chips (``grad_step_ms.train``)."""
+    import jax
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
 
-    from apex_tpu.models import bert_large_config
+    from apex_tpu.mesh import DATA_AXIS
+    from apex_tpu.parallel import DistributedDataParallel
 
-    return bert_large_config(
-        vocab_size=cfg["held_vocab"], hidden_size=cfg["hidden_size"],
-        num_layers=cfg["num_hidden_layers"],
-        num_heads=cfg["num_attention_heads"],
-        intermediate_size=cfg["intermediate_size"],
-        max_position_embeddings=cfg["max_position_embeddings"],
-        type_vocab_size=cfg["type_vocab_size"],
-        hidden_dropout=cfg["hidden_dropout_prob"],
-        attention_dropout=cfg["attention_probs_dropout_prob"],
-        layernorm_eps=cfg["layer_norm_eps"],
-        dtype=jnp.dtype(cfg["compute_dtype"]),
-        param_dtype=jnp.dtype(cfg["param_dtype"]))
+    ddp = DistributedDataParallel(model)
 
+    def loss_fn(params, batch, step):
+        loss, grads = grad_step(params, batch, step)
+        return lax.pmean(loss, DATA_AXIS), ddp.allreduce_gradients(grads)
 
-def hyper(mix: dict) -> dict:
-    return {"lr": mix["lr"], "beta1": mix["betas"][0],
-            "beta2": mix["betas"][1], "eps": mix["eps"],
-            "weight_decay": mix["weight_decay"],
-            "max_grad_norm": mix["max_grad_norm"]}
+    return jax.jit(jax.shard_map(
+        loss_fn, mesh=mesh, in_specs=(P(), P(DATA_AXIS), P()),
+        out_specs=P(), check_vma=False))
 
 
 class Program:
     """The timed path: the grad step and the optimizer with its state."""
 
-    def __init__(self, cfg: dict, mix: dict, seed: int, grad_step=None):
+    def __init__(self, cfg: dict, mix: dict, seed: int, devices,
+                 grad_step=None):
         import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-        from apex_tpu.models import BertForPreTraining, make_pretrain_step
+        from apex_tpu.mesh import DATA_AXIS
         from apex_tpu.optimizers import FusedLAMB
 
-        self.model = BertForPreTraining(program_config(cfg))
-        z = jax.ShapeDtypeStruct((1, 8), np.int32)
-        like = jax.eval_shape(self.model.init, jax.random.PRNGKey(0), z, z,
-                              z)["params"]
-        self.like = like
+        family = families.load(cfg)
+        self.model = family.model(cfg)
+        self.like = family.param_shapes(self.model)
         self.seed = seed
-        self.params = weights.make_like(like, seed)
-        hp = hyper(mix)
+        self.params = weights.make_like(self.like, seed)
+        self.mesh, self.feed, self._drift = None, None, None
+        if len(devices) > 1:
+            self.mesh = Mesh(np.array(devices), (DATA_AXIS,))
+            self.feed = NamedSharding(self.mesh, P(DATA_AXIS))
+            self.params = jax.device_put(self.params,
+                                         NamedSharding(self.mesh, P()))
+        hp = traffic.train_hyper(mix)
         self.opt = FusedLAMB(
             self.params, lr=hp["lr"], betas=(hp["beta1"], hp["beta2"]),
             eps=hp["eps"], weight_decay=hp["weight_decay"],
             max_grad_norm=hp["max_grad_norm"],
-            exclude_from_weight_decay=lambda n: not reference.decayed(n))
+            exclude_from_weight_decay=lambda n: not family.decayed(n))
         # a proof script that reads many seeds in one process hands the
         # jitted step on; a run builds it here
-        self.grad_step = grad_step or make_pretrain_step(self.model)
+        if grad_step is None:
+            grad_step = family.grad_step(self.model)
+            if self.mesh is not None:
+                grad_step = data_parallel(grad_step, self.model, self.mesh)
+        self.grad_step = grad_step
         self.steps = 0
 
     def step(self, host_batch: Dict[str, np.ndarray]):
@@ -85,7 +101,7 @@ class Program:
         import jax.numpy as jnp
 
         with runtime.annotate("device_put"):
-            batch = jax.device_put(host_batch)
+            batch = jax.device_put(host_batch, self.feed)
         with runtime.annotate("grad_step"):
             loss, grads = self.grad_step(self.params, batch,
                                          jnp.int32(self.steps))
@@ -124,6 +140,40 @@ class Program:
         return {k: float(v) for k, v in weights.table_named(norms).items()}
 
 
+    def replica_drift(self) -> float:
+        """The largest norm, over leaves and chips, of (the leaf on a chip -
+        the leaf on chip 0) against chip 0's norm of it.  The update is
+        deterministic, so with the exchange every chip steps alike and this
+        is 0; parameters claimed replicated are each chip's own buffer."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+        from jax.sharding import PartitionSpec as P
+
+        from apex_tpu.mesh import DATA_AXIS
+
+        def drift(params):
+            first = lax.axis_index(DATA_AXIS) == 0
+
+            def leaf(x):
+                x = x.astype(jnp.float32)
+                x0 = lax.psum(jnp.where(first, x, 0.0), DATA_AXIS)
+                gap = lax.pmax(jnp.sqrt(jnp.sum(jnp.square(x - x0))),
+                               DATA_AXIS)
+                return gap / jnp.maximum(jnp.sqrt(jnp.sum(x0 * x0)), 1e-30)
+
+            # a NaN is the largest: jnp.max hands it on and the verdict
+            # fails a number that is not finite
+            return jnp.max(jnp.stack(jax.tree.leaves(
+                jax.tree.map(leaf, params))))
+
+        if self._drift is None:     # read twice in a run, built once
+            self._drift = jax.jit(jax.shard_map(
+                drift, mesh=self.mesh, in_specs=P(), out_specs=P(),
+                check_vma=False))
+        return float(self._drift(self.params))
+
+
 def first_steps(prog: Program, batches: List[dict], beta1: float) -> dict:
     """Drives ``prog`` through the followed steps by the window's own call
     and feed; returns what the comparison reads of them."""
@@ -133,17 +183,18 @@ def first_steps(prog: Program, batches: List[dict], beta1: float) -> dict:
         if n == 0:
             seen["grad_norms"] = prog.first_gradient_norms(beta1)
     seen["change_norms"] = prog.change_norms()
+    if prog.mesh is not None:
+        seen["replica_drift"] = prog.replica_drift()
     return seen
 
 
-def follow_reference(cfg: dict, mix: dict, seed: int, batches: List[dict],
-                     precision: str = "float32") -> dict:
-    import jax.numpy as jnp
-
-    params = weights.make_weights(reference.param_table(cfg), seed)
-    return reference.train(
-        params, [{k: jnp.asarray(v) for k, v in b.items()} for b in batches],
-        cfg, hyper(mix), precision)
+def numbers_of(seen: dict, ref: dict) -> Dict[str, float]:
+    """The numbers of the followed steps: the comparison with the reference,
+    and across chips the drift between the replicas."""
+    numbers = compare.train_numbers(seen, ref)
+    if "replica_drift" in seen:
+        numbers["replica_drift"] = seen["replica_drift"]
+    return numbers
 
 
 def run(ctx) -> dict:
@@ -151,12 +202,20 @@ def run(ctx) -> dict:
     import jax
 
     cfg, mix, seed = ctx.config, ctx.mix, ctx.seed
+    family = families.load(cfg)
+    chips = len(ctx.devices)
+    phases = runtime.Phases()
+    if mix["batch"] % chips:
+        raise ValueError(f"batch {mix['batch']} does not split over {chips} "
+                         f"chips")
     tokens_per_step = mix["batch"] * mix["seq_len"]
-    batches = traffic.train_batches(mix, cfg["held_vocab"],
-                                    cfg["type_vocab_size"], seed, POOL)
-    prog = Program(cfg, mix, seed)
+    batches = family.batches(cfg, mix, seed, POOL)
+    prog = Program(cfg, mix, seed, ctx.devices)
+    jax.block_until_ready(prog.opt.master)
+    phases.mark("program_s")
     seen = first_steps(prog, batches, mix["betas"][0])
     jax.block_until_ready(prog.params)
+    phases.mark("first_steps_s")
 
     compiles0 = ctx.compiles.count
     traced = None
@@ -186,28 +245,25 @@ def run(ctx) -> dict:
     last_loss = float(loss)
     compiles = ctx.compiles.count - compiles0
     peak_bytes = runtime.memory_peak_bytes(ctx.devices)
+    if prog.mesh is not None:
+        # the replicas after every step of the window too
+        seen["replica_drift"] = max(seen["replica_drift"],
+                                    prog.replica_drift())
+
+    phases.mark("window_s")
 
     # the program's state leaves the device before the reference runs
     del prog, loss
     gc.collect()
-    t_ref = time.perf_counter()
-    ref = follow_reference(cfg, mix, seed, batches[:FOLLOWED])
-    numbers = compare.train_numbers(seen, ref)
-    reference_s = time.perf_counter() - t_ref
+    ref = family.follow(cfg, mix, seed, batches[:FOLLOWED])
+    numbers = numbers_of(seen, ref)
+    phases.mark("reference_s")
     numbers["last_loss_finite"] = 0.0 if np.isfinite(last_loss) else 1.0
 
-    flops_per_token = flops.bert_train_flops_per_token(
-        hidden=cfg["hidden_size"], intermediate=cfg["intermediate_size"],
-        layers=cfg["num_hidden_layers"], vocab=cfg["held_vocab"],
-        seq_len=mix["seq_len"], mlm_k=mix["mlm_per_seq"])
     reading = {
-        "flops_per_token": flops_per_token,
+        "flops_per_token": family.train_flops_per_token(cfg, mix),
         "tokens_per_step": tokens_per_step,
-        "shapes": {"batch": mix["batch"], "seq_len": mix["seq_len"],
-                   "heads": cfg["num_attention_heads"],
-                   "head_dim": cfg["hidden_size"]
-                   // cfg["num_attention_heads"],
-                   "layers": cfg["num_hidden_layers"]},
+        "shapes": family.shapes(cfg, mix, chips),
     }
     if traced is not None:
         reading.update(trace=traced.trace, window_s=traced.window_s,
@@ -218,6 +274,8 @@ def run(ctx) -> dict:
         "attempted": steps, "failed": 0, "numbers": numbers,
         "memory_peak_bytes": peak_bytes, "reading": reading,
         "notes": {"steps": steps, "window_compiles": compiles, "elapsed_s": elapsed,
-                  "reference_s": reference_s, "last_loss": last_loss,
+                  "reference_s": phases["reference_s"],
+                  "phases": dict(phases),
+                  "last_loss": last_loss,
                   "first_losses": seen["losses"]},
     }

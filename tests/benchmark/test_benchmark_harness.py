@@ -13,6 +13,7 @@ import pytest
 
 from bench_fixtures import ROOT, tiny_root
 
+from benchmark import families
 from benchmark import run as bench_run
 from benchmark.harness import (compare, flops, peaks, stats, trace_reduce,
                                traffic, weights)
@@ -265,6 +266,97 @@ def test_trace_reduce_on_a_slice_recorded_on_the_v5e():
                for n, _, _ in ops)
 
 
+def test_short_name_groups_a_labelled_kernel_by_its_label():
+    with open(os.path.join(DATA, "trace_labelled.json"),
+              encoding="utf-8") as f:
+        doc = json.load(f)
+    ops = doc["train"]["trace"]["/device:TPU:0"]["XLA Ops"]
+    labelled = [n for n, _, _ in ops if "kernel_metadata" in n]
+    names = {trace_reduce.short_name(n) for n in labelled}
+    assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "l2norm",
+            "lamb_phase1", "lamb_phase2"} <= names
+    assert not any(" " in n or n == "metadata" for n in names)
+    # an op without a label keeps "<result> <opcode>"
+    plain = [n for n, _, _ in ops if "kernel_metadata" not in n]
+    assert plain and all(len(trace_reduce.short_name(n).split()) == 2
+                         for n in plain)
+    top = dict(trace_reduce.top_ops(doc["train"]["trace"], 10))
+    by_hand = sum(d for n, _, d in ops if '"flash_fwd"' in n) / 1e9
+    assert top["flash_fwd"] == pytest.approx(by_hand)
+    serve = dict(trace_reduce.top_ops(doc["serve"]["trace"], 10))
+    assert "paged_attention" in serve
+    assert not any(k.startswith("layer_") for k in serve)
+
+
+def test_load_keeps_the_benchmarks_spans_and_the_pumps():
+    import inspect
+
+    prefixes = inspect.signature(trace_reduce.load).parameters[
+        "host_prefix"].default
+    assert prefixes == ("bench:", "pump:")
+    assert "pump:admission".startswith(prefixes)
+    assert not "$core.py:42 backend_compile".startswith(prefixes)
+    trace = {"/device:TPU:0": {"XLA Ops": [["%a = f32[] add()", 0, 100],
+                                           ["%b = f32[] add()", 10_100, 100]]},
+             "/host:CPU": {"pump": [["pump:admission", 50, 10_100]],
+                           "main": [["bench:submit", 0, 200]]}}
+    assert trace_reduce.idle_gaps(trace, 10) == [["pump:admission", 1e-5]]
+
+
+def collective_trace():
+    with open(os.path.join(DATA, "trace_dp4_small.json"),
+              encoding="utf-8") as f:
+        return json.load(f)
+
+
+def test_allreduce_exposed_ms_on_a_small_four_chip_trace():
+    """Sync all-reduces whole, of an async pair the ``-done`` half, averaged
+    over the four planes, per traced step: against a sum made here."""
+    doc = collective_trace()
+    trace, steps = doc["trace"], doc["steps"]
+    planes = trace_reduce.device_planes(trace)
+    assert len(planes) == 4
+    counted = 0
+    kinds = set()
+    for plane in planes:
+        for name, _, dur in trace[plane]["XLA Ops"]:
+            if " all-reduce(" in name:
+                counted += dur
+                kinds.add(re.sub(r"[.\d]+$", "",
+                                 name.split(" = ")[0].lstrip("%")))
+    assert counted > 0 and kinds == {"all-reduce", "psum"}
+    # neither an op that takes a collective's result as an operand nor the
+    # half of an asynchronous pair that returns at once is read
+    from benchmark.layer_metrics.readers import collective_ms
+    rx = re.compile(collective_ms.pattern("all-reduce|all-reduce-done"))
+    assert not rx.search("%gte.1 = f32[] get-tuple-element((f32[], f32[8]) "
+                         "%all-reduce.99), index=0")
+    assert not rx.search("%all-reduce-start.3 = f32[8] all-reduce-start("
+                         "f32[8] %p), channel_id=1")
+    assert rx.search("%all-reduce-done.3 = f32[8] all-reduce-done(f32[8] "
+                     "%all-reduce-start.3)")
+    cell = bench_run.Cell.load("bert-large.pretrain-dp4")
+    reading = {"trace": trace, "steps": steps, "window_s": doc["window_s"],
+               "tokens": 1, "chips": 4, "flops_per_token": 1.0,
+               "shapes": {}, "peak": peaks.peak_for("TPU v5e")}
+    got = bench_run.read_layer_metrics(cell, reading)
+    assert got["allreduce_exposed_ms.train"] == {
+        "value": pytest.approx(counted / 4 / steps / 1e6, rel=1e-12),
+        "unit": "ms"}
+    assert got["allreduce_exposed_ms.train"]["value"] == pytest.approx(
+        doc["expected_allreduce_exposed_ms"], rel=1e-9)
+    # one chip has no collective: nothing to read, and the metric is not
+    # the one-chip cell's
+    one = {"/device:TPU:0": {"XLA Ops": [
+        e for e in trace[planes[0]]["XLA Ops"]
+        if " all-reduce(" not in e[0]]}}
+    assert collective_ms.read(dict(reading, trace=one),
+                              "all-reduce|all-reduce-done") is None
+    assert "allreduce_exposed_ms.train" not in [
+        m["name"] for m in bench_run.Cell.load(
+            "bert-large.pretrain-seq512").per_layer]
+
+
 def test_readers_return_nothing_where_there_is_nothing_to_read():
     cell = bench_run.Cell.load("bert-large.pretrain-seq512")
     assert bench_run.read_layer_metrics(cell, {
@@ -361,7 +453,16 @@ def test_every_name_leads_to_its_files_under_paths():
             cfg = json.load(f)
         assert cfg["source"] == c["source"]
         assert sorted(cfg["reduced"]) == sorted(c["reduced"])
-        assert cfg["runner"] in ("train", "serve")
+        # the runner is a file of the benchmark's, the model a family found
+        # by the source's own model_type, which exports that runner's contract
+        assert os.path.isfile(os.path.join(
+            ROOT, "benchmark", "runners", cfg["runner"] + ".py"))
+        assert os.path.isfile(os.path.join(
+            ROOT, "benchmark", "families", cfg["model_type"] + ".py"))
+        family = families.load(cfg)
+        missing = [name for name in families.CONTRACT[cfg["runner"]]
+                   if not callable(getattr(family, name, None))]
+        assert not missing, (cfg["model_type"], missing)
     for w in b["workloads"]:
         cell = bench_run.Cell.load(w["name"])
         assert cell.mix["limits"] and cell.per_layer and cell.end_to_end
@@ -374,9 +475,69 @@ def test_every_name_leads_to_its_files_under_paths():
     assert not any(w.startswith("/") or ".." in w for w in b["command"])
 
 
+def family_modules():
+    folder = os.path.join(ROOT, "benchmark", "families")
+    return sorted(f[:-3] for f in os.listdir(folder)
+                  if f.endswith(".py") and f != "__init__.py")
+
+
+@pytest.mark.parametrize("model_type", family_modules())
+def test_every_family_exports_the_contract_of_a_runner(model_type):
+    """A family serves the runner(s) whose whole contract it exports, and
+    at least one; some configuration of the benchmark names it."""
+    family = families.load({"model_type": model_type})
+    serves = [runner for runner, names in families.CONTRACT.items()
+              if all(callable(getattr(family, n, None)) for n in names)]
+    assert serves, f"{model_type} exports no runner's whole contract"
+    for runner in serves:
+        assert os.path.isfile(os.path.join(ROOT, "benchmark", "runners",
+                                           runner + ".py"))
+    used = [bench_run.load_json(os.path.join(ROOT, c["file"]))
+            for c in bench()["configs"]]
+    assert any(c["model_type"] == model_type and c["runner"] in serves
+               for c in used)
+
+
+def test_the_runners_and_run_py_name_no_model():
+    """The model lives in ``families/`` and ``references/``: outside comments
+    and docstrings the runners name no family and none of its keys."""
+    import io
+    import tokenize
+
+    rx = re.compile(r"gpt2|GPT|bert|Bert|n_embd")
+    for rel in ("run.py", "runners/serve.py", "runners/train.py"):
+        with open(os.path.join(ROOT, "benchmark", rel),
+                  encoding="utf-8") as f:
+            source = f.read()
+        for tok in tokenize.generate_tokens(io.StringIO(source).readline):
+            if tok.type == tokenize.COMMENT:
+                continue
+            if tok.type == tokenize.STRING and tok.string.startswith(
+                    ('"' * 3, "'" * 3)):
+                continue
+            assert not rx.search(tok.string), (rel, tok.start, tok.string)
+
+
+def test_the_mix_gives_the_references_hyper_parameters():
+    mix = bench_run.Cell.load("bert-large.pretrain-dp4").mix
+    assert traffic.train_hyper(mix) == {
+        "lr": 1e-3, "beta1": 0.9, "beta2": 0.999, "eps": 1e-6,
+        "weight_decay": 0.01, "max_grad_norm": 1.0}
+    one = bench_run.Cell.load("bert-large.pretrain-seq512").mix
+    assert traffic.train_hyper(one) == traffic.train_hyper(mix)
+    # the same recipe on four chips: four times the rows, a block a chip
+    assert mix["batch"] == 4 * one["batch"] == 4 * mix["reference_block_rows"]
+    assert {k: v for k, v in mix.items() if k not in (
+        "batch", "recipe", "reference_block_rows", "limits",
+        "limits_from")} == {k: v for k, v in one.items() if k not in (
+            "batch", "recipe", "limits", "limits_from")}
+
+
 def test_a_later_pr_adds_config_cell_and_metric_as_new_files_only(tmp_path):
     root = tiny_root(tmp_path)
     for rel in ("benchmark/run.py", "benchmark/harness/traffic.py",
+                "benchmark/runners/serve.py", "benchmark/runners/train.py",
+                "benchmark/families/__init__.py",
                 "benchmark/workloads/gpt2-large.chat-closed16.json",
                 "benchmark/layer_metrics/step_mfu.serve.json"):
         with open(os.path.join(root, rel), "rb") as a, \

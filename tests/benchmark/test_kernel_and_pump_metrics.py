@@ -18,6 +18,7 @@ from benchmark.layer_metrics.readers import (counter_ratio,
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 TRAIN, SERVE = "bert-large.pretrain-seq512", "gpt2-large.chat-closed16"
+TRAIN_DP = "bert-large.pretrain-dp4"
 NEW_TRAIN = {"flash_fwd_ms.train", "flash_bwd_ms.train",
              "lamb_kernels_ms.train", "layer_norm_ms.train",
              "xentropy_ms.train"}
@@ -206,9 +207,13 @@ def test_new_entries_sit_at_the_end_with_the_layers_benchmark_json_had():
     bench = bench_run.load_json(os.path.join(bench_run.ROOT,
                                              "BENCHMARK.json"))
     names = [m["name"] for m in bench["per_layer"]]
-    assert len(names) == 24 and set(names[13:]) == NEW_TRAIN | NEW_SERVE
+    assert len(names) == 25 and set(names[13:24]) == NEW_TRAIN | NEW_SERVE
     old_layers = {m["layer"] for m in bench["per_layer"][:13]}
-    assert {m["layer"] for m in bench["per_layer"][13:]} <= old_layers
-    for m in bench["per_layer"][13:]:
-        cell = TRAIN if m["name"].endswith(".train") else SERVE
-        assert m["workloads"] == [cell]
+    assert {m["layer"] for m in bench["per_layer"][13:24]} <= old_layers
+    for m in bench["per_layer"][13:24]:
+        cells = [TRAIN, TRAIN_DP] if m["name"].endswith(".train") else [SERVE]
+        assert m["workloads"] == cells
+    # PR 29's, of a layer the benchmark had no metric of
+    assert names[24] == "allreduce_exposed_ms.train"
+    assert bench["per_layer"][24]["workloads"] == [TRAIN_DP]
+    assert bench["per_layer"][24]["layer"] not in old_layers
