@@ -82,9 +82,13 @@ def init_cache(config, batch: int, max_len: int, *, dtype=None):
     O(window) HBM for arbitrarily long decodes (the Mistral serving
     pattern); writes wrap modulo the window and the mask reconstructs each
     slot's absolute position."""
-    kv_heads = getattr(config, "num_kv_heads", config.num_heads)
-    kv_local = divide(kv_heads, config.tensor_parallel_size)
-    d = config.head_dim
+    # what a layer stores per token is the pool's statement, shared with
+    # the paged cache this buffer is scattered into (imported here: the
+    # serving package imports this module)
+    from apex_tpu.serving.kv_pool import layout_of
+
+    layout = layout_of(config)
+    kv_local = divide(layout.heads, config.tensor_parallel_size)
     dt = dtype if dtype is not None else resolve_compute_dtype(config.dtype)
     t_buf = max_len
     if getattr(config, "rolling_cache", False):
@@ -93,14 +97,15 @@ def init_cache(config, batch: int, max_len: int, *, dtype=None):
         # ALWAYS window-sized: a ring shorter than the window would
         # silently drop reachable positions once decoding passes its size
         t_buf = config.sliding_window
-    shape = (batch, kv_local, t_buf, d)
-    layers = [{"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+    shape = (batch, kv_local, t_buf, layout.stored)
+    layers = [{name: jnp.zeros(shape, dt) for name in layout.tensors}
               for _ in range(config.num_layers)]
     return {"layers": layers, "len": 0}
 
 
 def cache_max_len(cache) -> int:
-    return cache["layers"][0]["k"].shape[2]
+    lc = cache["layers"][0]
+    return (lc["k"] if "k" in lc else lc["latent"]).shape[2]
 
 
 def check_chunk_bounds(cache, s: int, max_position_embeddings: int, *,
@@ -217,8 +222,11 @@ def _append_quantized_pages(pages, scales, chunk, bt, t, ps, max_pages,
     return pages, scales
 
 
-def update_paged_layer_cache(lc, k_chunk, v_chunk):
-    """Write an ``(slots, kv, s, d)`` K/V chunk into the page pool at each
+def update_paged_layer_cache(lc, *chunks):
+    """Write one ``(slots, heads, s, d)`` chunk per stored tensor of the
+    layer's layout (``k_chunk, v_chunk`` for per-head K and V; the one
+    latent entry for a latent pool — ``kv_pool.layout_of``, in the order
+    the layer view holds its ``*_pages``) into the page pool at each
     slot's current length: slot ``b``'s chunk position ``i`` lands in page
     ``block_tables[b, (len_b + i) // page_size]`` at offset
     ``(len_b + i) % page_size``. Distinct slots own distinct pages and a
@@ -231,30 +239,36 @@ def update_paged_layer_cache(lc, k_chunk, v_chunk):
     the chunk's pages requantize-on-grow through
     :func:`_append_quantized_pages`, and the per-page scales ride the
     layer view back to the model's ``paged_attention`` call."""
-    ps = lc["k_pages"].shape[2]
+    # the pool's own names (imported here: the serving package imports
+    # this module)
+    from apex_tpu.serving.kv_pool import pool_key, pool_tensors, scale_key
+
+    names = pool_tensors(lc)
+    if len(names) != len(chunks):
+        raise ValueError(f"the layer stores {names}, got {len(chunks)} "
+                         f"chunk(s) to write")
+    pools = [pool_key(n) for n in names]
+    ps = lc[pools[0]].shape[2]
     max_pages = lc["block_tables"].shape[1]
-    s = k_chunk.shape[2]
+    s = chunks[0].shape[2]
     t = lc["len"]                                            # (slots,)
     out = dict(lc)
     if "k_scales" in lc:
-        qmax = quant.kv_qmax(lc["k_pages"].dtype)
-        out["k_pages"], out["k_scales"] = _append_quantized_pages(
-            lc["k_pages"], lc["k_scales"], k_chunk, lc["block_tables"],
-            t, ps, max_pages, qmax)
-        out["v_pages"], out["v_scales"] = _append_quantized_pages(
-            lc["v_pages"], lc["v_scales"], v_chunk, lc["block_tables"],
-            t, ps, max_pages, qmax)
+        qmax = quant.kv_qmax(lc[pools[0]].dtype)
+        for name, key, chunk in zip(names, pools, chunks):
+            out[key], out[scale_key(name)] = _append_quantized_pages(
+                lc[key], lc[scale_key(name)], chunk, lc["block_tables"],
+                t, ps, max_pages, qmax)
         return out
     pos = t[:, None] + jnp.arange(s, dtype=t.dtype)[None, :]  # (slots, s)
     page = jnp.take_along_axis(
         lc["block_tables"], jnp.clip(pos // ps, 0, max_pages - 1), axis=1)
     off = pos % ps
     # advanced-index dims lead: [page, :, off, :] scatters (slots, s)
-    # index pairs over (kv, d) tiles — values arrive position-major
-    out["k_pages"] = lc["k_pages"].at[page, :, off, :].set(
-        k_chunk.transpose(0, 2, 1, 3).astype(lc["k_pages"].dtype))
-    out["v_pages"] = lc["v_pages"].at[page, :, off, :].set(
-        v_chunk.transpose(0, 2, 1, 3).astype(lc["v_pages"].dtype))
+    # index pairs over (heads, d) tiles — values arrive position-major
+    for key, chunk in zip(pools, chunks):
+        out[key] = lc[key].at[page, :, off, :].set(
+            chunk.transpose(0, 2, 1, 3).astype(lc[key].dtype))
     return out
 
 

@@ -80,8 +80,8 @@ from apex_tpu.ops.paged_attention import pages_fetched
 from apex_tpu.serving import kv_pool
 from apex_tpu.serving.policy import PriorityDeadlinePolicy
 from apex_tpu.serving.scheduler import (_RUN_COUNTERS, _RUN_HISTOGRAMS,
-                                        Request, _bucket_match_pages,
-                                        prompt_bucket)
+                                        ROUTING_STATS, Request,
+                                        _bucket_match_pages, prompt_bucket)
 from apex_tpu.utils import metrics
 
 __all__ = ["ServingError", "ServingFrontend", "StreamHandle"]
@@ -353,14 +353,17 @@ class _Chunk:
     (an admission syncing on the pool materializes the chunk first) so
     ``decode_step_ms`` measures the chunk, not later host work."""
 
-    __slots__ = ("toks", "idx", "t0", "toks_np", "t_done")
+    __slots__ = ("toks", "idx", "t0", "toks_np", "t_done", "routed")
 
-    def __init__(self, toks, idx, t0):
+    def __init__(self, toks, idx, t0, routed=()):
         self.toks = toks
         self.idx = idx
         self.t0 = t0
         self.toks_np = None
         self.t_done = None
+        # per step what the routing did (ROUTING_STATS), device-side until
+        # the harvest reads it with the tokens; () without routed experts
+        self.routed = routed
 
 
 class ServingFrontend:
@@ -441,17 +444,19 @@ class ServingFrontend:
         self._bubble = metrics.gauge("pump.bubble_ms", labels=labels)
         self._last_ready: Optional[float] = None
         self._wait_s = 0.0
-        # bytes of K and V one context token costs across all layers and
-        # chips, as the pool holds them (feeds serving.kv_bytes_attended)
+        # bytes one context token costs across all layers and chips by the
+        # pool's own account of an entry (K and V per head, or one latent
+        # entry; feeds serving.kv_bytes_attended)
         tp = int(getattr(engine, "tp_world", 1))
         self._kv_token_bytes = (
             kv_pool.page_bytes(engine.cfg, engine.page_size,
                                kv_dtype=engine.kv_dtype)
             * tp / engine.page_size)
         # pages the decode kernel's DMAs move for a slot of a given
-        # length, as each chip's call tiles its local heads (feeds
+        # length, as each chip's call tiles its local heads (a latent
+        # pool: its one head of stored lanes; feeds
         # serving.kv_bytes_fetched)
-        pool = engine.cache["layers"][0]["k_pages"]
+        pool = kv_pool.a_pool(engine.cache)
         self._pages_fetched = functools.partial(
             pages_fetched,
             kv_heads=pool.shape[1] // tp, page_size=engine.page_size,
@@ -938,10 +943,10 @@ class ServingFrontend:
             self._inflight = _Chunk((toks, counts), self._chunk, t0)
         else:
             (eng.cache, self._tok, self._done, self._n_left, self._samp_i,
-             toks) = eng._step_fn()(eng.cache, eng.variables, self._tok,
-                                    self._done, self._n_left,
-                                    self._req_keys, self._samp_i)
-            self._inflight = _Chunk(toks, self._chunk, t0)
+             toks, routed) = eng._step_fn()(
+                eng.cache, eng.variables, self._tok, self._done,
+                self._n_left, self._req_keys, self._samp_i)
+            self._inflight = _Chunk(toks, self._chunk, t0, routed)
         self.peak_slots = max(self.peak_slots, len(self._active))
         self._occ.set(len(self._active))
 
@@ -1005,6 +1010,14 @@ class ServingFrontend:
             # preemption flush harvests mid-chunk and only its labeled
             # histogram keeps that wall time
             self._per_run["pump.dispatch_ready_ms"].append(chunk_ms)
+        if not isinstance(chunk.routed, tuple):
+            # the chunk's own account of its routing, ready with its tokens
+            routed = dict(zip(ROUTING_STATS,
+                              np.asarray(chunk.routed).sum(axis=0).tolist()))
+            for name, n in routed.items():
+                self._C[name].inc(n)
+            self._C["expert_bytes_read"].inc(
+                routed["experts_hit"] * eng.cfg.routed_expert_bytes)
         eos = eng.eos_token_id
         spec = isinstance(toks_np, tuple)
         if spec:

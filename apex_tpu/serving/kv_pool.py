@@ -18,6 +18,25 @@ models' incremental-decode path, recognized by its ``block_tables`` key):
       "free_top":     () int32,
     }
 
+THE CACHE-LAYOUT SEAM. What a layer stores per token, and how wide, is
+stated ONCE: :func:`layout_of` reads it from the model's config
+(:class:`CacheLayout`) and everything that shapes, sizes or walks a page —
+:func:`init_paged_cache`, :func:`page_bytes`, :func:`prefill_into_pages`,
+``models/generation.update_paged_layer_cache`` and ``init_cache``, the
+shared-admit gather, the host tier's tiles, the frontend's per-token bytes
+— goes through it. Two layouts exist: per-head K and V (above: tensors
+``k``/``v``, ``num_kv_heads`` heads of ``head_dim``), and ONE LATENT ENTRY
+per token (a config that states ``kv_latent_width``, MLA's normalized
+``c_kv`` beside the rotated ``k_rope``: one tensor ``latent``, one shared
+"head", its rows lane-padded in the pool to a multiple of 128 — 576 values
+stored 640 wide — so that ``ops.paged_latent_attention`` contracts whole
+lane tiles; the padding is zeros and is never counted as a token's bytes).
+A layer dict then holds ``{"latent_pages": (num_pages, 1, page_size,
+stored)}`` and every pool op below, which moves page NAMES and walks
+``for key in layer``, runs unchanged. A latent pool has one head, so it
+cannot shard over a tensor-parallel mesh, and it has no quantized form:
+both refuse with :class:`LatentPoolUnsupported` where the engine is built.
+
 ``alloc_pages`` tracks ownership, not occupancy: the scheduler allocates a
 request's worst case (``ceil((prompt+max_new)/page_size)``) up front, so a
 slot owns pages its length has not reached yet — free/defrag must treat
@@ -77,7 +96,8 @@ the scales shard along the same kv-head axis as the pages (dim 1).
 
 from __future__ import annotations
 
-from typing import Optional
+import dataclasses
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -86,18 +106,87 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from apex_tpu.amp.policy import resolve_compute_dtype
 from apex_tpu.mesh import MODEL_AXIS
-from apex_tpu.ops._dispatch import cdiv
+from apex_tpu.ops._dispatch import cdiv, round_up
 from apex_tpu.ops.quant import kv_cast, kv_qmax, resolve_kv_dtype
 from apex_tpu.transformer.utils import divide
 from apex_tpu.utils import metrics
 
 
+class LatentPoolUnsupported(ValueError):
+    """``latent-pool-unsupported``: a pool of latent entries was asked to
+    shard over a tensor-parallel mesh or to hold quantized pages."""
+
+    def __init__(self, what: str):
+        super().__init__(
+            f"latent-pool-unsupported: {what} — a latent pool holds one "
+            "entry per token for ALL heads (no kv-head axis to shard, no "
+            "per-(page, kv_head) scale to quantize by); serve it on one "
+            "chip per replica with kv_dtype=None")
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheLayout:
+    """What ONE layer stores per token: ``tensors`` names the stored
+    tensors (a contiguous prefill cache holds them under these names, the
+    pool under ``<name>_pages``, quantized scales under ``<name>_scales``),
+    each ``heads`` heads (all chips together) of ``width`` values, held in
+    pool rows of ``stored`` lanes."""
+
+    tensors: Tuple[str, ...]
+    heads: int
+    width: int
+    stored: int
+
+    @property
+    def latent(self) -> bool:
+        return self.tensors == ("latent",)
+
+
+def layout_of(config) -> CacheLayout:
+    """The one statement of the cache layout: a config with
+    ``kv_latent_width`` stores one latent entry of that width per token
+    (lane-padded in the pool); any other stores K and V per kv head."""
+    latent = getattr(config, "kv_latent_width", None)
+    if latent:
+        return CacheLayout(("latent",), 1, int(latent),
+                           round_up(int(latent), 128))
+    return CacheLayout(("k", "v"),
+                       getattr(config, "num_kv_heads", config.num_heads),
+                       config.head_dim, config.head_dim)
+
+
+def pool_key(name: str) -> str:
+    return name + "_pages"
+
+
+def scale_key(name: str) -> str:
+    return name + "_scales"
+
+
+def pool_tensors(layer) -> Tuple[str, ...]:
+    """The layout's tensor names as a layer dict (pool or per-layer view)
+    holds them, in the layout's order."""
+    return tuple(k[:-len("_pages")] for k in layer if k.endswith("_pages"))
+
+
+def _pool_shape(num_pages: int, heads: int, page_size: int, stored: int):
+    # the ONE place a page's shape is built
+    return (num_pages, heads, page_size, stored)
+
+
+def a_pool(cache):
+    """One of the first layer's pools: all of a cache's pools share one
+    shape and dtype."""
+    layer = cache["layers"][0]
+    return layer[pool_key(pool_tensors(layer)[0])]
+
+
 def page_size_of(cache) -> int:
-    return cache["layers"][0]["k_pages"].shape[2]
+    return a_pool(cache).shape[2]
 
 
 def num_pages_of(cache) -> int:
-    return cache["layers"][0]["k_pages"].shape[0]
+    return a_pool(cache).shape[0]
 
 
 def pages_for(length, page_size: int):
@@ -122,17 +211,28 @@ def cache_specs(config, axis_name: str = MODEL_AXIS, *, kv_dtype=None):
     ``k_scales``/``v_scales`` ``(num_pages, kv)`` entries, sharded along
     the same kv-head axis (dim 1) as the pages — per-chip scale bytes
     halve with the pool shard."""
-    kv = PartitionSpec(None, axis_name)
     rep = PartitionSpec()
-    layer = {"k_pages": kv, "v_pages": kv}
-    if kv_dtype is not None:
-        layer.update({"k_scales": kv, "v_scales": kv})
+    layer = _layer_specs(config, axis_name, kv_dtype)
     return {
         "layers": [dict(layer) for _ in range(config.num_layers)],
         "block_tables": rep, "len": rep, "alloc_pages": rep,
         "shared_pages": rep, "page_ref": rep, "free_stack": rep,
         "free_top": rep,
     }
+
+
+def _layer_specs(config, axis_name: str, kv_dtype) -> dict:
+    """One layer's (or one tile batch's) specs: every stored tensor, and
+    its scales where quantized, shards along the kv-head axis (dim 1)."""
+    layout = layout_of(config)
+    if layout.latent:
+        raise LatentPoolUnsupported(
+            "tensor-parallel specs were asked of a latent pool")
+    kv = PartitionSpec(None, axis_name)
+    layer = {pool_key(n): kv for n in layout.tensors}
+    if kv_dtype is not None:
+        layer.update({scale_key(n): kv for n in layout.tensors})
+    return layer
 
 
 def init_paged_cache(config, num_slots: int, *, num_pages: int,
@@ -175,8 +275,13 @@ def init_paged_cache(config, num_slots: int, *, num_pages: int,
                          f"{page_size}")
     if num_pages < 2:
         raise ValueError("num_pages must be >= 2 (page 0 is reserved)")
-    kv_heads = getattr(config, "num_kv_heads", config.num_heads)
-    kv_local = divide(kv_heads, config.tensor_parallel_size)
+    layout = layout_of(config)
+    if layout.latent and (quant is not None or mesh is not None
+                          or config.tensor_parallel_size != 1):
+        raise LatentPoolUnsupported(
+            f"kv_dtype={kv_dtype!r}" if quant is not None
+            else "a tensor-parallel mesh")
+    kv_local = divide(layout.heads, config.tensor_parallel_size)
     kv_dim = kv_local
     if mesh is not None:
         tp_world = dict(mesh.shape).get(axis_name)
@@ -190,7 +295,6 @@ def init_paged_cache(config, num_slots: int, *, num_pages: int,
                 f"{config.tensor_parallel_size} — the model's shard "
                 "shapes and the pool's head sharding would disagree")
         kv_dim = kv_local * tp_world            # the GLOBAL head count
-    d = config.head_dim
     if quant is not None:
         dt = quant[0]
     else:
@@ -198,8 +302,9 @@ def init_paged_cache(config, num_slots: int, *, num_pages: int,
             else resolve_compute_dtype(config.dtype)
     if max_pages_per_seq is None:
         max_pages_per_seq = cdiv(config.max_position_embeddings, page_size)
-    shape = (num_pages, kv_dim, page_size, d)
+    shape = _pool_shape(num_pages, kv_dim, page_size, layout.stored)
     scale_shape = (num_pages, kv_dim)
+    names = layout.tensors
     if mesh is not None and (abstract or not isinstance(mesh, Mesh)):
         # trace/AOT form: no buffers, just (sharded) shapes
         specs = cache_specs(config, axis_name, kv_dtype=kv_dtype)
@@ -209,16 +314,14 @@ def init_paged_cache(config, num_slots: int, *, num_pages: int,
             sharding = NamedSharding(mesh, spec) if stamp else None
             return jax.ShapeDtypeStruct(sh, dt_, sharding=sharding)
 
-        kv_spec = specs["layers"][0]["k_pages"]
+        kv_spec = specs["layers"][0][pool_key(names[0])]
         rep = PartitionSpec()
 
         def layer_sds():
-            lc = {"k_pages": sds(shape, dt, kv_spec),
-                  "v_pages": sds(shape, dt, kv_spec)}
+            lc = {pool_key(n): sds(shape, dt, kv_spec) for n in names}
             if quant is not None:
-                sc_spec = specs["layers"][0]["k_scales"]
-                lc["k_scales"] = sds(scale_shape, jnp.float32, sc_spec)
-                lc["v_scales"] = sds(scale_shape, jnp.float32, sc_spec)
+                lc.update({scale_key(n): sds(scale_shape, jnp.float32,
+                                             kv_spec) for n in names})
             return lc
 
         return {
@@ -234,11 +337,10 @@ def init_paged_cache(config, num_slots: int, *, num_pages: int,
         }
     def build():
         def layer_buf():
-            lc = {"k_pages": jnp.zeros(shape, dt),
-                  "v_pages": jnp.zeros(shape, dt)}
+            lc = {pool_key(n): jnp.zeros(shape, dt) for n in names}
             if quant is not None:
-                lc["k_scales"] = jnp.zeros(scale_shape, jnp.float32)
-                lc["v_scales"] = jnp.zeros(scale_shape, jnp.float32)
+                lc.update({scale_key(n): jnp.zeros(scale_shape, jnp.float32)
+                           for n in names})
             return lc
         layers = [layer_buf() for _ in range(config.num_layers)]
         return {
@@ -474,10 +576,7 @@ def tile_specs(config, axis_name: str = MODEL_AXIS, *, kv_dtype=None):
     so under TP each chip gathers/scatters its own head-shard and the
     host tier holds the pages at FULL head width (``serving/tp.py``
     maps the ``"tiles"`` compile role to this tree)."""
-    kv = PartitionSpec(None, axis_name)
-    layer = {"k_pages": kv, "v_pages": kv}
-    if kv_dtype is not None:
-        layer.update({"k_scales": kv, "v_scales": kv})
+    layer = _layer_specs(config, axis_name, kv_dtype)
     return [dict(layer) for _ in range(config.num_layers)]
 
 
@@ -600,7 +699,8 @@ def defrag(cache, extra_live=None):
 
 def prefill_into_pages(cache, slot, contig_layers, s0, *, start=0):
     """Scatter a CONTIGUOUS prefill cache (the models' flash-prefill
-    output: per-layer ``k``/``v`` of shape ``(1, kv, len_bucket, d)``)
+    output: per layer the layout's tensors, ``k``/``v`` or ``latent``, each
+    of shape ``(1, heads, len_bucket, stored)``)
     into slot ``slot``'s already-allocated pages, and set its length to
     ``s0`` (traced OK; positions past ``s0`` — prompt-bucket padding —
     scatter to the null page). Position ``p`` lands in table entry
@@ -615,7 +715,8 @@ def prefill_into_pages(cache, slot, contig_layers, s0, *, start=0):
     bt = cache["block_tables"]
     ps = page_size_of(cache)
     max_pages = bt.shape[1]
-    len_bucket = contig_layers[0]["k"].shape[2]
+    names = pool_tensors(cache["layers"][0])
+    len_bucket = contig_layers[0][names[0]].shape[2]
     pos = jnp.arange(len_bucket, dtype=jnp.int32)
     valid = jnp.logical_and(pos >= start, pos < s0)
     row = bt[slot]
@@ -654,20 +755,17 @@ def prefill_into_pages(cache, slot, contig_layers, s0, *, start=0):
 
     new_layers = []
     for lc, src in zip(cache["layers"], contig_layers):
-        k = src["k"][0].transpose(1, 0, 2)       # (len_bucket, kv, d)
-        v = src["v"][0].transpose(1, 0, 2)
-        if quantized:
-            kp, ks = scatter_q(lc["k_pages"], lc["k_scales"], k)
-            vp, vs = scatter_q(lc["v_pages"], lc["v_scales"], v)
-            new_layers.append({"k_pages": kp, "v_pages": vp,
-                               "k_scales": ks, "v_scales": vs})
-        else:
-            new_layers.append({
-                "k_pages": lc["k_pages"].at[phys, :, off, :].set(
-                    k.astype(lc["k_pages"].dtype)),
-                "v_pages": lc["v_pages"].at[phys, :, off, :].set(
-                    v.astype(lc["v_pages"].dtype)),
-            })
+        new = {}
+        for name in names:
+            x = src[name][0].transpose(1, 0, 2)  # (len_bucket, heads, d)
+            pages = lc[pool_key(name)]
+            if quantized:
+                new[pool_key(name)], new[scale_key(name)] = scatter_q(
+                    pages, lc[scale_key(name)], x)
+            else:
+                new[pool_key(name)] = pages.at[phys, :, off, :].set(
+                    x.astype(pages.dtype))
+        new_layers.append(new)
     out["layers"] = new_layers
     out["len"] = cache["len"].at[slot].set(jnp.asarray(s0, jnp.int32))
     return out
@@ -679,9 +777,11 @@ def prefill_into_pages(cache, slot, contig_layers, s0, *, start=0):
 
 def page_bytes(config, page_size: int = 16, *, kv_dtype=None,
                dtype=None) -> int:
-    """Pool bytes ONE page costs across all layers: the K and V page
-    tiles at the pool dtype, plus — quantized pools — their two f32
-    per-(page, kv_head) scale entries. The honest per-page denominator
+    """Pool bytes ONE page costs across all layers: what the layout's
+    tensors store for ``page_size`` tokens at the pool dtype (per-head K
+    and V tiles; a latent pool's one entry at its stated width — the lane
+    padding of a latent row is the pool's, not a token's), plus —
+    quantized pools — their f32 per-(page, kv_head) scale entries. The honest per-page denominator
     for capacity planning: at ``page_size=16, head_dim=64`` an int8 page
     costs ``(16*64 + 4) / (2*16*64) ≈ 0.502`` of a bf16 page, which is
     where the ~2x slot capacity comes from."""
@@ -691,13 +791,13 @@ def page_bytes(config, page_size: int = 16, *, kv_dtype=None,
     else:
         dt = dtype if dtype is not None \
             else resolve_compute_dtype(config.dtype)
-    kv_heads = getattr(config, "num_kv_heads", config.num_heads)
-    kv_local = divide(kv_heads, config.tensor_parallel_size)
-    per_tensor = kv_local * page_size * config.head_dim * \
+    layout = layout_of(config)
+    kv_local = divide(layout.heads, config.tensor_parallel_size)
+    per_tensor = kv_local * page_size * layout.width * \
         jnp.dtype(dt).itemsize
     if quant is not None:
         per_tensor += kv_local * jnp.dtype(jnp.float32).itemsize
-    return 2 * per_tensor * config.num_layers
+    return len(layout.tensors) * per_tensor * config.num_layers
 
 
 def max_slots_for_pool_bytes(config, pool_bytes: int, *,

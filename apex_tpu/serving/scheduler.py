@@ -59,6 +59,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import inspect
 import itertools
 from typing import Any, Optional, Sequence
 
@@ -77,6 +78,8 @@ from apex_tpu.ops.quant import resolve_kv_dtype
 from apex_tpu.serving import kv_pool
 from apex_tpu.serving.host_tier import HostPageTier
 from apex_tpu.serving.prefix_cache import PrefixCache
+from apex_tpu.transformer.moe.dropless import (ROUTING_COLLECTION,
+                                               ROUTING_STATS)
 
 #: run() counters in the instrument registry (``serving.<name>``); the
 #: per-run stats dict is the DELTA of these across the run — the registry
@@ -99,7 +102,14 @@ _RUN_COUNTERS = ("admitted", "retired", "decode_steps", "busy_slot_steps",
                  "pump_admission_seconds", "pump_blocked_seconds",
                  "pump_bubble_seconds", "queue_wait_seconds",
                  "first_token_wait_seconds", "kv_bytes_attended",
-                 "kv_bytes_fetched")
+                 "kv_bytes_fetched",
+                 # what the decode steps' routing did (models with routed
+                 # experts; ``transformer/moe/dropless.ROUTING_STATS``, in
+                 # its order, summed over expert layers and decode steps,
+                 # every row of a step counted, idle slots too) and the
+                 # expert weights the hit experts made the steps read
+                 "expert_pairs_routed", "experts_hit", "expert_load_max",
+                 "expert_bytes_read")
 
 #: per-request latency histograms (``serving.<name>``, log-bucketed ms)
 _RUN_HISTOGRAMS = ("ttft_ms", "tpot_ms", "queue_wait_ms", "decode_step_ms")
@@ -170,6 +180,23 @@ def prompt_bucket(s0: int, page_size: int, max_positions: int) -> int:
     return min(round_up(max(s0, 1), page_size), max_positions)
 
 
+def _logits_at(model, variables, ids, cache, last):
+    """``(logits [1, V] at chunk position ``last``, cache)`` of one
+    admission forward. An admit program reads ONE position's logits; a
+    model whose ``__call__`` takes ``logits_positions`` runs its head there
+    alone (at a 150k vocabulary the logits of a 16k-token prompt are 4.7 GB
+    that nothing reads), any other computes them all and is sliced, as
+    before."""
+    if "logits_positions" in inspect.signature(
+            type(model).__call__).parameters:
+        logits, cache = model.apply(
+            variables, ids, cache=cache,
+            logits_positions=jnp.reshape(last, (1, 1)))
+        return logits[:, 0], cache
+    logits, cache = model.apply(variables, ids, cache=cache)
+    return lax.dynamic_slice_in_dim(logits, last, 1, axis=1)[:, 0], cache
+
+
 def _bucket_match_pages(m: int) -> int:
     """Round a radix match depth DOWN to a power of two pages. Retirement
     inserts prompts AND generated tokens, so raw match depths take many
@@ -226,7 +253,7 @@ def make_shared_admit(model, *, t_start: int, tail_bucket: int,
         layers = []
         for pool_lc, lc in zip(cache["layers"], contig["layers"]):
             def gathered(pages, dst, scales=None):
-                # (m, kv, ps, d) page tiles -> the buffer's leading
+                # (m, heads, ps, d) page tiles -> the buffer's leading
                 # t_start positions; a quantized pool dequantizes by its
                 # gathered per-(page, kv_head) scales on the way out
                 kv, d = pages.shape[1], pages.shape[3]
@@ -237,21 +264,21 @@ def make_shared_admit(model, *, t_start: int, tail_bucket: int,
                     1, kv, t_start, d)
                 return dst.at[:, :, :t_start, :].set(
                     block.astype(dst.dtype))
-            quantized = "k_scales" in pool_lc
-            layers.append(
-                {"k": gathered(pool_lc["k_pages"][shared_row[:m]], lc["k"],
-                               pool_lc["k_scales"][shared_row[:m]]
-                               if quantized else None),
-                 "v": gathered(pool_lc["v_pages"][shared_row[:m]], lc["v"],
-                               pool_lc["v_scales"][shared_row[:m]]
-                               if quantized else None)})
+            # every tensor the layout stores (k and v, or one latent
+            # entry), each from its own pool
+            layers.append({
+                name: gathered(
+                    pool_lc[kv_pool.pool_key(name)][shared_row[:m]],
+                    lc[name],
+                    pool_lc[kv_pool.scale_key(name)][shared_row[:m]]
+                    if kv_pool.scale_key(name) in pool_lc else None)
+                for name in lc})
         # static len t_start: the tail chunk is a chunked continuation —
         # bounds check at trace time, dense cached attention over the
         # buffer (the flash path needs len 0, which the prefix occupies)
         contig = {"layers": layers, "len": t_start}
-        logits, contig = model.apply(variables, tail_ids, cache=contig)
-        last = lax.dynamic_slice_in_dim(logits, s0 - t_start - 1, 1,
-                                        axis=1)[:, 0]
+        last, contig = _logits_at(model, variables, tail_ids, contig,
+                                  s0 - t_start - 1)
         cache = kv_pool.alloc_slot_shared(cache, slot, shared_row, m,
                                           n_private)
         cache = kv_pool.prefill_into_pages(cache, slot, contig["layers"],
@@ -609,8 +636,7 @@ class PagedDecodeEngine:
         def admit(cache, variables, ids, s0, slot, n_pages, req_key,
                   samp0=0):
             contig = init_cache(self.cfg, 1, bucket)
-            logits, contig = model.apply(variables, ids, cache=contig)
-            last = lax.dynamic_slice_in_dim(logits, s0 - 1, 1, axis=1)[:, 0]
+            last, contig = _logits_at(model, variables, ids, contig, s0 - 1)
             cache = kv_pool.alloc_slot(cache, slot, n_pages)
             cache = kv_pool.prefill_into_pages(cache, slot,
                                                contig["layers"], s0)
@@ -730,7 +756,14 @@ class PagedDecodeEngine:
         def one_step(variables, carry, _):
             cache, tok, done, n_left, req_keys, samp_i = carry
             len_before = cache["len"]
-            logits, cache = model.apply(variables, tok[:, None], cache=cache)
+            # what the step's routing did rides back with its tokens: the
+            # layers sow one ROUTING_STATS vector each, summed here (a
+            # model without routed experts sows nothing: an empty tuple)
+            (logits, cache), sown = model.apply(
+                variables, tok[:, None], cache=cache,
+                mutable=[ROUTING_COLLECTION])
+            routed = jax.tree.leaves(sown)
+            routed = sum(routed[1:], routed[0]) if routed else ()
             # freeze done/idle slots' lengths: their forward ran (static
             # shapes) against the null-page sink, but their position must
             # not creep — unbounded growth would walk the position table
@@ -757,20 +790,20 @@ class PagedDecodeEngine:
             if eos is not None:
                 done = jnp.logical_or(done, nxt == eos)
             done = jnp.logical_or(done, n_left <= 0)
-            return (cache, nxt, done, n_left, req_keys, samp_i), nxt
+            return (cache, nxt, done, n_left, req_keys, samp_i), (nxt, routed)
 
         def step(cache, variables, tok, done, n_left, req_keys, samp_i):
             # greedy mode never reads req_keys; the carry layout stays
             # identical across greedy/sampled so both share one step
-            (cache, tok, done, n_left, _, samp_i), toks = lax.scan(
+            (cache, tok, done, n_left, _, samp_i), (toks, routed) = lax.scan(
                 functools.partial(one_step, variables),
                 (cache, tok, done, n_left, req_keys, samp_i),
                 None, length=self.sync_every)
-            return cache, tok, done, n_left, samp_i, toks
+            return cache, tok, done, n_left, samp_i, toks, routed
 
         self._step_jit = self._compile(
             step, ("cache", "vars") + ("rep",) * 5,
-            ("cache",) + ("rep",) * 5, _donate_cache())
+            ("cache",) + ("rep",) * 6, _donate_cache())
         return self._step_jit
 
     def _spec_step_fn(self):

@@ -72,3 +72,38 @@ class TopKRouter(nn.Module):
         logits = x.astype(jnp.float32) @ w.astype(jnp.float32).T
         probs = nn.softmax(logits, axis=-1)
         return probs, logits
+
+
+class SigmoidBiasTopKRouter(nn.Module):
+    """Sigmoid scores with a selection bias (DeepSeek-V3's ``noaux_tc``
+    with one group): ``s = sigmoid(x W^T)`` in fp32; the ``k`` largest of
+    ``s + b`` are chosen, ``b`` the learned ``e_score_correction_bias``
+    that balances load without an auxiliary loss; the chosen experts'
+    weights are ``s`` WITHOUT ``b``, renormalized to sum 1 where
+    ``norm_topk_prob``, times ``routed_scaling_factor``.
+
+    Returns ``(idx, weights)``: ``(tokens, k)`` int32 expert ids and their
+    fp32 weights. No capacity, no dropping: what consumes them decides how
+    the (token, expert) pairs are computed
+    (:mod:`apex_tpu.transformer.moe.dropless`).
+    """
+
+    num_experts: int
+    k: int
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    params_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        w = self.param("weight", nn.initializers.lecun_normal(),
+                       (self.num_experts, x.shape[-1]), self.params_dtype)
+        b = self.param("e_score_correction_bias", nn.initializers.zeros,
+                       (self.num_experts,), self.params_dtype)
+        scores = jax.nn.sigmoid(
+            x.astype(jnp.float32) @ w.astype(jnp.float32).T)
+        _, idx = lax.top_k(scores + b.astype(jnp.float32), self.k)
+        weights = jnp.take_along_axis(scores, idx, axis=-1)
+        if self.norm_topk_prob:
+            weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+        return idx, weights * self.routed_scaling_factor

@@ -38,6 +38,8 @@ CASE_NAMES = [
     "gpt2_small_decode128_int8",      # serving path: scan decode + W8A8
     "paged_attention_gpt2s_decode",   # paged serving: scalar-prefetch gather
     "paged_attention_gpt2l_cell",     # the benchmark's serving cell: 20 heads x 8 pages a step
+    "paged_latent_attention_glm_cell",  # the latent cell: 20 heads x one 640-lane entry
+    "moe_grouped_experts_glm_decode",   # ragged_dot -> XLA's %ragged-dot-* Mosaic calls
     "gpt2s_prefix_cached_admit",      # prefix cache: tail-only admission
     "gpt2s_paged_spec_verify",        # s=4 query block: spec verify step
     "gpt2s_chunked_prefill_step",     # chunked prefill through the s>1 path

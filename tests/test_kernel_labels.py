@@ -28,7 +28,8 @@ def _cases():
     the same kernel at the shape a benchmark cell calls it with, so a
     block shape that Mosaic's rules refuse fails here first."""
     from apex_tpu.ops import (flash_attention, flat_buffer, optim_kernels,
-                              paged_attention, softmax_cross_entropy)
+                              paged_attention, paged_latent_attention,
+                              softmax_cross_entropy)
     from apex_tpu.ops.group_norm import group_norm_nhwc
     from apex_tpu.ops.layer_norm import layer_norm
     from apex_tpu.ops.quant import fused_dequant_matmul
@@ -73,6 +74,17 @@ def _cases():
             _sds((16, 20, 1, 64), bf16), _sds((729, 20, 16, 64), bf16),
             _sds((729, 20, 16, 64), bf16), _sds((16, 64), i32),
             _sds((16,), i32)]),
+        "paged_latent_attention": (
+            functools.partial(paged_latent_attention, value_width=128), [
+                _sds((2, 4, 1, 256), bf16), _sds((9, 1, 16, 256), bf16),
+                _sds((2, 4), i32), _sds((2,), i32)]),
+        # the latent cell: 32 slots, 20 heads, one 640-lane entry a token
+        # (512 of them the values), 2048-page tables, a 4 GiB pool
+        "paged_latent_attention@glm-4.7-flash.docqa-closed32": (
+            functools.partial(paged_latent_attention, value_width=512), [
+                _sds((32, 20, 1, 640), bf16),
+                _sds((38837, 1, 16, 640), bf16), _sds((32, 2048), i32),
+                _sds((32,), i32)]),
         "layer_norm_fwd": (layer_norm, ln_args),
         "layer_norm_bwd": (jax.grad(sq_sum(layer_norm), (0, 1, 2)), ln_args),
         "xentropy_fwd": (softmax_cross_entropy, xent),
@@ -106,7 +118,8 @@ def test_the_cases_cover_the_closed_set():
 
 
 @pytest.mark.parametrize("case", _dispatch.KERNEL_LABELS + (
-    "paged_attention@gpt2-large.chat-closed16",))
+    "paged_attention@gpt2-large.chat-closed16",
+    "paged_latent_attention@glm-4.7-flash.docqa-closed32"))
 def test_label_reaches_the_lowered_program(case):
     """``metadata={"kernel": label}`` lands on the Mosaic custom call as
     ``kernel_metadata``; the benchmark's pattern finds it there."""
@@ -183,11 +196,52 @@ def _optimizer_step_name():
     return opt._jit_step.__name__
 
 
+def _data_parallel_step_name():
+    """The benchmark's own four-chip grad step (``runners/train.py``) keeps
+    the one-chip step's name, so ``grad_step_ms.train`` reads
+    ``jit_loss_fn`` on any number of chips."""
+    from jax.sharding import Mesh
+
+    from apex_tpu.mesh import DATA_AXIS
+    from apex_tpu.models import BertForPreTraining, make_pretrain_step
+    from apex_tpu.models.bert import bert_tiny_config
+    from benchmark.runners import train
+
+    model = BertForPreTraining(bert_tiny_config())
+    mesh = Mesh(np.asarray(jax.devices()[:2]), (DATA_AXIS,))
+    return train.data_parallel(make_pretrain_step(model), model,
+                               mesh).__name__
+
+
 @pytest.mark.parametrize("want,name_of", [
     ("loss_fn", _grad_step_name),
+    ("loss_fn", _data_parallel_step_name),
     ("_pure", _optimizer_step_name),
     ("admit", lambda: _tiny_engine()._admit_fn(16).__name__),
     ("step", lambda: _tiny_engine()._step_fn().__name__),
 ])
 def test_jitted_function_names_the_benchmark_depends_on(want, name_of):
     assert name_of() == want
+
+
+def test_routed_experts_keep_the_names_their_metrics_read():
+    """``moe_experts_ms.serve`` and ``moe_experts_roofline.serve`` find the
+    routed products by the name XLA gives ``jax.lax.ragged_dot`` on the TPU
+    (``%ragged-dot-*`` Mosaic calls: no label rides them), inside the
+    program region ``moe_experts``; the counter metrics read
+    ``_RUN_COUNTERS`` by name."""
+    from apex_tpu.serving.scheduler import _RUN_COUNTERS
+    from apex_tpu.transformer.moe import ROUTING_STATS, grouped_experts
+
+    assert ROUTING_STATS == ("expert_pairs_routed", "experts_hit",
+                             "expert_load_max")
+    assert set(ROUTING_STATS + ("expert_bytes_read", "kv_bytes_attended",
+                                "kv_bytes_fetched", "decode_steps")) \
+        <= set(_RUN_COUNTERS)
+    text = jax.jit(grouped_experts).trace(
+        _sds((8, 128), bf16), _sds((8, 2), i32), _sds((8, 2)),
+        _sds((4, 128, 256), bf16), _sds((4, 128, 256), bf16),
+        _sds((4, 256, 128), bf16)).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert text.count("ragged_dot") >= 3
+    assert "moe_experts" in text

@@ -305,6 +305,29 @@ def kernel_cases():
             _sds((729, 20, 16, 64), bf16), _sds((16, 64), i32),
             _sds((16,), i32)])
 
+    # -- the latent serving cell (glm-4.7-flash.docqa-closed32): 32 slots,
+    # 20 query heads over ONE shared 640-lane entry a token (512 of them
+    # the values), 2048-page tables (32,768 positions: the published
+    # 202,752 would put 1.6 MB of resolved table into 1 MiB of SMEM), the
+    # 4 GiB pool's 38,837 pages
+    from apex_tpu.ops.paged_latent_attention import paged_latent_attention
+
+    yield ("paged_latent_attention_glm_cell",
+           functools.partial(paged_latent_attention, value_width=512),
+           [_sds((32, 20, 1, 640), bf16), _sds((38837, 1, 16, 640), bf16),
+            _sds((32, 2048), i32), _sds((32,), i32)])
+
+    # -- the same cell's routed experts inside the decode chunk: 32 rows,
+    # top 4 of 64 experts of 2048 x 1536; jax.lax.ragged_dot has to come
+    # out as XLA's own Mosaic calls (%ragged-dot-*), which the benchmark's
+    # moe_experts_* metrics find by that name
+    from apex_tpu.transformer.moe import grouped_experts
+
+    yield ("moe_grouped_experts_glm_decode", grouped_experts,
+           [_sds((32, 2048), bf16), _sds((32, 4), i32), _sds((32, 4), f32),
+            _sds((64, 2048, 1536), bf16), _sds((64, 2048, 1536), bf16),
+            _sds((64, 1536, 2048), bf16)])
+
     # -- the s>1 query-block generalization (ISSUE 13): the speculative
     # verify step reads a 4-token block (draft_len 3 + 1 pending) per
     # slot through the SAME kernel — the per-row causal band
