@@ -53,6 +53,7 @@ from jax import lax
 from apex_tpu.amp.policy import resolve_compute_dtype
 from apex_tpu.mesh import MODEL_AXIS
 from apex_tpu.ops import quant
+from apex_tpu.ops.paged_write import paged_write
 from apex_tpu.transformer.tensor_parallel.mappings import (
     axis_is_bound as _axis_bound,
     gather_from_tensor_model_parallel_region,
@@ -229,16 +230,22 @@ def update_paged_layer_cache(lc, *chunks):
     the layer view holds its ``*_pages``) into the page pool at each
     slot's current length: slot ``b``'s chunk position ``i`` lands in page
     ``block_tables[b, (len_b + i) // page_size]`` at offset
-    ``(len_b + i) % page_size``. Distinct slots own distinct pages and a
-    slot's ``s`` positions are distinct ``(page, offset)`` pairs (callers
-    keep ``s <= page_size``, the paged kernel's own bound), so the scatter
-    indices never collide; an idle slot (block table row all null-page)
-    writes into the reserved page 0, which no live sequence ever reads.
+    ``(len_b + i) % page_size``. Distinct slots own distinct pages
+    (callers keep ``s <= page_size``, the paged kernel's own bound); an
+    idle slot (block table row all null-page) writes into the reserved
+    page 0, which no live sequence ever reads.
+
+    The write is ``ops.paged_write``: in place and ROW-MAJOR, as the
+    decode kernels read the pool. A scatter over the head axis here set
+    the layout of the whole program's pool, and a copy of every layer's
+    pool stood in front of every kernel of every step (docs/serving.md
+    "Page-pool layout").
 
     A QUANTIZED pool (``k_scales`` in the layer view) quantizes on write:
     the chunk's pages requantize-on-grow through
-    :func:`_append_quantized_pages`, and the per-page scales ride the
-    layer view back to the model's ``paged_attention`` call."""
+    :func:`_append_quantized_pages` (whole pages rewritten along the
+    leading axis: another write, and no cell's), and the per-page scales
+    ride the layer view back to the model's ``paged_attention`` call."""
     # the pool's own names (imported here: the serving package imports
     # this module)
     from apex_tpu.serving.kv_pool import pool_key, pool_tensors, scale_key
@@ -248,27 +255,17 @@ def update_paged_layer_cache(lc, *chunks):
         raise ValueError(f"the layer stores {names}, got {len(chunks)} "
                          f"chunk(s) to write")
     pools = [pool_key(n) for n in names]
-    ps = lc[pools[0]].shape[2]
-    max_pages = lc["block_tables"].shape[1]
-    s = chunks[0].shape[2]
-    t = lc["len"]                                            # (slots,)
     out = dict(lc)
     if "k_scales" in lc:
+        ps = lc[pools[0]].shape[2]
         qmax = quant.kv_qmax(lc[pools[0]].dtype)
         for name, key, chunk in zip(names, pools, chunks):
             out[key], out[scale_key(name)] = _append_quantized_pages(
                 lc[key], lc[scale_key(name)], chunk, lc["block_tables"],
-                t, ps, max_pages, qmax)
+                lc["len"], ps, lc["block_tables"].shape[1], qmax)
         return out
-    pos = t[:, None] + jnp.arange(s, dtype=t.dtype)[None, :]  # (slots, s)
-    page = jnp.take_along_axis(
-        lc["block_tables"], jnp.clip(pos // ps, 0, max_pages - 1), axis=1)
-    off = pos % ps
-    # advanced-index dims lead: [page, :, off, :] scatters (slots, s)
-    # index pairs over (heads, d) tiles — values arrive position-major
-    for key, chunk in zip(pools, chunks):
-        out[key] = lc[key].at[page, :, off, :].set(
-            chunk.transpose(0, 2, 1, 3).astype(lc[key].dtype))
+    out.update(zip(pools, paged_write(
+        [lc[key] for key in pools], chunks, lc["block_tables"], lc["len"])))
     return out
 
 

@@ -19,6 +19,7 @@ from apex_tpu.ops.paged_latent_attention import (  # noqa: F401
     paged_latent_attention,
     paged_latent_attention_reference,
 )
+from apex_tpu.ops.paged_write import paged_write  # noqa: F401
 from apex_tpu.ops.ring_attention import (  # noqa: F401
     from_zigzag,
     ring_attention,

@@ -20,9 +20,14 @@ pages))`` with ``pages`` and ``heads`` derived from the shapes alone
 (:func:`_tile`: as many pages as hold 128 tokens for every kv head,
 cut down only where the K and V buffers would pass a VMEM budget). The
 step's K and V are ``pages`` operands a tensor, each one whole page
-``(1, heads, page_size, d)`` of the pool as it lies in HBM — the layout
-keeps a page's heads contiguous, so nothing outside the kernel changes
-to fetch a page whole. At GPT-2 large (16 slots, 20 heads of 64,
+``(1, heads, page_size, d)`` of the pool as it lies in HBM — row-major,
+a page's heads contiguous, so a page is fetched whole with no copy in
+front of the call. What keeps that true is the WRITE: every program
+that writes the pool goes through ``ops.paged_write``, which leaves it
+row-major (a scatter over the head axis made XLA carry the pool with a
+token's heads contiguous and re-lay every layer's whole pool before
+every call of this kernel; ``tests/test_aot_mosaic.py`` pins the
+compiled decode chunk). At GPT-2 large (16 slots, 20 heads of 64,
 64-page tables) that is 128 grid steps of 16 pages of 40 KB a tensor
 where a one-page one-head tile took 20 480 steps of 2 KB and spent its
 time on the steps, never the bytes (PERF.md, PR 28).

@@ -107,6 +107,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from apex_tpu.amp.policy import resolve_compute_dtype
 from apex_tpu.mesh import MODEL_AXIS
 from apex_tpu.ops._dispatch import cdiv, round_up
+from apex_tpu.ops.paged_write import paged_write
 from apex_tpu.ops.quant import kv_cast, kv_qmax, resolve_kv_dtype
 from apex_tpu.transformer.utils import divide
 from apex_tpu.utils import metrics
@@ -698,77 +699,88 @@ def defrag(cache, extra_live=None):
 
 
 def prefill_into_pages(cache, slot, contig_layers, s0, *, start=0):
-    """Scatter a CONTIGUOUS prefill cache (the models' flash-prefill
+    """Write a CONTIGUOUS prefill cache (the models' flash-prefill
     output: per layer the layout's tensors, ``k``/``v`` or ``latent``, each
     of shape ``(1, heads, len_bucket, stored)``)
     into slot ``slot``'s already-allocated pages, and set its length to
     ``s0`` (traced OK; positions past ``s0`` — prompt-bucket padding —
-    scatter to the null page). Position ``p`` lands in table entry
-    ``p // page_size`` at offset ``p % page_size``.
+    are not written: their steps sink to the null page). Position ``p``
+    lands in table entry ``p // page_size`` at offset ``p % page_size``.
 
     ``start``: first position to write (default 0). A shared-prefix
     admission prefills only the uncached tail — positions below ``start``
     are the prefix-cache pages the slot merely reads, and MUST NOT be
-    scattered (they are shared, and the partially-computed prefix slots of
+    written (they are shared, and the partially-computed prefix slots of
     the contiguous buffer may hold gathered — not recomputed — values
-    anyway); they mask to the null-page sink like bucket padding."""
-    bt = cache["block_tables"]
-    ps = page_size_of(cache)
-    max_pages = bt.shape[1]
+    anyway); they sink to the null page like bucket padding.
+
+    The write is ``ops.paged_write``, whole pages in place and row-major
+    like a decode step's (docs/serving.md "Page-pool layout"); a
+    QUANTIZED pool quantizes whole table entries and keeps its own
+    scatter (no cell runs it)."""
     names = pool_tensors(cache["layers"][0])
+    out = dict(cache)
+    out["len"] = cache["len"].at[slot].set(jnp.asarray(s0, jnp.int32))
+    row = jax.lax.dynamic_slice_in_dim(cache["block_tables"], slot, 1, axis=0)
+    if "k_scales" in cache["layers"][0]:
+        out["layers"] = _prefill_quantized_pages(
+            cache, names, row[0], contig_layers, s0, start)
+        return out
+    origin = jnp.zeros((1,), jnp.int32)   # the buffer starts at position 0
+    keys = [pool_key(n) for n in names]
+    out["layers"] = [
+        dict(zip(keys, paged_write([lc[k] for k in keys],
+                                   [src[n] for n in names], row, origin,
+                                   start=start, stop=s0)))
+        for lc, src in zip(cache["layers"], contig_layers)]
+    return out
+
+
+def _prefill_quantized_pages(cache, names, row, contig_layers, s0, start):
+    """``prefill_into_pages`` for a quantized pool: quantize-on-write
+    (docs/serving.md "Quantized KV pages"): each
+    written table entry gets a fresh per-(page, kv_head) symmetric
+    scale from ITS tokens' amax — alloc reset these pages to scale
+    0, so set (not max) is exact. Entries below ``start`` (shared
+    prefix pages) and bucket padding have no valid positions: their
+    writes sink to the null page and their scale row targets page 0
+    — shared pages keep their shared scales."""
+    ps = page_size_of(cache)
+    max_pages = row.shape[0]
     len_bucket = contig_layers[0][names[0]].shape[2]
     pos = jnp.arange(len_bucket, dtype=jnp.int32)
     valid = jnp.logical_and(pos >= start, pos < s0)
-    row = bt[slot]
     phys = jnp.where(valid, row[jnp.clip(pos // ps, 0, max_pages - 1)], 0)
     off = pos % ps
+    qmax = kv_qmax(cache["layers"][0]["k_pages"].dtype)
+    nb = cdiv(len_bucket, ps)
+    pad = nb * ps - len_bucket
+    valid_p = jnp.pad(valid, (0, pad))
+    ent_any = valid_p.reshape(nb, ps).any(axis=1)          # (nb,)
+    page_e = jnp.where(ent_any, row[:nb], 0)
+    ent_of = jnp.clip(pos // ps, 0, nb - 1)
 
-    out = dict(cache)
-    quantized = "k_scales" in cache["layers"][0]
-    if quantized:
-        # quantize-on-write (docs/serving.md "Quantized KV pages"): each
-        # written table entry gets a fresh per-(page, kv_head) symmetric
-        # scale from ITS tokens' amax — alloc reset these pages to scale
-        # 0, so set (not max) is exact. Entries below ``start`` (shared
-        # prefix pages) and bucket padding have no valid positions: their
-        # writes sink to the null page and their scale row targets page 0
-        # — shared pages keep their shared scales.
-        qmax = kv_qmax(cache["layers"][0]["k_pages"].dtype)
-        nb = cdiv(len_bucket, ps)
-        pad = nb * ps - len_bucket
-        valid_p = jnp.pad(valid, (0, pad))
-        ent_any = valid_p.reshape(nb, ps).any(axis=1)          # (nb,)
-        page_e = jnp.where(ent_any, row[:nb], 0)
-        ent_of = jnp.clip(pos // ps, 0, nb - 1)
-
-        def scatter_q(pages, scales, x):
-            xf = x.astype(jnp.float32)           # (len_bucket, kv, d)
-            ax = jnp.where(valid[:, None, None], jnp.abs(xf), 0.0)
-            ax = jnp.pad(ax, ((0, pad), (0, 0), (0, 0)))
-            amax = ax.reshape(nb, ps, *x.shape[1:]).max(axis=(1, 3))
-            sc = amax / qmax                                   # (nb, kv)
-            inv = jnp.where(sc > 0, 1.0 / jnp.maximum(sc, 1e-30), 0.0)
-            q = kv_cast(xf * inv[ent_of][:, :, None], pages.dtype, qmax)
-            return (pages.at[phys, :, off, :].set(q),
-                    scales.at[page_e].set(
-                        jnp.where(ent_any[:, None], sc, 0.0)))
+    def scatter_q(pages, scales, x):
+        xf = x.astype(jnp.float32)           # (len_bucket, kv, d)
+        ax = jnp.where(valid[:, None, None], jnp.abs(xf), 0.0)
+        ax = jnp.pad(ax, ((0, pad), (0, 0), (0, 0)))
+        amax = ax.reshape(nb, ps, *x.shape[1:]).max(axis=(1, 3))
+        sc = amax / qmax                                   # (nb, kv)
+        inv = jnp.where(sc > 0, 1.0 / jnp.maximum(sc, 1e-30), 0.0)
+        q = kv_cast(xf * inv[ent_of][:, :, None], pages.dtype, qmax)
+        return (pages.at[phys, :, off, :].set(q),
+                scales.at[page_e].set(
+                    jnp.where(ent_any[:, None], sc, 0.0)))
 
     new_layers = []
     for lc, src in zip(cache["layers"], contig_layers):
         new = {}
         for name in names:
             x = src[name][0].transpose(1, 0, 2)  # (len_bucket, heads, d)
-            pages = lc[pool_key(name)]
-            if quantized:
-                new[pool_key(name)], new[scale_key(name)] = scatter_q(
-                    pages, lc[scale_key(name)], x)
-            else:
-                new[pool_key(name)] = pages.at[phys, :, off, :].set(
-                    x.astype(pages.dtype))
+            new[pool_key(name)], new[scale_key(name)] = scatter_q(
+                lc[pool_key(name)], lc[scale_key(name)], x)
         new_layers.append(new)
-    out["layers"] = new_layers
-    out["len"] = cache["len"].at[slot].set(jnp.asarray(s0, jnp.int32))
-    return out
+    return new_layers
 
 
 # --------------------------------------------------------------------------

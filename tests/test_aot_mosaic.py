@@ -224,6 +224,120 @@ def test_multichip_tp_paged_serving_compiles_for_tpu(topo):
         est["tp4_paged_engine_decode_chunk"].peak_bytes, est
 
 
+def _gpt2l_cell():
+    """``gpt2-large.chat-closed16`` at its widths, 2 of 36 layers: 16
+    slots, 20 heads of 64, the 2 GiB pool's 729 pages of 16, 64-page
+    tables, ``sync_every`` 4."""
+    import jax.numpy as jnp
+
+    from apex_tpu.models.gpt import GPTConfig, GPTModel
+
+    cfg = GPTConfig(vocab_size=50304, hidden_size=1280, num_layers=2,
+                    num_heads=20, max_position_embeddings=1024,
+                    dtype=jnp.bfloat16, param_dtype=jnp.float32)
+    return GPTModel(cfg), dict(slots=16, num_pages=729, max_pages=64)
+
+
+def _glm_cell():
+    """``glm-4.7-flash.docqa-closed32`` at its widths, one layer of each
+    kind (dense, routed experts): 32 slots, one 640-lane latent entry a
+    token, the 4 GiB pool's 38,837 pages, 2048-page tables."""
+    from apex_tpu.models.glm4_moe_lite import (Glm4MoeLiteConfig,
+                                               Glm4MoeLiteModel)
+
+    cfg = Glm4MoeLiteConfig(num_layers=2, max_position_embeddings=32768)
+    return Glm4MoeLiteModel(cfg), dict(slots=32, num_pages=38837,
+                                       max_pages=2048)
+
+
+#: name -> (the cell's model and pool, the engine program, its arguments
+#: after ``(cache, variables)`` from the slot count)
+POOL_PROGRAMS = {
+    "gpt2l_decode_chunk": (_gpt2l_cell, "step"),
+    "glm_decode_chunk": (_glm_cell, "step"),
+    "gpt2l_admit256": (_gpt2l_cell, "admit"),
+}
+
+
+@pytest.mark.parametrize("name", list(POOL_PROGRAMS))
+def test_no_program_re_lays_the_page_pool(name, mesh):
+    """Every program that writes the page pool writes it ROW-MAJOR, the
+    layout the decode kernels (like every Mosaic call) take it in
+    (docs/serving.md "Page-pool layout"). A scatter over the pool's head
+    axis made XLA carry the pool ``{3,1,2,0}`` and put a ``copy`` of every
+    layer's whole K and V pool in front of every kernel of every decode
+    step: 18 of the 33 ms of a GPT-2 large step (PERF.md, PR 32).
+
+    The decode chunk (``PagedDecodeEngine._step_fn()``), compiled for the
+    described v5e at a cell's widths: no ``copy`` or ``transpose`` of the
+    pool's shape inside the ``while`` body. An admit program: no value of
+    the pool's shape laid out ``{3,1,2,0}`` anywhere. (What stays, and is
+    not counted: a 64-wide pool's buffers lie in the device's default
+    layout, page axis minor-most, so each program copies the pool once
+    where it enters and once where it leaves; docs/serving.md says why
+    that is not cured here.) In both, the page write is a Mosaic call that
+    aliases its pool operand."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    import tpu_aot
+    from apex_tpu.serving import kv_pool
+    from apex_tpu.serving.scheduler import PagedDecodeEngine
+
+    cell, program = POOL_PROGRAMS[name]
+    model, pool = cell()
+    slots = pool["slots"]
+    # the engine's own pool is small (it is never run); the compiled
+    # program takes the cell's
+    engine = PagedDecodeEngine(model, variables=None, num_slots=slots,
+                               page_size=16, num_pages=slots + 1,
+                               max_pages_per_seq=pool["max_pages"],
+                               sync_every=4)
+    cache = jax.eval_shape(lambda: kv_pool.init_paged_cache(
+        model.config, slots, num_pages=pool["num_pages"], page_size=16,
+        max_pages_per_seq=pool["max_pages"]))
+    i32 = jnp.int32
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), i32)))
+    sds = jax.ShapeDtypeStruct
+    if program == "step":
+        fn = engine._step_fn()
+        rest = [sds((slots,), i32), sds((slots,), jnp.bool_),
+                sds((slots,), i32), sds((slots, 2), jnp.uint32),
+                sds((slots,), i32)]
+    else:
+        fn = engine._admit_fn(256)
+        rest = [sds((1, 256), i32), sds((), i32), sds((), i32),
+                sds((), i32), sds((2,), jnp.uint32)]
+    txt = tpu_aot.compile_replicated(mesh, fn, [cache, variables] + rest,
+                                     (0,)).as_text()
+
+    pools = {",".join(map(str, x.shape))
+             for lc in cache["layers"] for x in lc.values()}
+    assert len(pools) == 1, pools
+    shape = re.escape(pools.pop())
+    if program == "step":
+        moved = [ln.strip()[:200] for ln in txt.splitlines()
+                 if re.search(rf"= \w+\[{shape}\]\S* (copy|transpose)\(", ln)
+                 and re.search(r'op_name="[^"]*while/body', ln)]
+        assert not moved, (
+            f"{len(moved)} pool-shaped copies in the decode chunk's loop "
+            f"body: {moved[:2]}")
+    twisted = re.findall(rf"\w+\[{shape}\]\{{3,1,2,0[^}}]*\}}", txt)
+    assert not twisted, (
+        f"{len(twisted)} pool-shaped values laid out {{3,1,2,0}}: "
+        f"{twisted[:2]}")
+    # the label's JSON opens on a line of its own, after the call's
+    # attributes
+    writes = re.findall(r'custom_call_target="tpu_custom_call"([^\n]*)\n'
+                        r'"kernel":"paged_write"', txt)
+    assert writes, "the page write is no Mosaic call"
+    assert all("output_to_operand_aliasing" in attrs for attrs in writes), (
+        "a page write does not alias its pool operand")
+
+
 def test_tight_headdim_compiles(mesh):
     """Compile half of the tight-head-dim gate: the unpadded d=64 layout
     must stay legal under Mosaic (runtime parity is the on-chip test)."""

@@ -31,6 +31,7 @@ def _cases():
                               paged_attention, paged_latent_attention,
                               softmax_cross_entropy)
     from apex_tpu.ops.group_norm import group_norm_nhwc
+    from apex_tpu.ops.paged_write import paged_write
     from apex_tpu.ops.layer_norm import layer_norm
     from apex_tpu.ops.quant import fused_dequant_matmul
     from apex_tpu.ops.scaled_softmax import scaled_softmax
@@ -85,6 +86,19 @@ def _cases():
                 _sds((32, 20, 1, 640), bf16),
                 _sds((38837, 1, 16, 640), bf16), _sds((32, 2048), i32),
                 _sds((32,), i32)]),
+        "paged_write": (
+            lambda k, v, ck, cv, bt, ln: paged_write([k, v], [ck, cv], bt,
+                                                     ln), [
+                pages, pages, _sds((2, 2, 1, 64), bf16),
+                _sds((2, 2, 1, 64), bf16), _sds((2, 4), i32),
+                _sds((2,), i32)]),
+        # a decode step's token of 16 slots into the cell's K and V pools
+        "paged_write@gpt2-large.chat-closed16": (
+            lambda k, v, ck, cv, bt, ln: paged_write([k, v], [ck, cv], bt,
+                                                     ln), [
+                _sds((729, 20, 16, 64), bf16), _sds((729, 20, 16, 64), bf16),
+                _sds((16, 20, 1, 64), bf16), _sds((16, 20, 1, 64), bf16),
+                _sds((16, 64), i32), _sds((16,), i32)]),
         "layer_norm_fwd": (layer_norm, ln_args),
         "layer_norm_bwd": (jax.grad(sq_sum(layer_norm), (0, 1, 2)), ln_args),
         "xentropy_fwd": (softmax_cross_entropy, xent),
@@ -119,7 +133,8 @@ def test_the_cases_cover_the_closed_set():
 
 @pytest.mark.parametrize("case", _dispatch.KERNEL_LABELS + (
     "paged_attention@gpt2-large.chat-closed16",
-    "paged_latent_attention@glm-4.7-flash.docqa-closed32"))
+    "paged_latent_attention@glm-4.7-flash.docqa-closed32",
+    "paged_write@gpt2-large.chat-closed16"))
 def test_label_reaches_the_lowered_program(case):
     """``metadata={"kernel": label}`` lands on the Mosaic custom call as
     ``kernel_metadata``; the benchmark's pattern finds it there."""

@@ -368,3 +368,74 @@ def test_flash_attention_sliding_window(tpu, rng):
     g = jax.jit(jax.grad(lambda q: jnp.sum(flash_attention(
         q, q, q, causal=True, window=128).astype(jnp.float32) ** 2)))(q)
     assert np.isfinite(np.asarray(g, np.float32)).all()
+
+
+#: the page write at the serving cells' widths: pool, slots, table
+#: width, s, and an admission's bounds
+_PAGED_WRITE_CASES = {
+    "gpt2l_decode": dict(pool=(729, 20, 16, 64), n=2, slots=16, mp=64, s=1),
+    "gpt2l_verify_s4": dict(pool=(729, 20, 16, 64), n=2, slots=16, mp=64,
+                            s=4),
+    "gpt2l_chunk_s16": dict(pool=(729, 20, 16, 64), n=2, slots=1, mp=64,
+                            s=16),
+    "gpt2l_admit256": dict(pool=(729, 20, 16, 64), n=2, slots=1, mp=64,
+                           s=256, start=32, stop=201),
+    "glm_decode": dict(pool=(4097, 1, 16, 640), n=1, slots=32, mp=128, s=1),
+    "glm_admit16k": dict(pool=(4097, 1, 16, 640), n=1, slots=1, mp=2048,
+                         s=16384, stop=16001),
+    "f32_decode": dict(pool=(257, 8, 16, 128), n=2, slots=8, mp=32, s=1,
+                       dtype=jnp.float32),
+}
+
+
+@pytest.mark.parametrize("name", list(_PAGED_WRITE_CASES))
+def test_paged_write_matches_the_scatter_on_chip(name, tpu, rng):
+    """What the interpreter cannot show: the pipeline prefetches the next
+    grid step's page while this one's is written back, in place. Live
+    slots own distinct pages (neighbours included), idle slots all name
+    page 0; four writes in a row as a decode chunk makes them, then every
+    page but 0 against the scatter, bit for bit."""
+    from test_paged_write import scatter_reference
+
+    from apex_tpu.ops.paged_write import paged_write
+
+    c = _PAGED_WRITE_CASES[name]
+    num_pages, heads, ps, d = c["pool"]
+    slots, mp, s = c["slots"], c["mp"], c["s"]
+    dtype = c.get("dtype", jnp.bfloat16)
+    bounds = {k: c[k] for k in ("start", "stop") if k in c}
+    # consecutive pages, so that neighbours in HBM belong to different
+    # slots' steps; slots 1 and 5 idle where there are that many
+    per = (num_pages - 1) // slots
+    tables = (1 + np.arange(slots)[:, None] + slots * np.arange(
+        min(per, mp))[None, :]).astype(np.int32)
+    tables = np.pad(tables, ((0, 0), (0, mp - tables.shape[1])))
+    lengths = rng.integers(0, ps * min(per, mp) - 4 * s, (slots,)) \
+        if s <= ps else np.zeros((slots,), np.int64)
+    idle = [b for b in (1, 5) if b < slots and slots > 2]
+    tables[idle] = 0
+    tables, lengths = jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+    pools = [jnp.asarray(rng.standard_normal(c["pool"]), dtype)
+             for _ in range(c["n"])]
+    rounds = 4 if s <= ps else 1
+    chunks = [[jnp.asarray(rng.standard_normal((slots, heads, s, d)), dtype)
+               for _ in range(c["n"])] for _ in range(rounds)]
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def run(write, pools, chunks):
+        for r, chunk in enumerate(chunks):
+            pools = write(pools, chunk, tables, lengths + r * s, **bounds)
+        return pools
+
+    def reference(pools, chunk, *a, **kw):
+        return [scatter_reference(p, x, *a, **kw)
+                for p, x in zip(pools, chunk)]
+
+    got = run(paged_write, pools, chunks)
+    want = run(reference, pools, chunks)
+    for out, ref, pages in zip(got, want, pools):
+        np.testing.assert_array_equal(np.asarray(out[1:], np.float32),
+                                      np.asarray(ref[1:], np.float32))
+        assert not np.array_equal(np.asarray(out[1:], np.float32),
+                                  np.asarray(pages[1:], np.float32))
+
