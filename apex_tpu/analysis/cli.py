@@ -2,8 +2,7 @@
 
 ``python -m apex_tpu.analysis`` (or the ``apex-tpu-lint`` console
 script) with no path arguments scans the production surface — the
-``apex_tpu/`` package plus the repo-root ``tpu_*.py`` / ``bench*.py``
-drivers. Exit status:
+``apex_tpu/`` package plus the repo-root ``tpu_*.py`` drivers. Exit status:
 
 * 0 — clean (every finding suppressed inline or absorbed by the
   baseline);
@@ -30,7 +29,7 @@ from apex_tpu.analysis.rules import RULES, module_rules, project_rules
 from apex_tpu.analysis.suppressions import Suppressions
 from apex_tpu.analysis.walker import Finding, ModuleIndex
 
-DEFAULT_GLOBS = ("apex_tpu/**/*.py", "tpu_*.py", "bench*.py")
+DEFAULT_GLOBS = ("apex_tpu/**/*.py", "tpu_*.py")
 DEFAULT_BASELINE = "tpu_lint_baseline.json"
 
 #: generated/vendored files never worth linting
@@ -183,7 +182,7 @@ def _build_parser() -> argparse.ArgumentParser:
                     "surface)")
     p.add_argument("paths", nargs="*",
                    help="files/dirs to scan (default: apex_tpu/, "
-                        "tpu_*.py, bench*.py under --root)")
+                        "tpu_*.py under --root)")
     p.add_argument("--root", default=".",
                    help="repo root for default globs, the baseline file "
                         "and the cross-file drift rules")
@@ -223,8 +222,8 @@ def _build_parser() -> argparse.ArgumentParser:
     p.add_argument("--contract", action="store_true",
                    help="run the wire/observability contract tier "
                         "instead: index every metric family, event "
-                        "kind, HTTP route, SSE frame, schema pin and "
-                        "ledger class against its consumers (docs "
+                        "kind, HTTP route, SSE frame and schema pin "
+                        "against its consumers (docs "
                         "catalogs, goldens, validators, parsers) and "
                         "prove both directions agree")
     p.add_argument("--diff", default=None, metavar="BASE_REV",

@@ -2,7 +2,7 @@
 
 The stack's remaining un-proved surface is string-keyed: metric
 families, event kinds, HTTP routes, SSE frame kinds, ``apex-tpu/*``
-schema pins, report field pins, ledger gating classes. Producers and
+schema pins, report field pins. Producers and
 consumers of those names live in different files (and two of the
 consumers are not even python — the docs catalogs and the golden
 Prometheus exposition), so no per-module check can see them drift.

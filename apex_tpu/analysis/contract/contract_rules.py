@@ -4,9 +4,8 @@ Every rule here checks one direction of one string-keyed contract
 against the shared :class:`~apex_tpu.analysis.contract.extract.
 ContractIndex`: instrument families vs the docs catalog and the golden
 exposition, event kinds vs their readers, HTTP routes and SSE frames vs
-both sides of the socket, ``apex-tpu/*`` schema pins vs their writers
-and validators, and the perf ledger's extraction tuples vs the report
-pins and gating classes. The bias matches the other tiers: a rule
+both sides of the socket, and ``apex-tpu/*`` schema pins vs their
+writers and validators. The bias matches the other tiers: a rule
 speaks only where the index holds a statically resolved fact, and the
 repo's intentional gaps are inline-suppressed at the fact's site with a
 justification — the baseline ships (and stays) empty.
@@ -22,7 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import difflib
-from typing import Callable, Dict, Iterator, List, Optional, Tuple
+from typing import Callable, Dict, Iterator, List
 
 from apex_tpu.analysis.contract.extract import (ContractIndex, MetricSite,
                                                 Site)
@@ -315,85 +314,7 @@ def check_endpoint_undocumented(index: ContractIndex) -> Iterator[Finding]:
 
 
 # --------------------------------------------------------------------------
-# 8. contract-ledger-class-drift
-# --------------------------------------------------------------------------
-
-#: ledger extraction tuple -> (report pin tuple, banked-name prefix);
-#: the ledger flattens ``scenario.<name>.<prefix><field>``
-_EXTRACTION_PINS: Tuple[Tuple[str, str, str], ...] = (
-    ("_SCENARIO_FIELDS", "AGGREGATE_FIELDS", ""),
-    ("_SCENARIO_ROUTER_FIELDS", "ROUTER_FIELDS", ""),
-    ("_SCENARIO_HOST_TIER_FIELDS", "HOST_TIER_FIELDS", ""),
-    ("_SCENARIO_FLEET_FIELDS", "FLEET_FIELDS", "fleet_"),
-    ("_SCENARIO_HTTP_FIELDS", "HTTP_FIELDS", "http_"),
-)
-
-
-def _gating_class(name: str, hb: Tuple[str, ...], lb: Tuple[str, ...],
-                  rates: Tuple[str, ...]) -> Optional[str]:
-    """Mirror of ``obs.ledger.check``'s classification: cost metrics
-    gate exactly, direction-classified metrics band-gate (absolute for
-    rate suffixes), anything else is silently informational."""
-    if name.startswith("cost."):
-        return "exact"
-    if any(s in name for s in hb) or any(s in name for s in lb):
-        if any(name.endswith(s) for s in rates):
-            return "absolute-rate"
-        return "relative-band"
-    return None
-
-
-def _element_site(tup, i: int) -> Site:
-    if i < len(tup.element_sites):
-        return tup.element_sites[i]
-    return tup.site
-
-
-@contract_rule("contract-ledger-class-drift", "error",
-               "a ledger extraction field matches no gating class "
-               "(silently informational) or is absent from the report "
-               "pin it extracts from")
-def check_ledger_class_drift(index: ContractIndex) -> Iterator[Finding]:
-    r = CONTRACT_RULES["contract-ledger-class-drift"]
-    hb_t = index.tuple_by_name("_HIGHER_BETTER")
-    lb_t = index.tuple_by_name("_LOWER_BETTER")
-    if hb_t is None or lb_t is None:
-        return               # no ledger surface scanned
-    rates_t = index.tuple_by_name("_RATE_SUFFIXES")
-    hb, lb = hb_t.values, lb_t.values
-    rates = rates_t.values if rates_t else ()
-    for ext_name, pin_name, prefix in _EXTRACTION_PINS:
-        ext = index.tuple_by_name(ext_name)
-        if ext is None:
-            continue
-        pin = index.tuple_by_name(pin_name)
-        for i, field in enumerate(ext.values):
-            site = _element_site(ext, i)
-            if pin is not None and field not in pin.values:
-                yield _finding(
-                    r, site,
-                    f"`{ext_name}` extracts `{field}` but the report "
-                    f"pin `{pin_name}` does not produce that key")
-            if _gating_class(prefix + field, hb, lb, rates) is None:
-                yield _finding(
-                    r, site,
-                    f"banked metric `scenario.<name>.{prefix}{field}` "
-                    "matches no gating class (no direction substring, "
-                    "no rate suffix) — the ledger records it but never "
-                    "gates it")
-    bench = index.tuple_by_name("_BENCH_FIELDS")
-    if bench is not None:
-        for i, field in enumerate(bench.values):
-            if _gating_class(field, hb, lb, rates) is None:
-                yield _finding(
-                    r, _element_site(bench, i),
-                    f"banked bench field `{field}` matches no gating "
-                    "class (no direction substring, no rate suffix) — "
-                    "the ledger records it but never gates it")
-
-
-# --------------------------------------------------------------------------
-# 9. contract-golden-stale
+# 8. contract-golden-stale
 # --------------------------------------------------------------------------
 
 _RAW_SERIES_SUFFIXES = ("_count", "_mean", "_last")

@@ -2,7 +2,7 @@
 
 The stack's wire surface is held together by NAMES — metric families,
 event kinds, HTTP routes, SSE frame kinds, schema-version literals,
-pinned field tuples, ledger gating classes — and none of the other
+pinned field tuples — and none of the other
 tiers can see two of them drift apart. This module builds the
 repo-wide :class:`ContractIndex` the ``contract-*`` rules check:
 
@@ -11,10 +11,8 @@ repo-wide :class:`ContractIndex` the ``contract-*`` rules check:
   ``metrics.counter/gauge/histogram`` registration with its statically
   resolved family name and label-key set, every ``EventLog.emit`` kind,
   the HTTP route dispatch comparisons and raw client request paths,
-  ``_sse(...)`` frame emissions, ``apex-tpu/...`` schema constants with
-  their writer stamps and validator comparisons, and every
-  module-level tuple-of-strings constant (the report field pins and the
-  ledger extraction/gating tuples);
+  ``_sse(...)`` frame emissions, and ``apex-tpu/...`` schema constants
+  with their writer stamps and validator comparisons;
 - **python consumers**: literal ``e["kind"] ==`` / ``.get("kind") ==``
   comparisons (NOT ``.kind`` attribute reads — ``FaultSpec.kind`` is a
   fault name, not an event kind) and the SSE client's
@@ -104,17 +102,6 @@ class SchemaConst:
 
 
 @dataclasses.dataclass
-class StrTupleConst:
-    """A module-level tuple-of-strings constant (field pins, ledger
-    extraction tuples, gating classes) with one site per element."""
-    module: str
-    name: str
-    values: Tuple[str, ...]
-    site: Site = None
-    element_sites: Tuple[Site, ...] = ()
-
-
-@dataclasses.dataclass
 class ContractIndex:
     metrics: List[MetricSite] = dataclasses.field(default_factory=list)
     unresolved_metrics: List[Tuple[Site, str]] = \
@@ -133,8 +120,6 @@ class ContractIndex:
     schemas: List[SchemaConst] = dataclasses.field(default_factory=list)
     raw_schema_stamps: List[Tuple[str, Site]] = \
         dataclasses.field(default_factory=list)
-    str_tuples: Dict[Tuple[str, str], StrTupleConst] = \
-        dataclasses.field(default_factory=dict)
     # -- text consumers ----------------------------------------------------
     doc_metrics: Dict[str, Site] = dataclasses.field(default_factory=dict)
     doc_events: Dict[str, Site] = dataclasses.field(default_factory=dict)
@@ -150,13 +135,6 @@ class ContractIndex:
         for m in self.metrics:
             out.setdefault(m.family, []).append(m)
         return out
-
-    def tuple_by_name(self, name: str) -> Optional[StrTupleConst]:
-        """The unique tuple constant with this name, if exactly one
-        module defines it (the pin/ledger names are repo-unique)."""
-        hits = [t for (_, n), t in self.str_tuples.items() if n == name]
-        return hits[0] if len(hits) == 1 else None
-
 
 def _module_dotted(path: str) -> str:
     mod = path[:-3] if path.endswith(".py") else path
@@ -441,16 +419,6 @@ class _ModuleExtractor(ast.NodeVisitor):
                 self.index.schemas.append(SchemaConst(
                     name=name, value=node.value.value,
                     site=_site(self.mi, node)))
-            vals = _const_str_values(node.value)
-            if vals is not None and isinstance(node.value,
-                                               (ast.Tuple, ast.List)):
-                self.index.str_tuples[
-                    (_module_dotted(self.mi.path), name)] = \
-                    StrTupleConst(
-                        module=_module_dotted(self.mi.path), name=name,
-                        values=vals, site=_site(self.mi, node),
-                        element_sites=tuple(_site(self.mi, e)
-                                            for e in node.value.elts))
         # ``x["schema"] = CONST`` writer stamps
         if len(node.targets) == 1 \
                 and isinstance(node.targets[0], ast.Subscript):
@@ -482,9 +450,8 @@ class _ModuleExtractor(ast.NodeVisitor):
                 if isinstance(lit, ast.Constant) \
                         and isinstance(lit.value, str):
                     if lit.value.startswith(_SCHEMA_PREFIX):
-                        # prefix validator (the ledger's scenarios
-                        # reader): validates every schema const whose
-                        # value it prefixes
+                        # prefix validator: validates every schema
+                        # const whose value it prefixes
                         for sc in self.index.schemas:
                             if sc.value.startswith(lit.value):
                                 sc.validated = True

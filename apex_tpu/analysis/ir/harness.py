@@ -44,8 +44,8 @@ class CaseProgram:
     max_traces: int = 1
     x64: bool = False
     #: builder-supplied side facts consumers cannot recover from the
-    #: jaxpr (e.g. the TP cases' sharded/replicated weight-byte split —
-    #: ``obs/costs.py`` prices per-chip HBM from it)
+    #: jaxpr: the mem tier reads ``arg_specs``, ``mesh_axes`` and
+    #: ``hbm_budget_bytes``
     meta: Optional[dict] = None
 
 
@@ -188,18 +188,6 @@ def _build_engine_chunk() -> CaseProgram:
     return CaseProgram(fn=engine._step_fn(), args=args)
 
 
-def _weight_bytes(tree) -> int:
-    import jax
-
-    total = 0
-    for leaf in jax.tree.leaves(tree):
-        n = 1
-        for d in leaf.shape:
-            n *= int(d)
-        total += n * leaf.dtype.itemsize
-    return total
-
-
 def _build_spec_engine_program() -> CaseProgram:
     """The IN-ENGINE speculative decode chunk (ISSUE 13): the jitted
     ``sync_every``-round scan where each round runs ``draft_len``
@@ -207,8 +195,7 @@ def _build_spec_engine_program() -> CaseProgram:
     in ONE ``s = draft_len + 1`` paged target step. The draft is a
     1-layer gpt2s-dims model — the shape regime where the round's
     weight stream (W_target + k * W_draft) amortized over >= 2 accepted
-    tokens beats the non-speculative per-token stream, which
-    ``obs/costs.py`` prices from this case's ``meta``. The two variants
+    tokens beats the non-speculative per-token stream. The two variants
     pin that per-slot decode state (tok/done/n_left) is TRACED, never a
     compile key: concrete values and abstract structs must stage ONE
     program."""
@@ -245,12 +232,8 @@ def _build_spec_engine_program() -> CaseProgram:
     variant = (cache_abs, dcache_abs, dvars, ddvars,
                np.zeros((4,), np.int32), np.zeros((4,), bool),
                np.full((4,), 7, np.int32))
-    meta = {"draft_len": engine.draft_len, "k": engine.draft_len + 1,
-            "sync_every": engine.sync_every,
-            "target_weight_bytes": _weight_bytes(dvars),
-            "draft_weight_bytes": _weight_bytes(ddvars)}
     return CaseProgram(fn=engine._spec_step_fn(), args=args,
-                       variants=[variant], max_traces=1, meta=meta)
+                       variants=[variant], max_traces=1)
 
 
 def _build_prefill_chunk_program() -> CaseProgram:
@@ -395,9 +378,7 @@ def _build_int8kv_engine_program(kind: str) -> CaseProgram:
     paged kernel WITH its per-(page, kv_head) scale operands and
     in-kernel dequant, the admission the quantize-on-write prefill
     scatter. Same compile-key contract as the fp cases (two same-bucket
-    admission variants, ``max_traces=1``); ``obs/costs.py`` reads the
-    decode chunk's abstract pool to price the narrow KV stream
-    (``cost.decode.int8_kv.*``)."""
+    admission variants, ``max_traces=1``)."""
     import jax
     import jax.numpy as jnp
 
@@ -451,10 +432,7 @@ def _build_wq_engine_program(kind: str, policy: str) -> CaseProgram:
     dequant in VMEM next to the contraction), embeddings/norms/head
     stay fp. ``policy="int4"`` also drops the fp leaves to bf16 (the
     documented aggressive pairing). Same compile-key contract as the fp
-    cases (two same-bucket admission variants, ``max_traces=1``);
-    ``obs/costs.py`` reads the decode chunk's abstract weight tree to
-    price the narrow stream (``cost.decode.w8.*`` / ``cost.decode.w4.*``
-    — per-LEAF dtype bytes, scale reads included)."""
+    cases (two same-bucket admission variants, ``max_traces=1``)."""
     import jax
     import jax.numpy as jnp
 
@@ -636,29 +614,12 @@ def _build_tp_engine_program(kind: str, kv_dtype=None,
         kv_dtype=kv_dtype)
     dvars, var_specs = infer_variable_specs(model)
 
-    def _bytes(leaf):
-        n = 1
-        for d in leaf.shape:
-            n *= int(d)
-        return n * leaf.dtype.itemsize
-
-    sharded = repl = 0
-    # PartitionSpec is an unregistered type, i.e. a pytree LEAF — the
-    # two leaf lists align one-to-one
-    for leaf, spec in zip(jax.tree.leaves(dvars),
-                          jax.tree.leaves(var_specs)):
-        if any(s is not None for s in spec):
-            sharded += _bytes(leaf)
-        else:
-            repl += _bytes(leaf)
     # the declared sharding contract: the mem tier's spec rules
     # (mem-spec-indivisible & co.) check these against the mesh before
     # shard_map ever traces, and its HBM sweep scopes to per-chip bytes
     from jax.sharding import PartitionSpec as P
 
-    meta = {"tp": tp, "sharded_weight_bytes": sharded,
-            "replicated_weight_bytes": repl,
-            "mesh_axes": {"model": tp}}
+    meta = {"mesh_axes": {"model": tp}}
     i32 = jnp.int32
     if kind == "decode":
         args = (engine.cache, dvars,

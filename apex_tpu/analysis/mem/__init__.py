@@ -5,11 +5,11 @@ tier reads staged jaxprs, the conc tier reads the host side; this tier
 proves MEMORY FIT — per-chip, before any compile, on any machine:
 
 - **tiled-layout-aware peak HBM** (``layout.py`` + ``estimator.py``):
-  the cost model's liveness sweep re-priced at TPU tile-padded sizes
+  a liveness sweep priced at TPU tile-padded sizes
   (minor dim -> 128 lanes, second-minor -> the dtype's sublane
   multiple), at LOCAL shard shapes inside shard_map, with each scan's
   carry double-buffered and donated buffers alias-credited — checked
-  against the case's declared ``ChipProfile`` budget;
+  against one v5e chip's 16 GiB, or the case's declared budget;
 - **per-``pallas_call`` VMEM** vs the 16 MiB scoped stack;
 - **sharding contracts** over shard_map programs: divisibility,
   replicated-output honesty under ``check_vma=False``, donation spec

@@ -4,11 +4,11 @@ Three computations over one traced case (a :class:`CaseIR` from the IR
 harness — the mem tier deliberately re-uses the same registry/trace
 path so "registered for lint" means "covered by the fit proof"):
 
-- **per-chip peak HBM** (:func:`estimate_case`): the liveness sweep of
-  ``obs/costs.py`` but (a) pricing every array at its TPU tiled-layout
-  PADDED size (``layout.py``), (b) analyzing a shard_map-wrapped
-  program at its body's LOCAL shard shapes — per-chip bytes, exactly
-  like the cost model prices per-chip FLOPs, (c) charging each
+- **per-chip peak HBM** (:func:`estimate_case`): a liveness sweep over
+  the top-level equations (a) pricing every array at its TPU
+  tiled-layout PADDED size (``layout.py``), (b) analyzing a
+  shard_map-wrapped program at its body's LOCAL shard shapes — per-chip
+  bytes, (c) charging each
   ``lax.scan`` an extra copy of its carry (XLA double-buffers the
   decode scan's pool carry — the PR 10 lesson), and (d) crediting
   in-place updates: a scatter/dynamic_update_slice/scan whose output
@@ -202,8 +202,8 @@ def _padded_liveness(jaxpr, owned_inputs=frozenset()
                      ) -> Tuple[int, int, int, int]:
     """(peak_with_double_buffer, peak_without, scan_carry_extra_max,
     inplace_credit_total) over the top-level equation list at padded
-    sizes. Same sweep shape as ``obs.costs._peak_live_bytes`` — inner-
-    jaxpr scratch is not modeled — plus two refinements:
+    sizes. Inner-jaxpr scratch is not modeled; two refinements over a
+    plain liveness sweep:
 
     - each scan charges an extra copy of its carry (XLA's double
       buffering);

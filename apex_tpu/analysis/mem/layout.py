@@ -1,5 +1,5 @@
-"""TPU tiled-layout padding math, shared by the mem tier and the cost
-model.
+"""TPU tiled-layout padding math and the chip's HBM size, for the mem
+tier.
 
 On-chip arrays are stored in (sublane, lane) tiles: the minor dimension
 pads to a multiple of 128 lanes, the second-minor to a multiple of the
@@ -12,10 +12,9 @@ The practical consequence this module exists to price (docs/
 tp_serving.md "Pool sizing"): a ``head_dim=64`` KV pool pays 2x its
 logical bytes on chip — 64 lanes pad to 128 — which is how PR 10's
 first 512-slot acceptance pool OOM'd a 16 GiB chip at 25.6 GiB
-"logical" 12.8. ``obs/costs.py`` deliberately prices LOGICAL bytes
-(bandwidth and roofline math follow the bytes the program streams);
-this helper answers the other question — the bytes the array OCCUPIES —
-which is the one HBM/VMEM fit proofs need.
+"logical" 12.8. Bandwidth and roofline math follow the LOGICAL bytes a
+program streams; this helper answers the other question — the bytes the
+array OCCUPIES — which is the one HBM/VMEM fit proofs need.
 
 Stdlib-only on purpose: callers hand in plain shapes + an object with
 ``itemsize`` (a numpy/jax dtype) or an aval.
@@ -25,6 +24,10 @@ from __future__ import annotations
 
 from typing import Sequence, Tuple
 
+#: HBM of one TPU v5e chip, the chip every fit proof here is made for
+#: (Google Cloud documentation, "TPU v5e": 16 GB)
+HBM_BYTES_V5E = 16 * 1024 ** 3
+
 LANE = 128          #: minor-dim tile width (all dtypes)
 _SUBLANE_4B = 8     #: second-minor tile height for 4-byte elements
 
@@ -32,8 +35,8 @@ _SUBLANE_4B = 8     #: second-minor tile height for 4-byte elements
 def _itemsize(dtype) -> int:
     size = getattr(dtype, "itemsize", None)
     if size is None:
-        # extended dtypes (PRNG keys) carry no itemsize; 4 B/elem is the
-        # same stand-in obs/costs.py uses for what is metadata-sized
+        # extended dtypes (PRNG keys) carry no itemsize; 4 B/elem is a
+        # stand-in for what is metadata-sized
         return 4
     return max(int(size), 1)
 

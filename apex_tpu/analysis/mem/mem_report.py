@@ -34,6 +34,7 @@ from apex_tpu.analysis.ir.ir_report import (_case_anchor,
                                             _SuppressionCache,
                                             eqn_anchor)
 from apex_tpu.analysis.mem.estimator import MemEstimate, estimate_case
+from apex_tpu.analysis.mem.layout import HBM_BYTES_V5E
 from apex_tpu.analysis.mem.mem_rules import MEM_RULES, MemContext
 from apex_tpu.analysis.walker import Finding
 
@@ -47,18 +48,13 @@ ACCEPTANCE_TO_AOT = {
 
 
 def hbm_budget(prog: CaseProgram) -> Tuple[int, str]:
-    """The case's declared per-chip HBM budget: an explicit
-    ``meta['hbm_budget_bytes']`` override, else the
-    ``meta['chip_profile']`` entry of ``obs.costs.PROFILES``
-    (default v5e, 16 GiB — the serving acceptance chip)."""
+    """The case's per-chip HBM budget and its label: an explicit
+    ``meta['hbm_budget_bytes']`` override, else one v5e chip's 16 GiB
+    (the serving acceptance chip)."""
     meta = prog.meta or {}
     if "hbm_budget_bytes" in meta:
         return int(meta["hbm_budget_bytes"]), "declared"
-    from apex_tpu.obs.costs import PROFILES
-
-    name = meta.get("chip_profile", "v5e")
-    profile = PROFILES.get(name, PROFILES["v5e"])
-    return profile.hbm_bytes, profile.name
+    return HBM_BYTES_V5E, "v5e"
 
 
 # --------------------------------------------------------------------------
@@ -93,7 +89,7 @@ def _build_tp4_acceptance(kind: str, weight_policy=None) -> CaseProgram:
         sync_every=4)
     dvars, var_specs = infer_variable_specs(engine.model)
     i32 = jnp.int32
-    meta = {"chip_profile": "v5e", "mesh_axes": {"model": tp}}
+    meta = {"mesh_axes": {"model": tp}}
     n = tpu_aot.TP_SERVING_SLOTS
     if kind == "decode":
         args = (engine.cache, dvars,

@@ -71,7 +71,7 @@ class MemContext:
     ir: object                    # CaseIR
     est: MemEstimate
     budget_bytes: int
-    budget_label: str             # "v5e" / "v5p" / "meta override"
+    budget_label: str             # "v5e" / "declared"
 
     @property
     def meta(self) -> dict:

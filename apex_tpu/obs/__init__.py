@@ -1,6 +1,6 @@
 """apex_tpu.obs — serving/training observability (docs/observability.md).
 
-Three host-side layers over the ``apex_tpu.utils.metrics`` instrument
+Four host-side layers over the ``apex_tpu.utils.metrics`` instrument
 registry, built for operating the continuous-batching serving engine the
 way production paged-KV systems are operated (Orca, Yu et al. 2022;
 vLLM, Kwon et al. 2023) — per-request lifecycle traces in the spirit of
@@ -14,17 +14,10 @@ Dapper (Sigelman et al. 2010):
   JSONL postmortem ``dump()``.
 - ``export`` — Prometheus text exposition + JSON snapshots of the
   metric registry, file-based or via a stdlib HTTP endpoint
-  (``/metrics``, ``/healthz``, ``/costs``).
-
-Performance attribution (PR 8) adds three more, CLI-first:
-
-- ``costs``  — deterministic jaxpr roofline cost model over the lint
-  harness's programs (``python -m apex_tpu.obs.costs``).
+  (``/metrics``, ``/healthz``).
 - ``compile_watch`` — :class:`CompileWatcher`: jit recompile /
   trace-cache-miss counters keyed by function name, with the serving
   frontend's recompile-storm warning built on top.
-- ``ledger`` — the persistent cost ledger + regression gate
-  (``python -m apex_tpu.obs.ledger --check``, ``COST_LEDGER.jsonl``).
 
 The fleet plane (``fleet``, docs/observability.md "Fleet plane") spans
 processes: process-independent trace ids stitched across replica
@@ -37,8 +30,7 @@ the schema-pinned postmortem flight recorder
 from apex_tpu.obs.compile_watch import CompileWatcher, watcher
 from apex_tpu.obs.events import EventLog
 from apex_tpu.obs.export import (describe, health_doc, json_snapshot,
-                                 latest_costs, prometheus_text,
-                                 publish_costs, serve, write_snapshot)
+                                 prometheus_text, serve, write_snapshot)
 from apex_tpu.obs.fleet import (FLIGHT_SCHEMA, BurnRateAlerter,
                                 FleetCollector, build_flight,
                                 mint_trace_id, parse_traceparent,
@@ -49,8 +41,8 @@ from apex_tpu.obs.spans import PHASES, Span, SpanTracer
 __all__ = ["BurnRateAlerter", "CompileWatcher", "EventLog",
            "FLIGHT_SCHEMA", "FleetCollector", "PHASES", "Span",
            "SpanTracer", "build_flight", "describe", "health_doc",
-           "json_snapshot", "latest_costs", "mint_trace_id",
-           "parse_traceparent", "prometheus_text", "publish_costs",
+           "json_snapshot", "mint_trace_id",
+           "parse_traceparent", "prometheus_text",
            "row_from_snapshot", "serve", "stitch_traces",
            "traceparent", "validate_flight", "watcher",
            "write_snapshot"]

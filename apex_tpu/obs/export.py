@@ -13,10 +13,9 @@ touches a device. Three transports:
 - :func:`json_snapshot` / :func:`write_snapshot` — the full registry as
   one JSON document (a CI artifact next to the bench JSON).
 - :func:`serve` — optional stdlib ``http.server`` endpoint exposing
-  ``/metrics`` (Prometheus), ``/metrics.json``, ``/healthz`` (liveness:
-  pump-alive + queue depth of the frontend passed via ``serve(...,
-  frontend=)``), and ``/costs`` (the latest cost-model snapshot
-  registered via :func:`publish_costs`) on a daemon thread; returns the
+  ``/metrics`` (Prometheus), ``/metrics.json`` and ``/healthz``
+  (liveness: pump-alive + queue depth of the frontend passed via
+  ``serve(..., frontend=)``) on a daemon thread; returns the
   server (``.server_address`` for the bound port, ``.shutdown()`` to
   stop). No third-party client library, per the no-new-deps rule.
 """
@@ -34,7 +33,7 @@ from typing import Dict, Optional
 from apex_tpu.utils import metrics
 
 __all__ = ["prometheus_text", "json_snapshot", "write_snapshot", "serve",
-           "publish_costs", "latest_costs", "health_doc", "describe"]
+           "health_doc", "describe"]
 
 _NAME_OK = re.compile(r"[a-zA-Z_:][a-zA-Z0-9_:]*$")
 
@@ -216,25 +215,6 @@ def write_snapshot(path: str, fmt: Optional[str] = None,
     return path
 
 
-# latest published cost-model snapshot (``/costs``): one process-wide
-# document, written by whoever ran the cost CLI/report last
-_COSTS_LOCK = threading.Lock()
-_COSTS_DOC: Optional[dict] = None
-
-
-def publish_costs(doc: Optional[dict]) -> None:
-    """Make a cost report (``apex_tpu.obs.costs.cost_report(...)``) the
-    document ``/costs`` serves (``None`` unpublishes: back to 404)."""
-    global _COSTS_DOC
-    with _COSTS_LOCK:
-        _COSTS_DOC = doc
-
-
-def latest_costs() -> Optional[dict]:
-    with _COSTS_LOCK:
-        return _COSTS_DOC
-
-
 def health_doc(frontend=None, router=None) -> dict:
     """The ``/healthz`` payload: process liveness plus — when a serving
     frontend is wired in — pump-thread liveness, queue depth, active
@@ -299,13 +279,6 @@ class _Handler(BaseHTTPRequestHandler):
         elif path == "/healthz":
             doc = health_doc(getattr(self.server, "frontend", None),
                              router=getattr(self.server, "router", None))
-            body = (json.dumps(doc, sort_keys=True) + "\n").encode()
-            ctype = "application/json"
-        elif path == "/costs":
-            doc = latest_costs()
-            if doc is None:
-                self.send_error(404, "no cost snapshot published")
-                return
             body = (json.dumps(doc, sort_keys=True) + "\n").encode()
             ctype = "application/json"
         else:

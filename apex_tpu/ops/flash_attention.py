@@ -44,36 +44,8 @@ _INTERPRET = _dispatch.interpret
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-_BLOCK_TABLE = None
-
-
-def _block_table():
-    """Autotuned (sq, sk, d, dtype) -> (block_q, block_k) winners, measured
-    on-chip by tpu_autotune.py and committed as _flash_block_table.json
-    next to this file. Missing file / missing key -> heuristic default."""
-    global _BLOCK_TABLE
-    if _BLOCK_TABLE is None:
-        import json
-        import os
-
-        path = os.path.join(os.path.dirname(__file__),
-                            "_flash_block_table.json")
-        try:
-            with open(path) as f:
-                _BLOCK_TABLE = {k: tuple(v) for k, v in json.load(f).items()}
-        except FileNotFoundError:
-            _BLOCK_TABLE = {}
-    return _BLOCK_TABLE
-
-
 def _block_sizes(sq: int, sk: int, block_q: Optional[int],
-                 block_k: Optional[int], d: Optional[int] = None,
-                 dtype=None):
-    if block_q is None and block_k is None and d is not None:
-        hit = _block_table().get(f"{sq},{sk},{d},{jnp.dtype(dtype).name}")
-        if hit:
-            return (min(hit[0], _dispatch.round_up(sq, 8)),
-                    min(hit[1], _dispatch.round_up(sk, 128)))
+                 block_k: Optional[int]):
     bq = block_q or min(128, _dispatch.round_up(sq, 8))
     bk = block_k or min(128, _dispatch.round_up(sk, 128))
     return bq, bk
@@ -82,9 +54,9 @@ def _block_sizes(sq: int, sk: int, block_q: Optional[int],
 #: keep a sublane-aligned head dim (64 for BERT/GPT-2 heads) unpadded
 #: instead of rounding it up to 128 lanes. It participates in traced shapes
 #: and jit caches are not keyed on it, so it is a constant, not a switch:
-#: the tests and tpu_autotune.py that try the other layout set it and call
+#: the tests that try the other layout set it and call
 #: ``jax.clear_caches()``. Which layout is faster on the chip is not
-#: measured yet.
+#: measured yet (ROADMAP Speed item 2).
 _TIGHT_HEADDIM = False
 
 
@@ -310,7 +282,7 @@ def _fa_fwd(q, k, v, bias, q_seg, kv_seg, seed, scale, causal, dropout_rate,
     batch, heads, q_len, d = q.shape
     kv_len = k.shape[2]
     rep = _gqa_rep(heads, k.shape[1])
-    bq, bk = _block_sizes(q_len, kv_len, block_q, block_k, d, q.dtype)
+    bq, bk = _block_sizes(q_len, kv_len, block_q, block_k)
     d_pad = _head_pad(d)
 
     qp = _pad_to(_pad_to(q, 2, bq), 3, d_pad)
@@ -557,7 +529,7 @@ def _fa_bwd_impl(q, k, v, bias, q_seg, kv_seg, seed, scale, causal,
     kv_len = k.shape[2]
     kv_heads = k.shape[1]
     rep = _gqa_rep(heads, kv_heads)
-    bq, bk = _block_sizes(q_len, kv_len, block_q, block_k, d, q.dtype)
+    bq, bk = _block_sizes(q_len, kv_len, block_q, block_k)
     d_pad = _head_pad(d)
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)

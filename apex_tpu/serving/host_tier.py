@@ -5,8 +5,8 @@ At long contexts the page pool, not the weights, caps ``num_slots``
 (docs/tp_serving.md "Pool sizing"), and before this tier an evicted
 radix page or a discarded preemption spill was simply recomputed — the
 eviction-churn scenario lights ``prefix_cache.churn`` exactly there.
-Copying a full page over the host link is strictly cheaper than
-re-prefilling it (``cost.decode.host_tier.*`` prices both sides), so
+A page copied back over the host link stands in for re-prefilling its
+tokens (which of the two is cheaper on the chip is not measured), so
 refcount-0 pages the device pool can no longer afford DEMOTE here and
 PROMOTE back into freshly allocated pages on the next prefix hit or
 preemption resume, instead of being thrown away.

@@ -11,7 +11,7 @@ repo's no-new-deps stance): :class:`HttpServingServer` exposes
 - ``POST /v1/cancel/<request_id>`` — cancel a live stream (the request
   retires at its next sync boundary; the SSE stream terminates with
   ``finish_reason: "cancelled"``);
-- ``GET /healthz`` / ``/metrics`` / ``/metrics.json`` / ``/costs`` — the
+- ``GET /healthz`` / ``/metrics`` / ``/metrics.json`` — the
   observability endpoints ``apex_tpu.obs.export`` has always served,
   unified on the serving port (``health_doc`` grows an ``http`` block
   and — when the target is a router — the per-replica block).
@@ -333,13 +333,6 @@ class HttpServingServer:
         elif method == "GET" and path == "/metrics.json":
             await self._resp(writer, 200,
                              _json_bytes(obs_export.json_snapshot()))
-        elif method == "GET" and path == "/costs":
-            doc = obs_export.latest_costs()
-            if doc is None:
-                await self._resp(writer, 404, _json_bytes(
-                    {"error": "no cost snapshot published"}))
-            else:
-                await self._resp(writer, 200, _json_bytes(doc))
         else:
             await self._resp(writer, 404, _json_bytes(
                 {"error": f"no route {method} {path}"}))

@@ -818,8 +818,7 @@ def max_slots_for_pool_bytes(config, pool_bytes: int, *,
     """How many ``pages_per_slot``-page slots a ``pool_bytes`` budget
     admits (the null page 0 is carved out first). Holding ``pool_bytes``
     fixed, ``kv_dtype='int8'`` admits ~2x the slots of the bf16 pool —
-    the acceptance pin in ``tests/test_quantized_kv.py`` and the
-    slot-capacity telemetry in ``tpu_decode_bench.py``."""
+    the acceptance pin in ``tests/test_quantized_kv.py``."""
     pb = page_bytes(config, page_size, kv_dtype=kv_dtype, dtype=dtype)
     num_pages = pool_bytes // pb
     return max(int(num_pages - 1) // pages_per_slot, 0)

@@ -32,9 +32,7 @@ makes workloads DECLARATIVE, SEEDED, and REPLAYABLE:
 
 CLI: ``python -m apex_tpu.serving.scenarios --list`` /
 ``--scenario NAME [--scenario NAME ...] --json OUT --seed N [--check]``
-(also installed as ``apex-tpu-scenarios``). The ``scenario.<name>.*``
-SLO fields of a ``--json`` document are what the cost ledger band-gates
-(``obs.ledger --bench``).
+(also installed as ``apex-tpu-scenarios``).
 
 Docs: docs/scenarios.md (spec format, seeding contract, catalog, report
 schema, extension guide).

@@ -3,10 +3,9 @@ installed as ``apex-tpu-scenarios``).
 
 Runs catalog scenarios on the local backend (CI pins CPU via
 ``JAX_PLATFORMS=cpu``) and writes one JSON document —
-``{"schema": "apex-tpu/scenarios/v1", "scenarios": {name: report}}`` —
-whose per-scenario reports the perf ledger's ``--bench`` extraction
-understands (``scenario.<name>.ttft_ms_p95`` etc.). Exit codes: 0 ok,
-1 a ``--check`` amplifier found divergence, 2 usage/unknown scenario.
+``{"schema": "apex-tpu/scenarios/v1", "scenarios": {name: report}}``.
+Exit codes: 0 ok, 1 a ``--check`` amplifier found divergence, 2
+usage/unknown scenario.
 """
 
 from __future__ import annotations
@@ -116,7 +115,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                 # a trace is only replayable under the spec that
                 # materialized it — the events carry the spec's model
                 # bounds (vocab/position table), and the report would
-                # otherwise bank A's trace under B's ledger baselines
+                # otherwise carry A's trace under B's name
                 print(f"[scenarios] trace {args.trace} was materialized "
                       f"for scenario {trace.scenario!r}, not {name!r}")
                 return 2

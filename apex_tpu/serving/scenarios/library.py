@@ -2,11 +2,11 @@
 
 Every entry is a factory ``(seed) -> ScenarioSpec`` registered under a
 stable name; ``scenario_spec(name, seed, **overrides)`` builds the spec
-and applies top-level ``dataclasses.replace`` overrides (how the bench
-and tests scale a scenario up or down without forking its definition).
+and applies top-level ``dataclasses.replace`` overrides (how the tests
+scale a scenario up or down without forking its definition).
 Sizes here are deliberately tiny-model/CPU-tier: a scenario is a
 workload SHAPE + SLO harness, reproducible in tier-1 time — on-chip
-throughput numbers stay ``tpu_decode_bench.py``'s business.
+throughput numbers are ``benchmark/``'s business.
 
 The catalog (docs/scenarios.md has the prose):
 
@@ -39,9 +39,9 @@ The catalog (docs/scenarios.md has the prose):
 - ``windowed-llama`` — sliding-window Llama on the PAGED path (the band
   rides the paged kernel, dead pages drop at sync boundaries): long
   generations at O(window) live pages per slot.
-- ``bench-mixed-length`` / ``bench-shared-prefix`` — the decode bench's
-  two original workloads, now defined here (``tpu_decode_bench.py``
-  materializes these instead of carrying inline generators).
+- ``bench-mixed-length`` / ``bench-shared-prefix`` — mixed
+  prompt/output lengths, and one system prompt before random tails: the
+  two workloads the CLI tests and the ``--http`` smoke replay.
 - ``preemption-storm`` — the ROADMAP-5 adversary: a rapid
   high-priority deadline stream over one slot forces repeated
   preempt/resume cycles on a long-running bulk request; the recompile
@@ -410,7 +410,7 @@ def _router_affinity_ab(seed: int) -> ScenarioSpec:
     # the multi-tenant radix-cache workload over TWO replicas, banked
     # both ways: affinity routing (tenant header -> one replica, its
     # cache warm) vs round-robin (headers smeared over both caches).
-    # The aggregate hit-rate delta is the ledger-banked proof
+    # The aggregate hit-rate delta is the proof
     return ScenarioSpec(
         name="router-affinity-ab", seed=seed, n_requests=24,
         arrival=Arrival(kind="poisson", rate_rps=500.0),
@@ -433,9 +433,8 @@ def _router_affinity_ab(seed: int) -> ScenarioSpec:
 
 @register("bench-mixed-length")
 def _bench_mixed_length(seed: int) -> ScenarioSpec:
-    # tpu_decode_bench's original paged workload, catalogued: mixed
-    # prompt/output lengths so continuous batching beats lock-step
-    # padding (the step-savings assert)
+    # mixed prompt/output lengths, so continuous batching beats
+    # lock-step padding
     return ScenarioSpec(
         name="bench-mixed-length", seed=seed, n_requests=8,
         arrival=Arrival(kind="poisson", rate_rps=500.0),
@@ -444,8 +443,7 @@ def _bench_mixed_length(seed: int) -> ScenarioSpec:
         tenants=(Tenant("default"),),
         engine=EngineSpec(model="gpt2-tiny", num_slots=3, page_size=8,
                           prefix_cache=False),
-        description="the decode bench's mixed-length closed-loop "
-                    "workload")
+        description="mixed prompt/output lengths, closed loop")
 
 
 @register("bench-shared-prefix")
@@ -459,4 +457,4 @@ def _bench_shared_prefix(seed: int) -> ScenarioSpec:
         tenants=(Tenant("shared", system_prompt_tokens=4 * ps),),
         engine=EngineSpec(model="gpt2-tiny", num_slots=2, page_size=ps,
                           prefix_cache=True),
-        description="the decode bench's shared-system-prompt workload")
+        description="one shared system prompt before random tails")

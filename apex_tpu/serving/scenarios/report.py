@@ -1,19 +1,14 @@
 """The pinned-schema ScenarioReport: per-tenant + aggregate SLO stats.
 
-One scenario run produces one report dict with a FIXED shape (CI, the
-perf ledger, and the tests all key into it — ``validate_report`` is the
-contract check). Latency percentiles are computed from the span tracer's
+One scenario run produces one report dict with a FIXED shape (CI and
+the tests key into it — ``validate_report`` is the contract check).
+Latency percentiles are computed from the span tracer's
 per-request lifecycles (docs/observability.md) — exact percentiles over
 this run's requests, the same source the frontend's run stats use — so
 the per-tenant splits and the aggregate are consistent by construction.
 Engine counters (hit rate, preemptions, evictions, window drops) come
 from the frontend's ``stats()`` delta dict and are embedded verbatim
 under ``engine`` for postmortems.
-
-``python -m apex_tpu.obs.ledger --append --bench SCENARIOS_<tag>.json``
-extracts ``scenario.<name>.ttft_ms_p95`` / ``tpot_ms_p95`` /
-``deadline_miss_rate`` from the aggregate block and band-gates them like
-the other wall-time metrics.
 """
 
 from __future__ import annotations
@@ -28,7 +23,10 @@ __all__ = ["REPORT_SCHEMA", "SCENARIOS_SCHEMA", "AGGREGATE_FIELDS",
            "validate_report"]
 
 REPORT_SCHEMA = "apex-tpu/scenario-report/v1"
-#: the multi-scenario CLI document wrapping one report per scenario
+#: the multi-scenario CLI document wrapping one report per scenario.
+#: Write-only CI evidence like the ``--fleet`` sidecar below: each report
+#: inside it carries ``REPORT_SCHEMA``, which ``validate_report`` reads.
+# tpu-lint: disable=contract-schema-unpinned -- write-only CI evidence
 SCENARIOS_SCHEMA = "apex-tpu/scenarios/v1"
 #: the ``--fleet`` sidecar document (per-scenario federated fleet
 #: blocks). Write-only CI evidence — banked per round for human review,
@@ -199,8 +197,7 @@ def build_report(spec, trace, outputs, stats: dict, tracer,
 
 def validate_report(report: dict) -> None:
     """The schema pin: raise ``ValueError`` on any missing key (CI's
-    smoke and the tests call this so the ledger extraction can rely on
-    the shape)."""
+    smoke and the tests call this so readers can rely on the shape)."""
     for key in ("schema", "scenario", "seed", "model", "n_requests",
                 "n_tenants", "trace_sha256", "aggregate", "per_tenant",
                 "engine"):

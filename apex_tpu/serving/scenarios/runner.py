@@ -49,9 +49,9 @@ __all__ = ["EngineSpec", "ScenarioSpec", "ScenarioResult", "MODELS",
 
 #: scenario model registry: tiny CPU-fast configs (the scenario layer is
 #: a workload/SLO harness, not a throughput bench — on-chip numbers
-#: come from tpu_decode_bench.py at real sizes).
-#: ``gpt2-small`` exists for the bench's full-size trace materialization
-#: (vocab/position bounds); don't replay it on CPU.
+#: come from ``benchmark/`` at real sizes).
+#: ``gpt2-small`` exists to materialize a trace inside a full-size
+#: model's vocab/position bounds; don't replay it on CPU.
 MODELS = ("gpt2-tiny", "llama-tiny", "llama-tiny-windowed",
           "gpt2-small")
 

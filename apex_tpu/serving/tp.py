@@ -106,8 +106,8 @@ def tp_mesh(tp: int, devices=None, axis_name: str = MODEL_AXIS) -> Mesh:
 
 def abstract_tp_mesh(tp: int, axis_name: str = MODEL_AXIS):
     """A deviceless ``AbstractMesh`` for trace-only TP engines (the IR
-    lint harness / cost model trace the shard_map programs on any host,
-    with any device count — no real mesh required)."""
+    lint harness traces the shard_map programs on any host, with any
+    device count — no real mesh required)."""
     from jax.sharding import AbstractMesh
 
     return AbstractMesh((tp,), (axis_name,))
@@ -246,8 +246,8 @@ class TensorParallelPagedEngine(PagedDecodeEngine):
 
     ``abstract=True`` (implied by an ``AbstractMesh``) builds the
     trace-only form: no device buffers, ``ShapeDtypeStruct`` cache,
-    ``variables=None`` — for the IR lint harness, the cost model, and
-    the deviceless AOT tier. Such an engine cannot ``run()``.
+    ``variables=None`` — for the IR lint harness and the deviceless
+    AOT tier. Such an engine cannot ``run()``.
     """
 
     def __init__(self, model, variables, *, mesh=None,

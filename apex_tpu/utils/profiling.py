@@ -11,7 +11,7 @@ annotation + block_until_ready timing from day one as a gap to EXCEED.
   (the nvtx-range analog).
 - ``trace(logdir)``: context manager around jax.profiler.trace.
 - ``time_fn(fn, *args)``: wall-time with block_until_ready (the
-  cuda-synchronize discipline) — used by bench.py.
+  cuda-synchronize discipline).
 """
 
 from __future__ import annotations

@@ -17,7 +17,7 @@ import os
 #
 # APEX_TPU_REAL=1 keeps the ambient TPU backend instead: the on-chip kernel
 # suite (tests/test_real_tpu_kernels.py) then compiles every Pallas kernel
-# via Mosaic at bench-relevant shapes and runs it. Run it on the chip as:
+# via Mosaic at real shapes and runs it. Run it on the chip as:
 #   APEX_TPU_REAL=1 python -m pytest tests/test_real_tpu_kernels.py -v
 REAL_TPU = os.environ.get("APEX_TPU_REAL") == "1"
 

@@ -11,8 +11,8 @@ This is the ONE test file that describes a topology: only one process at a
 time may load the TPU's library, so the call lives in a fixture (never at
 import) and every such test lives here, on one xdist worker. The kernel
 cases that compile in a few seconds run in tier-1; the multi-chip sweeps
-are ``slow``. The full sweep (every config + the BERT-Large train step +
-the autotune candidate set) is ``python tpu_aot.py`` -> ``AOT_<tag>.json``.
+are ``slow``. The full sweep (every config + the BERT-Large train step)
+is ``python tpu_aot.py`` -> ``AOT_<tag>.json``.
 """
 
 import os

@@ -175,29 +175,6 @@ def validate(doc):
         {"apex_tpu/mod.py": _DISPATCH,
          "docs/http.md": _ENDPOINTS_BOTH},
     ),
-    "contract-ledger-class-drift": (
-        {"apex_tpu/mod.py": """\
-_HIGHER_BETTER = ("tokens_per_sec", "hit_rate")
-_LOWER_BETTER = ("_ms", "misses")
-_RATE_SUFFIXES = ("hit_rate",)
-
-_BENCH_FIELDS = (
-    "decode_ttft_ms",
-    "prefix_hit_rate",
-    "mystery_knob",
-)
-"""},
-        {"apex_tpu/mod.py": """\
-_HIGHER_BETTER = ("tokens_per_sec", "hit_rate")
-_LOWER_BETTER = ("_ms", "misses")
-_RATE_SUFFIXES = ("hit_rate",)
-
-_BENCH_FIELDS = (
-    "decode_ttft_ms",
-    "prefix_hit_rate",
-)
-"""},
-    ),
     "contract-golden-stale": (
         {"apex_tpu/mod.py": """\
 def observe(metrics):
@@ -403,7 +380,7 @@ def test_list_rules_shows_contract_tier(capsys):
     assert cli.main(["--list-rules"]) == 0
     out = capsys.readouterr().out
     assert "contract:wire" in out
-    assert "contract-ledger-class-drift" in out
+    assert "contract-golden-stale" in out
     assert "mem:budget" in out
 
 

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from apex_tpu.ops.flash_attention import (
+    _block_sizes,
     flash_attention,
     mha_reference,
 )
@@ -80,6 +81,22 @@ def test_block_size_invariance(rng):
     a = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
     b_ = flash_attention(q, k, v, causal=True, block_q=64, block_k=256)
     np.testing.assert_allclose(a, b_, atol=1e-5, rtol=1e-5)
+
+
+# (sq = sk, block asked for) -> tiles, for every flash call the four
+# benchmark cells make: the training step, ``gpt2-large``'s six prompt
+# buckets, ``glm-4.7-flash``'s five with ``_PREFILL_BLOCK``. A change of
+# any tile is a change of the traced program: a ``perf_opt`` PR's, measured.
+@pytest.mark.parametrize("s,block,tiles", [
+    (512, None, (128, 128)),
+    (96, None, (96, 128)), (160, None, (128, 128)), (256, None, (128, 128)),
+    (384, None, (128, 128)), (768, None, (128, 128)),
+    (1024, 512, (512, 512)), (2048, 512, (512, 512)),
+    (4096, 512, (512, 512)), (8192, 512, (512, 512)),
+    (16384, 512, (512, 512)),
+])
+def test_tiles_at_the_benchmark_cells_shapes(s, block, tiles):
+    assert _block_sizes(s, s, block, block) == tiles
 
 
 def _np_keep(bh, s1, s2, rate, seed):

@@ -162,7 +162,7 @@ def test_stream_token_identical_and_endpoints(tiny, rng):
                                           "max_new_tokens": 6})
         np.testing.assert_array_equal(toks, _ref(model, v, prompt, 6))
         assert finish == "stop"
-        # the unified port: health + metrics + costs next to generate
+        # the unified port: health + metrics next to generate
         status, body = _get(srv.port, "/healthz")
         doc = json.loads(body)
         assert status == 200 and doc["ok"]
@@ -172,11 +172,11 @@ def test_stream_token_identical_and_endpoints(tiny, rng):
         assert status == 200 and b"http_tokens" in body
         status, body = _get(srv.port, "/metrics.json")
         assert status == 200 and "counters" in json.loads(body)
-        # no cost snapshot published in this process -> a clean 404,
-        # not a crash (publish_costs flips it to 200; test_costs owns
-        # that path)
+        # ``/costs`` went with the cost model: a 404 like any unknown
+        # path, with the route named in the JSON body
         status, body = _get(srv.port, "/costs")
-        assert status == 404 and b"no cost snapshot" in body
+        assert status == 404
+        assert json.loads(body)["error"] == "no route GET /costs"
         assert srv.http_counter_deltas()["tokens"] == 6
 
 

@@ -651,35 +651,6 @@ def test_healthz_router_block(tmp_path):
         server.server_close()
 
 
-def test_costs_endpoint_payload_shape():
-    """/costs 404s until a snapshot is published, then serves the
-    report with the pinned top-level shape."""
-    from apex_tpu.obs import export
-
-    server = serve(port=0)
-    try:
-        host, port = server.server_address[:2]
-        url = f"http://{host}:{port}/costs"
-        with pytest.raises(urllib.error.HTTPError) as err:
-            urllib.request.urlopen(url)
-        assert err.value.code == 404
-        export.publish_costs({
-            "schema": 1, "profile": {"name": "v5e"},
-            "totals": {"flops": 1, "hbm_bytes": 2, "predicted_ms": 0.1},
-            "cases": [], "by_domain": {}, "decode_split": None,
-            "errors": []})
-        with urllib.request.urlopen(url) as r:
-            assert r.headers["Content-Type"] == "application/json"
-            doc = json.loads(r.read())
-        assert set(doc) == {"schema", "profile", "totals", "cases",
-                            "by_domain", "decode_split", "errors"}
-        assert export.latest_costs()["schema"] == 1
-    finally:
-        export.publish_costs(None)     # leave no cross-test snapshot
-        server.shutdown()
-        server.server_close()
-
-
 # --------------------------------------------------------------------------
 # 5. event log
 # --------------------------------------------------------------------------

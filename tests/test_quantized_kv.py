@@ -4,8 +4,8 @@ dequantized inside the paged-attention kernel.
 
 Invariant tier (fast): the dtype-resolution contract and its NAMED
 errors (no silent fp32 fallback), the >= 1.9x fixed-budget slot-capacity
-pin (the acceptance number), the <= 0.55x per-step KV byte pin through
-the cost model's own ``_kv_step_bytes_max``, kernel parity against the
+pin (the acceptance number), ``page_bytes`` against the bytes the pool
+holds per page (int8 <= 0.55x), kernel parity against the
 dequantizing reference at s=1 and s>1, prefill/append quantization error
 bounds, requantize-on-grow's full-page bit-stability (the invariant
 prefix sharing and preemption spill lean on), defrag's exact scale
@@ -143,22 +143,27 @@ def test_slot_capacity_and_page_byte_pins():
             assert f8_slots == q_slots          # same 1-byte pages
 
 
-def test_cost_model_kv_step_bytes_ratio():
-    """The ledger pin's substrate: ``obs.costs._kv_step_bytes_max`` over
-    the ACTUAL pool avals prices the int8 pool's per-step KV reads
-    (scale rows included) at <= 0.55x the bf16 pool's."""
-    from apex_tpu.obs.costs import _kv_step_bytes_max
-
+def test_page_bytes_is_what_the_pool_holds_per_page():
+    """``kv_pool.page_bytes`` against the ACTUAL pool avals: the bytes
+    every layer's tensors hold for one page (an int8 pool's scale rows
+    included) are exactly what it states, and the int8 page costs
+    <= 0.55x the unquantized one."""
     cfg = gpt_tiny_config()
+    num_pages = 33
 
-    def pool(kv_dtype):
-        return jax.eval_shape(
-            lambda: init_paged_cache(cfg, num_slots=4, num_pages=33,
+    def held_per_page(kv_dtype):
+        pool = jax.eval_shape(
+            lambda: init_paged_cache(cfg, num_slots=4, num_pages=num_pages,
                                      page_size=16, max_pages_per_seq=16,
                                      kv_dtype=kv_dtype))
+        held = sum(x.size * x.dtype.itemsize
+                   for x in jax.tree.leaves(pool["layers"]))
+        assert held % num_pages == 0
+        return held // num_pages
 
-    fp_bytes, _ = _kv_step_bytes_max(pool(None))
-    q_bytes, _ = _kv_step_bytes_max(pool("int8"))
+    fp_bytes, q_bytes = held_per_page(None), held_per_page("int8")
+    assert fp_bytes == page_bytes(cfg, 16)
+    assert q_bytes == page_bytes(cfg, 16, kv_dtype="int8")
     assert q_bytes <= 0.55 * fp_bytes, (q_bytes, fp_bytes)
 
 

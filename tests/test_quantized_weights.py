@@ -11,8 +11,7 @@ invariant, quantization error bounds per kind, fused-kernel parity
 against the dequantizing reference for all three kinds, the policy
 round trip leaving fp leaves untouched (bit-identical embeddings/norms/
 biases), and the per-step weight-byte ratio pins at real gpt2-small
-shapes (w8 <= 0.55x fp, w4 <= 0.35x fp — scale reads included), the
-substrate of ``cost.decode.w8.weight_bytes_ratio_vs_bf16``.
+shapes (w8 <= 0.55x fp, w4 <= 0.35x fp — scale reads included).
 
 Engine tier (slow): greedy decode through the real engines — int8, fp8
 and int4-grouped weight trees vs the fp tree on GPT and windowed Llama,
@@ -213,10 +212,9 @@ def test_policy_roundtrip_leaves_fp_untouched(rng):
 
 def test_weight_bytes_ratio_pins():
     """The acceptance numbers at REAL gpt2-small shapes, straight off
-    the abstract param trees the cost model prices (per-LEAF dtype
-    bytes, scale reads included): int8 policy <= 0.55x the fp tree,
-    int4 policy (+ bf16 fp leaves, the documented aggressive pairing)
-    <= 0.35x — ``cost.decode.w8/w4.weight_bytes_ratio_vs_bf16``."""
+    the abstract param trees (per-LEAF dtype bytes, scale reads
+    included): int8 policy <= 0.55x the fp tree, int4 policy (+ bf16 fp
+    leaves, the documented aggressive pairing) <= 0.35x."""
     from apex_tpu.models.gpt import gpt2_small_config
 
     def tree_bytes(cfg):

@@ -191,7 +191,7 @@ def test_saved_trace_replays_identically(tmp_path):
     same tokens as the materialized original. (Slow tier since ISSUE 15
     to hold the 870 s verify wall: the CLI --trace round-trip — save,
     wrong-scenario refusal, seed provenance, sha pin — stays tier-1 in
-    test_cli_json_document_and_ledger_extraction.)"""
+    test_cli_json_document_and_trace_replay.)"""
     r1 = run_scenario(_SMALL)
     path = tmp_path / "small.trace.jsonl"
     r1.trace.save(path)
@@ -297,7 +297,7 @@ def test_chaos_replica_kill_scenario_recovers_token_exact():
     """ISSUE 11 acceptance: the catalogued mid-decode replica kill
     completes every request — the greedy-identity amplifier proves the
     failover corrupted nothing — with the failure facts in the pinned
-    router block and both rates banked for the ledger. (Slow tier since
+    router block. (Slow tier since
     ISSUE 15 to hold the 870 s verify wall: the kill bar stays tier-1
     twice over — tests/test_router.py::
     test_replica_kill_mid_decode_recovers_token_identical in-process and
@@ -340,11 +340,11 @@ def test_chaos_pump_stall_scenario_is_latency_only():
 def test_router_affinity_ab_beats_round_robin():
     """ISSUE 11 acceptance: the multi-tenant workload's aggregate
     prefix hit-rate under affinity routing strictly beats round-robin
-    on the same trace (both numbers + the delta land in the report for
-    the ledger to bank). (Slow tier: the deterministic tier-1 twin is
+    on the same trace (both numbers + the delta land in the report).
+    (Slow tier: the deterministic tier-1 twin is
     tests/test_router.py::
     test_affinity_hit_rate_beats_round_robin_deterministic; CI's chaos
-    smoke replays this full entry per round and the ledger gates it.)"""
+    smoke replays this full entry per round.)"""
     r = run_scenario(scenario_spec("router-affinity-ab", seed=0))
     rb = r.report["router"]
     assert rb["routing"] == "affinity"
@@ -504,49 +504,6 @@ def test_chaos_disconnect_storm_prefixes_and_no_leak():
     validate_report(r.report)
 
 
-def test_ledger_extracts_router_fields(tmp_path):
-    """CHAOS_<tag>.json (a scenarios/v1 document of router scenarios)
-    yields the band-gated scenario.<name>.failover_recovered_rate and
-    hit-rate A/B metrics."""
-    import json as json_mod
-
-    from apex_tpu.obs.ledger import bench_metrics_from_file
-
-    doc = {"schema": "apex-tpu/scenarios/v1", "seed": 0,
-           "scenarios": {"chaos-replica-kill": {
-               "aggregate": {"ttft_ms_p95": 12.5, "tpot_ms_p95": 3.0,
-                             "deadline_miss_rate": 0.0},
-               "router": {"failover_recovered_rate": 1.0,
-                          "affinity_hit_rate": 0.6,
-                          "round_robin_hit_rate": 0.45,
-                          "affinity_delta_hit_rate": 0.15},
-               "http": {"backpressure_spills": 2, "disconnects": 4,
-                        "conn_reset_retries": 2,
-                        "slow_reader_stalls": 2, "errors": 0}}}}
-    path = tmp_path / "CHAOS_test.json"
-    path.write_text(json_mod.dumps(doc))
-    m, meta = bench_metrics_from_file(path)
-    assert m["scenario.chaos-replica-kill.failover_recovered_rate"] \
-        == 1.0
-    assert m["scenario.chaos-replica-kill.affinity_hit_rate"] == 0.6
-    assert m["scenario.chaos-replica-kill.affinity_delta_hit_rate"] \
-        == pytest.approx(0.15)
-    # the HTTP chaos block lands as informational (never band-gated)
-    # counters — the banked spill/disconnect proof per round
-    assert m["scenario.chaos-replica-kill.http_backpressure_spills"] \
-        == 2.0
-    assert m["scenario.chaos-replica-kill.http_disconnects"] == 4.0
-    # direction classes: recovered/hit rates gate on the absolute rate
-    # band as higher-better
-    from apex_tpu.obs.ledger import check as ledger_check
-    entries = [{"metrics": m, "tag": "base", "git_rev": "x"}]
-    worse = dict(m)
-    worse["scenario.chaos-replica-kill.failover_recovered_rate"] = 0.5
-    regs = ledger_check(worse, entries)
-    assert any("failover_recovered_rate" in r.metric for r in regs)
-    assert not ledger_check(dict(m), entries)
-
-
 def test_host_tier_churn_scenario_beats_tier_off():
     """ISSUE 17 acceptance: at the eviction-churn pool size the host
     spill tier turns churned re-prefills into promotes — the report's
@@ -565,50 +522,14 @@ def test_host_tier_churn_scenario_beats_tier_off():
     assert r.report["checks"]["scheduling_invariance"] is True
 
 
-def test_ledger_extracts_host_tier_fields(tmp_path):
-    """A scenarios/v1 document with a host_tier block yields the
-    band-gated scenario.<name>.tier_*_hit_rate / promote_hit_rate
-    metrics (all end in hit_rate: absolute rate band, higher-better)."""
-    import json as json_mod
-
-    from apex_tpu.obs.ledger import bench_metrics_from_file
-
-    doc = {"schema": "apex-tpu/scenarios/v1", "seed": 0,
-           "scenarios": {"host-tier-churn": {
-               "aggregate": {"ttft_ms_p95": 9.0},
-               "host_tier": {"tier_on_hit_rate": 0.75,
-                             "tier_off_hit_rate": 0.625,
-                             "tier_delta_hit_rate": 0.125,
-                             "promote_hit_rate": 0.33,
-                             "demotes": 42, "promotes": 16}}}}
-    path = tmp_path / "SCENARIOS_test.json"
-    path.write_text(json_mod.dumps(doc))
-    m, _ = bench_metrics_from_file(path)
-    assert m["scenario.host-tier-churn.tier_on_hit_rate"] == 0.75
-    assert m["scenario.host-tier-churn.tier_off_hit_rate"] == 0.625
-    assert m["scenario.host-tier-churn.tier_delta_hit_rate"] \
-        == pytest.approx(0.125)
-    assert m["scenario.host-tier-churn.promote_hit_rate"] \
-        == pytest.approx(0.33)
-
-    # a tier-delta collapse gates as a regression (higher-better rate)
-    from apex_tpu.obs.ledger import check as ledger_check
-    entries = [{"metrics": m, "tag": "base", "git_rev": "x"}]
-    worse = dict(m)
-    worse["scenario.host-tier-churn.tier_on_hit_rate"] = 0.3
-    regs = ledger_check(worse, entries)
-    assert any("tier_on_hit_rate" in r.metric for r in regs)
-    assert not ledger_check(dict(m), entries)
+# --- CLI ---------------------------------------------------------------------
 
 
-# --- CLI + ledger integration ------------------------------------------------
-
-
-def test_cli_json_document_and_ledger_extraction(tmp_path):
+def test_cli_json_document_and_trace_replay(tmp_path):
     """python -m apex_tpu.serving.scenarios writes the scenarios/v1
-    document whose per-scenario SLO fields the perf ledger extracts as
-    scenario.<name>.* (the band-gated wall-time metrics)."""
-    from apex_tpu.obs.ledger import bench_metrics_from_file
+    document (one validated report per scenario), refuses unknown
+    scenarios and foreign traces, and replays a saved trace under the
+    trace's own seed."""
     from apex_tpu.serving.scenarios.__main__ import main
 
     out = tmp_path / "scen.json"
@@ -617,13 +538,12 @@ def test_cli_json_document_and_ledger_extraction(tmp_path):
     assert rc == 0
     doc = json.loads(out.read_text())
     assert doc["schema"] == "apex-tpu/scenarios/v1"
+    assert doc["seed"] == 4 and set(doc["scenarios"]) \
+        == {"bench-mixed-length"}
     rep = doc["scenarios"]["bench-mixed-length"]
     validate_report(rep)
-    m, meta = bench_metrics_from_file(out)
-    assert meta["schema"] == "apex-tpu/scenarios/v1"
-    assert m["scenario.bench-mixed-length.ttft_ms_p95"] > 0
-    assert m["scenario.bench-mixed-length.tpot_ms_p95"] > 0
-    assert "scenario.bench-mixed-length.deadline_miss_rate" in m
+    assert rep["aggregate"]["ttft_ms_p95"] > 0
+    assert rep["aggregate"]["tpot_ms_p95"] > 0
     # unknown scenario is a usage error caught BEFORE any replay runs
     # (a typo in the last --scenario must not cost the first ones'
     # replay time), --list succeeds
@@ -633,7 +553,7 @@ def test_cli_json_document_and_ledger_extraction(tmp_path):
     assert main(["--list"]) == 0
     # --trace refuses a trace materialized for a DIFFERENT scenario
     # (its events carry the other spec's model bounds, and its report
-    # would bank under the wrong ledger baselines)
+    # would carry the wrong scenario's name)
     tr = tmp_path / "mixed.trace.jsonl"
     materialize(scenario_spec("bench-mixed-length", seed=4)).save(tr)
     assert main(["--scenario", "steady-poisson",

@@ -8,7 +8,7 @@ engine stays SCHEDULING-INVARIANT (slot count, sync_every, arrival
 pacing); and the frontend/prefix-cache/scenario stack composes with the
 sharded engine transparently. Also covers the variable-sharding helper
 (Megatron fused-projection interleave) and the trace-only AbstractMesh
-form the lint harness / cost model use.
+form the lint harness uses.
 """
 
 import jax

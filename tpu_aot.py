@@ -1,9 +1,9 @@
 """Offline AOT-Mosaic sweep: compile for a described v5e, run nothing.
 
 Compiles every Pallas kernel at the on-chip suite's exact shapes — plus the
-full BERT-Large train step at the bench config, the multi-chip sharded
-programs and the flash-attention autotune candidate set — against a TPU
-topology that is described, not attached (``jax.experimental.topologies``).
+full BERT-Large train step at the benchmark's batch and the multi-chip
+sharded programs — against a TPU topology that is described, not attached
+(``jax.experimental.topologies``).
 The installed TPU compiler does the work on the CPU host: Mosaic block-rule
 violations, illegal layouts, scoped-VMEM overflows and HBM blowups all
 surface at this compile/memory level. A compile that passes is not a chip
@@ -350,10 +350,9 @@ def kernel_cases():
             _sds((513, 12, 16, 64), jnp.int8), _sds((8, 32), i32),
             _sds((8,), i32), _sds((513, 12), f32), _sds((513, 12), f32)])
 
-    # -- serving path (r5): tpu_decode_bench.py's exact programs — flash
-    # prefill + lax.scan single-token decode + argmax, GPT-2 small at the
-    # bench config (batch 8, prompt 128, 128 new tokens, bf16), fp AND
-    # int8 W8A8. The decode path had only ever compiled on CPU.
+    # -- serving path (r5): lock-step ``generate`` — flash prefill +
+    # lax.scan single-token decode + argmax, GPT-2 small (batch 8,
+    # prompt 128, 128 new tokens, bf16), fp AND int8 W8A8.
     import dataclasses
 
     from apex_tpu.models.generation import generate
@@ -522,7 +521,7 @@ def moe_case():
 
 
 def bert_train_step_case(batch_per_chip=8, remat=False):
-    """The full bench-gate program: BERT-Large loss+grads+FusedLAMB update at
+    """The whole training program: BERT-Large loss+grads+FusedLAMB update at
     batch ``batch_per_chip``, seq 512 — all kernels in one compiled program.
     Params/optimizer state are abstract (eval_shape + a field-initialized
     FusedLAMB), so no 1.4 GB host arrays are materialized."""
@@ -987,70 +986,10 @@ def multichip_aot(topo, only=None):
 
 
 # ---------------------------------------------------------------------------
-# autotune candidate compile sweep (VERDICT r4 next #3)
-# ---------------------------------------------------------------------------
-
-def autotune_candidate_sweep(mesh, tight_shapes=((8, 16, 512, 64),)):
-    """AOT-compile every (block_q, block_k) autotune candidate fwd+bwd at the
-    sweep shapes (tpu_autotune.SHAPES x CANDS) so the on-chip autotuner only
-    times, never debugs. Tight-head-dim variants at ``tight_shapes``."""
-    import importlib
-
-    import jax
-    import jax.numpy as jnp
-
-    import tpu_autotune
-
-    fa_impl = importlib.import_module("apex_tpu.ops.flash_attention")
-    flash_attention = fa_impl.flash_attention
-    out = {}
-    for shape in tpu_autotune.SHAPES:
-        b, h, s, d = shape
-        key = "x".join(map(str, shape))
-        out[key] = {}
-        for tight in (False, True):
-            if tight and shape not in tight_shapes:
-                continue
-            for bq, bk in tpu_autotune.CANDS:
-                if bq > s or bk > s:
-                    continue
-
-                def loss(q, k, v, bq=bq, bk=bk):
-                    o = flash_attention(q, k, v, causal=True,
-                                        block_q=bq, block_k=bk)
-                    return jnp.sum(o.astype(jnp.float32) ** 2)
-
-                grad = jax.grad(loss, argnums=(0, 1, 2))
-                qkv = [_sds((b, h, s, d), jnp.bfloat16)] * 3
-                label = f"{bq},{bk}" + (",tight" if tight else "")
-                orig_tight = fa_impl._TIGHT_HEADDIM
-                fa_impl._TIGHT_HEADDIM = tight
-                try:
-                    t0 = time.perf_counter()
-                    compiled = compile_replicated(mesh, grad, qkv)
-                    txt = compiled.as_text()
-                    out[key][label] = {
-                        "ok": True,
-                        "sites": txt.count("tpu_custom_call"),
-                        "compile_s": round(time.perf_counter() - t0, 1),
-                    }
-                except Exception as e:  # noqa: BLE001
-                    out[key][label] = {
-                        "ok": False,
-                        "error": f"{type(e).__name__}: {str(e)[:160]}",
-                    }
-                finally:
-                    fa_impl._TIGHT_HEADDIM = orig_tight
-                log(f"  autotune {key} ({label}): "
-                    f"{'ok' if out[key][label]['ok'] else 'FAIL'}")
-    return out
-
-
-# ---------------------------------------------------------------------------
 # driver
 # ---------------------------------------------------------------------------
 
-def run(skip_autotune=False, skip_overlap=False, only=None):
+def run(only=None):
     import jax
 
     # constants materialize on the host; every TPU compile here is for the
@@ -1059,10 +998,10 @@ def run(skip_autotune=False, skip_overlap=False, only=None):
     from apex_tpu.ops._dispatch import forced_mosaic
 
     with forced_mosaic():
-        return _run(skip_autotune, skip_overlap, only)
+        return _run(only)
 
 
-def _run(skip_autotune, skip_overlap, only):
+def _run(only):
     topo = _topology()
     mesh = _mesh(topo)
     log(f"topology {TOPOLOGY_NAME}: {len(topo.devices)} devices")
@@ -1139,25 +1078,6 @@ def _run(skip_autotune, skip_overlap, only):
         out["multichip_ok"] = sum(1 for r in mc.values() if r.get("ok"))
         out["multichip_fail"] = len(mc) - out["multichip_ok"]
 
-    if not skip_autotune and not only:
-        log("autotune candidate compile sweep...")
-        try:
-            out["autotune_candidates"] = autotune_candidate_sweep(mesh)
-        except Exception as e:  # noqa: BLE001
-            log(traceback.format_exc())
-            out["autotune_candidates_error"] = (
-                f"{type(e).__name__}: {str(e)[:300]}")
-
-    if not skip_overlap and not only:
-        log("AOT overlap check (tpu_profile)...")
-        try:
-            import tpu_profile
-
-            out["aot_overlap"] = tpu_profile.aot_overlap_check()
-        except Exception as e:  # noqa: BLE001
-            log(traceback.format_exc())
-            out["aot_overlap_error"] = f"{type(e).__name__}: {str(e)[:300]}"
-
     n_ok = sum(1 for r in results.values() if r.get("ok"))
     n_over = sum(1 for r in results.values()
                  if r.get("ok") and not r.get("under_16gib_budget", True))
@@ -1169,14 +1089,12 @@ def _run(skip_autotune, skip_overlap, only):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--skip-autotune", action="store_true")
-    ap.add_argument("--skip-overlap", action="store_true")
     ap.add_argument("--only", nargs="*", default=None,
                     help="run only the named cases (smoke/debug)")
     args = ap.parse_args()
 
     tag = os.environ.get("APEX_TPU_TAG", "session")
-    out = run(args.skip_autotune, args.skip_overlap, args.only)
+    out = run(args.only)
     path = os.path.join(REPO, f"AOT_{tag}.json")
     with open(path, "w") as f:
         json.dump(out, f, indent=1)
