@@ -53,12 +53,27 @@ one batched contraction over the head axis, ``(heads, s*rep, d) .
 (heads, pages*page_size, d)``: on the MXU at every ``s*rep``, since at
 ``s*rep = 1`` the live step already costs little more than a dead one.
 
-Layout: the pool is ``(num_pages, kv_heads, page_size, head_dim)`` — a
-page operand's minor two dims are the array's own ``(page_size,
-head_dim)``, legal under Mosaic's block rule at every page size that is
-a sublane multiple. GQA queries reshape to ``(b, kv, s*rep, d)`` and
-contract against the UNexpanded kv-head pages, the same no-repeat
-discipline as flash_attention and cached_attention.
+Layout: the pool is ``(num_pages, kv_heads // pack, page_size, head_dim *
+pack)`` — a page operand's minor two dims are the array's own
+``(page_size, lanes)``, legal under Mosaic's block rule at every page
+size that is a sublane multiple. GQA queries reshape to ``(b, kv, s*rep,
+d)`` and contract against the UNexpanded kv-head pages, the same
+no-repeat discipline as flash_attention and cached_attention.
+
+``pack`` heads to a pool row (``serving/kv_pool.heads_per_row``: two
+64-wide heads in 128 lanes, so that the pool's row-major layout is the
+one the device holds it in between programs and nothing re-lays it where
+a program begins or ends; docs/serving.md "Page-pool layout"). The
+wrapper reads ``pack`` off the shapes (``pool lanes // q's head_dim``)
+and the kernel body never learns of it: it runs over ``kv // pack`` rows
+of ``d * pack`` lanes with ``rep * pack`` query rows a position. The
+queries of a row's head ``p`` lie in lanes ``[p*d, (p+1)*d)`` and are
+zero elsewhere (block-diagonal), so the one contraction over all 128
+lanes gives each head its own scores exactly — the other heads' lanes
+meet zeros, where a 64-wide page used to meet VMEM padding, and the MXU
+pass is as wide as it was. The value product gives every query row all
+of the row's lanes, and the wrapper keeps head ``p``'s rows' lanes
+``[p*d, (p+1)*d)``.
 
 Off-TPU the kernel runs through the Pallas interpreter
 (``ops/_dispatch.interpret``), so CPU tests cover the real kernel code.
@@ -67,7 +82,8 @@ Tensor parallelism (``serving/tp.py``, docs/tp_serving.md): the kernel
 is TP-native by shape, not by flag. Heads never interact — the batched
 contraction's head axis is embarrassingly parallel — so inside ``shard_map``
 with the pool sharded along its kv-head axis, each chip calls this
-kernel on its LOCAL ``(num_pages, kv_heads/tp, page_size, d)`` shard
+kernel on its LOCAL shard (its ``kv_heads/tp`` heads, ``pack`` to a
+row: ``pack`` divides one chip's heads, so a row never straddles two)
 with its local query heads and the REPLICATED block tables / lengths:
 the same ``h % kv == 0`` GQA contract holds locally (both counts divide
 by ``tp`` — GQA groups partition whole), no collective appears here,
@@ -95,15 +111,19 @@ _INTERPRET = _dispatch.interpret
 _STEP_TOKENS = 128
 #: VMEM the K and V page buffers of one grid step may take — both
 #: tensors, double-buffered by the pipeline, tile padding included (a
-#: d=64 page pads to 128 lanes). Half of Mosaic's 16 MiB scoped stack;
+#: page narrower than 128 lanes pads to them: a pool that could not pack
+#: its heads, ``serving/kv_pool.heads_per_row``; a packed row of two
+#: 64-wide heads fills its lanes). Half of Mosaic's 16 MiB scoped stack;
 #: the rest holds q, the (m, l, acc) carry and the step's f32 scores
 _KV_VMEM_BUDGET = 8 * 1024 * 1024
 
 
 @functools.cache
 def _tile(kv: int, page_size: int, d: int, dtype, max_pages: int):
-    """``(pages, heads)`` of one grid step, from the shapes alone: as
-    many consecutive table entries as fill :data:`_STEP_TOKENS` (never
+    """``(pages, heads)`` of one grid step, from the shapes alone
+    (``kv`` and ``d`` are the POOL's: its rows and their lanes, whatever
+    heads a row packs): as many consecutive table entries as fill
+    :data:`_STEP_TOKENS` (never
     more than the table has) for all ``kv`` heads; where that overflows
     :data:`_KV_VMEM_BUDGET` the page block halves first, then the heads
     split into the largest divisor of ``kv`` that fits."""
@@ -140,7 +160,9 @@ def pages_fetched(length: int, *, kv_heads: int, page_size: int,
     ``length`` positions: the kernel fetches by block, so every block
     that holds a live page counts whole (feeds
     ``serving.kv_bytes_fetched``; per kv-head block the same count of
-    narrower pages)."""
+    narrower pages). ``kv_heads`` and ``head_dim`` are the pool's own
+    axes 1 and 3 (rows and lanes), so that this tiles as the call
+    does."""
     pages, _ = _tile(kv_heads, page_size, head_dim, dtype, max_pages)
     first, last = _live_pages(length, page_size, s_q, window)
     return (last // pages - first // pages + 1) * pages
@@ -266,8 +288,8 @@ def _validate(q, k_pages, v_pages, block_tables, lengths, window=None,
     if k_pages.shape != v_pages.shape:
         raise ValueError(f"k_pages {k_pages.shape} != v_pages "
                          f"{v_pages.shape}")
-    num_pages, kv, page_size, d = k_pages.shape
-    b, h, s_q, qd = q.shape
+    num_pages, kv_rows, page_size, lanes = k_pages.shape
+    b, h, s_q, d = q.shape
     if not 1 <= s_q <= page_size:
         # the block's s queries live inside the last ceil(s/ps)+1 pages;
         # bounding s by the page size keeps the per-page band mask a
@@ -278,11 +300,17 @@ def _validate(q, k_pages, v_pages, block_tables, lengths, window=None,
             f"paged attention takes query blocks of 1..page_size "
             f"({page_size}) positions per step, got s={s_q}; longer "
             f"chunks must use the contiguous prefill path")
-    if qd != d:
-        raise ValueError(f"head_dim mismatch: q {qd} vs pages {d}")
-    if h % kv != 0:
+    if lanes % d != 0:
+        raise ValueError(f"head_dim mismatch: q {d} vs pages {lanes} "
+                         f"(a pool row holds whole heads side by side)")
+    pack = lanes // d
+    if pack > 1 and k_scales is not None:
+        raise ValueError(
+            f"a quantized pool holds one head a row (its scales are per "
+            f"(page, kv_head)), got rows of {pack} heads")
+    if h % (kv_rows * pack) != 0:
         raise ValueError(f"q heads ({h}) must be a multiple of kv heads "
-                         f"({kv})")
+                         f"({kv_rows * pack})")
     if page_size % 8 != 0:
         raise ValueError(f"page_size must be a sublane multiple (8), got "
                          f"{page_size}")
@@ -306,9 +334,11 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         speculative draft chunk, ``s``-sized chunks carry interleaved
         prefill). Query ``i`` sits at absolute position
         ``lengths[b] - s + i``.
-      k_pages / v_pages: ``(num_pages, kv_heads, page_size, head_dim)``
-        shared page pool (``kv_heads`` divides ``heads``; GQA never
-        expands). Inside a tensor-parallel ``shard_map`` region both
+      k_pages / v_pages: ``(num_pages, kv_heads // pack, page_size,
+        head_dim * pack)`` shared page pool, ``pack`` heads side by side
+        in a row (1 for a head of 128 values or more; read off the
+        shapes as ``lanes // head_dim``; ``kv_heads`` divides ``heads``;
+        GQA never expands). Inside a tensor-parallel ``shard_map`` region both
         counts are the LOCAL per-chip head shard (``serving/tp.py``) —
         the kernel is chip-count-blind.
       block_tables: int32 ``(batch, max_pages)``; entry ``[b, j]`` is the
@@ -345,13 +375,17 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     _validate(q, k_pages, v_pages, block_tables, lengths, window,
               k_scales, v_scales)
     quantized = k_scales is not None
+    # the kernel's kv, rep and d are the POOL's: its rows, the query
+    # heads that read one row (``pack`` kv heads of ``rep`` each) and its
+    # lanes. ``pack == 1`` is the kernel as it always was
     num_pages, kv, page_size, d = k_pages.shape
-    b, h, s_q = q.shape[0], q.shape[1], q.shape[2]
+    b, h, s_q, head_dim = q.shape
+    pack = d // head_dim
     rep = h // kv
     rows = s_q * rep
     max_pages = block_tables.shape[1]
     if scale is None:
-        scale = 1.0 / (d ** 0.5)
+        scale = 1.0 / (head_dim ** 0.5)
     pages, heads = _tile(kv, page_size, d, k_pages.dtype, max_pages)
     n_blocks = _dispatch.cdiv(max_pages, pages)
 
@@ -359,8 +393,16 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     # GQA group-member r, so the kernel recovers the position as
     # row // rep with the group's rows adjacent (one contraction per kv
     # head for all s*rep rows against the step's pages)
-    qr = (q.reshape(b, kv, rep, s_q, d).transpose(0, 1, 3, 2, 4)
-          .reshape(b, kv, rows, d))
+    qr = q.reshape(b, kv, rep, s_q, head_dim).transpose(0, 1, 3, 2, 4)
+    if pack > 1:
+        # group-member r = p*(rep/pack) + r' reads head p of the row:
+        # its query goes to lanes [p*head_dim, (p+1)*head_dim), zeros to
+        # the other heads' lanes
+        own = jnp.eye(pack, dtype=jnp.bool_)[:, None, :, None]
+        qr = jnp.where(
+            own, qr.reshape(b, kv, s_q, pack, rep // pack, 1, head_dim),
+            jnp.zeros((), q.dtype))
+    qr = qr.reshape(b, kv, rows, d)
     ln = lengths.astype(jnp.int32)
     # the physical page of every table entry a grid step names, each
     # entry clamped into its slot's live pages first: a dead entry (past
@@ -425,8 +467,13 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         kernel="paged_attention",
         interpret=_INTERPRET(),
     )(*operands)
-    return (out.reshape(b, kv, s_q, rep, d).transpose(0, 1, 3, 2, 4)
-            .reshape(b, h, s_q, d))
+    if pack > 1:
+        # every row came back with all of the pool row's lanes: head p's
+        # rows keep their own
+        out = out.reshape(b, kv, s_q, pack, rep // pack, pack, head_dim)
+        out = jnp.stack([out[:, :, :, p, :, p] for p in range(pack)], axis=3)
+    return (out.reshape(b, kv, s_q, rep, head_dim).transpose(0, 1, 3, 2, 4)
+            .reshape(b, h, s_q, head_dim))
 
 
 def paged_attention_reference(q, k_pages, v_pages, block_tables, lengths, *,
@@ -440,6 +487,10 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, lengths, *,
     _validate(q, k_pages, v_pages, block_tables, lengths, window,
               k_scales, v_scales)
     num_pages, kv, page_size, d = k_pages.shape
+    if d != q.shape[3]:
+        raise ValueError(
+            f"the reference knows heads only: hand it the pool one head a "
+            f"row (lanes {d} vs head_dim {q.shape[3]})")
     b, h, s_q = q.shape[0], q.shape[1], q.shape[2]
     rep = h // kv
     max_pages = block_tables.shape[1]

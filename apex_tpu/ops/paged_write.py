@@ -5,9 +5,17 @@ Every program that writes the pool (``models/generation.
 update_paged_layer_cache``: a decode step's token, a speculative verify
 block, a prefill chunk; ``serving/kv_pool.prefill_into_pages``: an
 admitted prompt) writes it through this kernel. The pool is
-``(num_pages, heads, page_size, stored)`` and ``paged_attention`` /
-``paged_latent_attention`` take it row-major, a page's heads contiguous,
-as every Mosaic call takes its operands. A ``.at[page, :, off, :].set``
+``(num_pages, rows, page_size, lanes)`` and ``paged_attention`` /
+``paged_latent_attention`` take it row-major, a page's rows contiguous,
+as every Mosaic call takes its operands. A pool ROW is one latent entry,
+one head of 128 values or more, or ``pack`` narrower heads side by side
+(``serving/kv_pool.heads_per_row``: two 64-wide heads in 128 lanes, so
+that row-major is also the layout the device gives the pool between
+programs; docs/serving.md "Page-pool layout"). Callers hand chunks per
+HEAD, ``(slots, heads, s, d)``; the wrapper reads ``pack`` off the shapes
+(``lanes // d``) and lays ``pack`` heads side by side
+(:func:`pack_heads`, a reshape and a transpose of the small chunk), so
+the kernel below sees rows and never a head. A ``.at[page, :, off, :].set``
 over the head axis is a scatter that XLA lays out with a token's
 ``(heads, stored)`` slab contiguous (``{3,1,2,0}``); that became the
 layout of the whole program's pool, and a ``copy`` of every layer's
@@ -19,8 +27,8 @@ row-major like the readers', its output ALIASES its pool operand
 it and nothing re-lays it (``tests/test_aot_mosaic.py`` pins the
 compiled text).
 
-One grid step ``(b, j)`` reads one whole page ``(1, heads, page_size,
-stored)`` of every tensor, merges the rows that land in it by a row mask
+One grid step ``(b, j)`` reads one whole page ``(1, rows, page_size,
+lanes)`` of every tensor, merges the rows that land in it by a row mask
 (``where(row == shift + i, new row i, page)``: no dynamic sublane store,
 a bf16 sublane packs two rows) and writes the page back. The page is
 named by a scalar-prefetched table the wrapper resolves from the block
@@ -50,8 +58,9 @@ nothing anyone reads. The grid is ``"arbitrary"`` on both axes for the
 same reason (page 0 may be written by many steps).
 
 Tensor parallelism: inside ``serving/tp.py``'s ``shard_map`` the pool is
-sharded on its head axis and this kernel sees ``heads / tp`` of them;
-heads never interact in a write.
+sharded on its row axis and this kernel sees one chip's rows (its
+``heads / tp`` heads, ``pack`` to a row); heads never interact in a
+write.
 """
 
 from __future__ import annotations
@@ -70,6 +79,30 @@ from apex_tpu.ops import _dispatch
 _INTERPRET = _dispatch.interpret
 
 
+def pack_heads(x, pack: int):
+    """``(a, heads, t, d)`` per head -> ``(a, heads // pack, t, d * pack)``
+    as a pool row holds it: row ``j`` carries heads ``j*pack .. j*pack +
+    pack - 1`` side by side, head ``j*pack + p`` in lanes ``[p*d,
+    (p+1)*d)``. ``pack == 1`` is the identity."""
+    if pack == 1:
+        return x
+    a, heads, t, d = x.shape
+    return (x.reshape(a, heads // pack, pack, t, d).transpose(0, 1, 3, 2, 4)
+            .reshape(a, heads // pack, t, d * pack))
+
+
+def unpack_heads(x, pack: int):
+    """:func:`pack_heads` undone: ``(a, rows, t, d * pack)`` -> ``(a, rows
+    * pack, t, d)`` (page tiles ``(pages, rows, page_size, lanes)`` and
+    whole pools alike)."""
+    if pack == 1:
+        return x
+    a, rows, t, lanes = x.shape
+    return (x.reshape(a, rows, t, pack, lanes // pack)
+            .transpose(0, 1, 3, 2, 4)
+            .reshape(a, rows * pack, t, lanes // pack))
+
+
 def _write_kernel(phys_ref, shift_ref, lo_ref, hi_ref, *refs, n, rows):
     del phys_ref                       # read by the index maps alone
     page_refs, src_refs, out_refs = refs[:n], refs[n:2 * n], refs[2 * n:]
@@ -78,6 +111,8 @@ def _write_kernel(phys_ref, shift_ref, lo_ref, hi_ref, *refs, n, rows):
     for page_ref, src_ref, out_ref in zip(page_refs, src_refs, out_refs):
         page = page_ref[0]                        # (heads, page_size, d)
         src = src_ref[0]                          # (heads, rows, d)
+        # ("heads" here and below: the pool's rows, each ``pack`` heads
+        # wide; the body never tells them apart)
         row = lax.broadcasted_iota(jnp.int32, page.shape, 1)
         live = jnp.logical_and(row >= lo, row < hi)
         for i in range(rows):
@@ -93,10 +128,13 @@ def paged_write(pools: Sequence, chunks: Sequence, block_tables, lengths, *,
     """Write one chunk per pool tensor into the page pool, in place.
 
     Args:
-      pools: the layer's pool tensors, each ``(num_pages, heads,
-        page_size, stored)`` (per-head K and V; the one latent entry
-        with ``heads = 1``); all written at the same ``(page, offset)``.
-      chunks: one ``(slots, heads, s, stored)`` chunk per pool tensor.
+      pools: the layer's pool tensors, each ``(num_pages, heads // pack,
+        page_size, d * pack)`` (per-head K and V, ``pack`` heads to a
+        row; the one latent entry with ``heads = pack = 1``); all written
+        at the same ``(page, offset)``.
+      chunks: one ``(slots, heads, s, d)`` chunk per pool tensor, per
+        HEAD whatever the pool packs: ``pack`` is read off the two
+        shapes.
       block_tables: int32 ``(slots, max_pages)``.
       lengths: int32 ``(slots,)``: chunk position ``i`` of slot ``b`` is
         absolute position ``lengths[b] + i``. With ``s > page_size``
@@ -121,18 +159,22 @@ def _write(pools, chunks, block_tables, lengths, start, stop, *, interpret):
     if len(pools) != len(chunks) or not pools:
         raise ValueError(f"paged_write needs one chunk per pool tensor, "
                          f"got {len(pools)} pool(s), {len(chunks)} chunk(s)")
-    num_pages, heads, ps, _ = pools[0].shape
+    num_pages, heads, ps, _ = pools[0].shape       # heads: the pool's rows
     slots, _, s, _ = chunks[0].shape
+    packed = []
     for pages, chunk in zip(pools, chunks):
         if pages.shape[:3] != (num_pages, heads, ps) or pages.ndim != 4:
             raise ValueError(f"pool tensors must share (num_pages, heads, "
                              f"page_size): {pages.shape} vs "
                              f"{pools[0].shape}")
-        if chunk.shape != (slots, heads, s, pages.shape[3]):
+        # heads one pool row holds side by side, from the shapes alone
+        pack = max(pages.shape[3] // chunk.shape[3], 1)
+        if chunk.shape != (slots, heads * pack, s, pages.shape[3] // pack):
             raise ValueError(
-                f"chunk {chunk.shape} does not match (slots, heads, s, "
-                f"stored) = {(slots, heads, s, pages.shape[3])} of pool "
-                f"{pages.shape}")
+                f"chunk {chunk.shape} does not match (slots, heads, s, d) "
+                f"= {(slots, heads * pack, s, pages.shape[3] // pack)} of "
+                f"pool {pages.shape} ({pack} head(s) a row)")
+        packed.append(pack_heads(chunk.astype(pages.dtype), pack))
     max_pages = block_tables.shape[1]
     if block_tables.shape[0] != slots or lengths.shape != (slots,):
         raise ValueError(f"block_tables {block_tables.shape} / lengths "
@@ -186,7 +228,6 @@ def _write(pools, chunks, block_tables, lengths, start, stop, *, interpret):
             dimension_semantics=("arbitrary", "arbitrary")),
         kernel="paged_write",
         interpret=interpret,
-    )(phys, shift, lo, hi, *pools,
-      *[c.astype(p.dtype) for c, p in zip(chunks, pools)])
+    )(phys, shift, lo, hi, *pools, *packed)
     return list(out)
 

@@ -6,7 +6,9 @@ sequence wastes its slot (and its cache HBM) until the whole batch drains.
 This package replaces that with the two serving-stack staples:
 
 - **Paged KV cache** (``kv_pool``): per-layer K/V live in a static
-  ``(num_pages, kv, page_size, d)`` pool; each sequence owns
+  ``(num_pages, kv // pack, page_size, d * pack)`` pool (``pack`` heads
+  side by side in a 128-lane row: ``kv_pool.heads_per_row``); each
+  sequence owns
   ``ceil(len/page_size)`` pages named by an int32 block table. Alloc /
   free / defrag are pure-JAX index ops over a fixed-size free stack — no
   shape ever changes, so nothing recompiles at admission or retirement.

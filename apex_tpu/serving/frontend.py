@@ -453,9 +453,10 @@ class ServingFrontend:
                                kv_dtype=engine.kv_dtype)
             * tp / engine.page_size)
         # pages the decode kernel's DMAs move for a slot of a given
-        # length, as each chip's call tiles its local heads (a latent
-        # pool: its one head of stored lanes; feeds
-        # serving.kv_bytes_fetched)
+        # length, as each chip's call tiles its local share of the pool
+        # as held: rows (a head, or heads_per_row of them side by side; a
+        # latent pool's one) of the pool's lanes; feeds
+        # serving.kv_bytes_fetched
         pool = kv_pool.a_pool(engine.cache)
         self._pages_fetched = functools.partial(
             pages_fetched,
@@ -1806,6 +1807,11 @@ class ServingFrontend:
             # the single-chip engine; per-chip throughput = aggregate /
             # tp_world (the pool/weight shards each chip streams)
             "tp_world": int(getattr(eng, "tp_world", 1)),
+            # heads one row of the pool it serves from holds side by side
+            # (kv_pool.heads_per_row; 2 at a 64-wide head: the pool then
+            # lies as the kernels read it and no program re-lays it)
+            "pool_heads_per_row": kv_pool.heads_per_row_of(eng.cache,
+                                                           eng.cfg),
             "preemptions": int(d["preemptions"]),
             "resumes": int(d["resumes"]),
             "backpressure_spills": int(d["backpressure_spills"]),

@@ -4,9 +4,11 @@ Layout (the PAGED cache pytree — a drop-in ``cache=`` argument for the
 models' incremental-decode path, recognized by its ``block_tables`` key):
 
     pcache = {
-      "layers": [{"k_pages": (num_pages, kv_local, page_size, d),
+      "layers": [{"k_pages": (num_pages, kv_local // pack, page_size,
+                              d * pack),
                   "v_pages": ...,
-                  # quantized pools only (init_paged_cache(kv_dtype=)):
+                  # quantized pools only (init_paged_cache(kv_dtype=),
+                  # pack == 1):
                   "k_scales": (num_pages, kv_local) f32, "v_scales": ...}]
                 * num_layers,
       "block_tables": (num_slots, max_pages_per_seq) int32,
@@ -36,6 +38,25 @@ stored)}`` and every pool op below, which moves page NAMES and walks
 ``for key in layer``, runs unchanged. A latent pool has one head, so it
 cannot shard over a tensor-parallel mesh, and it has no quantized form:
 both refuse with :class:`LatentPoolUnsupported` where the engine is built.
+
+A POOL ROW IS 128 LANES WHERE IT CAN BE. A per-head pool whose head width
+divides 128 holds ``pack = 128 // width`` heads side by side in one row
+(:func:`heads_per_row`, the one place ``pack`` is decided: from the
+width, the LOCAL kv-head count and whether the pool is quantized, and
+from nothing else; two 64-wide heads a row at GPT-2 large). Row ``j``
+holds heads ``j*pack .. j*pack + pack - 1``, head ``j*pack + p`` in lanes
+``[p*width, (p+1)*width)``. Why: a tensor whose minor dimension is 128
+lanes lies ROW-MAJOR by the device's default, which is how every Mosaic
+call takes it, so no program re-lays the pool where it begins or ends;
+a 64-wide one lies page-axis-minor-most, and every program copied every
+layer's K and V pool twice (docs/serving.md "Page-pool layout"). The
+models and ``update_paged_layer_cache`` still speak per HEAD, ``(b,
+heads, s, d)``: ``ops.paged_write`` and ``ops.paged_attention`` read
+``pack`` off the shapes they are handed, and the one other reader of a
+page's inside (the shared-admit gather) unpacks through
+``ops.paged_write.unpack_heads``. A quantized pool keeps one head a row
+(its scales are per ``(page, kv_head)`` and its writers are scatters of
+their own), as does a head count ``pack`` does not divide.
 
 ``alloc_pages`` tracks ownership, not occupancy: the scheduler allocates a
 request's worst case (``ceil((prompt+max_new)/page_size)``) up front, so a
@@ -71,13 +92,15 @@ a masked ``mode="drop"`` scatter — both jittable at one shape forever
 (the ``n`` is a traced scalar, the mask is what varies).
 
 The lane-alignment discipline mirrors ``ops/flat_buffer.py``: a page tile
-is ``(page_size, head_dim)``, so ``page_size`` must be a sublane multiple
-(8) and should be >= 16 for bf16 pools.
+is ``(page_size, head_dim * pack)``, so ``page_size`` must be a sublane
+multiple (8) and should be >= 16 for bf16 pools.
 
 Tensor parallelism (``serving/tp.py``, docs/tp_serving.md): with
 ``init_paged_cache(..., mesh=)`` the pool is allocated GLOBALLY at the
 full ``num_kv_heads`` and sharded along the head axis over the mesh's
-``tp`` axis (:func:`cache_specs`) — each chip holds its
+``tp`` axis (:func:`cache_specs`; the axis counts rows of ``pack``
+heads, and ``pack`` divides the LOCAL head count, so a row never
+straddles two chips) — each chip holds its
 ``num_kv_heads/tp`` head group of every page, while block tables / free
 stack / lengths / refcounts stay replicated, so every pure-JAX pool op
 in this module runs unchanged inside ``shard_map`` (none of them index
@@ -125,6 +148,11 @@ class LatentPoolUnsupported(ValueError):
             "chip per replica with kv_dtype=None")
 
 
+#: lanes of one vector register row: a tensor whose minor dimension is this
+#: wide lies row-major by the device's default layout
+_LANES = 128
+
+
 @dataclasses.dataclass(frozen=True)
 class CacheLayout:
     """What ONE layer stores per token: ``tensors`` names the stored
@@ -150,7 +178,7 @@ def layout_of(config) -> CacheLayout:
     latent = getattr(config, "kv_latent_width", None)
     if latent:
         return CacheLayout(("latent",), 1, int(latent),
-                           round_up(int(latent), 128))
+                           round_up(int(latent), _LANES))
     return CacheLayout(("k", "v"),
                        getattr(config, "num_kv_heads", config.num_heads),
                        config.head_dim, config.head_dim)
@@ -170,9 +198,27 @@ def pool_tensors(layer) -> Tuple[str, ...]:
     return tuple(k[:-len("_pages")] for k in layer if k.endswith("_pages"))
 
 
-def _pool_shape(num_pages: int, heads: int, page_size: int, stored: int):
-    # the ONE place a page's shape is built
-    return (num_pages, heads, page_size, stored)
+def heads_per_row(width: int, kv_local: int, *, quantized: bool = False
+                  ) -> int:
+    """``pack``: how many heads one pool row holds side by side. The ONE
+    place it is decided, from what the pool is: ``width`` (a head's stored
+    lanes), ``kv_local`` (ONE chip's kv heads: a row never straddles two
+    chips) and whether the pages are quantized. ``128 // width`` where the
+    width divides 128 and that count divides the heads; else 1 (a head or
+    a latent entry of 128 lanes or more, a width like 96, an odd head
+    count, and a quantized pool, whose scales are per ``(page,
+    kv_head)``)."""
+    if quantized or width >= _LANES or _LANES % width:
+        return 1
+    pack = _LANES // width
+    return pack if kv_local % pack == 0 else 1
+
+
+def _pool_shape(num_pages: int, heads: int, page_size: int, stored: int,
+                pack: int):
+    # the ONE place a page's shape is built: ``heads`` heads of ``stored``
+    # lanes, ``pack`` of them to a row
+    return (num_pages, heads // pack, page_size, stored * pack)
 
 
 def a_pool(cache):
@@ -180,6 +226,12 @@ def a_pool(cache):
     shape and dtype."""
     layer = cache["layers"][0]
     return layer[pool_key(pool_tensors(layer)[0])]
+
+
+def heads_per_row_of(cache, config) -> int:
+    """The ``pack`` a built cache holds (its pool's lanes over the
+    layout's stored width): what the frontend's stats report."""
+    return a_pool(cache).shape[3] // layout_of(config).stored
 
 
 def page_size_of(cache) -> int:
@@ -200,7 +252,8 @@ def pages_for(length, page_size: int):
 def cache_specs(config, axis_name: str = MODEL_AXIS, *, kv_dtype=None):
     """PartitionSpec pytree mirroring the paged-cache structure for a
     tensor-parallel mesh (``serving/tp.py``): the per-layer K/V pools
-    shard along the kv-HEAD axis (dim 1 — each chip holds
+    shard along the kv-HEAD axis (dim 1, which counts rows of ``pack``
+    heads: :func:`heads_per_row` — each chip holds
     ``num_kv_heads/tp`` heads of EVERY page, so its pool shard is
     ``1/tp`` the bytes), while the block tables, free stack, lengths,
     and refcounts stay replicated (the host admission/retirement logic
@@ -303,7 +356,9 @@ def init_paged_cache(config, num_slots: int, *, num_pages: int,
             else resolve_compute_dtype(config.dtype)
     if max_pages_per_seq is None:
         max_pages_per_seq = cdiv(config.max_position_embeddings, page_size)
-    shape = _pool_shape(num_pages, kv_dim, page_size, layout.stored)
+    shape = _pool_shape(
+        num_pages, kv_dim, page_size, layout.stored,
+        heads_per_row(layout.stored, kv_local, quantized=quant is not None))
     scale_shape = (num_pages, kv_dim)
     names = layout.tensors
     if mesh is not None and (abstract or not isinstance(mesh, Mesh)):
@@ -572,7 +627,8 @@ HOST_COPY_CHUNK = 8
 def tile_specs(config, axis_name: str = MODEL_AXIS, *, kv_dtype=None):
     """PartitionSpec pytree for one gather/promote tile batch (the
     ``gather_pages`` result / ``promote_pages`` operand): per-layer
-    ``(HOST_COPY_CHUNK, kv, page_size, d)`` K/V tiles shard along the
+    ``(HOST_COPY_CHUNK, kv // pack, page_size, d * pack)`` K/V tiles (pool
+    pages as held) shard along the
     kv-HEAD axis (dim 1) exactly like the pool pages they were cut from,
     so under TP each chip gathers/scatters its own head-shard and the
     host tier holds the pages at FULL head width (``serving/tp.py``
@@ -701,7 +757,8 @@ def defrag(cache, extra_live=None):
 def prefill_into_pages(cache, slot, contig_layers, s0, *, start=0):
     """Write a CONTIGUOUS prefill cache (the models' flash-prefill
     output: per layer the layout's tensors, ``k``/``v`` or ``latent``, each
-    of shape ``(1, heads, len_bucket, stored)``)
+    of shape ``(1, heads, len_bucket, stored)``, per HEAD whatever the pool
+    packs into a row: the write lays them side by side)
     into slot ``slot``'s already-allocated pages, and set its length to
     ``s0`` (traced OK; positions past ``s0`` — prompt-bucket padding —
     are not written: their steps sink to the null page). Position ``p``

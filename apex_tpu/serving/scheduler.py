@@ -74,6 +74,7 @@ from apex_tpu.models.generation import (_greedy_token, _sample_token,
 from apex_tpu.obs.events import EventLog
 from apex_tpu.obs.spans import SpanTracer
 from apex_tpu.ops._dispatch import round_up
+from apex_tpu.ops.paged_write import unpack_heads
 from apex_tpu.ops.quant import resolve_kv_dtype
 from apex_tpu.serving import kv_pool
 from apex_tpu.serving.host_tier import HostPageTier
@@ -253,9 +254,13 @@ def make_shared_admit(model, *, t_start: int, tail_bucket: int,
         layers = []
         for pool_lc, lc in zip(cache["layers"], contig["layers"]):
             def gathered(pages, dst, scales=None):
-                # (m, heads, ps, d) page tiles -> the buffer's leading
-                # t_start positions; a quantized pool dequantizes by its
-                # gathered per-(page, kv_head) scales on the way out
+                # (m, heads // pack, ps, d * pack) page tiles, one head a
+                # row again (the pool's pack, off the two shapes) -> the
+                # buffer's leading t_start positions; a quantized pool
+                # dequantizes by its gathered per-(page, kv_head) scales
+                # on the way out
+                pages = unpack_heads(pages,
+                                     pages.shape[3] // dst.shape[3])
                 kv, d = pages.shape[1], pages.shape[3]
                 if scales is not None:
                     pages = pages.astype(jnp.float32) * \

@@ -9,7 +9,9 @@ attention heads and MLP columns over the ``model`` axis — and GQA head
 groups partition the SAME way, so the paged pool shards along its
 kv-head axis with zero change to the paging logic:
 
-- **K/V pool**: global ``(num_pages, num_kv_heads, page_size, d)``,
+- **K/V pool**: global ``(num_pages, num_kv_heads // pack, page_size,
+  d * pack)`` (``pack`` heads side by side in a 128-lane row where it
+  divides ONE chip's heads: ``kv_pool.heads_per_row``),
   sharded ``P(None, tp)`` — each chip holds ``num_kv_heads/tp`` heads of
   EVERY page, i.e. ``1/tp`` of the pool bytes. A model whose pool misses
   one chip's 16 GiB fits the mesh (the acceptance case in ``tpu_aot.py``

@@ -261,22 +261,29 @@ POOL_PROGRAMS = {
 
 @pytest.mark.parametrize("name", list(POOL_PROGRAMS))
 def test_no_program_re_lays_the_page_pool(name, mesh):
-    """Every program that writes the page pool writes it ROW-MAJOR, the
-    layout the decode kernels (like every Mosaic call) take it in
-    (docs/serving.md "Page-pool layout"). A scatter over the pool's head
-    axis made XLA carry the pool ``{3,1,2,0}`` and put a ``copy`` of every
-    layer's whole K and V pool in front of every kernel of every decode
-    step: 18 of the 33 ms of a GPT-2 large step (PERF.md, PR 32).
+    """No program re-lays the page pool: not in its loop, not where it
+    begins, not where it ends (docs/serving.md "Page-pool layout").
 
-    The decode chunk (``PagedDecodeEngine._step_fn()``), compiled for the
-    described v5e at a cell's widths: no ``copy`` or ``transpose`` of the
-    pool's shape inside the ``while`` body. An admit program: no value of
-    the pool's shape laid out ``{3,1,2,0}`` anywhere. (What stays, and is
-    not counted: a 64-wide pool's buffers lie in the device's default
-    layout, page axis minor-most, so each program copies the pool once
-    where it enters and once where it leaves; docs/serving.md says why
-    that is not cured here.) In both, the page write is a Mosaic call that
-    aliases its pool operand."""
+    Two things keep it so. Every program that writes the pool writes it
+    ROW-MAJOR, the layout the decode kernels (like every Mosaic call)
+    take it in: a scatter over the pool's head axis made XLA carry the
+    pool ``{3,1,2,0}`` and put a ``copy`` of every layer's whole K and V
+    pool in front of every kernel of every decode step, 18 of the 33 ms
+    of a GPT-2 large step (PERF.md, PR 32). And the pool is HELD 128
+    lanes wide (two 64-wide heads a row, ``kv_pool.heads_per_row``), so
+    row-major is also the device's default layout for it between
+    programs: a ``bf16[729,20,16,64]`` pool lay ``{0,3,2,1}`` there and
+    every program copied every layer's pool once at its entry and once
+    at its exit, 6 of a 15 ms step and 24 of an admission's 32 ms
+    (PERF.md, PR 34).
+
+    The decode chunk (``PagedDecodeEngine._step_fn()``) and an admit
+    program, compiled for the described v5e at a cell's widths: no
+    ``copy`` or ``transpose`` whose result has the pool's shape ANYWHERE
+    in the program, no value of the pool's shape laid out ``{3,1,2,0}``,
+    and every pool parameter and result of the entry computation
+    row-major, ``{3,2,1,0}``. In both, the page write is a Mosaic call
+    that aliases its pool operand."""
     import re
 
     import jax
@@ -318,13 +325,20 @@ def test_no_program_re_lays_the_page_pool(name, mesh):
              for lc in cache["layers"] for x in lc.values()}
     assert len(pools) == 1, pools
     shape = re.escape(pools.pop())
-    if program == "step":
-        moved = [ln.strip()[:200] for ln in txt.splitlines()
-                 if re.search(rf"= \w+\[{shape}\]\S* (copy|transpose)\(", ln)
-                 and re.search(r'op_name="[^"]*while/body', ln)]
-        assert not moved, (
-            f"{len(moved)} pool-shaped copies in the decode chunk's loop "
-            f"body: {moved[:2]}")
+    moved = [ln.strip()[:200] for ln in txt.splitlines()
+             if re.search(rf"= \w+\[{shape}\]\S* (copy|transpose)\(", ln)]
+    assert not moved, (
+        f"{len(moved)} pool-shaped copies in the program (loop body: "
+        f"{sum('while/body' in m for m in moved)}): {moved[:2]}")
+    # the module's header line holds ``entry_computation_layout={(the
+    # parameters)->(the results)}``
+    entry = next(ln for ln in txt.splitlines()
+                 if "entry_computation_layout" in ln)
+    held = re.findall(rf"\w+\[{shape}\]\{{([\d,]+)", entry)
+    # K and V (or one latent tensor) a layer, as parameter and as result
+    assert len(held) == 2 * len(cache["layers"]) * len(cache["layers"][0])
+    assert set(held) == {"3,2,1,0"}, (
+        f"the pool enters or leaves the program laid out {set(held)}")
     twisted = re.findall(rf"\w+\[{shape}\]\{{3,1,2,0[^}}]*\}}", txt)
     assert not twisted, (
         f"{len(twisted)} pool-shaped values laid out {{3,1,2,0}}: "

@@ -445,3 +445,100 @@ def test_tile_is_derived_from_the_shapes(kv, ps, d, dtype, mp, want):
     from apex_tpu.ops.paged_attention import _tile
 
     assert _tile(kv, ps, d, dtype, mp) == want
+
+
+# --- a pool that holds ``pack`` heads side by side in a 128-lane row --------
+#
+# (``kv_pool.heads_per_row``; docs/serving.md "Page-pool layout"). The
+# wrapper reads ``pack`` off the shapes; the pool as the engine would hold
+# it is built here the way ``kv_pool`` builds it, so a head count that
+# ``pack`` does not divide falls back to one head a row in the test as in
+# the engine.
+
+#: name -> kv heads, q heads, head width, s, lengths (INCLUDING the s
+#: current tokens), window, the pack the pool must come out with
+_PACKED_CASES = {
+    "pack2_s1": dict(kv=4, h=4, d=64, s=1, lens=[5, 17, 32, 0], pack=2),
+    "pack2_gqa_rep3_s1": dict(kv=2, h=6, d=64, s=1, lens=[9, 24], pack=2),
+    "pack2_verify_s4": dict(kv=4, h=4, d=64, s=4, lens=[4, 13, 27],
+                            pack=2),
+    "pack2_chunk_s8_straddles_pages": dict(kv=2, h=4, d=64, s=8,
+                                           lens=[11, 20, 29], pack=2),
+    "pack2_window": dict(kv=4, h=8, d=64, s=1, lens=[7, 23, 40], window=11,
+                         pack=2),
+    "pack2_window_s4": dict(kv=2, h=2, d=64, s=4, lens=[9, 30], window=6,
+                            pack=2),
+    "pack4_s1": dict(kv=4, h=4, d=32, s=1, lens=[3, 16, 31], pack=4),
+    "pack4_gqa_rep2_s3": dict(kv=8, h=16, d=32, s=3, lens=[6, 19], pack=4),
+    "pack4_bf16": dict(kv=4, h=8, d=32, s=2, lens=[10, 25], pack=4,
+                       dtype=jnp.bfloat16),
+    # what cannot pack keeps one head a row
+    "odd_head_count_falls_back": dict(kv=5, h=5, d=64, s=1, lens=[8, 21],
+                                      pack=1),
+    "three_heads_of_32_fall_back": dict(kv=3, h=6, d=32, s=2, lens=[5, 18],
+                                        pack=1),
+    "width_96_falls_back": dict(kv=2, h=2, d=96, s=1, lens=[12, 30],
+                                pack=1),
+    "width_128_is_one_head_a_row": dict(kv=2, h=4, d=128, s=1,
+                                        lens=[12, 30], pack=1),
+}
+
+
+@pytest.mark.parametrize("name", list(_PACKED_CASES))
+def test_packed_pool_matches_reference_and_the_unpacked_pool(rng, name):
+    from apex_tpu.ops.paged_write import pack_heads, unpack_heads
+    from apex_tpu.serving import kv_pool
+
+    case = _PACKED_CASES[name]
+    kv, h, d, s = case["kv"], case["h"], case["d"], case["s"]
+    dtype = case.get("dtype", jnp.float32)
+    window = case.get("window")
+    ps, mp = 8, 5
+    b = len(case["lens"])
+    P = b * mp + 2
+    pack = kv_pool.heads_per_row(d, kv)
+    assert pack == case["pack"]
+    k_pages, v_pages = _pool(rng, P, kv, ps, d, dtype)
+    held = kv_pool._pool_shape(P, kv, ps, d, pack)
+    k_held, v_held = pack_heads(k_pages, pack), pack_heads(v_pages, pack)
+    assert k_held.shape == held and held[1] * held[3] == kv * d
+    assert pack == 1 or held[3] == 128
+    np.testing.assert_array_equal(np.asarray(unpack_heads(k_held, pack)),
+                                  np.asarray(k_pages))
+    q = jnp.asarray(rng.standard_normal((b, h, s, d)), dtype)
+    bt = _tables(rng, b, mp, P)
+    lens = jnp.asarray(case["lens"], jnp.int32)
+
+    out = jax.jit(lambda *a: paged_attention(*a, window=window))(
+        q, k_held, v_held, bt, lens)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    ref = paged_attention_reference(q, k_pages, v_pages, bt, lens,
+                                    window=window)
+    tol = TOL if dtype == jnp.float32 else dict(rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), **tol)
+    # and the packed read is the unpacked read: the other heads' lanes
+    # meet zeros
+    one = paged_attention(q, k_pages, v_pages, bt, lens, window=window)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(one, np.float32), **tol)
+
+
+def test_packed_pool_refuses_scales_and_a_width_that_is_no_divisor(rng):
+    k_pages, v_pages = _pool(rng, 6, 2, 8, 128)
+    bt = jnp.ones((1, 2), jnp.int32)
+    lens = jnp.asarray([3], jnp.int32)
+    q = jnp.zeros((1, 4, 1, 64), jnp.float32)
+    sc = jnp.ones((6, 2), jnp.float32)
+    with pytest.raises(ValueError, match="one head a row"):
+        paged_attention(q, k_pages, v_pages, bt, lens, k_scales=sc,
+                        v_scales=sc)
+    with pytest.raises(ValueError, match="head_dim mismatch"):
+        paged_attention(jnp.zeros((1, 2, 1, 96), jnp.float32), k_pages,
+                        v_pages, bt, lens)
+    with pytest.raises(ValueError, match="multiple of kv heads"):
+        paged_attention(jnp.zeros((1, 6, 1, 64), jnp.float32), k_pages,
+                        v_pages, bt, lens)
+    # the reference is per head: a pool as held is unpacked before it
+    with pytest.raises(ValueError, match="heads only"):
+        paged_attention_reference(q, k_pages, v_pages, bt, lens)

@@ -139,3 +139,97 @@ def test_paged_write_refuses_mismatched_operands():
         paged_write([pages], [chunk[:, :1]], bt, ln)
     with pytest.raises(ValueError, match="do not match 2 slot"):
         paged_write([pages], [chunk], bt[:1], ln)
+
+
+# --- a pool that holds ``pack`` heads side by side in a 128-lane row --------
+#
+# (``kv_pool.heads_per_row``; docs/serving.md "Page-pool layout"): the
+# callers still hand chunks per head, the wrapper reads ``pack`` off the
+# two shapes, and every head's values land in the same ``(page, offset)``
+# cell as in a pool of one head a row, bit for bit.
+
+#: name -> heads, head width, s, lengths, the pack the pool must come out
+#: with, and the admission's bounds
+PACKED_CASES = {
+    "pack2_decode_s1": dict(heads=4, d=64, s=1, lengths=[0, 5, 7, 8, 23],
+                            pack=2),
+    "pack2_verify_s4_straddles_a_page": dict(
+        heads=4, d=64, s=4, lengths=[6, 5, 4, 13, 16], pack=2),
+    "pack2_chunk_s8_whole_and_split_pages": dict(
+        heads=2, d=64, s=8, lengths=[0, 8, 3, 15], pack=2),
+    "pack2_idle_slot": dict(heads=4, d=64, s=1, lengths=[3, 0, 9],
+                            idle=[1], pack=2),
+    "pack2_f32": dict(heads=2, d=64, s=4, lengths=[5, 14], pack=2,
+                      dtype=jnp.float32),
+    "pack4_decode_s1": dict(heads=8, d=32, s=1, lengths=[1, 30, 12],
+                            pack=4),
+    "pack4_verify_s3": dict(heads=4, d=32, s=3, lengths=[7, 14], pack=4),
+    "pack2_prompt_40_of_a_48_bucket": dict(
+        heads=4, d=64, s=48, lengths=[0], stop=40, max_pages=8, pack=2),
+    "pack2_prompt_tail_after_a_shared_prefix": dict(
+        heads=4, d=64, s=48, lengths=[0], start=16, stop=43, max_pages=8,
+        pack=2),
+    "pack4_prompt_bucket_no_page_multiple": dict(
+        heads=4, d=32, s=20, lengths=[0], stop=19, max_pages=4, pack=4),
+    # what cannot pack keeps one head a row
+    "odd_head_count_falls_back": dict(heads=5, d=64, s=1, lengths=[2, 17],
+                                      pack=1),
+    "width_96_falls_back": dict(heads=2, d=96, s=4, lengths=[6, 9],
+                                pack=1),
+}
+
+
+@pytest.mark.parametrize("name", list(PACKED_CASES))
+def test_packed_pool_holds_what_the_unpacked_pool_holds(name):
+    from apex_tpu.ops.paged_write import pack_heads, unpack_heads
+    from apex_tpu.serving import kv_pool
+
+    case = PACKED_CASES[name]
+    rng = np.random.default_rng(100 + sorted(PACKED_CASES).index(name))
+    heads, d, s = case["heads"], case["d"], case["s"]
+    dtype = case.get("dtype", jnp.bfloat16)
+    pack = kv_pool.heads_per_row(d, heads)
+    assert pack == case["pack"]
+    lengths = jnp.asarray(case["lengths"], jnp.int32)
+    slots = len(case["lengths"])
+    max_pages = case.get("max_pages", 5)
+    num_pages = slots * max_pages + 3
+    tables = _tables(slots, max_pages, rng, num_pages)
+    for b in case.get("idle", ()):
+        tables[b] = 0
+    tables = jnp.asarray(tables)
+    pools = [jnp.asarray(rng.standard_normal((num_pages, heads, PS, d)),
+                         dtype) for _ in range(2)]
+    held = [pack_heads(p, pack) for p in pools]
+    assert held[0].shape == kv_pool._pool_shape(num_pages, heads, PS, d,
+                                                pack)
+    chunks = [jnp.asarray(rng.standard_normal((slots, heads, s, d)), dtype)
+              for _ in range(2)]
+    bounds = {k: case[k] for k in ("start", "stop") if k in case}
+
+    got = jax.jit(lambda p, c: paged_write(p, c, tables, lengths, **bounds))(
+        held, chunks)
+    one = paged_write(pools, chunks, tables, lengths, **bounds)
+    for out, pool_held, flat, pages, chunk in zip(got, held, one, pools,
+                                                  chunks):
+        assert out.dtype == pages.dtype and out.shape == pool_held.shape
+        out = np.asarray(unpack_heads(out, pack).astype(jnp.float32))
+        np.testing.assert_array_equal(
+            out[1:], np.asarray(flat[1:].astype(jnp.float32)))
+        want = scatter_reference(pages, chunk, tables, lengths, **bounds)
+        np.testing.assert_array_equal(
+            out[1:], np.asarray(want[1:].astype(jnp.float32)))
+        assert np.any(out[1:] != np.asarray(pages[1:].astype(jnp.float32)))
+
+
+def test_packed_write_refuses_a_chunk_that_is_no_whole_row():
+    pages = jnp.zeros((5, 2, PS, 128), jnp.bfloat16)
+    bt, ln = jnp.zeros((2, 3), jnp.int32), jnp.zeros((2,), jnp.int32)
+    # 4 heads of 64 fill two rows of 128; 3 do not, nor do 4 of 96
+    paged_write([pages], [jnp.zeros((2, 4, 1, 64), jnp.bfloat16)], bt, ln)
+    with pytest.raises(ValueError, match="does not match"):
+        paged_write([pages], [jnp.zeros((2, 3, 1, 64), jnp.bfloat16)], bt,
+                    ln)
+    with pytest.raises(ValueError, match="does not match"):
+        paged_write([pages], [jnp.zeros((2, 4, 1, 96), jnp.bfloat16)], bt,
+                    ln)

@@ -407,3 +407,145 @@ def test_prefix_cache_requires_paged(rng):
     with pytest.raises(ValueError):
         generate(model, v, jnp.zeros((1, 8), jnp.int32), max_new_tokens=2,
                  prefix_cache=True)
+
+
+# --- over a pool held two (or four) heads a 128-lane row --------------------
+#
+# docs/serving.md "Page-pool layout". The shared-admit program gathers
+# page tiles into a per-head contiguous buffer (it unpacks), the tail is
+# written through the packed page write, and a resume re-admits over
+# pages the preempted segment wrote. The baseline is the same engine over
+# the pool as it was held before, one head a row; the test steers that,
+# the program has no option for it.
+
+def _packed_model(heads):
+    cfg = gpt_tiny_config(hidden_size=128, num_heads=heads, num_layers=1)
+    model = GPTModel(cfg)
+    v = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    return cfg, model, v
+
+
+@pytest.mark.parametrize("heads,pack", [(2, 2), (4, 4)])
+def test_shared_prefix_admission_over_a_packed_pool(rng, monkeypatch, heads,
+                                                    pack):
+    from apex_tpu.serving import kv_pool
+
+    cfg, model, v = _packed_model(heads)
+    sys_p = rng.integers(0, cfg.vocab_size, (4 * PS,)).astype(np.int32)
+    reqs = [_req(rng, sys_p, int(t), int(m))
+            for t, m in zip(rng.integers(3, 12, 4), rng.integers(3, 6, 4))]
+
+    e_on = PagedDecodeEngine(model, v, num_slots=2, page_size=PS,
+                             prefix_cache=True)
+    assert kv_pool.a_pool(e_on.cache).shape[1:] == (heads // pack, PS, 128)
+    o_on, s_on = e_on.run(reqs)
+    assert s_on["pool_heads_per_row"] == pack
+    assert s_on["prefix_hits"] >= len(reqs) - 2
+    assert s_on["prefill_tokens_skipped"] >= (len(reqs) - 2) * 4 * PS
+
+    with monkeypatch.context() as m:
+        m.setattr(kv_pool, "heads_per_row", lambda *a, **k: 1)
+        e_flat = PagedDecodeEngine(model, v, num_slots=2, page_size=PS,
+                                   prefix_cache=True)
+        assert kv_pool.a_pool(e_flat.cache).shape[1:] == (heads, PS,
+                                                          128 // pack)
+        o_flat, s_flat = e_flat.run(reqs)
+    assert s_flat["pool_heads_per_row"] == 1
+    for key in ("prefix_hits", "prefill_tokens_skipped", "decode_steps"):
+        assert s_on[key] == s_flat[key], key
+    for a, b in zip(o_on, o_flat):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(o_on[-1], _lockstep(model, v, reqs[-1]))
+    assert int(e_on.cache["page_ref"].sum()) == 0
+    usable = e_on.cache["free_stack"].shape[0] - 1
+    assert int(free_page_count(e_on.cache)) == usable - len(e_on.prefix)
+
+
+@pytest.mark.parametrize("heads,pack", [(2, 2), (4, 4)])
+def test_preempt_and_resume_over_a_packed_pool(rng, monkeypatch, heads,
+                                               pack):
+    """A high-priority arrival evicts a low-priority slot; the victim
+    resumes over the pages its first segment wrote (a shared admission
+    whose prefix the decode steps' page writes filled). Token for token
+    the one-head-a-row pool's run, and the counters the decode kernel's
+    roofline reads count the same bytes however a row is held."""
+    from apex_tpu.serving import (PriorityDeadlinePolicy, ServingFrontend,
+                                  kv_pool)
+
+    cfg, model, v = _packed_model(heads)
+    low = [Request(prompt=rng.integers(0, cfg.vocab_size, (24,)
+                                       ).astype(np.int32),
+                   max_new_tokens=12, priority=0) for _ in range(2)]
+    hi = Request(prompt=rng.integers(0, cfg.vocab_size, (24,)
+                                     ).astype(np.int32),
+                 max_new_tokens=4, priority=5)
+
+    def serve():
+        engine = PagedDecodeEngine(model, v, num_slots=2, page_size=PS,
+                                   prefix_cache=True)
+        fe = ServingFrontend(engine, policy=PriorityDeadlinePolicy(
+            preempt_on_priority=True))
+        handles = [fe.submit(r, request_id=i) for i, r in enumerate(low)]
+        while fe.queue_depth:
+            fe.pump()
+        for _ in range(3):                # give the victims some progress
+            fe.pump()
+        handles.append(fe.submit(hi, request_id=2))
+        fe.drain()
+        return fe, [np.asarray(h.result()) for h in handles]
+
+    fe, outs = serve()
+    stats = fe.stats()
+    assert stats["pool_heads_per_row"] == pack
+    assert stats["preemptions"] >= 1 and stats["resumes"] >= 1
+    assert stats["prefill_tokens_skipped"] >= PS
+    with monkeypatch.context() as m:
+        m.setattr(kv_pool, "heads_per_row", lambda *a, **k: 1)
+        fe_flat, outs_flat = serve()
+    flat = fe_flat.stats()
+    assert flat["pool_heads_per_row"] == 1
+    for a, b in zip(outs, outs_flat):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(outs[0], _lockstep(model, v, low[0]))
+    for key in ("preemptions", "resumes", "prefill_tokens_skipped",
+                "decode_steps"):
+        assert stats[key] == flat[key], key
+    d, d_flat = fe.counter_deltas(), fe_flat.counter_deltas()
+    for key in ("kv_bytes_attended", "kv_bytes_fetched"):
+        assert d[key] == d_flat[key] > 0, key
+    usable = fe.engine.cache["free_stack"].shape[0] - 1
+    assert int(free_page_count(fe.engine.cache)) == \
+        usable - len(fe.engine.prefix)
+    assert int(fe.engine.cache["page_ref"].sum()) == 0
+
+
+def test_llama_gqa_windowed_over_a_packed_pool(rng, monkeypatch):
+    """Two kv heads of 64 in one row, two query heads to each (the packed
+    read's GQA rows), a sliding window (the band and the dropped pages):
+    token for token the one-head-a-row pool's run."""
+    from apex_tpu.models.llama import LlamaModel, llama_tiny_config
+    from apex_tpu.serving import kv_pool
+
+    cfg = llama_tiny_config(hidden_size=256, intermediate_size=352,
+                            num_heads=4, num_kv_heads=2, num_layers=1,
+                            sliding_window=12)
+    assert cfg.head_dim == 64
+    model = LlamaModel(cfg)
+    v = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    reqs = [Request(prompt=rng.integers(0, cfg.vocab_size, (L,)
+                                        ).astype(np.int32),
+                    max_new_tokens=m)
+            for L, m in zip([5, 19], [18, 6])]
+    engine = PagedDecodeEngine(model, v, num_slots=2, page_size=PS)
+    assert kv_pool.a_pool(engine.cache).shape[1:] == (1, PS, 128)
+    outs, stats = engine.run(reqs)
+    assert stats["pool_heads_per_row"] == 2
+    assert stats["window_dropped_pages"] > 0
+    with monkeypatch.context() as m:
+        m.setattr(kv_pool, "heads_per_row", lambda *a, **k: 1)
+        flat = PagedDecodeEngine(model, v, num_slots=2, page_size=PS)
+        assert kv_pool.a_pool(flat.cache).shape[1:] == (2, PS, 64)
+        flat_outs, flat_stats = flat.run(reqs)
+    assert flat_stats["window_dropped_pages"] == stats["window_dropped_pages"]
+    for a, b in zip(outs, flat_outs):
+        np.testing.assert_array_equal(a, b)

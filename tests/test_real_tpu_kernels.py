@@ -370,16 +370,20 @@ def test_flash_attention_sliding_window(tpu, rng):
     assert np.isfinite(np.asarray(g, np.float32)).all()
 
 
-#: the page write at the serving cells' widths: pool, slots, table
-#: width, s, and an admission's bounds
+#: the page write at the serving cells' widths: pool AS HELD (GPT-2
+#: large: two 64-wide heads a 128-lane row), the chunk's (heads, width)
+#: where a row packs, slots, table width, s, and an admission's bounds
 _PAGED_WRITE_CASES = {
-    "gpt2l_decode": dict(pool=(729, 20, 16, 64), n=2, slots=16, mp=64, s=1),
-    "gpt2l_verify_s4": dict(pool=(729, 20, 16, 64), n=2, slots=16, mp=64,
-                            s=4),
-    "gpt2l_chunk_s16": dict(pool=(729, 20, 16, 64), n=2, slots=1, mp=64,
-                            s=16),
-    "gpt2l_admit256": dict(pool=(729, 20, 16, 64), n=2, slots=1, mp=64,
-                           s=256, start=32, stop=201),
+    "gpt2l_decode": dict(pool=(729, 10, 16, 128), chunk=(20, 64), n=2,
+                         slots=16, mp=64, s=1),
+    "gpt2l_verify_s4": dict(pool=(729, 10, 16, 128), chunk=(20, 64), n=2,
+                            slots=16, mp=64, s=4),
+    "gpt2l_chunk_s16": dict(pool=(729, 10, 16, 128), chunk=(20, 64), n=2,
+                            slots=1, mp=64, s=16),
+    "gpt2l_admit256": dict(pool=(729, 10, 16, 128), chunk=(20, 64), n=2,
+                           slots=1, mp=64, s=256, start=32, stop=201),
+    "one_head_a_row_decode": dict(pool=(729, 20, 16, 64), n=2, slots=16,
+                                  mp=64, s=1),
     "glm_decode": dict(pool=(4097, 1, 16, 640), n=1, slots=32, mp=128, s=1),
     "glm_admit16k": dict(pool=(4097, 1, 16, 640), n=1, slots=1, mp=2048,
                          s=16384, stop=16001),
@@ -394,13 +398,16 @@ def test_paged_write_matches_the_scatter_on_chip(name, tpu, rng):
     grid step's page while this one's is written back, in place. Live
     slots own distinct pages (neighbours included), idle slots all name
     page 0; four writes in a row as a decode chunk makes them, then every
-    page but 0 against the scatter, bit for bit."""
+    page but 0 against the scatter (into a pool of one head a row), bit
+    for bit."""
     from test_paged_write import scatter_reference
 
-    from apex_tpu.ops.paged_write import paged_write
+    from apex_tpu.ops.paged_write import paged_write, unpack_heads
 
     c = _PAGED_WRITE_CASES[name]
-    num_pages, heads, ps, d = c["pool"]
+    num_pages, _, ps, _ = c["pool"]
+    heads, d = c.get("chunk", (c["pool"][1], c["pool"][3]))
+    pack = c["pool"][3] // d
     slots, mp, s = c["slots"], c["mp"], c["s"]
     dtype = c.get("dtype", jnp.bfloat16)
     bounds = {k: c[k] for k in ("start", "stop") if k in c}
@@ -432,10 +439,41 @@ def test_paged_write_matches_the_scatter_on_chip(name, tpu, rng):
                 for p, x in zip(pools, chunk)]
 
     got = run(paged_write, pools, chunks)
-    want = run(reference, pools, chunks)
+    want = run(reference, [unpack_heads(p, pack) for p in pools], chunks)
     for out, ref, pages in zip(got, want, pools):
-        np.testing.assert_array_equal(np.asarray(out[1:], np.float32),
-                                      np.asarray(ref[1:], np.float32))
+        assert out.shape == pages.shape
+        np.testing.assert_array_equal(
+            np.asarray(unpack_heads(out, pack)[1:], np.float32),
+            np.asarray(ref[1:], np.float32))
         assert not np.array_equal(np.asarray(out[1:], np.float32),
                                   np.asarray(pages[1:], np.float32))
+
+
+@pytest.mark.parametrize("s,window", [(1, None), (4, None), (1, 200)])
+def test_packed_paged_attention_matches_reference_on_chip(s, window, tpu,
+                                                          rng):
+    """GPT-2 large's decode read over the pool as held, two 64-wide heads
+    a 128-lane row: the block-diagonal queries must lower under Mosaic
+    and give each head its own scores."""
+    from apex_tpu.ops.paged_attention import (paged_attention,
+                                              paged_attention_reference)
+    from apex_tpu.ops.paged_write import unpack_heads
+
+    slots, mp, ps = 16, 64, 16
+    k_held, v_held = (jnp.asarray(rng.standard_normal((729, 10, ps, 128)),
+                                  jnp.bfloat16) for _ in range(2))
+    tables = jnp.asarray(rng.permutation(np.arange(1, 729))[
+        :slots * 45].reshape(slots, 45), jnp.int32)
+    tables = jnp.pad(tables, ((0, 0), (0, mp - 45)))
+    lengths = jnp.asarray(rng.integers(s, 45 * ps, (slots,)), jnp.int32)
+    lengths = lengths.at[3].set(0).at[7].set(45 * ps)
+    q = jnp.asarray(rng.standard_normal((slots, 20, s, 64)), jnp.bfloat16)
+    got = jax.jit(lambda *a: paged_attention(*a, window=window))(
+        q, k_held, v_held, tables, lengths)
+    want = paged_attention_reference(
+        q, unpack_heads(k_held, 2), unpack_heads(v_held, 2), tables,
+        lengths, window=window)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=2e-2, atol=2e-2)
 

@@ -260,3 +260,135 @@ def test_engine_validates_requests(rng):
     with pytest.raises(RuntimeError):
         small.run([Request(prompt=np.zeros((30,), np.int32),
                            max_new_tokens=10)])
+
+# --- the pool is held 128 lanes wide where it can be ------------------------
+#
+# docs/serving.md "Page-pool layout": ``kv_pool.heads_per_row`` decides, in
+# one place and from what the pool is, how many heads a pool row holds
+# side by side.
+
+@pytest.mark.parametrize("width,kv_local,quantized,want", [
+    (64, 20, False, 2),       # GPT-2 large: two heads a 128-lane row
+    (64, 5, False, 1),        # tp=4 of it: 5 heads a chip, 2 does not divide
+    (64, 10, False, 2),       # tp=2 of it
+    (64, 2, False, 2),
+    (64, 1, False, 1),        # one 64-wide head cannot fill a row
+    (32, 8, False, 4),
+    (32, 6, False, 1),        # 4 does not divide 6: no half-measure of 2
+    (16, 16, False, 8),
+    (16, 4, False, 1),        # the tiny test configs: 4 heads of 16
+    (128, 8, False, 1),       # Llama's heads: nothing changes
+    (256, 20, False, 1),
+    (96, 4, False, 1),        # no divisor of 128
+    (576, 1, False, 1),       # a latent entry as stated
+    (640, 1, False, 1),       # and as stored
+    (64, 20, True, 1),        # a quantized pool: scales are per head
+    (32, 8, True, 1),
+])
+def test_heads_per_row_table(width, kv_local, quantized, want):
+    from apex_tpu.serving import kv_pool
+
+    assert kv_pool.heads_per_row(width, kv_local,
+                                 quantized=quantized) == want
+    shape = kv_pool._pool_shape(7, kv_local, 16, width, want)
+    # the same values in the same bytes, a row 128 lanes where it packs
+    assert shape == (7, kv_local // want, 16, width * want)
+    assert shape[1] * shape[3] == kv_local * width
+    assert want == 1 or shape[3] == 128
+
+
+@pytest.mark.parametrize("make,pool_row,pack", [
+    (lambda: gpt_tiny_config(hidden_size=128, num_heads=2), (1, 128), 2),
+    (lambda: gpt_tiny_config(hidden_size=128, num_heads=4), (1, 128), 4),
+    (lambda: gpt_tiny_config(), (4, 16), 1),
+    (lambda: gpt_tiny_config(hidden_size=192, num_heads=3), (3, 64), 1),
+])
+def test_init_paged_cache_holds_the_pool_packed(make, pool_row, pack):
+    from apex_tpu.serving import kv_pool
+
+    cfg = make()
+    cache = init_paged_cache(cfg, num_slots=2, num_pages=6, page_size=8)
+    for lc in cache["layers"]:
+        assert lc["k_pages"].shape == (6, pool_row[0], 8, pool_row[1])
+        assert lc["v_pages"].shape == lc["k_pages"].shape
+    assert kv_pool.heads_per_row_of(cache, cfg) == pack
+    # what a token stores does not change with how a row is held
+    assert kv_pool.page_bytes(cfg, 8) == (
+        2 * cfg.num_heads * cfg.head_dim * 8 * 4 * cfg.num_layers)
+    # a quantized pool keeps one head a row beside its per-head scales
+    q = init_paged_cache(cfg, num_slots=2, num_pages=6, page_size=8,
+                         kv_dtype="int8")
+    assert q["layers"][0]["k_pages"].shape == (6, cfg.num_heads, 8,
+                                               cfg.head_dim)
+    assert q["layers"][0]["k_scales"].shape == (6, cfg.num_heads)
+
+
+def test_prefill_scatter_roundtrip_packed_pool(rng):
+    """``prefill_into_pages`` takes the contiguous buffer per HEAD and the
+    pool holds two heads a row: position p of head ``2j + q`` lands at
+    table entry p//ps, offset p%ps, row j, lanes [64q, 64q + 64)."""
+    cfg = gpt_tiny_config(hidden_size=256, num_heads=4)
+    ps, s0, bucket = 8, 13, 16
+    cache = init_paged_cache(cfg, num_slots=1, num_pages=8, page_size=ps)
+    assert cache["layers"][0]["k_pages"].shape == (8, 2, ps, 128)
+    cache = alloc_slot(cache, 0, pages_for(s0, ps))
+    contig = [{n: jnp.asarray(rng.standard_normal((1, 4, bucket, 64)),
+                              jnp.float32) for n in ("k", "v")}
+              for _ in range(cfg.num_layers)]
+    cache = prefill_into_pages(cache, 0, contig, jnp.int32(s0))
+    bt = np.asarray(cache["block_tables"][0])
+    for li in range(cfg.num_layers):
+        for n in ("k", "v"):
+            pages = np.asarray(cache["layers"][li][n + "_pages"])
+            want = np.asarray(contig[li][n][0])       # (heads, bucket, d)
+            for p in range(s0):
+                got = pages[bt[p // ps], :, p % ps, :].reshape(4, 64)
+                np.testing.assert_array_equal(got, want[:, p, :])
+
+
+def _one_head_a_row(monkeypatch):
+    """The pool as it was held before: the baseline a packed pool must
+    serve the same tokens as. Steered here, in the test: the program has
+    no option that chooses the pool's shape."""
+    from apex_tpu.serving import kv_pool
+
+    monkeypatch.setattr(kv_pool, "heads_per_row", lambda *a, **k: 1)
+
+
+@pytest.mark.parametrize("heads,pack", [(2, 2), (4, 4)])
+def test_engine_over_a_packed_pool_serves_the_unpacked_pools_tokens(
+        rng, monkeypatch, heads, pack):
+    """Mixed lengths over fewer slots than requests (admissions through
+    the prompt write, decode steps through the page write and the packed
+    read): token for token what the pool of one head a row serves, and
+    what lock-step ``generate`` gives."""
+    cfg = gpt_tiny_config(hidden_size=128, num_heads=heads, num_layers=1)
+    model = GPTModel(cfg)
+    v = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    reqs = [Request(prompt=np.asarray(
+                rng.integers(0, cfg.vocab_size, (L,)), np.int32),
+                max_new_tokens=m)
+            for L, m in zip([5, 16, 23], [6, 3, 5])]
+
+    engine = PagedDecodeEngine(model, v, num_slots=2, page_size=8)
+    assert engine.cache["layers"][0]["k_pages"].shape[1:] == (
+        heads // pack, 8, 128)
+    outs, stats = engine.run(reqs)
+    assert stats["pool_heads_per_row"] == pack
+
+    with monkeypatch.context() as m:
+        _one_head_a_row(m)
+        flat = PagedDecodeEngine(model, v, num_slots=2, page_size=8)
+        assert flat.cache["layers"][0]["k_pages"].shape[1:] == (
+            heads, 8, 128 // pack)
+        flat_outs, flat_stats = flat.run(reqs)
+    assert flat_stats["pool_heads_per_row"] == 1
+    for out, one in zip(outs, flat_outs):
+        np.testing.assert_array_equal(out, one)
+    req = reqs[-1]
+    ref = np.asarray(generate(model, v, np.asarray(req.prompt)[None],
+                              max_new_tokens=req.max_new_tokens))
+    np.testing.assert_array_equal(outs[-1], ref[0, req.prompt.shape[0]:])
+    assert stats["decode_steps"] == flat_stats["decode_steps"]
+    assert int(free_page_count(engine.cache)) == \
+        engine.cache["free_stack"].shape[0] - 1

@@ -294,3 +294,46 @@ def test_tp2_frontend_preemption_composes(tp_setup, rng):
     assert hi.result(timeout=0).shape[0] >= 1
     for h in low:
         assert h.result(timeout=0).shape[0] >= 1
+
+
+@pytest.mark.parametrize("heads,tp_rows,pack", [
+    (4, 1, 2),    # 2 heads of 64 a chip: one 128-lane row a chip
+    (2, 1, 1),    # 1 head of 64 a chip: a row would straddle the two chips
+])
+def test_tp2_over_a_pool_of_64_wide_heads(rng, heads, tp_rows, pack):
+    """The pool's head axis counts rows of ``pack`` heads, and ``pack`` is
+    decided from ONE chip's head count (``kv_pool.heads_per_row``): where
+    it divides, each chip's shard is whole rows of its own heads; where
+    it does not, the pool keeps one head a row. Either way the tp=2
+    engine serves the single-chip engine's tokens (which packs by ITS
+    head count)."""
+    from apex_tpu.serving import kv_pool
+
+    cfg1 = gpt_tiny_config(hidden_size=64 * heads, num_heads=heads,
+                           num_layers=1)
+    m1 = GPTModel(cfg1)
+    v1 = m1.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    m2 = GPTModel(gpt_tiny_config(hidden_size=64 * heads, num_heads=heads,
+                                  num_layers=1, tensor_parallel_size=2))
+    mesh = tp_mesh(2)
+    v2, _ = shard_model_variables(m2, v1, mesh)
+    reqs = _requests(rng, n=2)
+    e1 = PagedDecodeEngine(m1, v1, num_slots=2, page_size=8,
+                           eos_token_id=EOS, prefix_cache=True)
+    assert kv_pool.a_pool(e1.cache).shape[3] == 128
+    o1, _ = e1.run(reqs)
+    e2 = TensorParallelPagedEngine(m2, v2, mesh=mesh, num_slots=2,
+                                   page_size=8, eos_token_id=EOS,
+                                   prefix_cache=True)
+    pool = kv_pool.a_pool(e2.cache)
+    assert pool.shape[1:] == (heads // pack, 8, 64 * pack)
+    assert {s.data.shape[1] for s in pool.addressable_shards} == {tp_rows}
+    o2, s2 = e2.run(reqs)
+    assert s2["tp_world"] == 2 and s2["pool_heads_per_row"] == pack
+    for a, b in zip(o1, o2):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # a warm second run admits through the shared-prefix gather
+    o3, s3 = e2.run(reqs)
+    assert s3["prefix_hits"] > 0
+    for a, b in zip(o1, o3):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -288,21 +288,23 @@ def kernel_cases():
     # -- paged-attention serving decode kernel (apex_tpu/serving): GPT-2
     # small pool at 8 slots — 512 usable pages of 16 tokens (+ null page),
     # 32-page tables (512-token sequences). Scalar-prefetch block tables
-    # are the new Mosaic feature this case gates.
+    # are the new Mosaic feature this case gates. The pool as the engine
+    # holds it: two 64-wide heads a 128-lane row (kv_pool.heads_per_row),
+    # the queries per head
     from apex_tpu.ops.paged_attention import paged_attention
 
     yield ("paged_attention_gpt2s_decode", paged_attention,
-           [_sds((8, 12, 1, 64), bf16), _sds((513, 12, 16, 64), bf16),
-            _sds((513, 12, 16, 64), bf16), _sds((8, 32), i32),
+           [_sds((8, 12, 1, 64), bf16), _sds((513, 6, 16, 128), bf16),
+            _sds((513, 6, 16, 128), bf16), _sds((8, 32), i32),
             _sds((8,), i32)])
 
     # -- the benchmark's serving cell (gpt2-large.chat-closed16): 16 slots,
-    # 20 heads of 64, 64-page tables over the 2 GiB pool; one grid step
-    # takes all 20 heads of 8 pages, so 16 page operands of
-    # (1, 20, 16, 64) a tensor ride one call
+    # 20 heads of 64 held two a row, 64-page tables over the 2 GiB pool;
+    # one grid step takes all 10 rows of 8 pages, so 16 page operands of
+    # (1, 10, 16, 128) a tensor ride one call
     yield ("paged_attention_gpt2l_cell", paged_attention,
-           [_sds((16, 20, 1, 64), bf16), _sds((729, 20, 16, 64), bf16),
-            _sds((729, 20, 16, 64), bf16), _sds((16, 64), i32),
+           [_sds((16, 20, 1, 64), bf16), _sds((729, 10, 16, 128), bf16),
+            _sds((729, 10, 16, 128), bf16), _sds((16, 64), i32),
             _sds((16,), i32)])
 
     # -- the latent serving cell (glm-4.7-flash.docqa-closed32): 32 slots,
@@ -334,8 +336,8 @@ def kernel_cases():
     # (len - s + i) is the only new Mosaic surface, so one s=4 case
     # gates it at the gpt2s pool shape.
     yield ("gpt2s_paged_spec_verify", paged_attention,
-           [_sds((8, 12, 4, 64), bf16), _sds((513, 12, 16, 64), bf16),
-            _sds((513, 12, 16, 64), bf16), _sds((8, 32), i32),
+           [_sds((8, 12, 4, 64), bf16), _sds((513, 6, 16, 128), bf16),
+            _sds((513, 6, 16, 128), bf16), _sds((8, 32), i32),
             _sds((8,), i32)])
 
     # -- quantized KV pages (docs/serving.md "Quantized KV pages"): the
