@@ -140,14 +140,45 @@ def is_paged(cache) -> bool:
     return "block_tables" in cache
 
 
-def layer_cache(cache, i: int):
+def layer_cache(cache, i: int, table=None):
     """Per-layer view for decoder block ``i`` (adds the shared length —
-    and, for a paged cache, the shared block tables)."""
+    and, for a paged cache, the shared block tables). ``table``: the
+    ``(block_tables, len)`` of the layer's OWN group where the pool holds
+    more than one (:func:`paged_layer_tables`); the block writes and reads
+    through them and never tells the difference."""
     lc = dict(cache["layers"][i])
     lc["len"] = cache["len"]
-    if is_paged(cache):
+    if table is not None:
+        lc["block_tables"], lc["len"] = table
+    elif is_paged(cache):
         lc["block_tables"] = cache["block_tables"]
     return lc
+
+
+def paged_layer_tables(cache, config, s: int):
+    """One entry a layer for :func:`layer_cache`'s ``table``: ``None`` for
+    a layer of the block table's own group, and for a layer of a RING
+    group (windowed layers beside full ones: ``serving/kv_pool.
+    layer_groups``) the group's view of its slots' rings at this step,
+    computed once a group."""
+    from apex_tpu.serving.kv_pool import (layer_groups, page_size_of,
+                                          ring_view)
+
+    tables = [None] * config.num_layers
+    for group in layer_groups(config):
+        if not group.ring:
+            continue
+        if s != 1:
+            raise NotImplementedError(
+                f"a ring group takes one token a slot and step, got a "
+                f"chunk of {s}: a ring holds the band of ONE query "
+                f"position (speculation and chunked prefill are refused "
+                f"where the engine is built)")
+        view = ring_view(cache["len"], window=group.window,
+                         page_size=page_size_of(cache))
+        for i in group.layers:
+            tables[i] = view
+    return tables
 
 
 def is_static_prefill(lc, s: int) -> bool:

@@ -464,7 +464,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        kernel="paged_attention",
+        # a banded call carries a label of its own, so that a trace tells
+        # a windowed layer's calls from a full layer's in one program
+        kernel=("paged_attention" if window is None
+                else "paged_window_attention"),
         interpret=_INTERPRET(),
     )(*operands)
     if pack > 1:

@@ -66,7 +66,7 @@ import queue as _queue
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -288,6 +288,16 @@ class StreamHandle:
         return self._output
 
 
+class _KvGroup(NamedTuple):
+    """One group of layers as the byte counters see it."""
+
+    group: kv_pool.LayerGroup
+    ring: Optional[int]           # pages a slot holds of it, where a ring
+    page_bytes: float             # one page over its layers and all chips
+    pages_fetched: Callable       # ops.paged_attention.pages_fetched, bound
+    #                               to the group's pool and table
+
+
 class _Entry:
     """Pump-internal request state, reused across preempt/resume cycles:
     ``prompt`` is the CURRENT segment's prompt (the original plus every
@@ -444,26 +454,35 @@ class ServingFrontend:
         self._bubble = metrics.gauge("pump.bubble_ms", labels=labels)
         self._last_ready: Optional[float] = None
         self._wait_s = 0.0
-        # bytes one context token costs across all layers and chips by the
-        # pool's own account of an entry (K and V per head, or one latent
-        # entry; feeds serving.kv_bytes_attended)
+        # per group of layers (kv_pool.layer_groups; one, unless the
+        # model mixes windowed and full layers): the bytes one page and
+        # one context token cost across the group's layers and all chips
+        # by the pool's own account of an entry (K and V per head, or one
+        # latent entry; feeds serving.kv_bytes_attended and its split by
+        # kind), and the pages the decode kernel's DMAs move for a slot of
+        # a given length, as each chip's call tiles its local share of
+        # the group's pool as held: rows (a head, or heads_per_row of
+        # them side by side; a latent pool's one) of the pool's lanes,
+        # over the group's own table (a ring group's is its R pages);
+        # feeds serving.kv_bytes_fetched
         tp = int(getattr(engine, "tp_world", 1))
-        self._kv_token_bytes = (
-            kv_pool.page_bytes(engine.cfg, engine.page_size,
-                               kv_dtype=engine.kv_dtype)
-            * tp / engine.page_size)
-        # pages the decode kernel's DMAs move for a slot of a given
-        # length, as each chip's call tiles its local share of the pool
-        # as held: rows (a head, or heads_per_row of them side by side; a
-        # latent pool's one) of the pool's lanes; feeds
-        # serving.kv_bytes_fetched
-        pool = kv_pool.a_pool(engine.cache)
-        self._pages_fetched = functools.partial(
-            pages_fetched,
-            kv_heads=pool.shape[1] // tp, page_size=engine.page_size,
-            head_dim=pool.shape[3], dtype=pool.dtype,
-            max_pages=engine.cache["block_tables"].shape[1],
-            s_q=engine.draft_len + 1, window=engine.window)
+        self._kv_groups = []
+        for g in engine.groups:
+            pool = kv_pool.a_pool(engine.cache, g.layers[0])
+            ring = (kv_pool.ring_pages(g.window, engine.page_size)
+                    if g.ring else None)
+            self._kv_groups.append(_KvGroup(
+                g, ring,
+                kv_pool.page_bytes(engine.cfg, engine.page_size,
+                                   kv_dtype=engine.kv_dtype,
+                                   layers=len(g.layers)) * tp,
+                functools.partial(
+                    pages_fetched, kv_heads=pool.shape[1] // tp,
+                    page_size=engine.page_size, head_dim=pool.shape[3],
+                    dtype=pool.dtype,
+                    max_pages=ring
+                    or engine.cache["block_tables"].shape[1],
+                    s_q=engine.draft_len + 1, window=g.window)))
         # TPOT-SLO burn rate: (time, missed) per SLO-carrying retirement
         # inside the policy's rolling window (pump-confined state)
         self._slo_window: deque = deque()
@@ -926,12 +945,31 @@ class ServingFrontend:
                     if e.joined <= self._chunk]
         self._C["busy_slot_steps"].inc(len(decoding) * eng.sync_every)
         self._C["decode_steps"].inc(eng.sync_every)
-        self._C["kv_bytes_attended"].inc(
-            sum(self._tokens_attended(e) for e in decoding)
-            * self._kv_token_bytes)
-        self._C["kv_bytes_fetched"].inc(
-            sum(self._pages_moved(e) for e in decoding)
-            * self._kv_token_bytes * eng.page_size)
+        ps = eng.page_size
+        full = banded = fetched = held = 0.0
+        for kv in self._kv_groups:
+            window = kv.group.window
+            attended = kv.page_bytes / ps * sum(
+                self._tokens_attended(e, window) for e in decoding)
+            if window is None:
+                full += attended
+            else:
+                banded += attended
+            fetched += kv.page_bytes * sum(
+                self._pages_moved(e, kv) for e in decoding)
+            # pages the slots own: a ring group's R each, for ever; the
+            # block table's as the entry holds them now
+            held += kv.page_bytes * (
+                kv.ring * len(decoding) if kv.ring
+                else sum(e.n_private for e in decoding))
+        self._C["kv_bytes_attended"].inc(full + banded)
+        self._C["kv_full_bytes_attended"].inc(full)
+        self._C["kv_window_bytes_attended"].inc(banded)
+        self._C["kv_bytes_fetched"].inc(fetched)
+        self._C["kv_bytes_held_steps"].inc(held * eng.sync_every)
+        self._C["context_token_steps"].inc(eng.sync_every * sum(
+            e.s0 + (self._chunk - e.joined) * eng.sync_every + 1
+            for e in decoding))
         t0 = self.clock()
         if eng.draft_len:
             # speculative chunk: the payload is (target predictions,
@@ -951,36 +989,43 @@ class ServingFrontend:
         self.peak_slots = max(self.peak_slots, len(self._active))
         self._occ.set(len(self._active))
 
-    def _tokens_attended(self, entry: _Entry) -> int:
+    def _tokens_attended(self, entry: _Entry, window: Optional[int]) -> int:
         """Context tokens the chunk being dispatched attends for one
-        decoding slot, summed over its LIVE steps: step ``j`` of a slot
-        that has run ``ran`` steps since it joined reads the K/V of
-        ``s0 + ran + j + 1`` tokens (its own included), banded to the
-        window; steps past the token budget are frozen and read nothing
-        the algorithm needs. What the algorithm must read — the kernel's
-        grid may touch more. (A speculative chunk is counted one verify
-        round per step at the least context the round can have.)"""
+        decoding slot in a layer of the given ``window`` (``None``:
+        full), summed over its LIVE steps: step ``j`` of a slot that has
+        run ``ran`` steps since it joined reads the K/V of ``s0 + ran +
+        j + 1`` tokens (its own included), banded to the window; steps
+        past the token budget are frozen and read nothing the algorithm
+        needs. What the algorithm must read — the kernel's grid may touch
+        more. (A speculative chunk is counted one verify round per step
+        at the least context the round can have.)"""
         eng = self.engine
         ran = (self._chunk - entry.joined) * eng.sync_every
         live = min(eng.sync_every, max(entry.seg_new - 1 - ran, 0))
         first = entry.s0 + ran + 1
-        if eng.window is not None:
-            return sum(min(eng.window, first + j) for j in range(live))
+        if window is not None:
+            return sum(min(window, first + j) for j in range(live))
         return live * first + live * (live - 1) // 2
 
-    def _pages_moved(self, entry: _Entry) -> int:
-        """Pages the decode kernel fetches for one decoding slot over
-        the chunk being dispatched, summed over ALL its steps: the
-        kernel moves whole page blocks (``ops.paged_attention.
-        pages_fetched``), and a frozen step still runs the forward at
-        the slot's last length. Over ``_tokens_attended`` x page_size
-        this is the rounding the tile costs."""
+    def _pages_moved(self, entry: _Entry, kv: "_KvGroup") -> int:
+        """Pages the decode kernel fetches for one decoding slot in a
+        layer of group ``kv`` over the chunk being dispatched, summed
+        over ALL its steps: the kernel moves whole page blocks
+        (``ops.paged_attention.pages_fetched``), and a frozen step still
+        runs the forward at the slot's last length. Over
+        ``_tokens_attended`` x page_size this is the rounding the tile
+        costs. A ring group's call sees the slot from the band's first
+        page on (``kv_pool.ring_view``)."""
         eng = self.engine
         ran = (self._chunk - entry.joined) * eng.sync_every
-        return sum(
-            self._pages_fetched(
-                entry.s0 + min(ran + j, max(entry.seg_new - 1, 0)) + 1)
-            for j in range(eng.sync_every))
+        moved = 0
+        for j in range(eng.sync_every):
+            length = entry.s0 + min(ran + j, max(entry.seg_new - 1, 0)) + 1
+            if kv.ring:
+                length -= (max(length - kv.group.window, 0)
+                           // eng.page_size * eng.page_size)
+            moved += kv.pages_fetched(length)
+        return moved
 
     def _materialize(self, chunk: _Chunk) -> np.ndarray:
         """Block for the chunk's tokens (overlapping whatever device
@@ -1870,4 +1915,14 @@ class ServingFrontend:
             if isinstance(val, bool):
                 continue
             metrics.record(f"serving.{name}", val)
+        # the groups of layers the pool holds (kv_pool.layer_groups):
+        # which layers, how far back they read, and the pages the group's
+        # pool holds for its slots (a ring group: R a slot, for ever; the
+        # block table's group: what the free stack hands out)
+        stats["kv_groups"] = [
+            {"layers": list(kv.group.layers), "window": kv.group.window,
+             "ring_pages_per_slot": kv.ring,
+             "pages_held": (kv.ring * eng.num_slots if kv.ring
+                            else kv_pool.num_pages_of(eng.cache) - 1)}
+            for kv in self._kv_groups]
         return stats

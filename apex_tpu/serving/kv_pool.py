@@ -39,6 +39,50 @@ stored)}`` and every pool op below, which moves page NAMES and walks
 cannot shard over a tensor-parallel mesh, and it has no quantized form:
 both refuse with :class:`LatentPoolUnsupported` where the engine is built.
 
+GROUPS OF LAYERS. Layers that store the same thing and read the same span
+of it (equal :class:`CacheLayout` and window: :func:`layer_groups`) form a
+GROUP, and a group has its own pool tensors, block table and page count. A
+model whose layers are all alike — every model but one with
+``layer_windows`` — is ONE group: the pytree above, the free stack and,
+where the one group has a window, the drop-behind-the-band protocol
+(``drop_slot_pages``), all as they were. A model that mixes windowed and
+full layers (``models/mellum.py``: three ``sliding_attention`` layers to
+one ``full_attention``) has one FULL group, which is everything above
+(``block_tables`` ... ``free_top`` are its state, ``num_pages`` its pages,
+a request's worst case allocated at admission), and one RING group per
+window, which owns no entry of that state at all:
+
+* a ring layer's pools hold ``1 + num_slots * R`` pages: the null page
+  (every writer's sink, as in the full group) and ``R =
+  ring_pages(window, page_size) = ceil(window / page_size) + 1`` pages a
+  slot, slot ``b``'s at ``1 + b*R ..``, for the engine's lifetime. The
+  ``+ 1`` is the page rounding: a band of ``window`` positions that does
+  not start on a page boundary straddles one page more than it fills.
+  ``sync_every`` adds nothing to it: the ring is overwritten in place by
+  the decode steps themselves, inside the chunk's scan, and no host
+  action stands between two steps (a drop-behind pool would have to hold
+  the ``sync_every`` tokens of a chunk on top);
+* logical page ``p`` of a slot lives in ring page ``p mod R``. The table
+  is arithmetic on the slot's length (:func:`ring_view`), not storage:
+  the view a layer writes and reads through starts at the band's first
+  live page ``f`` (entry ``i`` is ring page ``(f + i) mod R``) with the
+  length counted from ``f * page_size``, so ``ops.paged_write`` and
+  ``ops.paged_attention(window=)`` are called as they are — the causal
+  mask and the band are differences of positions — and the table the
+  decode kernel keeps in scalar memory is ``num_slots x R``, not
+  ``num_slots x max_pages``;
+* nothing is allocated at admission and nothing released at retirement:
+  an admission writes the last ``R`` pages' worth of its prompt
+  (:func:`prefill_into_pages`), a retired slot's ring is simply written
+  over by the next request. A slot never owns more than ``R`` pages of a
+  ring group, at any moment.
+
+Whatever shares or hands on pages — the radix prefix cache, the host tier,
+speculation's rollback, chunked prefill — cannot do so with a page that is
+overwritten ``R`` pages later, so the engine refuses each with a ring (or
+a windowed) group, by name; so do quantized pages and a tensor-parallel
+mesh (``ROADMAP.md``).
+
 A POOL ROW IS 128 LANES WHERE IT CAN BE. A per-head pool whose head width
 divides 128 holds ``pack = 128 // width`` heads side by side in one row
 (:func:`heads_per_row`, the one place ``pack`` is decided: from the
@@ -136,6 +180,19 @@ from apex_tpu.transformer.utils import divide
 from apex_tpu.utils import metrics
 
 
+class RingGroupUnsupported(ValueError):
+    """``ring-group-unsupported``: a pool with a ring group (windowed
+    layers beside full ones) was asked for what a page that is overwritten
+    ``R`` pages later cannot give."""
+
+    def __init__(self, what: str):
+        super().__init__(
+            f"ring-group-unsupported: {what} does not compose with a "
+            "model that mixes windowed and full attention layers — its "
+            "windowed layers hold a fixed ring of pages a slot "
+            "(kv_pool.layer_groups), written over as the band moves on")
+
+
 class LatentPoolUnsupported(ValueError):
     """``latent-pool-unsupported``: a pool of latent entries was asked to
     shard over a tensor-parallel mesh or to hold quantized pages."""
@@ -184,6 +241,68 @@ def layout_of(config) -> CacheLayout:
                        config.head_dim, config.head_dim)
 
 
+@dataclasses.dataclass(frozen=True)
+class LayerGroup:
+    """Layers alike in what they store and how far back they read: their
+    ``layout``, their ``window`` (``None``: everything), their indices,
+    and whether the group is held as per-slot RINGS of pages (a windowed
+    group beside others) or by the block table and the free stack (the
+    full group; the one group of a model whose layers are all alike, with
+    a window or without)."""
+
+    layout: CacheLayout
+    window: Optional[int]
+    layers: Tuple[int, ...]
+    ring: bool
+
+
+def layer_groups(config) -> Tuple[LayerGroup, ...]:
+    """The groups of ``config``'s layers, in order of first appearance. A
+    config states per-layer windows as ``layer_windows`` (one entry a
+    layer, ``None`` = full); one without has ONE group, whose window is
+    its model-wide ``sliding_window`` if it has that."""
+    windows = getattr(config, "layer_windows", None)
+    if windows is None:
+        windows = (getattr(config, "sliding_window", None),) \
+            * config.num_layers
+    if len(windows) != config.num_layers:
+        raise ValueError(f"layer_windows has {len(windows)} entries for "
+                         f"{config.num_layers} layers")
+    layout = layout_of(config)
+    by_window = {}
+    for i, w in enumerate(windows):
+        by_window.setdefault(w, []).append(i)
+    mixed = len(by_window) > 1
+    return tuple(LayerGroup(layout, w, tuple(ls), mixed and w is not None)
+                 for w, ls in by_window.items())
+
+
+def ring_pages(window: int, page_size: int) -> int:
+    """``R``: pages of a ring group one slot holds (module docstring)."""
+    return cdiv(window, page_size) + 1
+
+
+def _ring_table(slot, first, count: int, ring: int):
+    # ring pages of logical pages first .. first + count - 1 of ``slot``
+    # (both may be vectors over slots): page 0 is the pool's null page
+    i = jnp.arange(count, dtype=jnp.int32)
+    return (1 + jnp.asarray(slot, jnp.int32)[..., None] * ring
+            + (jnp.asarray(first, jnp.int32)[..., None] + i) % ring)
+
+
+def ring_view(lengths, *, window: int, page_size: int):
+    """``(block_tables (slots, R), lengths (slots,))`` through which a ring
+    group's layers write and read a decode step: the table starts at the
+    first page the step's band reaches and the lengths count from that
+    page's first position. ``lengths``: the slots' tokens written BEFORE
+    the step (``cache["len"]``); the step is one token a slot."""
+    lengths = lengths.astype(jnp.int32)
+    ring = ring_pages(window, page_size)
+    first = jnp.maximum(lengths - window + 1, 0) // page_size
+    table = _ring_table(jnp.arange(lengths.shape[0]), first, ring, ring)
+    return table, lengths - first * page_size
+
+
 def pool_key(name: str) -> str:
     return name + "_pages"
 
@@ -221,10 +340,11 @@ def _pool_shape(num_pages: int, heads: int, page_size: int, stored: int,
     return (num_pages, heads // pack, page_size, stored * pack)
 
 
-def a_pool(cache):
-    """One of the first layer's pools: all of a cache's pools share one
-    shape and dtype."""
-    layer = cache["layers"][0]
+def a_pool(cache, layer: int = 0):
+    """One of a layer's pools (default: the first layer's). A group's
+    pools share one shape and dtype; groups differ in their page count
+    alone."""
+    layer = cache["layers"][layer]
     return layer[pool_key(pool_tensors(layer)[0])]
 
 
@@ -239,7 +359,9 @@ def page_size_of(cache) -> int:
 
 
 def num_pages_of(cache) -> int:
-    return a_pool(cache).shape[0]
+    """Pages of the block table's group (the full group; a ring group's
+    count is its pools' own)."""
+    return cache["page_ref"].shape[0]
 
 
 def pages_for(length, page_size: int):
@@ -335,6 +457,13 @@ def init_paged_cache(config, num_slots: int, *, num_pages: int,
         raise LatentPoolUnsupported(
             f"kv_dtype={kv_dtype!r}" if quant is not None
             else "a tensor-parallel mesh")
+    groups = layer_groups(config)
+    if any(g.ring for g in groups) and (
+            quant is not None or mesh is not None
+            or config.tensor_parallel_size != 1):
+        raise RingGroupUnsupported(
+            f"kv_dtype={kv_dtype!r}" if quant is not None
+            else "a tensor-parallel mesh")
     kv_local = divide(layout.heads, config.tensor_parallel_size)
     kv_dim = kv_local
     if mesh is not None:
@@ -391,14 +520,21 @@ def init_paged_cache(config, num_slots: int, *, num_pages: int,
             "free_stack": sds((num_pages,), jnp.int32, rep),
             "free_top": sds((), jnp.int32, rep),
         }
+    # a ring group's layers hold the null page and R pages a slot, whatever
+    # ``num_pages`` is: that buys pages of the block table's group alone
+    ring_shape = {
+        i: (1 + num_slots * ring_pages(g.window, page_size),) + shape[1:]
+        for g in groups if g.ring for i in g.layers}
+
     def build():
-        def layer_buf():
-            lc = {pool_key(n): jnp.zeros(shape, dt) for n in names}
+        def layer_buf(i):
+            lc = {pool_key(n): jnp.zeros(ring_shape.get(i, shape), dt)
+                  for n in names}
             if quant is not None:
                 lc.update({scale_key(n): jnp.zeros(scale_shape, jnp.float32)
                            for n in names})
             return lc
-        layers = [layer_buf() for _ in range(config.num_layers)]
+        layers = [layer_buf(i) for i in range(config.num_layers)]
         return {
             "layers": layers,
             "block_tables": jnp.zeros((num_slots, max_pages_per_seq),
@@ -694,7 +830,7 @@ def evict_pages(cache, pages_row, n):
     return out
 
 
-def defrag_map(cache, extra_live=None):
+def defrag_map(cache, extra_live=None, *, rings: Tuple[int, ...] = ()):
     """Compact live pages to the low end of the pool (stable order),
     rebuild the free stack from actual liveness, and return
     ``(cache, new_idx)`` where ``new_idx[old_page] = new_page`` — the
@@ -711,7 +847,10 @@ def defrag_map(cache, extra_live=None):
     reasons no block table shows — the prefix cache's refcount-0 resident
     pages. Omitting it with a prefix cache attached would collect the
     cache's pages as leaks (and hand them out while the radix tree still
-    names them)."""
+    names them).
+
+    ``rings``: the layers of ring groups (static). Their pools are no part
+    of the block table's pages and stay as they are."""
     bt = cache["block_tables"]
     num_pages = num_pages_of(cache)
     max_pages = bt.shape[1]
@@ -738,8 +877,8 @@ def defrag_map(cache, extra_live=None):
     # a page's scale moves with the page through the same permutation —
     # remapped quantized contents stay bit-identical to pre-defrag
     out["layers"] = [
-        {key: lc[key][old_of_new] for key in lc}
-        for lc in cache["layers"]]
+        lc if i in rings else {key: lc[key][old_of_new] for key in lc}
+        for i, lc in enumerate(cache["layers"])]
     out["block_tables"] = jnp.where(used_entries, new_idx[bt], 0)
     out["page_ref"] = cache["page_ref"][old_of_new]
     idx = jnp.arange(num_pages, dtype=jnp.int32)
@@ -748,13 +887,14 @@ def defrag_map(cache, extra_live=None):
     return out, new_idx
 
 
-def defrag(cache, extra_live=None):
+def defrag(cache, extra_live=None, *, rings: Tuple[int, ...] = ()):
     """``defrag_map`` without the remap (callers with no host-side page
     names to rewrite)."""
-    return defrag_map(cache, extra_live)[0]
+    return defrag_map(cache, extra_live, rings=rings)[0]
 
 
-def prefill_into_pages(cache, slot, contig_layers, s0, *, start=0):
+def prefill_into_pages(cache, slot, contig_layers, s0, *, start=0,
+                       groups: Optional[Tuple[LayerGroup, ...]] = None):
     """Write a CONTIGUOUS prefill cache (the models' flash-prefill
     output: per layer the layout's tensors, ``k``/``v`` or ``latent``, each
     of shape ``(1, heads, len_bucket, stored)``, per HEAD whatever the pool
@@ -774,7 +914,13 @@ def prefill_into_pages(cache, slot, contig_layers, s0, *, start=0):
     The write is ``ops.paged_write``, whole pages in place and row-major
     like a decode step's (docs/serving.md "Page-pool layout"); a
     QUANTIZED pool quantizes whole table entries and keeps its own
-    scatter (no cell runs it)."""
+    scatter (no cell runs it).
+
+    ``groups`` (``layer_groups(config)``, static; needed where one of them
+    is a ring): a RING group's layers get the last ``R`` pages' worth of
+    the buffer, the pages that hold everything the first decode step's
+    band reaches, written through the slot's ring view; the positions
+    before them are never written, there or anywhere."""
     names = pool_tensors(cache["layers"][0])
     out = dict(cache)
     out["len"] = cache["len"].at[slot].set(jnp.asarray(s0, jnp.int32))
@@ -785,11 +931,39 @@ def prefill_into_pages(cache, slot, contig_layers, s0, *, start=0):
         return out
     origin = jnp.zeros((1,), jnp.int32)   # the buffer starts at position 0
     keys = [pool_key(n) for n in names]
+    ring_of = {i: g for g in groups or () if g.ring for i in g.layers}
+    ps = page_size_of(cache)
+
+    def ring_write(lc, src, window):
+        # logical pages first .. first + count - 1 of the buffer: all of
+        # it where it fits the ring, else the R pages that end with the
+        # buffer's last (at or below the band's first live page, so the
+        # band's pages are among them)
+        ring = ring_pages(window, ps)
+        pages = cdiv(src[names[0]].shape[2], ps)
+        count = min(pages, ring)
+        first = jnp.clip(
+            jnp.maximum(jnp.asarray(s0, jnp.int32) - window + 1, 0) // ps,
+            0, pages - count)
+        table = _ring_table(slot, first, count, ring).reshape(1, count)
+        chunks = [src[n] for n in names]
+        if count < pages:
+            # (a buffer that is no whole number of pages is padded to
+            # one, so that the slice never runs off its end)
+            pad = pages * ps - chunks[0].shape[2]
+            chunks = [jax.lax.dynamic_slice_in_dim(
+                jnp.pad(c, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else c,
+                first * ps, count * ps, axis=2) for c in chunks]
+        return paged_write([lc[k] for k in keys], chunks, table, origin,
+                           stop=s0 - first * ps)
+
     out["layers"] = [
-        dict(zip(keys, paged_write([lc[k] for k in keys],
-                                   [src[n] for n in names], row, origin,
-                                   start=start, stop=s0)))
-        for lc, src in zip(cache["layers"], contig_layers)]
+        dict(zip(keys,
+                 ring_write(lc, src, ring_of[i].window) if i in ring_of
+                 else paged_write([lc[k] for k in keys],
+                                  [src[n] for n in names], row, origin,
+                                  start=start, stop=s0)))
+        for i, (lc, src) in enumerate(zip(cache["layers"], contig_layers))]
     return out
 
 
@@ -845,8 +1019,9 @@ def _prefill_quantized_pages(cache, names, row, contig_layers, s0, start):
 # --------------------------------------------------------------------------
 
 def page_bytes(config, page_size: int = 16, *, kv_dtype=None,
-               dtype=None) -> int:
-    """Pool bytes ONE page costs across all layers: what the layout's
+               dtype=None, layers: Optional[int] = None) -> int:
+    """Pool bytes ONE page costs across all layers (or across ``layers``
+    of them: one group's, ``len(group.layers)``): what the layout's
     tensors store for ``page_size`` tokens at the pool dtype (per-head K
     and V tiles; a latent pool's one entry at its stated width — the lane
     padding of a latent row is the pool's, not a token's), plus —
@@ -866,7 +1041,8 @@ def page_bytes(config, page_size: int = 16, *, kv_dtype=None,
         jnp.dtype(dt).itemsize
     if quant is not None:
         per_tensor += kv_local * jnp.dtype(jnp.float32).itemsize
-    return len(layout.tensors) * per_tensor * config.num_layers
+    return len(layout.tensors) * per_tensor * (
+        config.num_layers if layers is None else layers)
 
 
 def max_slots_for_pool_bytes(config, pool_bytes: int, *,

@@ -104,6 +104,13 @@ _RUN_COUNTERS = ("admitted", "retired", "decode_steps", "busy_slot_steps",
                  "pump_bubble_seconds", "queue_wait_seconds",
                  "first_token_wait_seconds", "kv_bytes_attended",
                  "kv_bytes_fetched",
+                 # kv_bytes_attended split by kind of layer (full /
+                 # windowed: kv_pool.layer_groups), and per dispatch the
+                 # bytes of the pages the decoding slots own in all groups
+                 # x sync_every beside the sum of their context lengths x
+                 # sync_every: their ratio is what a live token costs
+                 "kv_full_bytes_attended", "kv_window_bytes_attended",
+                 "kv_bytes_held_steps", "context_token_steps",
                  # what the decode steps' routing did (models with routed
                  # experts; ``transformer/moe/dropless.ROUTING_STATS``, in
                  # its order, summed over expert layers and decode steps,
@@ -395,24 +402,39 @@ class PagedDecodeEngine:
         self.rng = validate_sampling(temperature, top_k, top_p, rng)
         self.sync_every = sync_every
         self.axis_name = axis_name
-        # sliding-window models: the paged kernel bands attention to the
-        # window and the frontend drops pages below the band at sync
-        # boundaries (kv_pool.drop_slot_pages) — O(window) live pages per
-        # slot. Dropped pages cannot double as shared cache property, so
-        # the window and the radix prefix cache are mutually exclusive.
-        # CONTRACT: a config EXPOSING ``sliding_window`` promises its
-        # model's paged branch passes ``window=`` to ``paged_attention``
-        # (LlamaConfig does; GPTConfig has no such field) — the drop
-        # below frees pages the band can no longer read, so an unbanded
-        # paged path under this attribute would read freed null pages.
-        self.window = getattr(cfg, "sliding_window", None)
-        if prefix_cache and self.window is not None:
+        # what the layers hold, by kind (kv_pool.layer_groups). A model
+        # whose layers are all alike is ONE group; where that group has a
+        # window (``self.window``), the paged kernel bands attention to it
+        # and the frontend drops pages below the band at sync boundaries
+        # (kv_pool.drop_slot_pages) — O(window) live pages per slot.
+        # CONTRACT: a config EXPOSING ``sliding_window`` (or per-layer
+        # ``layer_windows``) promises its model's paged branch passes
+        # ``window=`` to ``paged_attention`` — the drop frees pages the
+        # band can no longer read, so an unbanded paged path under that
+        # attribute would read freed null pages. A model that MIXES
+        # windowed and full layers holds each windowed group as per-slot
+        # rings of pages (``self.ring_layers``), written over in place by
+        # the decode steps: nothing to drop. Either way a page of a
+        # windowed group is freed or overwritten while its request runs,
+        # so it cannot be shared cache property: the radix prefix cache,
+        # speculation and chunked prefill are each refused below for ANY
+        # windowed group, by the group's name.
+        self.groups = kv_pool.layer_groups(cfg)
+        self.window = (self.groups[0].window if len(self.groups) == 1
+                       else None)
+        self.ring_layers = tuple(i for g in self.groups if g.ring
+                                 for i in g.layers)
+        windowed = "; ".join(
+            f"layers {list(g.layers)} read a window of {g.window}"
+            for g in self.groups if g.window is not None)
+        if prefix_cache and windowed:
             raise ValueError(
-                "prefix_cache does not compose with sliding-window "
-                "models: the engine drops a windowed slot's pages once "
-                "they fall below the attention band, and a dropped page "
-                "cannot be shared radix-cache property (decode windowed "
-                "models with prefix_cache=False)")
+                f"prefix_cache does not compose with sliding-window "
+                f"layers ({windowed}): the engine drops or overwrites a "
+                f"windowed group's pages once they fall below the "
+                f"attention band, and such a page cannot be shared "
+                f"radix-cache property (decode windowed models with "
+                f"prefix_cache=False)")
         # in-engine speculative decode (docs/serving.md): every engine
         # step drafts ``draft_len`` tokens per slot through a small draft
         # model's own paged pool and verifies the block in ONE
@@ -442,13 +464,16 @@ class PagedDecodeEngine:
                     "speculative decode does not compose with "
                     "prefix_cache yet: shared pages would need a second "
                     "refcounted draft-pool mirror (run one or the other)")
-            if self.window is not None or getattr(
-                    draft_model.config, "sliding_window", None) is not None:
+            if windowed or any(
+                    g.window is not None
+                    for g in kv_pool.layer_groups(draft_model.config)):
                 raise ValueError(
-                    "speculative decode does not support sliding-window "
-                    "models: the frontend drops pages below the band, "
-                    "and the draft pool would need the same banded drop "
-                    "protocol (use a full-attention target and draft)")
+                    f"speculative decode does not support sliding-window "
+                    f"layers ({windowed or 'the draft model has them'}): "
+                    f"the pages below the band are dropped or written "
+                    f"over, and a rejected draft block could not be "
+                    f"rolled back over them (use a full-attention target "
+                    f"and draft)")
             if prefill_chunk is not None:
                 raise ValueError(
                     "speculative decode and chunked prefill are mutually "
@@ -467,12 +492,13 @@ class PagedDecodeEngine:
                     f"prefill_chunk must be in 1..page_size ({page_size}), "
                     f"got {prefill_chunk}: chunks ride the paged kernel's "
                     f"query block, which is capped at one page")
-            if self.window is not None:
+            if windowed:
                 raise ValueError(
-                    "chunked prefill does not support sliding-window "
-                    "models yet: in-progress chunks hold positions the "
-                    "window-page dropper would free mid-prefill (use "
-                    "monolithic admission for windowed models)")
+                    f"chunked prefill does not support sliding-window "
+                    f"layers yet ({windowed}): in-progress chunks hold "
+                    f"positions the window-page dropper would free, or a "
+                    f"ring would write over, mid-prefill (use monolithic "
+                    f"admission for windowed models)")
         if max_pages_per_seq is None:
             max_pages_per_seq = kv_pool.cdiv(cfg.max_position_embeddings,
                                              page_size)
@@ -533,8 +559,13 @@ class PagedDecodeEngine:
         self._evict_jit = self._compile(
             kv_pool.evict_pages, ("cache", "rep", "rep"), ("cache",),
             donate)
+        defrag_map = kv_pool.defrag_map
+        if self.ring_layers:
+            def defrag_map(cache, extra_live):
+                return kv_pool.defrag_map(cache, extra_live,
+                                          rings=self.ring_layers)
         self._defrag_jit = self._compile(
-            kv_pool.defrag_map, ("cache", "rep"), ("cache", "rep"), donate)
+            defrag_map, ("cache", "rep"), ("cache", "rep"), donate)
         self._drop_jit = self._compile(
             kv_pool.drop_slot_pages, ("cache", "rep", "rep"), ("cache",),
             donate)
@@ -643,8 +674,8 @@ class PagedDecodeEngine:
             contig = init_cache(self.cfg, 1, bucket)
             last, contig = _logits_at(model, variables, ids, contig, s0 - 1)
             cache = kv_pool.alloc_slot(cache, slot, n_pages)
-            cache = kv_pool.prefill_into_pages(cache, slot,
-                                               contig["layers"], s0)
+            cache = kv_pool.prefill_into_pages(
+                cache, slot, contig["layers"], s0, groups=self.groups)
             tok0 = self._first_token(last, req_key, samp0)[0]
             return cache, tok0
 

@@ -14,7 +14,8 @@ from apex_tpu.transformer.moe.dropless import (ROUTING_COLLECTION,
                                                ROUTING_STATS, DroplessMoEMLP,
                                                grouped_experts)
 from apex_tpu.transformer.moe.router import (SigmoidBiasTopKRouter,
-                                             TopKRouter, load_balancing_loss,
+                                             SoftmaxTopKRouter, TopKRouter,
+                                             load_balancing_loss,
                                              router_z_loss)
 
 __all__ = [
@@ -22,6 +23,6 @@ __all__ = [
     "compute_dispatch_combine", "make_moe_mlp", "moe_layer_selected",
     "slice_expert_shards",
     "TopKRouter", "load_balancing_loss", "router_z_loss",
-    "SigmoidBiasTopKRouter", "DroplessMoEMLP", "grouped_experts",
+    "SigmoidBiasTopKRouter", "SoftmaxTopKRouter", "DroplessMoEMLP", "grouped_experts",
     "ROUTING_COLLECTION", "ROUTING_STATS",
 ]

@@ -25,7 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from apex_tpu.transformer.moe.router import SigmoidBiasTopKRouter
+from apex_tpu.transformer.moe.router import (SigmoidBiasTopKRouter,
+                                             SoftmaxTopKRouter)
 
 #: the ``routing`` collection's vector, per expert layer and call: pairs
 #: routed (rows x k), distinct experts with at least one row, and the
@@ -58,10 +59,12 @@ def grouped_experts(x, idx, weights, gate, up, down):
 
 
 class DroplessMoEMLP(nn.Module):
-    """``sum_i w_i E_i(x) + E_shared(x)``: sigmoid-with-bias top-k routing
-    (:class:`SigmoidBiasTopKRouter`), SwiGLU experts of width
-    ``ffn_hidden_size`` stacked ``(E, in, out)``, and ``shared_experts``
-    always-on experts fused into one SwiGLU of their summed width."""
+    """``sum_i w_i E_i(x) + E_shared(x)``: top-k routing by ``router``
+    (``"sigmoid_bias"``: :class:`SigmoidBiasTopKRouter`, the default;
+    ``"softmax"``: :class:`SoftmaxTopKRouter`, which has no scaling
+    factor), SwiGLU experts of width ``ffn_hidden_size`` stacked ``(E, in,
+    out)``, and ``shared_experts`` always-on experts fused into one SwiGLU
+    of their summed width."""
 
     hidden_size: int
     ffn_hidden_size: int
@@ -71,16 +74,26 @@ class DroplessMoEMLP(nn.Module):
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
     params_dtype: jnp.dtype = jnp.float32
+    router: str = "sigmoid_bias"
+
+    def _router(self):
+        common = dict(norm_topk_prob=self.norm_topk_prob,
+                      params_dtype=self.params_dtype, name="router")
+        if self.router == "softmax":
+            return SoftmaxTopKRouter(self.num_experts, self.k, **common)
+        if self.router != "sigmoid_bias":
+            raise ValueError(f"unknown router {self.router!r} (has "
+                             f"'sigmoid_bias', 'softmax')")
+        return SigmoidBiasTopKRouter(
+            self.num_experts, self.k,
+            routed_scaling_factor=self.routed_scaling_factor, **common)
 
     @nn.compact
     def __call__(self, x):
         lead, d = x.shape[:-1], x.shape[-1]
         e, m = self.num_experts, self.ffn_hidden_size
         xt = x.reshape(-1, d)
-        idx, weights = SigmoidBiasTopKRouter(
-            e, self.k, norm_topk_prob=self.norm_topk_prob,
-            routed_scaling_factor=self.routed_scaling_factor,
-            params_dtype=self.params_dtype, name="router")(xt)
+        idx, weights = self._router()(xt)
         gate, up, down = ExpertStack(e, d, m, self.params_dtype,
                                      name="experts")()
         y, sizes = grouped_experts(xt, idx, weights, gate.astype(x.dtype),

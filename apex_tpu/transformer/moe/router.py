@@ -107,3 +107,31 @@ class SigmoidBiasTopKRouter(nn.Module):
         if self.norm_topk_prob:
             weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
         return idx, weights * self.routed_scaling_factor
+
+
+class SoftmaxTopKRouter(nn.Module):
+    """Softmax scores, the ``k`` largest (Qwen3-MoE's router): ``p =
+    softmax(x W^T)`` in fp32 over ALL experts; the ``k`` largest are
+    chosen and their weights are their own ``p``, renormalized to sum 1
+    where ``norm_topk_prob``. No bias, no scaling factor.
+
+    Returns ``(idx, weights)`` as :class:`SigmoidBiasTopKRouter` does, so
+    the two are interchangeable before
+    :mod:`apex_tpu.transformer.moe.dropless`.
+    """
+
+    num_experts: int
+    k: int
+    norm_topk_prob: bool = True
+    params_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        w = self.param("weight", nn.initializers.lecun_normal(),
+                       (self.num_experts, x.shape[-1]), self.params_dtype)
+        probs = jax.nn.softmax(
+            x.astype(jnp.float32) @ w.astype(jnp.float32).T, axis=-1)
+        weights, idx = lax.top_k(probs, self.k)
+        if self.norm_topk_prob:
+            weights = weights / weights.sum(-1, keepdims=True)
+        return idx, weights

@@ -352,6 +352,90 @@ def test_no_program_re_lays_the_page_pool(name, mesh):
         "a page write does not alias its pool operand")
 
 
+def _mellum_cell():
+    """``mellum2-12b-a2.5b.ide-closed48`` at its widths, one period of its
+    layers (sliding, sliding, sliding, full): 48 slots, 4 kv heads of 128,
+    the 2.5 GiB pool's 40,961 pages behind 2048-page tables for the full
+    layer and rings of 65 pages a slot for the sliding ones."""
+    from apex_tpu.models.mellum import MellumConfig, MellumModel
+
+    cfg = MellumConfig(num_layers=4,
+                       layer_types=MellumConfig().layer_types[:4],
+                       max_position_embeddings=32768)
+    return MellumModel(cfg), dict(slots=48, num_pages=40961, max_pages=2048)
+
+
+@pytest.mark.parametrize("program", ["step", "admit"])
+def test_two_groups_of_pages_compile_for_the_chip(program, mesh):
+    """The decode chunk and an admission of a model that mixes windowed and
+    full layers (``kv_pool.layer_groups``), compiled for the described v5e:
+    the decode chunk holds BOTH paged-attention programs as Mosaic calls,
+    banded (label ``paged_window_attention``, over the rings) and not
+    (``paged_attention``, over the block table), three to one; the
+    admission (4096 tokens: four windows, so the rings get a slice of the
+    buffer) writes every layer's pages through the aliasing page write;
+    and neither program re-lays either group's pool."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    import tpu_aot
+    from apex_tpu.serving import kv_pool
+    from apex_tpu.serving.scheduler import PagedDecodeEngine
+
+    model, pool = _mellum_cell()
+    slots = pool["slots"]
+    engine = PagedDecodeEngine(model, variables=None, num_slots=slots,
+                               page_size=16, num_pages=slots + 1,
+                               max_pages_per_seq=pool["max_pages"],
+                               sync_every=4)
+    cache = jax.eval_shape(lambda: kv_pool.init_paged_cache(
+        model.config, slots, num_pages=pool["num_pages"], page_size=16,
+        max_pages_per_seq=pool["max_pages"]))
+    i32 = jnp.int32
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), i32)))
+    sds = jax.ShapeDtypeStruct
+    if program == "step":
+        fn = engine._step_fn()
+        rest = [sds((slots,), i32), sds((slots,), jnp.bool_),
+                sds((slots,), i32), sds((slots, 2), jnp.uint32),
+                sds((slots,), i32)]
+    else:
+        fn = engine._admit_fn(4096)
+        rest = [sds((1, 4096), i32), sds((), i32), sds((), i32),
+                sds((), i32), sds((2,), jnp.uint32)]
+    txt = tpu_aot.compile_replicated(mesh, fn, [cache, variables] + rest,
+                                     (0,)).as_text()
+
+    pools = sorted({x.shape for lc in cache["layers"] for x in lc.values()})
+    assert pools == [(1 + 48 * 65, 4, 16, 128), (40961, 4, 16, 128)]
+    calls = re.findall(r'custom_call_target="tpu_custom_call"([^\n]*)\n'
+                       r'"kernel":"(\w+)"', txt)
+    labels = [label for _, label in calls]
+    if program == "step":
+        assert labels.count("paged_window_attention") == 3
+        assert labels.count("paged_attention") == 1
+    else:
+        assert labels.count("flash_fwd") == 4
+        assert not any(label.startswith("paged_") and label != "paged_write"
+                       for label in labels)
+    writes = [attrs for attrs, label in calls if label == "paged_write"]
+    assert len(writes) == 4
+    assert all("output_to_operand_aliasing" in attrs for attrs in writes)
+    for shape in pools:
+        shape = re.escape(",".join(map(str, shape)))
+        moved = [ln.strip()[:200] for ln in txt.splitlines()
+                 if re.search(rf"= \w+\[{shape}\]\S* (copy|transpose)\(",
+                              ln)]
+        assert not moved, moved[:2]
+        entry = next(ln for ln in txt.splitlines()
+                     if "entry_computation_layout" in ln)
+        assert set(re.findall(rf"\w+\[{shape}\]\{{([\d,]+)", entry)) == {
+            "3,2,1,0"}
+
+
 def test_tight_headdim_compiles(mesh):
     """Compile half of the tight-head-dim gate: the unpadded d=64 layout
     must stay legal under Mosaic (runtime parity is the on-chip test)."""

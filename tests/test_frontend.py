@@ -924,7 +924,8 @@ def test_kv_bytes_fetched_counts_whole_page_blocks(rng):
     for i, r in enumerate(_requests(rng, cfg, [(9, 6), (12, 4)])):
         fe.submit(r, request_id=i)
     fe.drain()
-    assert fe._pages_fetched(1) == fe._pages_fetched(128) == 16
+    (kv,) = fe._kv_groups                      # layers alike: one group
+    assert kv.pages_fetched(1) == kv.pages_fetched(128) == 16
     d = fe.counter_deltas()
     assert d["kv_bytes_fetched"] == d["busy_slot_steps"] * 16 * \
         kv_pool.page_bytes(cfg, fe.engine.page_size)

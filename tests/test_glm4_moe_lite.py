@@ -417,7 +417,8 @@ def test_routing_and_latent_byte_counters_against_a_hand_count(tiny):
         3 * cfg.hidden_size * cfg.moe_intermediate_size * 4
     # a context token costs one latent entry a layer, by the pool's account
     token_bytes = cfg.num_layers * cfg.kv_latent_width * 4
-    assert frontend._kv_token_bytes == token_bytes
+    (kv,) = frontend._kv_groups                # layers alike: one group
+    assert kv.page_bytes / engine.page_size == token_bytes
     attended = sum(sum(len(p) + j + 1 for j in range(4)) for p in prompts)
     assert c["kv_bytes_attended"] == attended * token_bytes
     assert c["kv_bytes_fetched"] >= c["kv_bytes_attended"]

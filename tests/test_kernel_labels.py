@@ -75,6 +75,24 @@ def _cases():
             _sds((16, 20, 1, 64), bf16), _sds((729, 20, 16, 64), bf16),
             _sds((729, 20, 16, 64), bf16), _sds((16, 64), i32),
             _sds((16,), i32)]),
+        # a call with a window carries a label of its own
+        "paged_window_attention": (
+            functools.partial(paged_attention, window=24), [
+                _sds((2, 4, 1, 64), bf16), pages, pages, _sds((2, 4), i32),
+                _sds((2,), i32)]),
+        # the mixed cell: 48 slots, 32 query heads over 4 kv heads of 128;
+        # the sliding layers' rings (65 pages a slot, window 1024) and the
+        # full layers' block table (2048 entries, a 2.5 GiB pool)
+        "paged_window_attention@mellum2-12b-a2.5b.ide-closed48": (
+            functools.partial(paged_attention, window=1024), [
+                _sds((48, 32, 1, 128), bf16),
+                _sds((3121, 4, 16, 128), bf16),
+                _sds((3121, 4, 16, 128), bf16), _sds((48, 65), i32),
+                _sds((48,), i32)]),
+        "paged_attention@mellum2-12b-a2.5b.ide-closed48": (paged_attention, [
+            _sds((48, 32, 1, 128), bf16), _sds((40961, 4, 16, 128), bf16),
+            _sds((40961, 4, 16, 128), bf16), _sds((48, 2048), i32),
+            _sds((48,), i32)]),
         "paged_latent_attention": (
             functools.partial(paged_latent_attention, value_width=128), [
                 _sds((2, 4, 1, 256), bf16), _sds((9, 1, 16, 256), bf16),
@@ -134,7 +152,9 @@ def test_the_cases_cover_the_closed_set():
 @pytest.mark.parametrize("case", _dispatch.KERNEL_LABELS + (
     "paged_attention@gpt2-large.chat-closed16",
     "paged_latent_attention@glm-4.7-flash.docqa-closed32",
-    "paged_write@gpt2-large.chat-closed16"))
+    "paged_write@gpt2-large.chat-closed16",
+    "paged_window_attention@mellum2-12b-a2.5b.ide-closed48",
+    "paged_attention@mellum2-12b-a2.5b.ide-closed48"))
 def test_label_reaches_the_lowered_program(case):
     """``metadata={"kernel": label}`` lands on the Mosaic custom call as
     ``kernel_metadata``; the benchmark's pattern finds it there."""
@@ -260,3 +280,34 @@ def test_routed_experts_keep_the_names_their_metrics_read():
             lowering_platforms=("tpu",)).as_text(debug_info=True)
     assert text.count("ragged_dot") >= 3
     assert "moe_experts" in text
+
+
+# -- the counters the benchmark's readers name ----------------------------------
+
+MIXED_CELL_COUNTERS = ("kv_full_bytes_attended", "kv_window_bytes_attended",
+                       "kv_bytes_held_steps", "context_token_steps")
+
+
+@pytest.mark.parametrize("counter", MIXED_CELL_COUNTERS)
+def test_the_counters_of_the_groups_of_layers_are_run_counters(counter):
+    """``paged_full_attention_roofline.serve``, ``paged_window_attention_
+    roofline.serve`` and ``kv_bytes_per_context_token.serve`` read these by
+    name from ``ServingFrontend.counter_deltas()``; a rename would leave
+    them silent."""
+    import glob
+    import json
+    import os
+
+    from apex_tpu.serving.scheduler import _RUN_COUNTERS
+
+    assert counter in _RUN_COUNTERS
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    named = set()
+    for path in glob.glob(os.path.join(root, "benchmark", "layer_metrics",
+                                       "*.serve.json")):
+        with open(path, encoding="utf-8") as f:
+            args = json.load(f).get("args", {})
+        named |= {v for k, v in args.items()
+                  if k in ("bytes_counter", "steps_counter", "numerator",
+                           "denominator")}
+    assert counter in named and named <= set(_RUN_COUNTERS)
