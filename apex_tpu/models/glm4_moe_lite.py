@@ -251,21 +251,13 @@ def _entry(c_kv, k_rope, stored: int):
     return _pad_last(jnp.concatenate([c_kv, k_rope], -1), stored)[:, :, None]
 
 
-#: q and k tile of a long prefill. The flash kernel's default 128 x 128 ran
-#: a 16k prompt at head width 256 at 8.6 % of the chip's peak (163 ms a
-#: layer, 84 % of the admission: PERF.md, PR 30); 1024 x 1024 overflows
-#: its VMEM stack. Chunks that are no multiple keep the default.
-_PREFILL_BLOCK = 512
-
-
 def _flash(q, k, v, scale):
     """Causal flash attention where the value width differs from the key
     width: the kernel takes one head width, so ``v`` rides zero-padded to
     the key width and the padding is cut from the output."""
     vd = v.shape[-1]
-    block = _PREFILL_BLOCK if q.shape[2] % _PREFILL_BLOCK == 0 else None
     out = flash_attention(q, k, _pad_last(v, k.shape[-1]), causal=True,
-                          scale=scale, block_q=block, block_k=block)
+                          scale=scale)
     return out[..., :vd]
 
 

@@ -51,7 +51,7 @@ from apex_tpu.models.generation import (advance_cache, cached_attention,
                                         paged_layer_tables,
                                         update_layer_cache,
                                         update_paged_layer_cache)
-from apex_tpu.models.glm4_moe_lite import _PREFILL_BLOCK, Embedding
+from apex_tpu.models.glm4_moe_lite import Embedding
 from apex_tpu.normalization import FusedRMSNorm
 from apex_tpu.ops import flash_attention
 from apex_tpu.ops.paged_attention import paged_attention
@@ -160,14 +160,6 @@ def _rotate(x, cos, sin):
         x.astype(jnp.float32), cos, sin).astype(x.dtype)
 
 
-def _flash(q, k, v, window):
-    # a long prefill's q and k tile, as GLM's (PERF.md, PR 30); chunks that
-    # are no multiple keep the kernel's default
-    block = _PREFILL_BLOCK if q.shape[2] % _PREFILL_BLOCK == 0 else None
-    return flash_attention(q, k, v, causal=True, window=window,
-                           block_q=block, block_k=block)
-
-
 class MellumAttention(nn.Module):
     """GQA with the layer's own ``window`` (``None``: full). ``rope`` is
     the layer type's ``(cos, sin)``; ``cache`` a per-layer view
@@ -189,7 +181,7 @@ class MellumAttention(nn.Module):
         k = _rotate(qkv[:, :, h:h + kv], *rope).transpose(0, 2, 1, 3)
         v = qkv[:, :, h + kv:].transpose(0, 2, 1, 3)
         if cache is None:
-            ctx = _flash(q, k, v, self.window)
+            ctx = flash_attention(q, k, v, causal=True, window=self.window)
         elif is_paged(cache):
             # the table and length are the layer's own group's: the block
             # table, or the slots' rings seen from the band's first page
@@ -200,8 +192,8 @@ class MellumAttention(nn.Module):
         else:
             prefill = is_static_prefill(cache, s)
             cache = update_layer_cache(cache, k, v)
-            ctx = _flash(q, k, v, self.window) if prefill \
-                else cached_attention(q, cache, window=self.window)
+            ctx = flash_attention(q, k, v, causal=True, window=self.window) \
+                if prefill else cached_attention(q, cache, window=self.window)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, h * d)
         out = Linear(e, h * d, pd, name="o_proj")(ctx.astype(x.dtype))
         return out if cache is None else (out, cache)

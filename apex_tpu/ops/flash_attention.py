@@ -44,36 +44,81 @@ _INTERPRET = _dispatch.interpret
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def _block_sizes(sq: int, sk: int, block_q: Optional[int],
-                 block_k: Optional[int]):
-    bq = block_q or min(128, _dispatch.round_up(sq, 8))
-    bk = block_k or min(128, _dispatch.round_up(sk, 128))
-    return bq, bk
-
-
-#: keep a sublane-aligned head dim (64 for BERT/GPT-2 heads) unpadded
-#: instead of rounding it up to 128 lanes. It participates in traced shapes
-#: and jit caches are not keyed on it, so it is a constant, not a switch:
-#: the tests that try the other layout set it and call
-#: ``jax.clear_caches()``. Which layout is faster on the chip is not
-#: measured yet (ROADMAP Speed item 2).
-_TIGHT_HEADDIM = False
-
-
 def _head_pad(d: int) -> int:
-    """Padded head-dim for the kernel blocks.
-
-    Default: round up to a 128-lane multiple — always legal. With
-    ``_TIGHT_HEADDIM`` a sublane-aligned d is kept as-is: the block's minor
-    dim then equals the full array dim, which Mosaic's (8, 128)-or-full-dim
-    rule permits, and the QK^T/PV contractions stop spending half their MXU
-    work on zero padding.
-    """
-    if d % 128 == 0:
-        return d
-    if _TIGHT_HEADDIM and d % 8 == 0:
-        return d
+    """The head width the kernels' blocks hold: ``d`` rounded up to 128
+    lanes. A 64-wide head left unpadded (legal: the block's minor dimension
+    then equals the array's) made every kernel slower on the chip, 255 /
+    182 / 261 us a call against 249 / 162 / 225 at b8 h16 s512 and 57.3
+    against 50.9 at GPT-2's 768-token admission, for ~2 ms less ``pad`` and
+    ``slice`` traffic around a training step's 72 calls: a tie end to end
+    (PERF.md section 6, PR 36). Padded is the layout every head width
+    compiles in."""
     return _dispatch.round_up(d, 128)
+
+
+#: the longest tile side. One (512, 512) tile a grid step took the three
+#: kernels at b8 h16 s512 d64 from 1216 / 822 / 1142 us a call on
+#: (128, 128) to 249 / 162 / 225 (PERF.md section 6, PR 36), and
+#: ``flash_fwd`` at head width 256 from 8.6 % to 49 % of the chip's peak
+#: (PR 30); (1024, 1024) at that width overflows Mosaic's stack.
+_MAX_BLOCK = 512
+
+#: what a tile may take of Mosaic's 16 MiB scoped VMEM stack; the rest is
+#: the compiler's (iotas, masks, the relayouts of a transposed product)
+_VMEM_BUDGET = 12 * 2 ** 20
+
+
+def _vmem_bytes(bq: int, bk: int, d_pad: int, itemsize: int,
+                bias: bool) -> int:
+    """VMEM one grid step of the widest of the three kernels (``dkv``) is
+    budgeted: the score, ``p``, ``dp`` and ``ds`` tiles in float32 and the
+    two copies the products take in the input dtype; q, do, k, v and the
+    two outputs double-buffered; the two float32 accumulators; the
+    ``(bq, 1)`` blocks of ``lse`` and ``delta`` (one lane of 128 each,
+    double-buffered); a float32 ``(bq, bk)`` bias block when there is one.
+    An upper bound: the compiler reuses tiles whose lives do not overlap."""
+    tile, rows = bq * bk, max(bq, bk) * d_pad
+    total = tile * (4 * 4 + 2 * itemsize)
+    total += 2 * itemsize * 2 * (bq + bk) * d_pad    # q, do, k, v
+    total += 2 * itemsize * 2 * rows + 4 * 2 * rows  # outputs, accumulators
+    total += 2 * 2 * bq * 128 * 4                    # lse, delta
+    if bias:
+        total += 2 * tile * 4
+    return total
+
+
+def _tile_options(n: int, align: int):
+    """Tile lengths for a sequence of ``n``, the one to prefer first: the
+    whole sequence where ``_MAX_BLOCK`` holds it, then the multiples of 128
+    that divide its 128-rounded length, so no choice pads further than
+    tiles of 128 did."""
+    whole = _dispatch.round_up(n, align)
+    n128 = _dispatch.round_up(n, 128)
+    opts = [t for t in range(_MAX_BLOCK, 0, -128) if n128 % t == 0]
+    if whole <= _MAX_BLOCK:
+        opts = [whole] + [t for t in opts if t < whole]
+    return opts
+
+
+def _block_sizes(sq: int, sk: int, block_q: Optional[int] = None,
+                 block_k: Optional[int] = None, *, d: int,
+                 itemsize: int = 2, bias: bool = False):
+    """The ``(bq, bk)`` tile of all three kernels, from the call's static
+    shapes alone: the largest tile up to ``_MAX_BLOCK`` a side that divides
+    the padded lengths and whose ``_vmem_bytes`` stay inside
+    ``_VMEM_BUDGET``, giving up q rows before k columns ((256, 512) beat
+    (512, 256) in every kernel). A grid step costs 0.5-0.6 us whatever its
+    tile, so a causal or banded call wants the large tile too, dead corner
+    included. ``block_q`` / ``block_k`` are for callers that tile on
+    purpose (the ring, the tests) and are taken as given."""
+    q_opts = [block_q] if block_q else _tile_options(sq, 8)
+    k_opts = [block_k] if block_k else _tile_options(sk, 128)
+    d_pad = _head_pad(d)
+    for bk in k_opts:
+        for bq in q_opts:
+            if _vmem_bytes(bq, bk, d_pad, itemsize, bias) <= _VMEM_BUDGET:
+                return bq, bk
+    return q_opts[-1], k_opts[-1]   # nothing fits: the compiler will say
 
 
 def _pad_to(x, axis, mult):
@@ -107,7 +152,7 @@ def _mask_block(s, *, b_q, b_k, bq, bk, q_len, kv_len, causal, causal_offset,
         # [r + offset - (window-1), r + offset]
         mask &= cols >= (rows + causal_offset - (window - 1))
     if q_seg is not None:
-        mask &= q_seg.reshape(-1, 1) == kv_seg.reshape(1, -1)
+        mask &= q_seg == kv_seg     # a (bq, 1) column against a (1, bk) row
     del q_len  # padded q rows produce garbage that the caller slices away
     return jnp.where(mask, s, DEFAULT_MASK_VALUE), mask
 
@@ -282,7 +327,8 @@ def _fa_fwd(q, k, v, bias, q_seg, kv_seg, seed, scale, causal, dropout_rate,
     batch, heads, q_len, d = q.shape
     kv_len = k.shape[2]
     rep = _gqa_rep(heads, k.shape[1])
-    bq, bk = _block_sizes(q_len, kv_len, block_q, block_k)
+    bq, bk = _block_sizes(q_len, kv_len, block_q, block_k, d=d,
+                          itemsize=q.dtype.itemsize, bias=bias is not None)
     d_pad = _head_pad(d)
 
     qp = _pad_to(_pad_to(q, 2, bq), 3, d_pad)
@@ -332,14 +378,16 @@ def _fa_fwd(q, k, v, bias, q_seg, kv_seg, seed, scale, causal, dropout_rate,
         # pad kv segments with -1 so padded keys never match a real segment
         if ksp.shape[1] != kv_seg.shape[1]:
             ksp = ksp.at[:, kv_seg.shape[1]:].set(-1)
-        # rank-3 with singleton middle dim so block last-two-dims = (1, bq)
-        # satisfies Mosaic's (8, 128)-or-full-dim rule
-        in_specs.append(pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, 0, i),
+        # q's ids ride as a (bq, 1) column and k's as a (1, bk) row (a
+        # singleton dim each, so the blocks' last two dims satisfy Mosaic's
+        # (8, 128)-or-full-dim rule): the compare broadcasts both and no
+        # grid step turns a row of lanes into a column (PERF.md, PR 36)
+        in_specs.append(pl.BlockSpec((1, bq, 1), lambda b, h, i, j: (b, i, 0),
                                      memory_space=pltpu.VMEM))
         in_specs.append(pl.BlockSpec(
             (1, 1, bk), lambda b, h, i, j: (b, 0, jmap(i, j)),
             memory_space=pltpu.VMEM))
-        args.extend([qsp[:, None], ksp[:, None]])
+        args.extend([qsp[:, :, None], ksp[:, None]])
     if dropout_rate > 0.0:
         in_specs.append(pl.BlockSpec((1, 3), lambda b, h, i, j: (0, 0),
                                      memory_space=pltpu.SMEM))
@@ -529,7 +577,8 @@ def _fa_bwd_impl(q, k, v, bias, q_seg, kv_seg, seed, scale, causal,
     kv_len = k.shape[2]
     kv_heads = k.shape[1]
     rep = _gqa_rep(heads, kv_heads)
-    bq, bk = _block_sizes(q_len, kv_len, block_q, block_k)
+    bq, bk = _block_sizes(q_len, kv_len, block_q, block_k, d=d,
+                          itemsize=q.dtype.itemsize, bias=bias is not None)
     d_pad = _head_pad(d)
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
@@ -581,7 +630,7 @@ def _fa_bwd_impl(q, k, v, bias, q_seg, kv_seg, seed, scale, causal,
         ksp = _pad_to(kv_seg.astype(jnp.int32), 1, bk)
         if ksp.shape[1] != kv_seg.shape[1]:
             ksp = ksp.at[:, kv_seg.shape[1]:].set(-1)
-        base_args.extend([qsp[:, None], ksp[:, None]])
+        base_args.extend([qsp[:, :, None], ksp[:, None]])
     if dropout_rate > 0.0:
         base_args.append(seed)
     if dyn_offset is not None:
@@ -612,7 +661,7 @@ def _fa_bwd_impl(q, k, v, bias, q_seg, kv_seg, seed, scale, causal,
                 lambda *g: (g[0] % bb, g[1] % bh, idx_q(g), idx_k(g)),
                 memory_space=pltpu.VMEM))
         if q_seg is not None:
-            specs.append(pl.BlockSpec((1, 1, bq), lambda *g: (g[0], 0, idx_q(g)),
+            specs.append(pl.BlockSpec((1, bq, 1), lambda *g: (g[0], idx_q(g), 0),
                                       memory_space=pltpu.VMEM))
             specs.append(pl.BlockSpec((1, 1, bk), lambda *g: (g[0], 0, idx_k(g)),
                                       memory_space=pltpu.VMEM))

@@ -436,17 +436,43 @@ def test_two_groups_of_pages_compile_for_the_chip(program, mesh):
             "3,2,1,0"}
 
 
-def test_tight_headdim_compiles(mesh):
-    """Compile half of the tight-head-dim gate: the unpadded d=64 layout
-    must stay legal under Mosaic (runtime parity is the on-chip test)."""
+#: what ``ops/flash_attention.py``'s rule picks at the training cell's shape
+#: (PERF.md section 6, PR 36): one (512, 512) tile a head, so a grid step a
+#: head; the 64-wide head held 128 lanes wide; q's segment ids a column.
+_HEAD = (1, 1, 512, 128)
+_STAT = (1, 1, 512, 1)
+_SEGS = [(1, 512, 1), (1, 1, 512)]
+_BERT_CELL_FLASH = {
+    "flash_fwd": [_HEAD] * 3 + _SEGS + [_HEAD, _STAT],
+    "flash_bwd_dq": [_HEAD] * 4 + [_STAT] * 2 + _SEGS + [_HEAD],
+    "flash_bwd_dkv": [_HEAD] * 4 + [_STAT] * 2 + _SEGS + [_HEAD] * 2,
+}
+
+
+@pytest.mark.parametrize("label", sorted(_BERT_CELL_FLASH))
+def test_flash_bert_cell_compiles_to_the_rules_tile(label, mesh, bert_cell):
+    """``flash_bert_cell_fwd_bwd``: each of the three labelled kernels is
+    there once, on a grid of one step a head with the blocks above."""
+    calls = [c for c in bert_cell["calls"] if c[0] == label]
+    assert len(calls) == 1, [c[0] for c in bert_cell["calls"]]
+    _, grid, blocks = calls[0]
+    assert grid == (8, 16, 1, 1)
+    assert blocks == _BERT_CELL_FLASH[label]
+
+
+def test_flash_bert_cell_is_three_mosaic_calls(bert_cell):
+    """Nothing but the three kernels is a Mosaic call: the benchmark reads
+    every custom call inside the module ``attention`` as flash's."""
+    assert bert_cell["text"].count(
+        'custom_call_target="tpu_custom_call"') == 3
+    assert sorted(c[0] for c in bert_cell["calls"]) == sorted(
+        _BERT_CELL_FLASH)
+
+
+@pytest.fixture(scope="module")
+def bert_cell(mesh):
     import tpu_aot
 
-    fa_impl, tcases = tpu_aot.tight_headdim_cases()
-    orig = fa_impl._TIGHT_HEADDIM
-    fa_impl._TIGHT_HEADDIM = True
-    try:
-        for name, fn, structs in tcases:
-            r = tpu_aot.case_result(mesh, fn, structs)
-            assert r["ok"] and r["tpu_custom_call_sites"] >= 1, (name, r)
-    finally:
-        fa_impl._TIGHT_HEADDIM = orig
+    _, fn, structs = tpu_aot.bert_cell_flash_case()
+    text = tpu_aot.compile_replicated(mesh, fn, structs).as_text()
+    return {"text": text, "calls": tpu_aot.mosaic_calls(text)}

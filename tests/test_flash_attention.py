@@ -11,7 +11,10 @@ import numpy as np
 import pytest
 
 from apex_tpu.ops.flash_attention import (
+    _VMEM_BUDGET,
     _block_sizes,
+    _head_pad,
+    _vmem_bytes,
     flash_attention,
     mha_reference,
 )
@@ -83,20 +86,100 @@ def test_block_size_invariance(rng):
     np.testing.assert_allclose(a, b_, atol=1e-5, rtol=1e-5)
 
 
-# (sq = sk, block asked for) -> tiles, for every flash call the four
-# benchmark cells make: the training step, ``gpt2-large``'s six prompt
-# buckets, ``glm-4.7-flash``'s five with ``_PREFILL_BLOCK``. A change of
-# any tile is a change of the traced program: a ``perf_opt`` PR's, measured.
-@pytest.mark.parametrize("s,block,tiles", [
-    (512, None, (128, 128)),
-    (96, None, (96, 128)), (160, None, (128, 128)), (256, None, (128, 128)),
-    (384, None, (128, 128)), (768, None, (128, 128)),
-    (1024, 512, (512, 512)), (2048, 512, (512, 512)),
-    (4096, 512, (512, 512)), (8192, 512, (512, 512)),
-    (16384, 512, (512, 512)),
-])
-def test_tiles_at_the_benchmark_cells_shapes(s, block, tiles):
-    assert _block_sizes(s, s, block, block) == tiles
+# (sq, sk, head width, itemsize, bias) -> the tile the rule picks, for every
+# flash call the benchmark's cells make and the rule's edges. A change of
+# any tile is a change of the traced program: a ``perf_opt`` PR's, measured
+# (PERF.md section 6, PR 36 has the sweep these came from).
+_RULE_CASES = {
+    "bert_b8h16_s512_d64_segment_ids": (512, 512, 64, 2, False, (512, 512)),
+    "gpt2_prompt_96": (96, 96, 64, 2, False, (96, 128)),
+    "gpt2_prompt_160": (160, 160, 64, 2, False, (160, 256)),
+    "gpt2_prompt_256": (256, 256, 64, 2, False, (256, 256)),
+    "gpt2_prompt_384": (384, 384, 64, 2, False, (384, 384)),
+    "gpt2_prompt_512": (512, 512, 64, 2, False, (512, 512)),
+    "gpt2_prompt_768": (768, 768, 64, 2, False, (384, 384)),
+    **{f"glm_prompt_{n}_d256": (n, n, 256, 2, False, (512, 512))
+       for n in (1024, 2048, 4096, 8192, 16384)},
+    **{f"mellum_prompt_{n}_d128_window1024": (n, n, 128, 2, False, (512, 512))
+       for n in (512, 4096, 8192, 16384)},
+    # edges
+    "length_512_does_not_divide_640": (640, 640, 64, 2, False, (128, 128)),
+    "length_512_does_not_divide_1152": (1152, 1152, 64, 2, False, (384, 384)),
+    "length_512_does_not_divide_1280": (1280, 1280, 128, 2, False, (256, 256)),
+    "ragged_100": (100, 100, 64, 2, False, (104, 128)),
+    "ragged_200_pads_to_256_as_before": (200, 200, 64, 2, False, (200, 256)),
+    "cross_attention_40_over_88": (40, 88, 32, 4, False, (40, 128)),
+    "cross_attention_long_keys": (64, 4096, 64, 2, False, (64, 512)),
+    "head_width_40_is_no_multiple_of_8": (512, 512, 40, 2, False, (512, 512)),
+    "bias_d64": (512, 512, 64, 2, True, (512, 512)),
+    "bias_d256_fills_the_budget": (2048, 2048, 256, 2, True, (512, 512)),
+    "bias_d256_float32_gives_up_q_rows": (2048, 2048, 256, 4, True, (256, 512)),
+    "backward_d256": (4096, 4096, 256, 2, False, (512, 512)),
+    "float32_d128": (1024, 1024, 128, 4, False, (512, 512)),
+    "head_width_512_gives_up_q_rows": (1024, 1024, 512, 2, False, (256, 512)),
+    "head_width_2048_gives_up_both": (1024, 1024, 2048, 2, False, (128, 128)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RULE_CASES))
+def test_tile_rule_at_the_cells_shapes_and_its_edges(case):
+    sq, sk, d, itemsize, bias, want = _RULE_CASES[case]
+    bq, bk = _block_sizes(sq, sk, d=d, itemsize=itemsize, bias=bias)
+    assert (bq, bk) == want
+    assert bq % 8 == 0 and bk % 128 == 0
+    # pads no further than tiles of 128 did
+    was_q = min(128, -(-sq // 8) * 8)
+    assert -(-sq // bq) * bq <= -(-sq // was_q) * was_q
+    assert -(-sk // bk) * bk <= -(-sk // 128) * 128
+    d_pad = _head_pad(d)
+    assert d_pad % 128 == 0 and 0 <= d_pad - d < 128
+    if case != "head_width_2048_gives_up_both":   # nothing fits: smallest
+        assert _vmem_bytes(bq, bk, d_pad, itemsize, bias) <= _VMEM_BUDGET
+
+
+def test_tile_rule_takes_a_callers_tile_as_given():
+    assert _block_sizes(2048, 2048, 64, 256, d=64) == (64, 256)
+    assert _block_sizes(2048, 2048, None, 256, d=64) == (512, 256)
+    assert _block_sizes(2048, 2048, 1024, None, d=256) == (1024, 128)
+
+
+def _grads(fn, q, k, v):
+    return jax.grad(lambda *a: (fn(*a).astype(jnp.float32) ** 2).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("case", ["segment_ids_512", "causal_384",
+                                  "window_200_of_768", "dropout_512"])
+def test_default_tile_parity_fwd_dq_dk_dv(rng, case):
+    """Forward, dq, dk and dv at the tile the RULE picks (one 512- or
+    384-long tile a side where the old default cut 128 x 128) against the
+    dense reference; dropout, whose mask the reference cannot draw, against
+    the same call on 128 x 128 tiles (the mask is a hash of positions, so
+    it must not know the tile)."""
+    s, tile = {"segment_ids_512": (512, 512), "causal_384": (384, 384),
+               "window_200_of_768": (768, 384),
+               "dropout_512": (512, 512)}[case]
+    q, k, v = _qkv(rng, 1, 2, s, s, 64, jnp.float32)
+    kw = {}
+    if case == "segment_ids_512":
+        cut = jnp.asarray(rng.integers(s // 2, s, (1, 1)))
+        kw["segment_ids"] = (jnp.arange(s)[None] >= cut).astype(jnp.int32)
+    elif case == "causal_384":
+        kw["causal"] = True
+    elif case == "window_200_of_768":
+        kw.update(causal=True, window=200)
+    else:
+        kw.update(dropout_rate=0.2, dropout_seed=11)
+    assert _block_sizes(s, s, d=64, itemsize=4) == (tile, tile)
+    fused = lambda *a: flash_attention(*a, **kw)
+    if case == "dropout_512":
+        ref = lambda *a: flash_attention(*a, block_q=128, block_k=128, **kw)
+    else:
+        ref = lambda *a: mha_reference(*a, **kw)
+    np.testing.assert_allclose(fused(q, k, v), ref(q, k, v),
+                               atol=3e-5, rtol=3e-5)
+    for a, b_ in zip(_grads(fused, q, k, v), _grads(ref, q, k, v)):
+        np.testing.assert_allclose(a, b_, atol=2e-4, rtol=5e-4)
 
 
 def _np_keep(bh, s1, s2, rate, seed):

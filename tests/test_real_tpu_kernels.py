@@ -281,46 +281,42 @@ def test_group_norm_backward_kernel_path(tpu, rng):
                                    rtol=3e-3, atol=3e-3)
 
 
-def test_flash_attention_tight_head_dim(tpu, rng, monkeypatch):
-    """Round-3 perf lever: tight head-dim keeps head_dim 64 unpadded (block
-    minor dim = full array dim) instead of zero-padding to 128 — halving
-    the QK^T/PV MXU work at BERT/GPT head shapes. This proves the layout
-    compiles under Mosaic and matches the padded path in BOTH forward and
-    backward."""
+@pytest.mark.parametrize("masking", ["segment_ids", "causal"])
+def test_flash_attention_head64_default_tile(tpu, rng, masking):
+    """What the rule picks at the training cell's shape (PR 36): one
+    (512, 512) tile a head, the 64-wide head padded to 128 lanes, q's
+    segment ids as a column. Forward and dq, dk, dv against the same call
+    on the 128 x 128 tiles every earlier chip run used."""
     from apex_tpu.ops import flash_attention
 
     b, h, d = 2, 8, 64
     q = jnp.asarray(rng.standard_normal((b, h, SEQ, d)), jnp.bfloat16)
     k = jnp.asarray(rng.standard_normal((b, h, SEQ, d)), jnp.bfloat16)
     v = jnp.asarray(rng.standard_normal((b, h, SEQ, d)), jnp.bfloat16)
+    if masking == "segment_ids":
+        cut = rng.integers(SEQ // 2, SEQ, (b, 1))
+        kw = {"segment_ids": jnp.asarray(
+            (np.arange(SEQ)[None] >= cut).astype(np.int32))}
+    else:
+        kw = {"causal": True}
 
-    def loss(q):
-        return jnp.sum(flash_attention(q, k, v, causal=True
-                                       ).astype(jnp.float32) ** 2)
+    def run(**tile):
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(q, k, v, **kw, **tile
+                                           ).astype(jnp.float32) ** 2)
+        out = jax.jit(functools.partial(flash_attention, **kw, **tile))(
+            q, k, v)
+        return out, jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
-    ref = jax.jit(functools.partial(flash_attention, causal=True))(q, k, v)
-    g_ref = jax.jit(jax.grad(loss))(q)
-
-    import importlib
-
-    # NB: `import apex_tpu.ops.flash_attention` resolves to the FUNCTION
-    # (ops/__init__ re-export shadows the submodule attribute)
-    fa_impl = importlib.import_module("apex_tpu.ops.flash_attention")
-
-    monkeypatch.setattr(fa_impl, "_TIGHT_HEADDIM", True)
-    try:
-        jax.clear_caches()
-        out = jax.jit(functools.partial(flash_attention, causal=True))(q, k, v)
-        g = jax.jit(jax.grad(loss))(q)
-    finally:
-        monkeypatch.setattr(fa_impl, "_TIGHT_HEADDIM", False)
-        jax.clear_caches()
+    out, grads = run()
+    ref, g_ref = run(block_q=128, block_k=128)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=2e-2, atol=2e-2)
-    np.testing.assert_allclose(np.asarray(g, np.float32),
-                               np.asarray(g_ref, np.float32),
-                               rtol=5e-2, atol=5e-2)
+    for g, r in zip(grads, g_ref):
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(r, np.float32),
+                                   rtol=5e-2, atol=5e-2)
 
 
 def test_moe_dense_dispatch_compiles(tpu, rng):
