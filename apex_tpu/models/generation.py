@@ -86,11 +86,12 @@ def init_cache(config, batch: int, max_len: int, *, dtype=None):
     # what a layer stores per token is the pool's statement, shared with
     # the paged cache this buffer is scattered into (imported here: the
     # serving package imports this module)
-    from apex_tpu.serving.kv_pool import layout_of
+    from apex_tpu.serving.kv_pool import layout_of, state_layers
 
     layout = layout_of(config)
     kv_local = divide(layout.heads, config.tensor_parallel_size)
     dt = dtype if dtype is not None else resolve_compute_dtype(config.dtype)
+    states = state_layers(config)
     t_buf = max_len
     if getattr(config, "rolling_cache", False):
         if not getattr(config, "sliding_window", None):
@@ -99,14 +100,20 @@ def init_cache(config, batch: int, max_len: int, *, dtype=None):
         # silently drop reachable positions once decoding passes its size
         t_buf = config.sliding_window
     shape = (batch, kv_local, t_buf, layout.stored)
-    layers = [{name: jnp.zeros(shape, dt) for name in layout.tensors}
-              for _ in range(config.num_layers)]
+    # a layer that keeps a state and no token (kv_pool.layer_groups) holds
+    # its state's tensors, a row a sequence, whatever ``max_len`` is
+    layers = [{t.name: jnp.zeros((batch,) + tuple(t.shape), t.dtype)
+               for t in states[i]} if i in states
+              else {name: jnp.zeros(shape, dt) for name in layout.tensors}
+              for i in range(config.num_layers)]
     return {"layers": layers, "len": 0}
 
 
 def cache_max_len(cache) -> int:
-    lc = cache["layers"][0]
-    return (lc["k"] if "k" in lc else lc["latent"]).shape[2]
+    for lc in cache["layers"]:
+        if "k" in lc or "latent" in lc:
+            return (lc["k"] if "k" in lc else lc["latent"]).shape[2]
+    raise ValueError("no layer of this cache stores tokens")
 
 
 def check_chunk_bounds(cache, s: int, max_position_embeddings: int, *,
@@ -153,6 +160,15 @@ def layer_cache(cache, i: int, table=None):
     elif is_paged(cache):
         lc["block_tables"] = cache["block_tables"]
     return lc
+
+
+def layer_state(cache, i: int):
+    """Per-layer view for a block that keeps a STATE and no token
+    (``serving/kv_pool.layer_groups``: a linear-attention layer): its
+    tensors, a row a sequence (a slot, in the engine's cache), and neither
+    table nor lengths: what it keeps does not depend on where a sequence
+    stands."""
+    return dict(cache["layers"][i])
 
 
 def paged_layer_tables(cache, config, s: int):

@@ -75,6 +75,7 @@ def forced_mosaic():
 KERNEL_LABELS = (
     "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_attention",
     "paged_window_attention", "paged_latent_attention", "paged_write",
+    "gated_delta_step",
     "layer_norm_fwd", "layer_norm_bwd", "xentropy_fwd", "xentropy_bwd",
     "l2norm", "lamb_phase1", "lamb_phase2", "adam", "sgd", "novograd",
     "scale", "group_norm_fwd", "group_norm_bwd", "scaled_softmax_fwd",

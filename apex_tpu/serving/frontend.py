@@ -80,7 +80,7 @@ from apex_tpu.ops.paged_attention import pages_fetched
 from apex_tpu.serving import kv_pool
 from apex_tpu.serving.policy import PriorityDeadlinePolicy
 from apex_tpu.serving.scheduler import (_RUN_COUNTERS, _RUN_HISTOGRAMS,
-                                        ROUTING_STATS, Request,
+                                        SHARE_ROUTING_STATS, Request,
                                         _bucket_match_pages, prompt_bucket)
 from apex_tpu.utils import metrics
 
@@ -467,7 +467,12 @@ class ServingFrontend:
         # feeds serving.kv_bytes_fetched
         tp = int(getattr(engine, "tp_world", 1))
         self._kv_groups = []
+        # a state group holds no page: a fixed number of bytes a slot, all
+        # of them read and written by every decode step of the slot
+        self._state_bytes_per_slot = kv_pool.state_bytes(engine.cfg)
         for g in engine.groups:
+            if g.state:
+                continue
             pool = kv_pool.a_pool(engine.cache, g.layers[0])
             ring = (kv_pool.ring_pages(g.window, engine.page_size)
                     if g.ring else None)
@@ -962,6 +967,11 @@ class ServingFrontend:
             held += kv.page_bytes * (
                 kv.ring * len(decoding) if kv.ring
                 else sum(e.n_private for e in decoding))
+        # the state groups' bytes: held like a ring's pages, for ever, and
+        # moved whole, in and out, by every step
+        state = self._state_bytes_per_slot * len(decoding)
+        held += state
+        self._C["state_bytes_moved"].inc(2 * state * eng.sync_every)
         self._C["kv_bytes_attended"].inc(full + banded)
         self._C["kv_full_bytes_attended"].inc(full)
         self._C["kv_window_bytes_attended"].inc(banded)
@@ -1058,7 +1068,9 @@ class ServingFrontend:
             self._per_run["pump.dispatch_ready_ms"].append(chunk_ms)
         if not isinstance(chunk.routed, tuple):
             # the chunk's own account of its routing, ready with its tokens
-            routed = dict(zip(ROUTING_STATS,
+            # (three numbers a step, or the four of a layer that holds a
+            # share of its experts: zip stops at the vector's end)
+            routed = dict(zip(SHARE_ROUTING_STATS,
                               np.asarray(chunk.routed).sum(axis=0).tolist()))
             for name, n in routed.items():
                 self._C[name].inc(n)
@@ -1918,11 +1930,18 @@ class ServingFrontend:
         # the groups of layers the pool holds (kv_pool.layer_groups):
         # which layers, how far back they read, and the pages the group's
         # pool holds for its slots (a ring group: R a slot, for ever; the
-        # block table's group: what the free stack hands out)
+        # block table's group: what the free stack hands out; a state
+        # group: no page, its tensors' bytes a slot)
+        paged = {kv.group: kv for kv in self._kv_groups}
         stats["kv_groups"] = [
-            {"layers": list(kv.group.layers), "window": kv.group.window,
-             "ring_pages_per_slot": kv.ring,
-             "pages_held": (kv.ring * eng.num_slots if kv.ring
+            {"layers": list(g.layers), "window": None,
+             "ring_pages_per_slot": None, "pages_held": 0,
+             "state": [t.name for t in g.state],
+             "state_bytes_per_slot": kv_pool.state_bytes(eng.cfg, group=g)}
+            if g.state else
+            {"layers": list(g.layers), "window": g.window,
+             "ring_pages_per_slot": paged[g].ring,
+             "pages_held": (paged[g].ring * eng.num_slots if paged[g].ring
                             else kv_pool.num_pages_of(eng.cache) - 1)}
-            for kv in self._kv_groups]
+            for g in eng.groups]
         return stats

@@ -83,6 +83,28 @@ overwritten ``R`` pages later, so the engine refuses each with a ring (or
 a windowed) group, by name; so do quantized pages and a tensor-parallel
 mesh (``ROADMAP.md``).
 
+A THIRD KIND OF GROUP STORES NO TOKEN AT ALL: a STATE group
+(``models/qwen3_next.py``: three ``linear_attention`` layers to one
+``full_attention``). A config states ``layer_states`` (one entry a layer:
+``None``, or the tensors the layer keeps per SEQUENCE, each a name, a shape
+and a dtype), and such a layer's dict holds exactly those tensors, ``(num_
+slots,) + shape`` (a gated delta-rule layer: its float32 recurrent state and
+the last inputs of its short convolution), whatever the sequences' lengths.
+A state group is sized by ``num_slots`` alone (:func:`state_bytes`, beside
+:func:`page_bytes`), and is no part of the free stack, the block table,
+:func:`alloc_slot` / :func:`release_slot`, :func:`defrag_map` or
+:func:`gather_pages`: slot ``b``'s row is the state of whatever request
+holds slot ``b``. An admission OVERWRITES the row whole with the state its
+prompt ends in (:func:`prefill_into_pages`), so retirement need not clear
+it; a decode step updates it in place (``ops.gated_delta_step`` aliases it,
+and the chunk's scan carries it without a copy). What a state cannot do:
+be shared by prefix (the state at a prefix's end is not kept), be rolled
+back past a rejected draft block, be handed from chunk to chunk of a
+chunked prefill through the paged ``s > 1`` path, go to the host tier,
+shard over a tensor-parallel mesh, or stand beside quantized pages: the
+engine refuses each by name (:class:`StateGroupUnsupported`;
+``ROADMAP.md``).
+
 A POOL ROW IS 128 LANES WHERE IT CAN BE. A per-head pool whose head width
 divides 128 holds ``pack = 128 // width`` heads side by side in one row
 (:func:`heads_per_row`, the one place ``pack`` is decided: from the
@@ -193,6 +215,19 @@ class RingGroupUnsupported(ValueError):
             "(kv_pool.layer_groups), written over as the band moves on")
 
 
+class StateGroupUnsupported(ValueError):
+    """``state-group-unsupported``: a pool with a state group (layers that
+    keep one recurrent state a slot and no token) was asked for what a
+    state cannot give."""
+
+    def __init__(self, what: str, why: str = ""):
+        super().__init__(
+            f"state-group-unsupported: {what} does not compose with a "
+            "model whose linear-attention layers keep one recurrent state "
+            "a slot and no token (kv_pool.layer_groups)"
+            + (f": {why}" if why else ""))
+
+
 class LatentPoolUnsupported(ValueError):
     """``latent-pool-unsupported``: a pool of latent entries was asked to
     shard over a tensor-parallel mesh or to hold quantized pages."""
@@ -248,33 +283,65 @@ class LayerGroup:
     and whether the group is held as per-slot RINGS of pages (a windowed
     group beside others) or by the block table and the free stack (the
     full group; the one group of a model whose layers are all alike, with
-    a window or without)."""
+    a window or without). A STATE group stores no token: ``state`` names
+    the tensors its layers keep a slot (each with ``name``, ``shape``,
+    ``dtype``), and it has neither layout nor window."""
 
-    layout: CacheLayout
+    layout: Optional[CacheLayout]
     window: Optional[int]
     layers: Tuple[int, ...]
     ring: bool
+    state: Tuple = ()
 
 
 def layer_groups(config) -> Tuple[LayerGroup, ...]:
     """The groups of ``config``'s layers, in order of first appearance. A
     config states per-layer windows as ``layer_windows`` (one entry a
     layer, ``None`` = full); one without has ONE group, whose window is
-    its model-wide ``sliding_window`` if it has that."""
+    its model-wide ``sliding_window`` if it has that. A config states the
+    layers that keep a state and no token as ``layer_states`` (one entry a
+    layer, ``None`` = a layer of pages): they form STATE groups, one per
+    distinct statement."""
     windows = getattr(config, "layer_windows", None)
     if windows is None:
         windows = (getattr(config, "sliding_window", None),) \
             * config.num_layers
-    if len(windows) != config.num_layers:
-        raise ValueError(f"layer_windows has {len(windows)} entries for "
-                         f"{config.num_layers} layers")
+    states = getattr(config, "layer_states", None) \
+        or (None,) * config.num_layers
+    for name, per_layer in (("layer_windows", windows),
+                            ("layer_states", states)):
+        if len(per_layer) != config.num_layers:
+            raise ValueError(f"{name} has {len(per_layer)} entries for "
+                             f"{config.num_layers} layers")
     layout = layout_of(config)
-    by_window = {}
-    for i, w in enumerate(windows):
-        by_window.setdefault(w, []).append(i)
-    mixed = len(by_window) > 1
-    return tuple(LayerGroup(layout, w, tuple(ls), mixed and w is not None)
-                 for w, ls in by_window.items())
+    by_kind = {}
+    for i, (w, st) in enumerate(zip(windows, states)):
+        by_kind.setdefault((None, tuple(st)) if st else (w, ()), []
+                           ).append(i)
+    mixed = len({w for w, st in by_kind if not st}) > 1
+    return tuple(
+        LayerGroup(None, None, tuple(ls), False, st) if st
+        else LayerGroup(layout, w, tuple(ls), mixed and w is not None)
+        for (w, st), ls in by_kind.items())
+
+
+def state_layers(config) -> dict:
+    """``{layer index: the tensors it keeps a slot}`` over ``config``'s
+    state groups (empty for a model whose layers all store tokens)."""
+    return {i: g.state for g in layer_groups(config) if g.state
+            for i in g.layers}
+
+
+def state_bytes(config, num_slots: int = 1, *,
+                group: Optional[LayerGroup] = None) -> int:
+    """Bytes the state groups (or the one ``group``) hold for ``num_slots``
+    slots over all their layers, beside :func:`page_bytes`: sized by the
+    slots alone, whatever the contexts' lengths (0 for a model with no such
+    layer)."""
+    groups = layer_groups(config) if group is None else (group,)
+    return num_slots * sum(
+        len(g.layers) * int(np.prod(t.shape)) * jnp.dtype(t.dtype).itemsize
+        for g in groups for t in g.state)
 
 
 def ring_pages(window: int, page_size: int) -> int:
@@ -340,11 +407,17 @@ def _pool_shape(num_pages: int, heads: int, page_size: int, stored: int,
     return (num_pages, heads // pack, page_size, stored * pack)
 
 
-def a_pool(cache, layer: int = 0):
-    """One of a layer's pools (default: the first layer's). A group's
-    pools share one shape and dtype; groups differ in their page count
-    alone."""
-    layer = cache["layers"][layer]
+def a_layer_of_pages(cache) -> dict:
+    """The first layer dict that holds pages (a state group's hold none)."""
+    return next(lc for lc in cache["layers"] if pool_tensors(lc))
+
+
+def a_pool(cache, layer: Optional[int] = None):
+    """One of a layer's pools (default: the first layer's that has pages).
+    A group's pools share one shape and dtype; groups differ in their page
+    count alone."""
+    layer = a_layer_of_pages(cache) if layer is None \
+        else cache["layers"][layer]
     return layer[pool_key(pool_tensors(layer)[0])]
 
 
@@ -404,6 +477,8 @@ def _layer_specs(config, axis_name: str, kv_dtype) -> dict:
     if layout.latent:
         raise LatentPoolUnsupported(
             "tensor-parallel specs were asked of a latent pool")
+    if state_layers(config):
+        raise StateGroupUnsupported("a tensor-parallel mesh")
     kv = PartitionSpec(None, axis_name)
     layer = {pool_key(n): kv for n in layout.tensors}
     if kv_dtype is not None:
@@ -462,6 +537,12 @@ def init_paged_cache(config, num_slots: int, *, num_pages: int,
             quant is not None or mesh is not None
             or config.tensor_parallel_size != 1):
         raise RingGroupUnsupported(
+            f"kv_dtype={kv_dtype!r}" if quant is not None
+            else "a tensor-parallel mesh")
+    states = state_layers(config)
+    if states and (quant is not None or mesh is not None
+                   or config.tensor_parallel_size != 1):
+        raise StateGroupUnsupported(
             f"kv_dtype={kv_dtype!r}" if quant is not None
             else "a tensor-parallel mesh")
     kv_local = divide(layout.heads, config.tensor_parallel_size)
@@ -528,6 +609,11 @@ def init_paged_cache(config, num_slots: int, *, num_pages: int,
 
     def build():
         def layer_buf(i):
+            if i in states:
+                # a row a slot, for the engine's lifetime: no page, no
+                # table entry, nothing the free stack knows of
+                return {t.name: jnp.zeros((num_slots,) + tuple(t.shape),
+                                          t.dtype) for t in states[i]}
             lc = {pool_key(n): jnp.zeros(ring_shape.get(i, shape), dt)
                   for n in names}
             if quant is not None:
@@ -571,7 +657,9 @@ def observe_pool(cache, labels: Optional[dict] = None) -> dict:
     ``kv_pool.free_pages``, ``kv_pool.pages_total`` (usable, i.e. minus
     the null page), ``kv_pool.shared_pages_active`` (pages with
     ``page_ref > 0`` — currently shared by live readers), and
-    ``kv_pool.page_refs_total`` (sum of active refcounts). ``labels``
+    ``kv_pool.page_refs_total`` (sum of active refcounts); with a state
+    group also ``kv_pool.state_bytes`` (what its layers hold for all
+    slots). ``labels``
     distinguishes pools (the engine passes its ``engine`` label — two
     engines' pools must not clobber one gauge). HOST-side only: reads
     two small device arrays (a scalar and the per-page refcounts) — the
@@ -583,7 +671,14 @@ def observe_pool(cache, labels: Optional[dict] = None) -> dict:
         "kv_pool.pages_total": num_pages_of(cache) - 1,
         "kv_pool.shared_pages_active": int((refs > 0).sum()),
         "kv_pool.page_refs_total": int(refs.sum()),
+        # what the state groups' layers hold for all slots
+        "kv_pool.state_bytes": int(sum(
+            x.nbytes for lc in cache["layers"] if not pool_tensors(lc)
+            for x in lc.values())),
     }
+    if not vals["kv_pool.state_bytes"]:
+        # a model with no such layer publishes the four gauges it had
+        del vals["kv_pool.state_bytes"]
     for name, v in vals.items():
         metrics.gauge(name, labels=labels).set(v)
     return vals
@@ -850,7 +945,8 @@ def defrag_map(cache, extra_live=None, *, rings: Tuple[int, ...] = ()):
     names them).
 
     ``rings``: the layers of ring groups (static). Their pools are no part
-    of the block table's pages and stay as they are."""
+    of the block table's pages and stay as they are; so does a state
+    group's layer, which holds no page at all."""
     bt = cache["block_tables"]
     num_pages = num_pages_of(cache)
     max_pages = bt.shape[1]
@@ -877,7 +973,8 @@ def defrag_map(cache, extra_live=None, *, rings: Tuple[int, ...] = ()):
     # a page's scale moves with the page through the same permutation —
     # remapped quantized contents stay bit-identical to pre-defrag
     out["layers"] = [
-        lc if i in rings else {key: lc[key][old_of_new] for key in lc}
+        lc if i in rings or not pool_tensors(lc)
+        else {key: lc[key][old_of_new] for key in lc}
         for i, lc in enumerate(cache["layers"])]
     out["block_tables"] = jnp.where(used_entries, new_idx[bt], 0)
     out["page_ref"] = cache["page_ref"][old_of_new]
@@ -920,8 +1017,12 @@ def prefill_into_pages(cache, slot, contig_layers, s0, *, start=0,
     is a ring): a RING group's layers get the last ``R`` pages' worth of
     the buffer, the pages that hold everything the first decode step's
     band reaches, written through the slot's ring view; the positions
-    before them are never written, there or anywhere."""
-    names = pool_tensors(cache["layers"][0])
+    before them are never written, there or anywhere.
+
+    A STATE group's layer (its dict holds no ``*_pages``) gets the buffer's
+    own tensors, row 0 of each: the state the prompt ended in overwrites
+    the slot's row whole, whatever the last request left there."""
+    names = pool_tensors(a_layer_of_pages(cache))
     out = dict(cache)
     out["len"] = cache["len"].at[slot].set(jnp.asarray(s0, jnp.int32))
     row = jax.lax.dynamic_slice_in_dim(cache["block_tables"], slot, 1, axis=0)
@@ -957,7 +1058,13 @@ def prefill_into_pages(cache, slot, contig_layers, s0, *, start=0,
         return paged_write([lc[k] for k in keys], chunks, table, origin,
                            stop=s0 - first * ps)
 
+    def state_write(lc, src):
+        return {name: jax.lax.dynamic_update_slice_in_dim(
+            held, src[name].astype(held.dtype), slot, axis=0)
+            for name, held in lc.items()}
+
     out["layers"] = [
+        state_write(lc, src) if not pool_tensors(lc) else
         dict(zip(keys,
                  ring_write(lc, src, ring_of[i].window) if i in ring_of
                  else paged_write([lc[k] for k in keys],
@@ -1020,8 +1127,9 @@ def _prefill_quantized_pages(cache, names, row, contig_layers, s0, start):
 
 def page_bytes(config, page_size: int = 16, *, kv_dtype=None,
                dtype=None, layers: Optional[int] = None) -> int:
-    """Pool bytes ONE page costs across all layers (or across ``layers``
-    of them: one group's, ``len(group.layers)``): what the layout's
+    """Pool bytes ONE page costs across all layers that hold pages (or
+    across ``layers`` of them: one group's, ``len(group.layers)``; a state
+    group's bytes are :func:`state_bytes`): what the layout's
     tensors store for ``page_size`` tokens at the pool dtype (per-head K
     and V tiles; a latent pool's one entry at its stated width — the lane
     padding of a latent row is the pool's, not a token's), plus —
@@ -1041,8 +1149,10 @@ def page_bytes(config, page_size: int = 16, *, kv_dtype=None,
         jnp.dtype(dt).itemsize
     if quant is not None:
         per_tensor += kv_local * jnp.dtype(jnp.float32).itemsize
-    return len(layout.tensors) * per_tensor * (
-        config.num_layers if layers is None else layers)
+    if layers is None:
+        # every layer that stores tokens (a state group's store none)
+        layers = config.num_layers - len(state_layers(config))
+    return len(layout.tensors) * per_tensor * layers
 
 
 def max_slots_for_pool_bytes(config, pool_bytes: int, *,

@@ -80,7 +80,7 @@ from apex_tpu.serving import kv_pool
 from apex_tpu.serving.host_tier import HostPageTier
 from apex_tpu.serving.prefix_cache import PrefixCache
 from apex_tpu.transformer.moe.dropless import (ROUTING_COLLECTION,
-                                               ROUTING_STATS)
+                                               SHARE_ROUTING_STATS)
 
 #: run() counters in the instrument registry (``serving.<name>``); the
 #: per-run stats dict is the DELTA of these across the run — the registry
@@ -117,7 +117,14 @@ _RUN_COUNTERS = ("admitted", "retired", "decode_steps", "busy_slot_steps",
                  # every row of a step counted, idle slots too) and the
                  # expert weights the hit experts made the steps read
                  "expert_pairs_routed", "experts_hit", "expert_load_max",
-                 "expert_bytes_read")
+                 "expert_bytes_read",
+                 # a chip that holds a SHARE of a layer's experts counts the
+                 # four above over the held ones, and here the routed pairs
+                 # whose expert lies on another chip; and per dispatch the
+                 # bytes the state groups' layers (kv_pool.layer_groups: one
+                 # recurrent state a slot) read and write for the decoding
+                 # slots x sync_every
+                 "expert_pairs_elsewhere", "state_bytes_moved")
 
 #: per-request latency histograms (``serving.<name>``, log-bucketed ms)
 _RUN_HISTOGRAMS = ("ttft_ms", "tpot_ms", "queue_wait_ms", "decode_step_ms")
@@ -195,11 +202,16 @@ def _logits_at(model, variables, ids, cache, last):
     alone (at a 150k vocabulary the logits of a 16k-token prompt are 4.7 GB
     that nothing reads), any other computes them all and is sliced, as
     before."""
-    if "logits_positions" in inspect.signature(
-            type(model).__call__).parameters:
+    params = inspect.signature(type(model).__call__).parameters
+    if "logits_positions" in params:
+        # a model whose layers keep a recurrent state is also told where
+        # the prompt ends inside its page bucket: padding must not reach
+        # the state (attention masks it; a recurrence does not)
+        extra = {"prompt_lengths": jnp.reshape(last + 1, (1,))} \
+            if "prompt_lengths" in params else {}
         logits, cache = model.apply(
             variables, ids, cache=cache,
-            logits_positions=jnp.reshape(last, (1, 1)))
+            logits_positions=jnp.reshape(last, (1, 1)), **extra)
         return logits[:, 0], cache
     logits, cache = model.apply(variables, ids, cache=cache)
     return lax.dynamic_slice_in_dim(logits, last, 1, axis=1)[:, 0], cache
@@ -427,6 +439,36 @@ class PagedDecodeEngine:
         windowed = "; ".join(
             f"layers {list(g.layers)} read a window of {g.window}"
             for g in self.groups if g.window is not None)
+        # a STATE group (layers that keep one recurrent state a slot and no
+        # token) is overwritten whole by an admission and updated in place
+        # by every decode step. What that rules out, each refused here by
+        # the group's name: sharing a prefix's pages (the state at the
+        # prefix's end is not kept), speculation (a rejected block cannot
+        # be rolled back out of a state), chunked prefill (the state would
+        # be handed from chunk to chunk through the paged s > 1 path) and
+        # the host tier; quantized pages and a tensor-parallel mesh are
+        # refused where the pool is made (kv_pool.init_paged_cache)
+        stateful = "; ".join(
+            f"layers {list(g.layers)} keep "
+            f"{', '.join(t.name for t in g.state)} a slot"
+            for g in self.groups if g.state)
+        if stateful:
+            for what, asked, why in (
+                    ("prefix_cache", prefix_cache,
+                     "the radix cache keys on pages, and the state at a "
+                     "prefix's end is not kept"),
+                    ("speculative decode (draft_len)", draft_len > 0,
+                     "a rejected draft block cannot be rolled back out "
+                     "of a state"),
+                    ("prefill_chunk", prefill_chunk is not None,
+                     "the state would be handed from chunk to chunk "
+                     "through the paged s > 1 path"),
+                    ("host_tier_bytes", bool(host_tier_bytes),
+                     "the host tier files pages under the radix cache's "
+                     "nodes")):
+                if asked:
+                    raise kv_pool.StateGroupUnsupported(
+                        what, f"{why} ({stateful})")
         if prefix_cache and windowed:
             raise ValueError(
                 f"prefix_cache does not compose with sliding-window "
@@ -464,6 +506,12 @@ class PagedDecodeEngine:
                     "speculative decode does not compose with "
                     "prefix_cache yet: shared pages would need a second "
                     "refcounted draft-pool mirror (run one or the other)")
+            if any(g.state
+                   for g in kv_pool.layer_groups(draft_model.config)):
+                raise kv_pool.StateGroupUnsupported(
+                    "a draft model for speculative decode",
+                    "a rejected draft block cannot be rolled back out of "
+                    "the draft's state")
             if windowed or any(
                     g.window is not None
                     for g in kv_pool.layer_groups(draft_model.config)):
@@ -793,7 +841,8 @@ class PagedDecodeEngine:
             cache, tok, done, n_left, req_keys, samp_i = carry
             len_before = cache["len"]
             # what the step's routing did rides back with its tokens: the
-            # layers sow one ROUTING_STATS vector each, summed here (a
+            # layers sow one ROUTING_STATS vector each (SHARE_ROUTING_STATS
+            # where a layer holds a share of its experts), summed here (a
             # model without routed experts sows nothing: an empty tuple)
             (logits, cache), sown = model.apply(
                 variables, tok[:, None], cache=cache,

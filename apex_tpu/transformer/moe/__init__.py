@@ -11,7 +11,9 @@ from apex_tpu.transformer.moe.layer import (MoEAuxLosses, MoEMLP,
                                             moe_layer_selected,
                                             slice_expert_shards)
 from apex_tpu.transformer.moe.dropless import (ROUTING_COLLECTION,
-                                               ROUTING_STATS, DroplessMoEMLP,
+                                               ROUTING_STATS,
+                                               SHARE_ROUTING_STATS,
+                                               DroplessMoEMLP,
                                                grouped_experts)
 from apex_tpu.transformer.moe.router import (SigmoidBiasTopKRouter,
                                              SoftmaxTopKRouter, TopKRouter,
@@ -24,5 +26,5 @@ __all__ = [
     "slice_expert_shards",
     "TopKRouter", "load_balancing_loss", "router_z_loss",
     "SigmoidBiasTopKRouter", "SoftmaxTopKRouter", "DroplessMoEMLP", "grouped_experts",
-    "ROUTING_COLLECTION", "ROUTING_STATS",
+    "ROUTING_COLLECTION", "ROUTING_STATS", "SHARE_ROUTING_STATS",
 ]

@@ -16,9 +16,21 @@ What the routing did is sown into the ``routing`` collection as one int32
 vector per layer (:data:`ROUTING_STATS`), so a caller that makes the
 collection mutable (the engine's decode chunk) gets it back with the
 tokens; every other caller pays nothing.
+
+A SHARE OF THE EXPERTS. A chip of a deployment that spreads a layer's
+experts over several chips holds ``held`` of them, ``first .. first + held -
+1`` (:class:`DroplessMoEMLP` ``held=``, ``first=``). The router is whole: all
+``num_experts`` outputs, the ``k`` largest, renormalised over those ``k`` and
+never over the held ones. A pair whose expert lies on another chip gets
+weight 0 and NO ROW in the grouped products: it sorts behind the last held
+expert's run and no group's size counts it, so the products' work follows
+the held pairs alone. What the absent experts would add is the other chips'
+to compute and the exchange's to bring; nothing here stands in for either.
 """
 
 from __future__ import annotations
+
+from typing import Optional
 
 import flax.linen as nn
 import jax
@@ -32,24 +44,42 @@ from apex_tpu.transformer.moe.router import (SigmoidBiasTopKRouter,
 #: routed (rows x k), distinct experts with at least one row, and the
 #: fullest expert's rows — over every row of the call, idle slots included
 ROUTING_STATS = ("expert_pairs_routed", "experts_hit", "expert_load_max")
+#: what a layer that holds a SHARE of its experts sows: the three above over
+#: the held pairs, experts and rows, then the pairs whose expert is elsewhere
+SHARE_ROUTING_STATS = ROUTING_STATS + ("expert_pairs_elsewhere",)
 ROUTING_COLLECTION = "routing"
 
 
-def grouped_experts(x, idx, weights, gate, up, down):
+def grouped_experts(x, idx, weights, gate, up, down, *, first=None):
     """``sum_i weights[t, i] * SwiGLU_{idx[t, i]}(x[t])`` for every row.
 
     ``x``: (T, d); ``idx``/``weights``: (T, k); ``gate``/``up``:
     (E, d, m); ``down``: (E, m, d). Returns ``(y (T, d) fp32, sizes (E,))``
-    with ``sizes`` the rows each expert got."""
+    with ``sizes`` the rows each expert got.
+
+    ``first`` (an int; None: the stack is all the experts): the stack holds
+    experts ``first .. first + E - 1`` of those ``idx`` names. A pair whose
+    expert is not among them counts in no group, so it costs no row of the
+    products, and adds nothing to ``y``."""
     t, k = idx.shape
+    held = gate.shape[0]
     flat = idx.reshape(-1)
+    if first is not None:
+        flat = flat - first
+        # elsewhere: behind the last held expert's run, in no group
+        flat = jnp.where((flat >= 0) & (flat < held), flat, held)
     order = jnp.argsort(flat, stable=True)               # pairs by expert
     rows = x[order // k]                                 # (T*k, d)
-    sizes = jnp.bincount(flat, length=gate.shape[0]).astype(jnp.int32)
+    sizes = jnp.bincount(flat, length=held).astype(jnp.int32)
     with jax.named_scope("moe_experts"):
         mid = jax.nn.silu(lax.ragged_dot(rows, gate, sizes)) \
             * lax.ragged_dot(rows, up, sizes)
         out = lax.ragged_dot(mid, down, sizes)           # (T*k, d)
+    if first is not None:
+        # the rows past the last group are no product's: whatever the
+        # kernel left there must not meet even a weight of 0
+        out = jnp.where((jnp.arange(t * k) < sizes.sum())[:, None], out, 0)
+        weights = jnp.where(flat.reshape(t, k) < held, weights, 0.0)
     # back to (token, choice) order: a gather through the inverse
     # permutation, so the combine is a fixed-order sum and not a scatter-add
     inverse = jnp.zeros_like(order).at[order].set(
@@ -64,7 +94,10 @@ class DroplessMoEMLP(nn.Module):
     ``"softmax"``: :class:`SoftmaxTopKRouter`, which has no scaling
     factor), SwiGLU experts of width ``ffn_hidden_size`` stacked ``(E, in,
     out)``, and ``shared_experts`` always-on experts fused into one SwiGLU
-    of their summed width."""
+    of their summed width, times ``sigmoid(w_g . x)`` where ``shared_gate``.
+    ``held`` (None: all) and ``first``: the share of the experts this chip
+    holds (module docstring): the stack is ``held`` experts, the router all
+    ``num_experts`` outputs."""
 
     hidden_size: int
     ffn_hidden_size: int
@@ -75,6 +108,9 @@ class DroplessMoEMLP(nn.Module):
     routed_scaling_factor: float = 1.0
     params_dtype: jnp.dtype = jnp.float32
     router: str = "sigmoid_bias"
+    held: Optional[int] = None
+    first: int = 0
+    shared_gate: bool = False
 
     def _router(self):
         common = dict(norm_topk_prob=self.norm_topk_prob,
@@ -94,17 +130,30 @@ class DroplessMoEMLP(nn.Module):
         e, m = self.num_experts, self.ffn_hidden_size
         xt = x.reshape(-1, d)
         idx, weights = self._router()(xt)
-        gate, up, down = ExpertStack(e, d, m, self.params_dtype,
-                                     name="experts")()
+        share = self.held is not None
+        if share and not 0 <= self.first <= e - self.held:
+            raise ValueError(f"experts {self.first} .. {self.first} + "
+                             f"{self.held} are not among {e}")
+        gate, up, down = ExpertStack(self.held if share else e, d, m,
+                                     self.params_dtype, name="experts")()
         y, sizes = grouped_experts(xt, idx, weights, gate.astype(x.dtype),
-                                   up.astype(x.dtype), down.astype(x.dtype))
-        self.sow(ROUTING_COLLECTION, "stats", jnp.stack([
-            jnp.int32(idx.size), (sizes > 0).sum().astype(jnp.int32),
-            sizes.max()]))
+                                   up.astype(x.dtype), down.astype(x.dtype),
+                                   first=self.first if share else None)
+        stats = [jnp.int32(idx.size), (sizes > 0).sum().astype(jnp.int32),
+                 sizes.max()]
+        if share:
+            stats[0] = sizes.sum()
+            stats.append(jnp.int32(idx.size) - sizes.sum())
+        self.sow(ROUTING_COLLECTION, "stats", jnp.stack(stats))
         y = y.astype(x.dtype)
         if self.shared_experts:
-            y = y + SwiGLU(d, m * self.shared_experts, self.params_dtype,
-                           name="shared")(xt)
+            shared = SwiGLU(d, m * self.shared_experts, self.params_dtype,
+                            name="shared")(xt)
+            if self.shared_gate:
+                shared = shared * jax.nn.sigmoid(
+                    Linear(1, d, self.params_dtype, name="shared_gate")(xt)
+                    .astype(jnp.float32)).astype(x.dtype)
+            y = y + shared
         return y.reshape(*lead, d)
 
 

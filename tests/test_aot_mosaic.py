@@ -436,6 +436,104 @@ def test_two_groups_of_pages_compile_for_the_chip(program, mesh):
             "3,2,1,0"}
 
 
+def _qwen3_next_cell():
+    """``qwen3-next-80b-a3b.longchat-closed64`` at its widths, one period of
+    its layers (linear, linear, linear, full): 64 slots, 128 of 512 experts
+    held, a quarter of the vocabulary, 2 kv heads of 256 behind 2048-page
+    tables over the 2 GiB pool's 32,769 pages, and for each linear layer a
+    float32 state of 32 x 128 x 128 a slot."""
+    from apex_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextModel
+
+    cfg = Qwen3NextConfig(num_layers=4, vocab_size=37984, experts_held=128,
+                          max_position_embeddings=32768)
+    return Qwen3NextModel(cfg), dict(slots=64, num_pages=32769,
+                                     max_pages=2048)
+
+
+@pytest.mark.parametrize("program", ["step", "admit"])
+def test_a_state_group_beside_pages_compiles_for_the_chip(program, mesh):
+    """The decode chunk and an admission of a model whose linear-attention
+    layers keep one recurrent state a slot (``kv_pool.layer_groups``: a
+    STATE group beside the block table's), compiled for the described v5e.
+
+    The decode chunk holds one ``gated_delta_step`` Mosaic call a linear
+    layer, each aliasing its state operand, and one unbanded
+    ``paged_attention`` for the full layer; NO ``copy`` or ``transpose``
+    anywhere in the program has the state's shape, so the chunk's scan over
+    steps and the loop over layers carry 1.6 GB of state where it lies; the
+    state enters and leaves the program row-major. The admission (4096
+    tokens) runs the chunked rule as plain XLA (no labelled kernel), flash
+    attention for the full layer, and writes the slot's state row with no
+    state-shaped copy either."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    import tpu_aot
+    from apex_tpu.serving import kv_pool
+    from apex_tpu.serving.scheduler import PagedDecodeEngine
+
+    model, pool = _qwen3_next_cell()
+    slots = pool["slots"]
+    engine = PagedDecodeEngine(model, variables=None, num_slots=2,
+                               page_size=16, num_pages=3,
+                               max_pages_per_seq=pool["max_pages"],
+                               sync_every=4)
+    cache = jax.eval_shape(lambda: kv_pool.init_paged_cache(
+        model.config, slots, num_pages=pool["num_pages"], page_size=16,
+        max_pages_per_seq=pool["max_pages"]))
+    i32 = jnp.int32
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), i32)))
+    sds = jax.ShapeDtypeStruct
+    if program == "step":
+        fn = engine._step_fn()
+        rest = [sds((slots,), i32), sds((slots,), jnp.bool_),
+                sds((slots,), i32), sds((slots, 2), jnp.uint32),
+                sds((slots,), i32)]
+    else:
+        fn = engine._admit_fn(4096)
+        rest = [sds((1, 4096), i32), sds((), i32), sds((), i32),
+                sds((), i32), sds((2,), jnp.uint32)]
+    compiled = tpu_aot.compile_replicated(mesh, fn,
+                                          [cache, variables] + rest, (0,))
+    txt = compiled.as_text()
+
+    state = (slots, 32, 128, 128)
+    assert [lc["delta_state"].shape for lc in cache["layers"][:3]] == [
+        state] * 3
+    assert sorted(cache["layers"][3]) == ["k_pages", "v_pages"]
+    calls = re.findall(r'custom_call_target="tpu_custom_call"([^\n]*)\n'
+                       r'"kernel":"(\w+)"', txt)
+    labels = [label for _, label in calls]
+    if program == "step":
+        steps = [attrs for attrs, label in calls
+                 if label == "gated_delta_step"]
+        assert len(steps) == 3
+        assert all("output_to_operand_aliasing" in attrs for attrs in steps)
+        assert labels.count("paged_attention") == 1
+    else:
+        assert "gated_delta_step" not in labels
+        assert labels.count("flash_fwd") == 1
+    assert labels.count("paged_write") == 1
+    for shape in (state, (pool["num_pages"], 2, 16, 256)):
+        shape = re.escape(",".join(map(str, shape)))
+        moved = [ln.strip()[:200] for ln in txt.splitlines()
+                 if re.search(rf"= \w+\[{shape}\]\S* (copy|transpose)\(",
+                              ln)]
+        assert not moved, moved[:2]
+        entry = next(ln for ln in txt.splitlines()
+                     if "entry_computation_layout" in ln)
+        assert set(re.findall(rf"\w+\[{shape}\]\{{([\d,]+)", entry)) == {
+            "3,2,1,0"}
+    # the whole program fits the chip beside what it is handed
+    ma = compiled.memory_analysis()
+    peak = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert peak < tpu_aot.HBM_BUDGET
+
+
 #: what ``ops/flash_attention.py``'s rule picks at the training cell's shape
 #: (PERF.md section 6, PR 36): one (512, 512) tile a head, so a grid step a
 #: head; the 64-wide head held 128 lanes wide; q's segment ids a column.

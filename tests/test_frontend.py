@@ -548,10 +548,15 @@ def test_pump_death_mid_decode_raises_serving_error_bounded(rng):
     import queue as queue_mod
 
     cfg, model, v = _model()
-    fe = _killed_frontend(model, v, at=2)
+    # submit BEFORE the pump thread starts: the injected kill counts pump
+    # iterations, and a pump that had already idled through two of them
+    # before the request arrived died with nothing in flight (one full run
+    # in eight under six workers: PERF.md section 7)
+    fe = _killed_frontend(model, v, at=2, start=False)
     try:
         prompt = rng.integers(0, cfg.vocab_size, (10,)).astype(np.int32)
         h = fe.submit(Request(prompt=prompt, max_new_tokens=40))
+        fe.start()
         # consumer 1: blocked in result() on another thread
         res: dict = {}
 

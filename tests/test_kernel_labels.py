@@ -30,6 +30,7 @@ def _cases():
     from apex_tpu.ops import (flash_attention, flat_buffer, optim_kernels,
                               paged_attention, paged_latent_attention,
                               softmax_cross_entropy)
+    from apex_tpu.ops.gated_delta import gated_delta_step
     from apex_tpu.ops.group_norm import group_norm_nhwc
     from apex_tpu.ops.paged_write import paged_write
     from apex_tpu.ops.layer_norm import layer_norm
@@ -93,6 +94,23 @@ def _cases():
             _sds((48, 32, 1, 128), bf16), _sds((40961, 4, 16, 128), bf16),
             _sds((40961, 4, 16, 128), bf16), _sds((48, 2048), i32),
             _sds((48,), i32)]),
+        # the state cell's full layers: 64 slots, 16 query heads over 2 kv
+        # heads of 256, 2048-entry tables over a 2 GiB pool
+        "paged_attention@qwen3-next-80b-a3b.longchat-closed64": (
+            paged_attention, [
+                _sds((64, 16, 1, 256), bf16), _sds((32769, 2, 16, 256), bf16),
+                _sds((32769, 2, 16, 256), bf16), _sds((64, 2048), i32),
+                _sds((64,), i32)]),
+        "gated_delta_step": (gated_delta_step, [
+            _sds((2, 4, 16, 128)), _sds((2, 2, 16)), _sds((2, 2, 16)),
+            _sds((2, 4, 128)), _sds((2, 4)), _sds((2, 4))]),
+        # its linear layers: a 2 MiB float32 state a slot, 32 value heads
+        # over 16 key heads of 128 x 128
+        "gated_delta_step@qwen3-next-80b-a3b.longchat-closed64": (
+            gated_delta_step, [
+                _sds((64, 32, 128, 128)), _sds((64, 16, 128)),
+                _sds((64, 16, 128)), _sds((64, 32, 128)), _sds((64, 32)),
+                _sds((64, 32))]),
         "paged_latent_attention": (
             functools.partial(paged_latent_attention, value_width=128), [
                 _sds((2, 4, 1, 256), bf16), _sds((9, 1, 16, 256), bf16),
@@ -154,7 +172,9 @@ def test_the_cases_cover_the_closed_set():
     "paged_latent_attention@glm-4.7-flash.docqa-closed32",
     "paged_write@gpt2-large.chat-closed16",
     "paged_window_attention@mellum2-12b-a2.5b.ide-closed48",
-    "paged_attention@mellum2-12b-a2.5b.ide-closed48"))
+    "paged_attention@mellum2-12b-a2.5b.ide-closed48",
+    "paged_attention@qwen3-next-80b-a3b.longchat-closed64",
+    "gated_delta_step@qwen3-next-80b-a3b.longchat-closed64"))
 def test_label_reaches_the_lowered_program(case):
     """``metadata={"kernel": label}`` lands on the Mosaic custom call as
     ``kernel_metadata``; the benchmark's pattern finds it there."""
@@ -285,7 +305,9 @@ def test_routed_experts_keep_the_names_their_metrics_read():
 # -- the counters the benchmark's readers name ----------------------------------
 
 MIXED_CELL_COUNTERS = ("kv_full_bytes_attended", "kv_window_bytes_attended",
-                       "kv_bytes_held_steps", "context_token_steps")
+                       "kv_bytes_held_steps", "context_token_steps",
+                       # the state cell's (PR 37)
+                       "state_bytes_moved")
 
 
 @pytest.mark.parametrize("counter", MIXED_CELL_COUNTERS)
@@ -311,3 +333,18 @@ def test_the_counters_of_the_groups_of_layers_are_run_counters(counter):
                   if k in ("bytes_counter", "steps_counter", "numerator",
                            "denominator")}
     assert counter in named and named <= set(_RUN_COUNTERS)
+
+
+def test_a_share_of_the_experts_counts_what_it_holds_under_these_names():
+    """``expert_pairs_elsewhere`` is a run counter and the fourth number a
+    layer that holds a share sows (``SHARE_ROUTING_STATS``); the three
+    before it keep their names and order, so the metric files that read
+    them read a share's held pairs, experts and rows."""
+    from apex_tpu.serving.scheduler import _RUN_COUNTERS
+    from apex_tpu.transformer.moe import ROUTING_STATS, SHARE_ROUTING_STATS
+
+    assert SHARE_ROUTING_STATS == ROUTING_STATS + ("expert_pairs_elsewhere",)
+    assert set(SHARE_ROUTING_STATS) <= set(_RUN_COUNTERS)
+    assert "gated_delta_step" in _dispatch.KERNEL_LABELS
+    # the chunked rule is plain XLA in this PR: no label of its own yet
+    assert "gated_delta_chunk" not in _dispatch.KERNEL_LABELS

@@ -473,3 +473,111 @@ def test_packed_paged_attention_matches_reference_on_chip(s, window, tpu,
                                np.asarray(want, np.float32),
                                rtol=2e-2, atol=2e-2)
 
+
+
+def _delta_inputs(rng, b, s, hk, h, dk, dv):
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(rng.standard_normal((b, s, hk, dk))) * dk ** -0.5
+    k = unit(rng.standard_normal((b, s, hk, dk)))
+    v = rng.standard_normal((b, s, h, dv))
+    g = -np.log1p(np.exp(rng.standard_normal((b, s, h))))
+    beta = 1.0 / (1.0 + np.exp(-rng.standard_normal((b, s, h))))
+    state = rng.standard_normal((b, h, dk, dv))
+    return tuple(jnp.asarray(x, jnp.float32)
+                 for x in (q, k, v, g, beta, state))
+
+
+def test_gated_delta_step_matches_the_recurrence_on_chip(tpu, rng):
+    """The decode step's kernel at Qwen3-Next's shapes (32 value heads over
+    16 key heads of 128 x 128, a 2 MiB state a slot) under Mosaic: a lane
+    of a column broadcast over the tile, reductions over sublanes, the
+    state written where it was read. float32 vector work: it is the
+    recurrence to rounding."""
+    from apex_tpu.ops.gated_delta import (gated_delta_reference,
+                                          gated_delta_step)
+
+    q, k, v, g, beta, state = _delta_inputs(rng, 8, 1, 16, 32, 128, 128)
+    with jax.default_matmul_precision("highest"):
+        want_o, want_s = gated_delta_reference(q, k, v, g, beta, state)
+    step = jax.jit(gated_delta_step, donate_argnums=(0,))
+    got_o, got_s = step(state + 0.0, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                        beta[:, 0])
+    np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o[:, 0]),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got_s), np.asarray(want_s),
+                               rtol=1e-5, atol=1e-5)
+    # four more steps through a scan that carries the state, as the decode
+    # chunk does
+    def chunk(state):
+        def one(c, _):
+            o, c = gated_delta_step(c, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                    beta[:, 0])
+            return c, o
+        return jax.lax.scan(one, state, None, length=4)
+    got_s4, _ = jax.jit(chunk, donate_argnums=(0,))(state + 0.0)
+    with jax.default_matmul_precision("highest"):
+        rep = lambda x: jnp.repeat(x, 4, axis=1)  # noqa: E731
+        _, want_s4 = gated_delta_reference(rep(q), rep(k), rep(v), rep(g),
+                                           rep(beta), state)
+    np.testing.assert_allclose(np.asarray(got_s4), np.asarray(want_s4),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("precision,tol", [("highest", 2e-5),
+                                           (None, 2e-2)])
+def test_gated_delta_chunk_matches_the_recurrence_on_chip(precision, tol,
+                                                          tpu, rng):
+    """The chunked rule over 1100 tokens (17 chunks and a bit, two blocks
+    of chunks, the second row 700 true tokens long) against the recurrence:
+    with float32 products at full precision to rounding; at the chip's
+    default (one bfloat16 pass a float32 product) to what that pass
+    costs."""
+    from apex_tpu.ops.gated_delta import (gated_delta_chunk,
+                                          gated_delta_reference)
+
+    q, k, v, g, beta, state = _delta_inputs(rng, 2, 1100, 16, 32, 128, 128)
+    lengths = jnp.asarray([1100, 700])
+    live = (jnp.arange(1100)[None] < lengths[:, None])[..., None]
+    with jax.default_matmul_precision("highest"):
+        want_o, want_s = jax.jit(gated_delta_reference)(
+            q, k, v, jnp.where(live, g, 0.0), jnp.where(live, beta, 0.0),
+            state)
+
+    def run():
+        return jax.jit(lambda *a: gated_delta_chunk(
+            *a, initial_state=state, lengths=lengths))(q, k, v, g, beta)
+    if precision:
+        with jax.default_matmul_precision(precision):
+            got_o, got_s = run()
+    else:
+        got_o, got_s = run()
+    scale = float(jnp.abs(want_o).max())
+    assert float(jnp.abs(got_o[0] - want_o[0]).max()) < tol * scale
+    assert float(jnp.abs(got_o[1, :700] - want_o[1, :700]).max()) \
+        < tol * scale
+    assert float(jnp.abs(got_s - want_s).max()) \
+        < tol * float(jnp.abs(want_s).max())
+
+
+@pytest.mark.parametrize("s", [1, 4])
+def test_paged_attention_head256_rep8_matches_reference_on_chip(s, tpu, rng):
+    """Qwen3-Next's full layers: 16 query heads over 2 key/value heads of
+    256, one head a pool row, 64 slots behind 2048-entry tables."""
+    from apex_tpu.ops.paged_attention import (paged_attention,
+                                              paged_attention_reference)
+
+    slots, mp, ps, live = 64, 2048, 16, 70
+    k_held, v_held = (jnp.asarray(rng.standard_normal((4609, 2, ps, 256)),
+                                  jnp.bfloat16) for _ in range(2))
+    tables = jnp.asarray(rng.permutation(np.arange(1, 4609))[
+        :slots * live].reshape(slots, live), jnp.int32)
+    tables = jnp.pad(tables, ((0, 0), (0, mp - live)))
+    lengths = jnp.asarray(rng.integers(s, live * ps, (slots,)), jnp.int32)
+    lengths = lengths.at[3].set(0).at[7].set(live * ps)
+    q = jnp.asarray(rng.standard_normal((slots, 16, s, 256)), jnp.bfloat16)
+    got = jax.jit(paged_attention)(q, k_held, v_held, tables, lengths)
+    want = jax.jit(paged_attention_reference)(
+        q, k_held, v_held, tables[:, :live], lengths)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=2e-2, atol=2e-2)
