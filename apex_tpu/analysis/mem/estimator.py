@@ -283,7 +283,7 @@ class VmemCall:
     est_bytes: int               # sum of padded block bytes x buffering
     buffering: int               # 2 when a non-trivial grid pipelines
     n_blocks: int
-    grid: Tuple[int, ...]
+    grid: Tuple[Optional[int], ...]   # None: a traced (dynamic) bound
 
 
 def vmem_calls(closed) -> List[VmemCall]:
@@ -294,10 +294,10 @@ def vmem_calls(closed) -> List[VmemCall]:
         gm = eqn.params.get("grid_mapping")
         if gm is None:
             continue
-        try:
-            grid = tuple(int(g) for g in gm.grid)
-        except (TypeError, ValueError):
-            grid = ()                      # dynamic grid: size unknown
+        # a traced bound (the paged kernels' walk axis) has no size to
+        # read: None, and it pipelines like any axis longer than 1
+        grid = tuple(int(g) if isinstance(g, int) else None
+                     for g in gm.grid)
         total = 0
         n_blocks = 0
         for bm in gm.block_mappings:
@@ -306,7 +306,7 @@ def vmem_calls(closed) -> List[VmemCall]:
             block = bm.block_aval
             total += tiled_padded_bytes(tuple(block.shape), block.dtype)
             n_blocks += 1
-        buffering = 2 if any(g > 1 for g in grid) else 1
+        buffering = 2 if any(g is None or g > 1 for g in grid) else 1
         name = eqn.params["name"] \
             or eqn.params["jaxpr"].debug_info.func_name
         out.append(VmemCall(eqn=eqn, kernel_name=name,

@@ -15,11 +15,10 @@ occupy positions ``lengths[b] - s + i`` (``i`` in ``0..s-1``), so the
 causal/window mask is a per-query-position band.
 
 The tile: ONE GRID STEP SERVES ALL KV HEADS OF ONE SLOT OVER A BLOCK OF
-CONSECUTIVE PAGES. The grid is ``(batch, kv // heads, cdiv(max_pages,
-pages))`` with ``pages`` and ``heads`` derived from the shapes alone
-(:func:`_tile`: as many pages as hold 128 tokens for every kv head,
-cut down only where the K and V buffers would pass a VMEM budget). The
-step's K and V are ``pages`` operands a tensor, each one whole page
+CONSECUTIVE PAGES, ``pages`` and ``heads`` derived from the shapes alone
+(``_page_walk._tile``: as many pages as hold 128 tokens for every kv
+head, cut down only where the K and V buffers would pass a VMEM budget).
+The step's K and V are ``pages`` operands a tensor, each one whole page
 ``(1, heads, page_size, d)`` of the pool as it lies in HBM — row-major,
 a page's heads contiguous, so a page is fetched whole with no copy in
 front of the call. What keeps that true is the WRITE: every program
@@ -27,31 +26,37 @@ that writes the pool goes through ``ops.paged_write``, which leaves it
 row-major (a scatter over the head axis made XLA carry the pool with a
 token's heads contiguous and re-lay every layer's whole pool before
 every call of this kernel; ``tests/test_aot_mosaic.py`` pins the
-compiled decode chunk). At GPT-2 large (16 slots, 20 heads of 64,
-64-page tables) that is 128 grid steps of 16 pages of 40 KB a tensor
-where a one-page one-head tile took 20 480 steps of 2 KB and spent its
-time on the steps, never the bytes (PERF.md, PR 28).
+compiled decode chunk). At GPT-2 large (16 slots, 20 heads of 64) a step
+moves 16 pages of 40 KB a tensor where a one-page one-head tile took
+160 steps of 2 KB for them and spent its time on the steps, never the
+bytes (PERF.md, PR 28).
 
-The page operands' index maps read a SCALAR-PREFETCH table
-(``pltpu.PrefetchScalarGridSpec``) of physical pages that the wrapper
-resolves once per call: entry ``e`` of slot ``b`` is ``block_tables[b,
-clip(e, first_live(b), last_live(b))]``. A dead entry — past the
-sequence end, below the sliding-window band, past the table where
-``max_pages`` is no multiple of ``pages`` — so repeats a live one: what
-the table holds there is never read, and a step whose entries all
-repeat the step before moves nothing (the pipeline skips a block whose
-index did not change). Dead blocks skip their body as well; inside a
-live block the position band masks whatever the clamp repeats. Resolving
-the clamp outside keeps an index map to one SMEM load: the scalar core
-evaluates ``2*pages + 2`` of them every grid step, and at this tile that
-walk, not the DMA or the dots, is most of a call.
+The walk: THE GRID IS ``(kv // heads, n_work)``, ITS LAST AXIS ONE STEP
+FOR EVERY BLOCK THAT HOLDS A LIVE PAGE, slot after slot, and ``n_work``
+is traced (``ops/_page_walk.py``, shared with the latent kernel; PERF.md,
+PR 38). The wrapper builds that work list once per call from the block
+tables and the lengths — for item ``w`` its slot, its block and the
+block's ``pages`` physical pages, every table entry clamped into its
+slot's live pages first — and hands it over as SCALAR-PREFETCH operands
+(``pltpu.PrefetchScalarGridSpec``) that the index maps read: a page
+operand's is one SMEM load (``phys[w * pages + i]``), the queries' and
+the output's read the item's slot. A dead entry inside a live block —
+past the sequence end, below the sliding-window band, past the table
+where ``max_pages`` is no multiple of ``pages`` — repeats a live one:
+what the table holds there is never read, and the position band in the
+body masks whatever the clamp repeats. A block with no live page is no
+item at all, so a table sized for 32,768 positions costs a 4k context
+what a 4k table would (a grid over every block of every table paid the
+scalar core's ``2*pages + 2`` index maps for each dead one: 0.4-0.7 us
+a step, five steps in six). An idle slot keeps one item, whose body is
+skipped, so that it still writes its zeros.
 
-Online softmax ``(m, l, acc)`` carries across the sequential page-block
-axis exactly like flash_attention's k-block axis, shaped for the step's
-heads; fp32 scores and accumulation (same numerics contract). Scores are
-one batched contraction over the head axis, ``(heads, s*rep, d) .
-(heads, pages*page_size, d)``: on the MXU at every ``s*rep``, since at
-``s*rep = 1`` the live step already costs little more than a dead one.
+Online softmax ``(m, l, acc)`` carries across a slot's items exactly
+like flash_attention's k-block axis (it starts at the slot's first item
+and is written out at its last), shaped for the step's heads; fp32
+scores and accumulation (same numerics contract). Scores are one batched
+contraction over the head axis, ``(heads, s*rep, d) . (heads,
+pages*page_size, d)``: on the MXU at every ``s*rep``.
 
 Layout: the pool is ``(num_pages, kv_heads // pack, page_size, head_dim *
 pack)`` — a page operand's minor two dims are the array's own
@@ -103,114 +108,44 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops import _dispatch
+from apex_tpu.ops._page_walk import PageWalk, _tile, page_walk
 from apex_tpu.ops.flash_attention import DEFAULT_MASK_VALUE
 
 _INTERPRET = _dispatch.interpret
 
-#: context tokens one grid step attends: one 128-lane tile of scores
-_STEP_TOKENS = 128
-#: VMEM the K and V page buffers of one grid step may take — both
-#: tensors, double-buffered by the pipeline, tile padding included (a
-#: page narrower than 128 lanes pads to them: a pool that could not pack
-#: its heads, ``serving/kv_pool.heads_per_row``; a packed row of two
-#: 64-wide heads fills its lanes). Half of Mosaic's 16 MiB scoped stack;
-#: the rest holds q, the (m, l, acc) carry and the step's f32 scores
-_KV_VMEM_BUDGET = 8 * 1024 * 1024
 
-
-@functools.cache
-def _tile(kv: int, page_size: int, d: int, dtype, max_pages: int):
-    """``(pages, heads)`` of one grid step, from the shapes alone
-    (``kv`` and ``d`` are the POOL's: its rows and their lanes, whatever
-    heads a row packs): as many consecutive table entries as fill
-    :data:`_STEP_TOKENS` (never
-    more than the table has) for all ``kv`` heads; where that overflows
-    :data:`_KV_VMEM_BUDGET` the page block halves first, then the heads
-    split into the largest divisor of ``kv`` that fits."""
-    item = jnp.dtype(dtype).itemsize
-    page_vmem = (4 * _dispatch.round_up(page_size, 32 // item)
-                 * _dispatch.round_up(d, 128) * item)   # per kv head
-    pages = max(1, min(max_pages, _STEP_TOKENS // page_size))
-    while pages > 1 and pages * kv * page_vmem > _KV_VMEM_BUDGET:
-        pages //= 2
-    heads = next(h for h in range(kv, 0, -1)
-                 if kv % h == 0 and (h == 1 or pages * h * page_vmem
-                                     <= _KV_VMEM_BUDGET))
-    return pages, heads
-
-
-def _live_pages(length, page_size: int, s_q: int, window, maximum=max):
-    """``(first, last)`` table entries of a slot that hold a position
-    some query of the block attends (``first == last == 0`` for an empty
-    slot). Pure arithmetic: the wrapper evaluates it on the traced
-    lengths (``maximum=jnp.maximum``), the serving host on ints."""
-    last = maximum(_dispatch.cdiv(length, page_size) - 1, 0)
-    if window is None:
-        return 0, last
-    # the earliest query sits at length - s_q and attends down to
-    # length - s_q - window + 1; pages wholly below that are dead for
-    # every query of the block and every later step
-    return maximum(length - s_q - window + 1, 0) // page_size, last
-
-
-def pages_fetched(length: int, *, kv_heads: int, page_size: int,
-                  head_dim: int, dtype, max_pages: int, s_q: int = 1,
-                  window: Optional[int] = None) -> int:
-    """Pages of K (and as many of V) one call DMAs for a slot of
-    ``length`` positions: the kernel fetches by block, so every block
-    that holds a live page counts whole (feeds
-    ``serving.kv_bytes_fetched``; per kv-head block the same count of
-    narrower pages). ``kv_heads`` and ``head_dim`` are the pool's own
-    axes 1 and 3 (rows and lanes), so that this tiles as the call
-    does."""
-    pages, _ = _tile(kv_heads, page_size, head_dim, dtype, max_pages)
-    first, last = _live_pages(length, page_size, s_q, window)
-    return (last // pages - first // pages + 1) * pages
-
-
-def _paged_kernel(phys_ref, len_ref, q_ref, *rest, scale, page_size, pages,
-                  s_q, rep, window=None, quantized=False):
+def _paged_kernel(*refs, scale, page_size, pages, s_q, rep, window=None,
+                  quantized=False):
+    (j, seq_len, first, last), (q_ref, *rest) = PageWalk.item(refs, axis=1)
     k_refs, v_refs, rest = rest[:pages], rest[pages:2 * pages], \
         rest[2 * pages:]
     if quantized:
         # two extra operands: the per-token dequant scales of this
-        # step's pages, gathered through the same clamped table entries
+        # item's pages, gathered through the same clamped table entries
         # as the page tiles (docs/serving.md "Quantized KV pages")
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
         ks_ref = vs_ref = None
         o_ref, acc_ref, m_ref, l_ref = rest
-    b = pl.program_id(0)
-    j = pl.program_id(2)
+    # the grid's last axis walks the live blocks of every slot in turn
+    # (ops/_page_walk.py): block j of the slot holds absolute positions
+    # [j*block, (j+1)*block) and at least one of them is live. With a
+    # window the walk starts at the block of the EARLIEST query's band
+    # floor (seq_len - s_q) - window + 1: what lies below is dead for
+    # every query of the block and every later step (the band only moves
+    # forward) — the serving engine drops such pages from the block
+    # table entirely (kv_pool.drop_slot_pages), and the walk never names
+    # what a dropped entry now points at (the null page)
     block = pages * page_size
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    seq_len = len_ref[b]
-
-    # block j holds absolute positions [j*block, (j+1)*block): dead
-    # blocks (at or past the sequence end) skip their FLOPs and their
-    # accumulator update, and the clamped index maps give them the table
-    # entry the step before already holds, so the pipeline fetches
-    # nothing for them either
-    block_live = j * block < seq_len
-    if window is not None:
-        # sliding-window band: query i of the block sits at position
-        # seq_len - s_q + i and attends (pos_i - window, pos_i]. A block
-        # whose LAST position is at or below the EARLIEST query's band
-        # floor (seq_len - s_q) - window is dead for every query in the
-        # block and every later step (the band only moves forward) — the
-        # serving engine drops such pages from the block table entirely
-        # (kv_pool.drop_slot_pages), and the clamp never reads what a
-        # dropped entry now points at (the null page)
-        block_live = jnp.logical_and(
-            block_live, (j + 1) * block + window + s_q - 1 > seq_len)
-
-    @pl.when(block_live)
+    # an idle slot's one item names whatever its table holds: not read
+    @pl.when(seq_len > 0)
     def _body():
         q = q_ref[0]                                  # (heads, s_q*rep, d)
         # the step's pages side by side: (heads, block, d). Quantized
@@ -225,7 +160,7 @@ def _paged_kernel(phys_ref, len_ref, q_ref, *rest, scale, page_size, pages,
         s = jnp.einsum("hqd,htd->hqt", q, k,
                        preferred_element_type=jnp.float32) * scale
         if quantized:
-            s = s * ks_ref[0, 0]                      # (heads, 1, block)
+            s = s * ks_ref[0]                         # (heads, 1, block)
         pos = lax.broadcasted_iota(jnp.int32, s.shape, 2) + j * block
         # rows are position-major: row r is query position seq_len - s_q
         # + r // rep (each query's rep GQA heads are adjacent rows)
@@ -247,7 +182,7 @@ def _paged_kernel(phys_ref, len_ref, q_ref, *rest, scale, page_size, pages,
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
         m_ref[...] = m_new
         if quantized:
-            p_in = p * vs_ref[0, 0]
+            p_in = p * vs_ref[0]
             v = jnp.concatenate([r[0].astype(jnp.float32) for r in v_refs],
                                 axis=1)
         else:
@@ -256,7 +191,7 @@ def _paged_kernel(phys_ref, len_ref, q_ref, *rest, scale, page_size, pages,
         acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
             "hqt,htd->hqd", p_in, v, preferred_element_type=jnp.float32)
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(last)
     def _finish():
         l = l_ref[...]
         # a zero-length slot (idle serving slot) outputs exactly 0
@@ -387,7 +322,6 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     if scale is None:
         scale = 1.0 / (head_dim ** 0.5)
     pages, heads = _tile(kv, page_size, d, k_pages.dtype, max_pages)
-    n_blocks = _dispatch.cdiv(max_pages, pages)
 
     # position-major row layout: row i*rep + r is query position i of
     # GQA group-member r, so the kernel recovers the position as
@@ -403,57 +337,38 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
             own, qr.reshape(b, kv, s_q, pack, rep // pack, 1, head_dim),
             jnp.zeros((), q.dtype))
     qr = qr.reshape(b, kv, rows, d)
-    ln = lengths.astype(jnp.int32)
-    # the physical page of every table entry a grid step names, each
-    # entry clamped into its slot's live pages first: a dead entry (past
-    # the end, below the band, or past the table where max_pages is no
-    # multiple of the page block) repeats a live one, so whatever it
-    # holds is never read, and a step whose entries all repeat the step
-    # before fetches nothing. Resolved here, once per call, so that an
-    # index map is one SMEM load: the scalar core walks 2*pages + 2 of
-    # them every grid step
-    first, last = _live_pages(ln, page_size, s_q, window, jnp.maximum)
-    entries = jnp.clip(
-        jnp.arange(n_blocks * pages, dtype=jnp.int32)[None, :],
-        jnp.asarray(first, jnp.int32)[..., None],
-        jnp.minimum(last, max_pages - 1)[:, None])
-    phys = jnp.take_along_axis(block_tables.astype(jnp.int32), entries,
-                               axis=1)              # (b, n_blocks*pages)
-
-    def page_spec(i):
-        return pl.BlockSpec(
-            (1, heads, page_size, d),
-            lambda b, g, j, phys, ln: (phys[b, j * pages + i], g, 0, 0))
-
-    q_spec = pl.BlockSpec((1, heads, rows, d),
-                          lambda b, g, j, phys, ln: (b, g, 0, 0))
-    in_specs = [q_spec] + [page_spec(i) for i in range(pages)] * 2
-    operands = [phys, ln, qr] + [k_pages] * pages + [v_pages] * pages
+    # the live blocks of every slot, one grid step each, and the physical
+    # pages of each resolved once per call (ops/_page_walk.py)
+    walk = page_walk(block_tables, lengths, page_size=page_size,
+                     pages=pages, s_q=s_q, window=window)
+    outer = (kv // heads,)
+    q_spec = walk.slot_spec((1, heads, rows, d),
+                            lambda slot, g: (slot, g, 0, 0))
+    in_specs = [q_spec] + [
+        walk.page_spec(i, (1, heads, page_size, d),
+                       lambda page, g: (page, g, 0, 0))
+        for i in range(pages)] * 2
+    operands = [qr] + [k_pages] * pages + [v_pages] * pages
     if quantized:
         # the (num_pages, kv) scales, gathered through the same clamped
         # entries and spread over each page's positions:
-        # (b, n_blocks, kv, 1, block) f32, one (heads, 1, block) tile a
-        # step that broadcasts over the rows
+        # (n_items, kv, 1, block) f32, one (heads, 1, block) tile a step
+        # that broadcasts over the rows
         def per_token(scales):
-            sc = jnp.take(scales.astype(jnp.float32), phys, axis=0)
-            sc = sc.reshape(b, n_blocks, pages, kv).transpose(0, 1, 3, 2)
-            return jnp.repeat(sc, page_size, axis=3)[:, :, :, None]
+            sc = jnp.take(scales.astype(jnp.float32), walk.phys, axis=0)
+            sc = jnp.repeat(sc.transpose(0, 2, 1), page_size, axis=2)
+            return sc[:, :, None]
 
-        in_specs += [pl.BlockSpec(
-            (1, 1, heads, 1, pages * page_size),
-            lambda b, g, j, phys, ln: (b, j, g, 0, 0))] * 2
+        in_specs += [walk.item_spec((1, heads, 1, pages * page_size),
+                                    lambda w, g: (w, g, 0, 0))] * 2
         operands += [per_token(k_scales), per_token(v_scales)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, kv // heads, n_blocks),
-        in_specs=in_specs,
-        out_specs=q_spec,
+    grid_spec = walk.grid_spec(
+        outer, in_specs=in_specs, out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((heads, rows, d), jnp.float32),
             pltpu.VMEM((heads, rows, 1), jnp.float32),
             pltpu.VMEM((heads, rows, 1), jnp.float32),
-        ],
-    )
+        ])
     out = _dispatch.pallas_call(
         functools.partial(_paged_kernel, scale=float(scale),
                           page_size=page_size, pages=pages,
@@ -461,15 +376,13 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                           quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, rows, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=walk.compiler_params(outer),
         # a banded call carries a label of its own, so that a trace tells
         # a windowed layer's calls from a full layer's in one program
         kernel=("paged_attention" if window is None
                 else "paged_window_attention"),
         interpret=_INTERPRET(),
-    )(*operands)
+    )(*walk.prefetch, *operands)
     if pack > 1:
         # every row came back with all of the pool row's lanes: head p's
         # rows keep their own

@@ -10,16 +10,18 @@ its first ``value_width`` columns:
     s[h, t] = q[h] . entry[t] * scale        q = [q_nope W_uk^T; q_rope; 0]
     o[h]    = softmax_t(s[h]) @ entry[:, :value_width]
 
-so every page is read ONCE and used twice. The tile is
-``ops/paged_attention.py``'s (PR 28): one grid step serves ALL query heads
-of a slot over a block of consecutive pages (``_tile`` with one kv head of
-``stored`` lanes), the page operands' index maps read a scalar-prefetch
-table of physical pages resolved once per call with every dead entry
-clamped onto a live one (so dead blocks fetch nothing and skip their body),
-online softmax carries across the page-block axis in fp32. The row axis is
-position-major (row ``i * heads + h`` is query position ``i`` of head
-``h``), so a block of ``s`` queries per slot — chunked prefill, a
-speculative verify — is the same kernel.
+so every page is read ONCE and used twice. The tile and the walk are
+``ops/paged_attention.py``'s, written once in ``ops/_page_walk.py``: one
+grid step serves ALL query heads of a slot over a block of consecutive
+pages (``_tile`` with one kv head of ``stored`` lanes); the grid is ONE
+axis over the work list of blocks that hold a live page, slot after slot,
+its bound traced; the page operands' index maps read the list's
+scalar-prefetched physical pages, every dead entry inside a live block
+clamped onto a live one; online softmax carries across a slot's items in
+fp32. This module holds the kernel body, its operands' shapes and the
+reference. The row axis is position-major (row ``i * heads + h`` is query
+position ``i`` of head ``h``), so a block of ``s`` queries per slot —
+chunked prefill, a speculative verify — is the same kernel.
 
 The padding lanes are zeros in the pool and in the query, so the score
 contraction runs over whole 128-lane tiles; ``value_width`` is a lane
@@ -39,28 +41,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops import _dispatch
+from apex_tpu.ops._page_walk import PageWalk, _tile, page_walk
 from apex_tpu.ops.flash_attention import DEFAULT_MASK_VALUE
-from apex_tpu.ops.paged_attention import _live_pages, _tile
 
 _INTERPRET = _dispatch.interpret
 
 
-def _latent_kernel(phys_ref, len_ref, q_ref, *rest, scale, page_size, pages,
-                   s_q, heads, value_width):
+def _latent_kernel(*refs, scale, page_size, pages, s_q, heads, value_width):
+    # the grid walks the live blocks of every slot in turn: block j of
+    # the slot holds positions [j*block, (j+1)*block)
+    (j, seq_len, first, last), (q_ref, *rest) = PageWalk.item(refs, axis=0)
     page_refs, (o_ref, acc_ref, m_ref, l_ref) = rest[:pages], rest[pages:]
-    b = pl.program_id(0)
-    j = pl.program_id(1)
     block = pages * page_size
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    seq_len = len_ref[b]
-
-    @pl.when(j * block < seq_len)
+    # an idle slot's one item names whatever its table holds: not read
+    @pl.when(seq_len > 0)
     def _body():
         q = q_ref[0]                                     # (rows, stored)
         entries = jnp.concatenate([r[0, 0] for r in page_refs], axis=0)
@@ -84,7 +85,7 @@ def _latent_kernel(phys_ref, len_ref, q_ref, *rest, scale, page_size, pages,
             p.astype(values.dtype), values,
             preferred_element_type=jnp.float32)
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(last)
     def _finish():
         l = l_ref[...]
         # a zero-length slot (idle serving slot) outputs exactly 0
@@ -148,50 +149,34 @@ def paged_latent_attention(q, latent_pages, block_tables, lengths, *,
     if scale is None:
         scale = 1.0 / (stored ** 0.5)
     pages, _ = _tile(1, page_size, stored, latent_pages.dtype, max_pages)
-    n_blocks = _dispatch.cdiv(max_pages, pages)
 
     qr = q.transpose(0, 2, 1, 3).reshape(b, rows, stored)   # position-major
-    ln = lengths.astype(jnp.int32)
-    # the physical page of every table entry a grid step names, each entry
-    # clamped into its slot's live pages first (ops/paged_attention.py)
-    first, last = _live_pages(ln, page_size, s_q, None, jnp.maximum)
-    entries = jnp.clip(
-        jnp.arange(n_blocks * pages, dtype=jnp.int32)[None, :],
-        jnp.asarray(first, jnp.int32)[..., None],
-        jnp.minimum(last, max_pages - 1)[:, None])
-    phys = jnp.take_along_axis(block_tables.astype(jnp.int32), entries,
-                               axis=1)                  # (b, n_blocks*pages)
-
-    def page_spec(i):
-        return pl.BlockSpec(
-            (1, 1, page_size, stored),
-            lambda b, j, phys, ln: (phys[b, j * pages + i], 0, 0, 0))
-
-    q_spec = pl.BlockSpec((1, rows, stored), lambda b, j, phys, ln: (b, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, n_blocks),
-        in_specs=[q_spec] + [page_spec(i) for i in range(pages)],
-        out_specs=pl.BlockSpec((1, rows, value_width),
-                               lambda b, j, phys, ln: (b, 0, 0)),
+    walk = page_walk(block_tables, lengths, page_size=page_size,
+                     pages=pages, s_q=s_q)
+    grid_spec = walk.grid_spec(
+        (),
+        in_specs=[walk.slot_spec((1, rows, stored),
+                                 lambda slot: (slot, 0, 0))] + [
+            walk.page_spec(i, (1, 1, page_size, stored),
+                           lambda page: (page, 0, 0, 0))
+            for i in range(pages)],
+        out_specs=walk.slot_spec((1, rows, value_width),
+                                 lambda slot: (slot, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((rows, value_width), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
-        ],
-    )
+        ])
     out = _dispatch.pallas_call(
         functools.partial(_latent_kernel, scale=float(scale),
                           page_size=page_size, pages=pages, s_q=s_q,
                           heads=heads, value_width=value_width),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, value_width), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
+        compiler_params=walk.compiler_params(()),
         kernel="paged_latent_attention",
         interpret=_INTERPRET(),
-    )(phys, ln, qr, *([latent_pages] * pages))
+    )(*walk.prefetch, qr, *([latent_pages] * pages))
     return out.reshape(b, s_q, heads, value_width).transpose(0, 2, 1, 3)
 
 

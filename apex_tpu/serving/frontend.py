@@ -76,7 +76,7 @@ from apex_tpu.obs import compile_watch
 from apex_tpu.obs import fleet
 from apex_tpu.obs.spans import SpanTracer
 from apex_tpu.ops._dispatch import round_up
-from apex_tpu.ops.paged_attention import pages_fetched
+from apex_tpu.ops._page_walk import pages_fetched
 from apex_tpu.serving import kv_pool
 from apex_tpu.serving.policy import PriorityDeadlinePolicy
 from apex_tpu.serving.scheduler import (_RUN_COUNTERS, _RUN_HISTOGRAMS,
@@ -294,7 +294,7 @@ class _KvGroup(NamedTuple):
     group: kv_pool.LayerGroup
     ring: Optional[int]           # pages a slot holds of it, where a ring
     page_bytes: float             # one page over its layers and all chips
-    pages_fetched: Callable       # ops.paged_attention.pages_fetched, bound
+    pages_fetched: Callable       # ops._page_walk.pages_fetched, bound
     #                               to the group's pool and table
 
 
@@ -1021,7 +1021,7 @@ class ServingFrontend:
         """Pages the decode kernel fetches for one decoding slot in a
         layer of group ``kv`` over the chunk being dispatched, summed
         over ALL its steps: the kernel moves whole page blocks
-        (``ops.paged_attention.pages_fetched``), and a frozen step still
+        (``ops._page_walk.pages_fetched``), and a frozen step still
         runs the forward at the slot's last length. Over
         ``_tokens_attended`` x page_size this is the rounding the tile
         costs. A ring group's call sees the slot from the band's first

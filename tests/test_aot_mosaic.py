@@ -350,17 +350,27 @@ def test_no_program_re_lays_the_page_pool(name, mesh):
     assert writes, "the page write is no Mosaic call"
     assert all("output_to_operand_aliasing" in attrs for attrs in writes), (
         "a page write does not alias its pool operand")
+    if program == "step":
+        # the work list of live blocks (ops/_page_walk.py) is the same for
+        # every layer of a decode step: the program computes it ONCE (one
+        # gather of the items' rows of 8 table entries), and both layers'
+        # kernels run under the same traced grid bound
+        n_items = slots * pool["max_pages"] // 8
+        assert len(re.findall(rf"= s32\[{n_items},8\]\S* gather\(", txt)) == 1
+        bounds = re.findall(r"custom-call\(([^,()]+), [^\n]*\n"
+                            r'"kernel":"paged_(?:latent_)?attention"', txt)
+        assert len(bounds) == 2 and bounds[0] == bounds[1], bounds
 
 
-def _mellum_cell():
-    """``mellum2-12b-a2.5b.ide-closed48`` at its widths, one period of its
+def _mellum_cell(periods=1):
+    """``mellum2-12b-a2.5b.ide-closed48`` at its widths, ``periods`` of its
     layers (sliding, sliding, sliding, full): 48 slots, 4 kv heads of 128,
     the 2.5 GiB pool's 40,961 pages behind 2048-page tables for the full
     layer and rings of 65 pages a slot for the sliding ones."""
     from apex_tpu.models.mellum import MellumConfig, MellumModel
 
-    cfg = MellumConfig(num_layers=4,
-                       layer_types=MellumConfig().layer_types[:4],
+    cfg = MellumConfig(num_layers=4 * periods,
+                       layer_types=MellumConfig().layer_types[:4 * periods],
                        max_position_embeddings=32768)
     return MellumModel(cfg), dict(slots=48, num_pages=40961, max_pages=2048)
 
@@ -436,15 +446,16 @@ def test_two_groups_of_pages_compile_for_the_chip(program, mesh):
             "3,2,1,0"}
 
 
-def _qwen3_next_cell():
-    """``qwen3-next-80b-a3b.longchat-closed64`` at its widths, one period of
+def _qwen3_next_cell(periods=1):
+    """``qwen3-next-80b-a3b.longchat-closed64`` at its widths, ``periods`` of
     its layers (linear, linear, linear, full): 64 slots, 128 of 512 experts
     held, a quarter of the vocabulary, 2 kv heads of 256 behind 2048-page
     tables over the 2 GiB pool's 32,769 pages, and for each linear layer a
     float32 state of 32 x 128 x 128 a slot."""
     from apex_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextModel
 
-    cfg = Qwen3NextConfig(num_layers=4, vocab_size=37984, experts_held=128,
+    cfg = Qwen3NextConfig(num_layers=4 * periods, vocab_size=37984,
+                          experts_held=128,
                           max_position_embeddings=32768)
     return Qwen3NextModel(cfg), dict(slots=64, num_pages=32769,
                                      max_pages=2048)
@@ -458,7 +469,9 @@ def test_a_state_group_beside_pages_compiles_for_the_chip(program, mesh):
 
     The decode chunk holds one ``gated_delta_step`` Mosaic call a linear
     layer, each aliasing its state operand, and one unbanded
-    ``paged_attention`` for the full layer; NO ``copy`` or ``transpose``
+    ``paged_attention`` for the full layer, whose grid bound is traced and
+    whose work list over 64 tables of 2048 entries fits the chip's scalar
+    memory; NO ``copy`` or ``transpose``
     anywhere in the program has the state's shape, so the chunk's scan over
     steps and the loop over layers carry 1.6 GB of state where it lies; the
     state enters and leaves the program row-major. The admission (4096
@@ -513,6 +526,19 @@ def test_a_state_group_beside_pages_compiles_for_the_chip(program, mesh):
         assert len(steps) == 3
         assert all("output_to_operand_aliasing" in attrs for attrs in steps)
         assert labels.count("paged_attention") == 1
+        # the walk's scalar-prefetch operands (ops/_page_walk.py), which
+        # Mosaic holds in the v5e's 1 MiB of SMEM for the whole call: a
+        # traced grid bound, then the resolved pages of the worst case's
+        # 64 x 256 work items (8 a block), their slots and blocks, the
+        # slots' running offsets and lengths
+        walk, = [attrs for attrs, label in calls
+                 if label == "paged_attention"]
+        operands = walk.split("operand_layout_constraints={")[1]
+        scalars = re.findall(r"s32\[(\d*)\]", operands.split("bf16[")[0])
+        assert scalars[0] == ""
+        scalars = [int(n) for n in scalars[1:]]
+        assert scalars == [64 * 256 * 8, 64 * 256, 64 * 256, 65, 64]
+        assert 4 * sum(scalars) == 655_876 < 2 ** 20
     else:
         assert "gated_delta_step" not in labels
         assert labels.count("flash_fwd") == 1
@@ -532,6 +558,63 @@ def test_a_state_group_beside_pages_compiles_for_the_chip(program, mesh):
     peak = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert peak < tpu_aot.HBM_BUDGET
+
+
+@pytest.mark.parametrize("cell", [_mellum_cell, _qwen3_next_cell])
+def test_full_layers_of_a_step_share_one_walk(cell, mesh):
+    """The two claimed cells run two full-attention layers a decode step
+    (two periods of their layers here): the work list of live blocks
+    (``ops/_page_walk.py``) is the same for both, and the compiled decode
+    chunk builds it ONCE — one gather of the items' rows of 8 table entries
+    over the 2048-entry tables, both ``paged_attention`` calls under the
+    same traced grid bound — and once more for Mellum's six banded calls
+    over their 65-entry rings."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    import tpu_aot
+    from apex_tpu.serving import kv_pool
+    from apex_tpu.serving.scheduler import PagedDecodeEngine
+
+    model, pool = cell(periods=2)
+    slots = pool["slots"]
+    engine = PagedDecodeEngine(model, variables=None, num_slots=2,
+                               page_size=16, num_pages=3,
+                               max_pages_per_seq=pool["max_pages"],
+                               sync_every=4)
+    cache = jax.eval_shape(lambda: kv_pool.init_paged_cache(
+        model.config, slots, num_pages=pool["num_pages"], page_size=16,
+        max_pages_per_seq=pool["max_pages"]))
+    i32 = jnp.int32
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), i32)))
+    sds = jax.ShapeDtypeStruct
+    rest = [sds((slots,), i32), sds((slots,), jnp.bool_),
+            sds((slots,), i32), sds((slots, 2), jnp.uint32),
+            sds((slots,), i32)]
+    txt = tpu_aot.compile_replicated(mesh, engine._step_fn(),
+                                     [cache, variables] + rest,
+                                     (0,)).as_text()
+
+    def bounds(label):
+        return re.findall(r"custom-call\(([^,()]+), [^\n]*\n"
+                          rf'"kernel":"{label}"', txt)
+
+    def walks(n_items):
+        return len(re.findall(rf"= s32\[{n_items},8\]\S* gather\(", txt))
+
+    assert walks(slots * pool["max_pages"] // 8) == 1
+    full = bounds("paged_attention")
+    assert len(full) == 2 and full[0] == full[1], full
+    banded = bounds("paged_window_attention")
+    if cell is _mellum_cell:
+        assert walks(slots * 9) == 1           # cdiv(65, 8) blocks a ring
+        assert len(banded) == 6 and len(set(banded)) == 1, banded
+        assert banded[0] != full[0]
+    else:
+        assert not banded
 
 
 #: what ``ops/flash_attention.py``'s rule picks at the training cell's shape

@@ -5,6 +5,7 @@ seam and the routing counters, each against the plain reference
 (``benchmark/references/glm4_moe_lite.py``) or a hand count."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -216,6 +217,58 @@ def test_latent_kernel_matches_its_twin_and_expanded_attention(s_q):
         want = np.einsum("hst,htv->hsv", p, v)
         absorbed = np.einsum("hsr,hvr->hsv", got[b], w_ukv[:, nope:])
         np.testing.assert_allclose(absorbed, want, atol=5e-5)
+
+
+#: lengths over 40-entry tables of 8-token pages, 16 pages a block: the
+#: latent kernel's walk over live blocks (ISSUE 38, ops/_page_walk.py)
+_LATENT_WALKS = {
+    "ragged_with_idle_slots_first_last_and_between":
+        dict(lens=[0, 300, 0, 130, 17, 0]),
+    "a_block_edge_and_one_past_it": dict(lens=[128, 129, 256, 257]),
+    "every_slot_full": dict(lens=[320, 320, 320]),
+    "one_live_slot": dict(lens=[0, 0, 200, 0]),
+    "every_slot_idle": dict(lens=[0, 0]),
+    "query_block_of_4": dict(lens=[0, 131, 4, 260, 128], s_q=4),
+    "query_block_of_a_page": dict(lens=[8, 136, 0, 129, 264], s_q=8),
+    "bf16_entries": dict(lens=[300, 0, 129, 16], dtype=jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("name", list(_LATENT_WALKS))
+def test_latent_kernel_walks_the_live_blocks(name):
+    """Against the twin over whole tables: dead entries hold pages of NaN
+    (past a slot's end, and all of an idle slot's table), which a walk
+    that named one would carry into the result."""
+    case = _LATENT_WALKS[name]
+    lens, s_q = case["lens"], case.get("s_q", 1)
+    dtype = case.get("dtype", jnp.float32)
+    rng = np.random.default_rng(11)
+    heads, rank, stored, ps, mp = 3, 128, 256, 8, 40
+    slots = len(lens)
+    held = [-(-n // ps) for n in lens]
+    entries = rng.normal(size=(2 + sum(held), 1, ps, stored)).astype(
+        np.float32)
+    entries[..., rank + 8:] = 0.0
+    own = iter(rng.permutation(np.arange(2, len(entries))))
+    tables = np.zeros((slots, mp), np.int32)
+    for slot, n in enumerate(held):
+        tables[slot, :n] = [next(own) for _ in range(n)]
+    q = rng.normal(size=(slots, heads, s_q, stored)).astype(np.float32)
+    q[..., rank + 8:] = 0.0
+    args = (jnp.asarray(q, dtype), jnp.asarray(entries, dtype),
+            jnp.asarray(tables), jnp.asarray(lens, jnp.int32))
+    want = np.asarray(paged_latent_attention_reference(
+        *args, value_width=rank, scale=0.1), np.float32)
+    poisoned = np.where(tables == 0, 1, tables)
+    got = np.asarray(jax.jit(functools.partial(
+        paged_latent_attention, value_width=rank, scale=0.1))(
+        args[0], args[1].at[:2].set(jnp.nan), jnp.asarray(poisoned),
+        args[3]), np.float32)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    for slot, n in enumerate(lens):
+        if n == 0:
+            assert not got[slot].any()
 
 
 def _routed_layer(t=12, d=32, m=24, e=8, k=4, shared=1):

@@ -298,6 +298,31 @@ def test_undonated_scan_carry_gets_no_inplace_credit():
     assert est.alias_bytes == 0
 
 
+def test_traced_grid_bound_still_counts_as_pipelined():
+    """The paged kernels' walk axis has a traced bound (``n_work``): the
+    estimator cannot size it, and must still charge every block its two
+    pipeline buffers — at the Qwen3-Next cell's shape (64 slots of 2048
+    entries, 2 kv heads of 256) the K and V page blocks alone are the
+    512 KiB that ``_page_walk._tile`` budgets double-buffered."""
+    from apex_tpu.ops._page_walk import _tile
+    from apex_tpu.ops.paged_attention import paged_attention
+
+    bf16 = jnp.bfloat16
+    args = (_sds((64, 16, 1, 256), bf16), _sds((4096, 2, 16, 256), bf16),
+            _sds((4096, 2, 16, 256), bf16), _sds((64, 2048), i32),
+            _sds((64,), i32))
+    ir = build_case_ir(AnalysisCase(
+        "qwen3_next_paged", "test",
+        lambda: CaseProgram(fn=paged_attention, args=args)))
+    (call,) = estimate_case(ir).vmem
+    assert call.grid == (1, None)          # (kv // heads, n_work)
+    assert call.buffering == 2
+    pages, heads = _tile(2, 16, 256, bf16, 2048)
+    kv_blocks = 2 * pages * heads * 16 * 256 * 2       # K and V, one buffer
+    assert call.est_bytes >= 2 * kv_blocks == 512 * 1024
+    assert not _fired(ir)
+
+
 def test_per_chip_scope_on_shard_map_programs():
     ir = _ir_for(_donation_spec_good, "scope_case")
     est = estimate_case(ir)

@@ -273,11 +273,15 @@ def test_query_block_windowed_matches_reference(rng):
 # 8, 8 pages of 16), bounded by the slot's live pages
 # --------------------------------------------------------------------------
 
-def _scattered_tables(rng, lens, max_pages, num_pages, ps, dead="null"):
+def _scattered_tables(rng, lens, max_pages, num_pages, ps, dead="null",
+                      below_band=None):
     """Each slot's live entries hold pages of its own (scrambled); its
     dead entries hold the null page 0, or — ``dead="stolen"`` — live
     pages of the OTHER slots, which the kernel must never read as this
-    slot's."""
+    slot's. ``dead="dropped"`` also hands the entries wholly under the
+    earliest query's band (``below_band = s_q + window - 1`` positions
+    under the length) back to the null page, as the engine does
+    (``kv_pool.drop_slot_pages``)."""
     b = len(lens)
     free = list(rng.permutation(np.arange(1, num_pages)))
     bt = np.zeros((b, max_pages), np.int32)
@@ -291,6 +295,9 @@ def _scattered_tables(rng, lens, max_pages, num_pages, ps, dead="null"):
             others = [p for p in live if p not in mine]
             for j in range(-(-int(n) // ps), max_pages):
                 bt[i, j] = others[(i + j) % len(others)]
+    if dead == "dropped":
+        for i, n in enumerate(lens):
+            bt[i, :max(int(n) - below_band, 0) // ps] = 0
     return jnp.asarray(bt)
 
 
@@ -324,6 +331,31 @@ _BLOCK_CASES = {
                                dtype=jnp.bfloat16),
     "gqa_rep4_d128": dict(kv=2, rep=4, d=128, ps=16, mp=20,
                           lens=[320, 17, 128, 250]),
+    # the work list of live blocks (ISSUE 38, ops/_page_walk.py): 16
+    # pages of 8 a block, the grid's last axis as long as the list
+    "idle_slots_first_last_and_between": dict(
+        kv=2, rep=2, d=16, ps=8, mp=48, lens=[0, 300, 0, 0, 130, 17, 0]),
+    "a_block_edge_and_one_past_it": dict(
+        kv=2, rep=1, d=16, ps=8, mp=48, lens=[128, 129, 256, 257, 384]),
+    "every_slot_full": dict(                 # the list at its static length
+        kv=2, rep=1, d=16, ps=8, mp=32, lens=[256, 256, 256, 256]),
+    "every_slot_full_of_a_table_no_multiple_of_the_block": dict(
+        kv=2, rep=2, d=16, ps=8, mp=20, lens=[160, 160, 160]),
+    "one_live_slot": dict(kv=2, rep=1, d=16, ps=8, mp=48,
+                          lens=[0, 0, 0, 300, 0]),
+    "every_slot_idle": dict(kv=2, rep=1, d=16, ps=8, mp=32, lens=[0, 0, 0]),
+    "window_with_dropped_leading_pages": dict(
+        kv=2, rep=1, d=16, ps=8, mp=40, window=37, dead="dropped",
+        lens=[300, 0, 165, 128, 36, 1, 0]),
+    "window_query_block_of_a_page_dropped_leading_pages": dict(
+        kv=2, rep=2, d=16, ps=8, mp=40, window=50, s_q=8, dead="dropped",
+        lens=[300, 264, 0, 136, 8]),
+    "query_block_of_4_ragged": dict(
+        kv=2, rep=2, d=16, ps=8, mp=40, s_q=4, lens=[0, 131, 4, 260, 0, 128]),
+    "query_block_of_a_page_ragged": dict(
+        kv=2, rep=1, d=16, ps=8, mp=40, s_q=8, lens=[8, 136, 0, 129, 264]),
+    "head_block_of_a_vmem_split": dict(      # two head blocks walk one list
+        kv=64, rep=1, d=256, ps=64, mp=4, lens=[70, 0, 256, 129]),
 }
 
 
@@ -338,7 +370,8 @@ def test_page_block_tile_matches_reference(rng, name):
     P = 2 + sum(-(-n // ps) for n in lens)
     k_pages, v_pages = _pool(rng, P, kv, ps, d, dtype)
     q = jnp.asarray(rng.standard_normal((b, kv * rep, s_q, d)), dtype)
-    bt = _scattered_tables(rng, lens, mp, P, ps, c.get("dead", "null"))
+    bt = _scattered_tables(rng, lens, mp, P, ps, c.get("dead", "null"),
+                           below_band=s_q + (window or 0) - 1)
     ln = jnp.asarray(lens, jnp.int32)
     out = np.asarray(paged_attention(q, k_pages, v_pages, bt, ln,
                                      window=window), np.float32)
@@ -379,13 +412,17 @@ def test_page_block_tile_never_reads_a_dead_entry(rng, window):
     np.testing.assert_array_equal(out, want)
 
 
+@pytest.mark.parametrize("lens", [
+    [300, 129, 128, 9, 0], [0, 320, 0, 320, 0], [0, 0, 257, 0, 0],
+    [320, 320, 320, 320, 0]], ids=["ragged", "idle_between_full_slots",
+                                   "one_live_slot", "full_but_the_last"])
 @pytest.mark.parametrize("kv_dtype", ["int8", "fp8"])
-def test_page_block_tile_quantized_pool(rng, kv_dtype):
+def test_page_block_tile_quantized_pool(rng, kv_dtype, lens):
     """A quantized pool across block edges: the per-page per-head scales
-    ride the same clamped entries as the pages (a NaN scale on the null
-    page is never gathered into a live position's arithmetic)."""
+    ride the same clamped entries as the pages, gathered a work item at a
+    time (a NaN scale on the null page is never gathered into a live
+    position's arithmetic)."""
     kv, rep, d, ps, mp = 2, 2, 16, 8, 40
-    lens = [300, 129, 128, 9, 0]
     b = len(lens)
     P = 2 + sum(-(-n // ps) for n in lens)
     kf, vf = _pool(rng, P, kv, ps, d)
@@ -426,11 +463,62 @@ def test_pages_fetched_counts_whole_live_blocks(length, window, s_q, want):
     """What ``serving.kv_bytes_fetched`` charges a slot: every block of
     8 pages (the cell's tile: page 16, 20 heads of 64, bf16) that holds
     a live page, whole."""
-    from apex_tpu.ops.paged_attention import pages_fetched
+    from apex_tpu.ops._page_walk import pages_fetched
 
     assert pages_fetched(length, kv_heads=20, page_size=16, head_dim=64,
                          dtype=jnp.bfloat16, max_pages=64, s_q=s_q,
                          window=window) == want
+
+
+@pytest.mark.parametrize("window,s_q", [(None, 1), (100, 1), (5, 4),
+                                        (None, 16), (300, 16)])
+def test_the_walk_is_the_blocks_pages_fetched_charges(window, s_q):
+    """``ops/_page_walk.page_walk``: its traced bound ``n_work`` is the
+    count of blocks ``pages_fetched`` charges over the same lengths, so
+    ``serving.kv_bytes_fetched`` over a window is the grid steps the
+    kernels ran; and the list is what a loop over the slots writes —
+    slot-major, ascending in the block, every entry clamped into its
+    slot's live pages, an idle slot one item."""
+    from apex_tpu.ops._page_walk import _live_pages, page_walk
+    from apex_tpu.ops._page_walk import pages_fetched
+
+    ps, pages, mp = 16, 8, 64
+    lens = [0, 1, 127, 128, 129, 0, 450, 1024, 261, 16, 0]
+    bt = np.arange(1, len(lens) * mp + 1, dtype=np.int32).reshape(-1, mp)
+
+    @jax.jit
+    def walked(tables, lengths):
+        walk = page_walk(tables, lengths, page_size=ps, pages=pages,
+                         s_q=s_q, window=window)
+        return walk.prefetch, walk.n_work
+
+    prefetch, n_work = walked(jnp.asarray(bt), jnp.asarray(lens, jnp.int32))
+    n_work = int(n_work)
+    assert n_work == sum(pages_fetched(
+        n, kv_heads=20, page_size=ps, head_dim=64, dtype=jnp.bfloat16,
+        max_pages=mp, s_q=s_q, window=window) for n in lens) // pages
+    slot_of, block_of, phys, starts = [], [], [], [0]
+    for slot, n in enumerate(lens):
+        first, last = _live_pages(n, ps, s_q, window)
+        for block in range(first // pages, last // pages + 1):
+            slot_of.append(slot)
+            block_of.append(block)
+            phys.append([bt[slot, min(max(block * pages + i, first), last)]
+                         for i in range(pages)])
+        starts.append(len(slot_of))
+    got_phys, got_slot, got_block, got_starts, got_len = map(
+        np.asarray, prefetch)
+    assert n_work == len(slot_of) == got_starts[-1]
+    assert got_slot.shape == (len(lens) * mp // pages,)   # the worst case
+    np.testing.assert_array_equal(got_slot[:n_work], slot_of)
+    np.testing.assert_array_equal(got_block[:n_work], block_of)
+    np.testing.assert_array_equal(
+        got_phys.reshape(-1, pages)[:n_work], phys)
+    np.testing.assert_array_equal(got_starts, starts)
+    np.testing.assert_array_equal(got_len, lens)
+    # what lies past the bound is never run, and names a page all the same
+    assert set(got_slot[n_work:]) <= {len(lens) - 1}
+    assert np.isin(got_phys, bt).all()
 
 
 @pytest.mark.parametrize("kv,ps,d,dtype,mp,want", [
@@ -442,7 +530,7 @@ def test_pages_fetched_counts_whole_live_blocks(length, window, s_q, want):
     (64, 64, 256, jnp.float32, 64, (1, 32)),      # VMEM forces a head block
 ])
 def test_tile_is_derived_from_the_shapes(kv, ps, d, dtype, mp, want):
-    from apex_tpu.ops.paged_attention import _tile
+    from apex_tpu.ops._page_walk import _tile
 
     assert _tile(kv, ps, d, dtype, mp) == want
 
@@ -481,6 +569,14 @@ _PACKED_CASES = {
                                 pack=1),
     "width_128_is_one_head_a_row": dict(kv=2, h=4, d=128, s=1,
                                         lens=[12, 30], pack=1),
+    # tables of three blocks of 16 pages: the work list under a packed row
+    "pack2_idle_slots_between_blocks": dict(
+        kv=4, h=8, d=64, s=1, mp=40, lens=[0, 300, 0, 129, 128, 0], pack=2),
+    "pack2_every_slot_full_s4": dict(kv=2, h=2, d=64, s=4, mp=32,
+                                     lens=[256, 256, 256], pack=2),
+    "pack2_window_across_blocks_s8": dict(
+        kv=2, h=4, d=64, s=8, mp=40, lens=[300, 0, 140, 8], window=41,
+        pack=2),
 }
 
 
@@ -493,7 +589,7 @@ def test_packed_pool_matches_reference_and_the_unpacked_pool(rng, name):
     kv, h, d, s = case["kv"], case["h"], case["d"], case["s"]
     dtype = case.get("dtype", jnp.float32)
     window = case.get("window")
-    ps, mp = 8, 5
+    ps, mp = 8, case.get("mp", 5)
     b = len(case["lens"])
     P = b * mp + 2
     pack = kv_pool.heads_per_row(d, kv)

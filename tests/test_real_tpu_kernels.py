@@ -581,3 +581,44 @@ def test_paged_attention_head256_rep8_matches_reference_on_chip(s, tpu, rng):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=2e-2, atol=2e-2)
+
+
+def test_paged_attention_walks_the_newest_cells_contexts_on_chip(tpu, rng):
+    """``qwen3-next-80b-a3b.longchat-closed64``'s full-attention call: 64
+    slots behind 2048-entry tables, 2 key/value heads of 256, contexts of
+    the cell's four prompt lengths (some a few decoded tokens on, some at
+    the block's exact edge) and idle slots between them — a work list of
+    about a sixth of the table's blocks under a traced grid bound
+    (``ops/_page_walk.py``). The reference gathers whole tables, so it runs
+    eight slots at a time over the entries any slot holds."""
+    from apex_tpu.ops.paged_attention import (paged_attention,
+                                              paged_attention_reference)
+
+    slots, mp, ps = 64, 2048, 16
+    lengths = rng.choice([0, 1024, 4096, 8192, 16384], slots)
+    lengths[:5] = [0, 1024, 4096, 8192, 16384]          # every kind is there
+    lengths[-1] = 0                                     # idle first and last
+    lengths[8::2] += rng.integers(1, 300, len(lengths[8::2])) * (
+        lengths[8::2] > 0)
+    held = -(-lengths // ps)
+    num_pages = 1 + int(held.sum())
+    k_held, v_held = (jnp.asarray(
+        rng.standard_normal((num_pages, 2, ps, 256)), jnp.bfloat16)
+        for _ in range(2))
+    own = iter(rng.permutation(np.arange(1, num_pages)))
+    tables = np.zeros((slots, mp), np.int32)
+    for slot, n in enumerate(held):
+        tables[slot, :n] = [next(own) for _ in range(n)]
+    tables, lengths = jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+    q = jnp.asarray(rng.standard_normal((slots, 16, 1, 256)), jnp.bfloat16)
+    got = np.asarray(jax.jit(paged_attention)(q, k_held, v_held, tables,
+                                              lengths), np.float32)
+    reference = jax.jit(paged_attention_reference)
+    live = int(held.max())
+    for lo in range(0, slots, 8):
+        want = reference(q[lo:lo + 8], k_held, v_held,
+                         tables[lo:lo + 8, :live], lengths[lo:lo + 8])
+        np.testing.assert_allclose(got[lo:lo + 8],
+                                   np.asarray(want, np.float32),
+                                   rtol=2e-2, atol=2e-2)
+    assert (got[np.asarray(lengths) == 0] == 0).all()
