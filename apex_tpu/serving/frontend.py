@@ -61,6 +61,8 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import gc
+import heapq
 import itertools
 import queue as _queue
 import threading
@@ -131,10 +133,30 @@ class ServingError(RuntimeError):
 _PUMP_SERIES = ("pump.dispatch_ready_ms", "pump.host_work_ms",
                 "pump.bubble_ms")
 
-#: pump phases whose host seconds feed a ``serving.*`` counter on exit
-#: (every phase is a ``pump:<name>`` span in an xprof capture)
-_PHASE_COUNTERS = {"admission": "pump_admission_seconds",
+#: the pump's top-level phases, whose host seconds feed a ``serving.*``
+#: counter on exit; with ``wait_device`` (every host block on a device
+#: value, nested inside the others) they are an iteration's whole account.
+#: Every phase is a ``pump:<name>`` span in an xprof capture; the second
+#: level (``PUMP_SPANS``) is spans alone, read from the trace
+_PHASE_COUNTERS = {"dispatch": "pump_dispatch_seconds",
+                   "harvest": "pump_harvest_seconds",
+                   "housekeeping": "pump_housekeeping_seconds",
+                   "admission": "pump_admission_seconds",
                    "wait_device": "pump_blocked_seconds"}
+
+#: every span the pump writes beside the device line (``"pump:" + name``;
+#: docs/frontend.md "Measuring the pump" says what each wraps). The
+#: benchmark's idle-gap metrics find them by these names
+#: (``tests/test_kernel_labels.py`` pins them)
+PUMP_SPANS = tuple(_PHASE_COUNTERS) + (
+    "dispatch.launch", "dispatch.account",
+    "harvest.account", "retire", "retire.release",
+    "admission.request", "admission.match", "admission.pool",
+    "admission.launch", "admission.join", "admission.feed")
+
+#: how many of its longest iterations a pump keeps (``stats()
+#: ["pump.slowest"]``)
+_SLOWEST_KEPT = 8
 
 
 class StreamHandle:
@@ -363,7 +385,8 @@ class _Chunk:
     (an admission syncing on the pool materializes the chunk first) so
     ``decode_step_ms`` measures the chunk, not later host work."""
 
-    __slots__ = ("toks", "idx", "t0", "toks_np", "t_done", "routed")
+    __slots__ = ("toks", "idx", "t0", "toks_np", "t_done", "routed",
+                 "account")
 
     def __init__(self, toks, idx, t0, routed=()):
         self.toks = toks
@@ -374,6 +397,11 @@ class _Chunk:
         # per step what the routing did (ROUTING_STATS), device-side until
         # the harvest reads it with the tokens; () without routed experts
         self.routed = routed
+        # the chunk's own account ({counter: amount}: steps, busy
+        # slot-steps, K/V and state bytes), computed after the launch and
+        # added with the routing at harvest: every per-chunk counter
+        # describes the same completed chunks
+        self.account: Dict[str, float] = {}
 
 
 class ServingFrontend:
@@ -454,6 +482,20 @@ class ServingFrontend:
         self._bubble = metrics.gauge("pump.bubble_ms", labels=labels)
         self._last_ready: Optional[float] = None
         self._wait_s = 0.0
+        # this iteration's host seconds by top-level phase, and the
+        # longest iterations of this frontend's life (a heap of
+        # (wall_ms, iteration, record), the shortest kept on top)
+        self._iter_s = dict.fromkeys(_PHASE_COUNTERS, 0.0)
+        self._iterations = 0
+        self._finished = 0               # handles finished (``retired``)
+        self._slowest: List[tuple] = []
+        self._slowest_emitted = False
+        # seconds the process spent in Python's collector while the
+        # background pump ran (gc.callbacks; any thread's collection
+        # holds the interpreter, the pump's included); the counter
+        # serving.gc_pause_seconds holds the same sum
+        self._gc_s = 0.0
+        self._gc_t0: Optional[float] = None
         # per group of layers (kv_pool.layer_groups; one, unless the
         # model mixes windowed and full layers): the bytes one page and
         # one context token cost across the group's layers and all chips
@@ -497,6 +539,7 @@ class ServingFrontend:
         self._watch = compile_watch.watcher()
         self._jit0 = self._watch.counts()
         self._jit_totals0 = self._watch.totals()
+        self._compiles_seen = self._jit_totals0[0]
         self._storm_seen: set = set()
         self._thread: Optional[threading.Thread] = None
         self._stop_evt = threading.Event()
@@ -672,12 +715,15 @@ class ServingFrontend:
         self._inflight = None
         for entry in victims:
             entry.handle._fail(exc)
+        self._emit_slowest()
 
     # tpu-lint: host-boundary -- body of pump() (see above)
     def _pump_impl(self) -> bool:
         eng = self.engine
         t_iter0 = self.clock()
         self._wait_s = 0.0
+        self._iter_s = dict.fromkeys(_PHASE_COUNTERS, 0.0)
+        finished0, gc0 = self._finished, self._gc_s
         self._drain_ingest()
         prev, self._inflight = self._inflight, None
         if any(not e.prefilling for e in self._active.values()):
@@ -727,20 +773,24 @@ class ServingFrontend:
                 "page evicted (pool too small for its page demand?)")
         if self._pool_dirty:
             with self._phase("housekeeping"):
+                # the gauges read the pool on the host: a wait for the
+                # stream, named as one
+                self._await_pool(kv_pool.free_page_count(eng.cache))
                 kv_pool.observe_pool(eng.cache, labels=eng.obs_labels)
             self._pool_dirty = False
         self._qdepth.set(len(self._pending))
+        wall_s = self.clock() - t_iter0
         if prev is not None or admitted:
             # host cost of this iteration net of time blocked on the
             # device (_wait_s: every pump:wait_device phase) — with the
             # chunk in flight, this is the work the pipeline hides
             # (bubble_ms above is what leaked through)
-            host_ms = max(0.0, (self.clock() - t_iter0 - self._wait_s)
-                          * 1e3)
+            host_ms = max(0.0, (wall_s - self._wait_s) * 1e3)
             self._host_H.observe(host_ms)
             self._per_run["pump.host_work_ms"].append(host_ms)
             self._C["pump_iterations"].inc()
             self._C["pump_host_seconds"].inc(host_ms * 1e-3)
+        self._note_iteration(wall_s, admitted, finished0, gc0)
         self._check_compile_storm()
         # a pending entry held by backpressure does not count as live
         # work: the pump has nothing to do for it until its consumer
@@ -756,11 +806,15 @@ class ServingFrontend:
     @contextlib.contextmanager
     def _phase(self, name: str):
         """One pump phase: a ``pump:<name>`` host span on the profiler's
-        clock (beside the device line in an xprof capture), whose host
-        seconds feed ``_PHASE_COUNTERS[name]`` on exit. Phases are flat
-        except ``wait_device`` — every host block on a device value —
-        which nests inside the others: it sums into ``_wait_s``, and an
-        enclosing phase's seconds are NET of it."""
+        clock (beside the device line in an xprof capture). Phases nest
+        two levels deep, named where the work happens
+        (``pump:admission.launch`` inside ``pump:admission``): a phase's
+        seconds INCLUDE its children and are NET of every
+        ``wait_device`` under it (every host block on a device value
+        sums into ``_wait_s``); its self time is its seconds less its
+        children's. A top-level phase adds its seconds to
+        ``_PHASE_COUNTERS[name]`` and to this iteration's split on exit;
+        the second level is read from the trace and feeds nothing."""
         counter = _PHASE_COUNTERS.get(name)
         with jax.profiler.TraceAnnotation("pump:" + name):
             t0, waited0 = self.clock(), self._wait_s
@@ -773,12 +827,74 @@ class ServingFrontend:
                 else:
                     seconds -= self._wait_s - waited0
                 if counter is not None:
-                    self._C[counter].inc(max(0.0, seconds))
+                    seconds = max(0.0, seconds)
+                    self._C[counter].inc(seconds)
+                    self._iter_s[name] += seconds
 
     def _await(self, value) -> np.ndarray:
         """Block for a device value, as a ``pump:wait_device`` phase."""
         with self._phase("wait_device"):
             return np.asarray(value)
+
+    def _await_pool(self, value) -> np.ndarray:
+        """Block for a value of ``eng.cache``. The read waits for
+        everything queued on the stream, the chunk in flight included:
+        that chunk is stamped FIRST, so ``decode_step_ms`` never charges
+        later host work to it and the next dispatch finds the moment the
+        device went idle (``pump.bubble_ms``)."""
+        if self._inflight is not None:
+            self._materialize(self._inflight)
+        return self._await(value)
+
+    def _note_iteration(self, wall_s: float, admitted: int, finished0: int,
+                        gc0: float) -> None:
+        """Keep this iteration if it is among the ``_SLOWEST_KEPT``
+        longest of the frontend's life: what an untraced run can say of
+        a stall (``stats()["pump.slowest"]``; docs/observability.md
+        "Reading a postmortem"). One comparison an iteration."""
+        self._iterations += 1
+        compiles = self._watch.totals()[0]
+        compiled, self._compiles_seen = (compiles - self._compiles_seen,
+                                         compiles)
+        wall_ms = wall_s * 1e3
+        full = len(self._slowest) == _SLOWEST_KEPT
+        if full and wall_ms <= self._slowest[0][0]:
+            return
+        host_ms = {name: s * 1e3 for name, s in self._iter_s.items()
+                   if name != "wait_device"}
+        record = {"iteration": self._iterations, "wall_ms": wall_ms,
+                  "wait_ms": self._wait_s * 1e3, "host_ms": host_ms,
+                  "admitted": admitted,
+                  "retired": self._finished - finished0,
+                  "compiles": compiled,
+                  "gc_ms": (self._gc_s - gc0) * 1e3}
+        (heapq.heapreplace if full else heapq.heappush)(
+            self._slowest, (wall_ms, self._iterations, record))
+
+    def _slowest_records(self) -> List[dict]:
+        """The longest pump iterations so far, longest first."""
+        return [record for _, _, record
+                in sorted(self._slowest, reverse=True)]
+
+    def _emit_slowest(self) -> None:
+        """At the pump's end (death or shutdown), once: the longest
+        iterations go into the engine's event ring, so the postmortem
+        dump and the router's flight bundle carry them."""
+        if self._slowest_emitted:
+            return
+        self._slowest_emitted = True
+        self.engine.events.emit("pump_slowest",
+                                iterations=self._slowest_records())
+
+    def _gc_hook(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` entry while the background pump runs."""
+        if phase == "start":
+            self._gc_t0 = self.clock()
+        elif self._gc_t0 is not None:
+            pause = self.clock() - self._gc_t0
+            self._gc_t0 = None
+            self._gc_s += pause
+            self._C["gc_pause_seconds"].inc(pause)
 
     def _await_first_token(self, tok) -> int:
         """Block for an admission's sampled token. The chunk in flight
@@ -808,6 +924,10 @@ class ServingFrontend:
         self._stop_evt.clear()
 
         def loop():
+            # the collector's pauses, for the slowest iterations' record:
+            # hooked while this thread runs, so stop() / shutdown() and a
+            # pump's death all take it out again
+            gc.callbacks.append(self._gc_hook)
             try:
                 while not self._stop_evt.is_set():
                     if not self.pump():
@@ -819,6 +939,8 @@ class ServingFrontend:
                 # live handle; this covers an exception in the loop
                 # bookkeeping itself (idempotent either way)
                 self._fail_all(exc)
+            finally:
+                gc.callbacks.remove(self._gc_hook)
 
         self._thread = threading.Thread(target=loop, daemon=True,
                                         name="serving-frontend-pump")
@@ -937,6 +1059,7 @@ class ServingFrontend:
             entry.handle._fail(exc)
         self._occ.set(0)
         self._qdepth.set(0)
+        self._emit_slowest()
         if self.failure is None:
             kv_pool.observe_pool(self.engine.cache,
                                  labels=self.engine.obs_labels)
@@ -944,12 +1067,45 @@ class ServingFrontend:
     # --- device chunk dispatch/harvest --------------------------------------
 
     def _dispatch(self) -> None:
+        """Launch the next decode chunk, THEN work out its account: with
+        the device idle, nothing the host only measures stands between
+        it and the launch."""
         eng = self.engine
         self._chunk += 1
+        with self._phase("dispatch.launch"):
+            t0 = self.clock()
+            if eng.draft_len:
+                # speculative chunk: the payload is (target predictions,
+                # per-slot per-round acceptance counts) — the harvest
+                # emits toks[r, slot, :counts[r, slot]]
+                (eng.cache, eng.draft_cache, self._tok, self._done,
+                 self._n_left, toks, counts) = eng._spec_step_fn()(
+                    eng.cache, eng.draft_cache, eng.variables,
+                    eng.draft_variables, self._tok, self._done,
+                    self._n_left)
+                self._inflight = _Chunk((toks, counts), self._chunk, t0)
+            else:
+                (eng.cache, self._tok, self._done, self._n_left,
+                 self._samp_i, toks, routed) = eng._step_fn()(
+                    eng.cache, eng.variables, self._tok, self._done,
+                    self._n_left, self._req_keys, self._samp_i)
+                self._inflight = _Chunk(toks, self._chunk, t0, routed)
+        with self._phase("dispatch.account"):
+            self._inflight.account = self._chunk_account()
+        self.peak_slots = max(self.peak_slots, len(self._active))
+        self._occ.set(len(self._active))
+
+    def _chunk_account(self) -> Dict[str, float]:
+        """What the chunk just launched does, by counter, from the
+        entries' state as it entered: steps and busy slot-steps, the K/V
+        bytes its live slot-steps attend (and their split by kind of
+        layer), the bytes the kernel's page blocks move, the bytes the
+        decoding slots hold beside the sum of their contexts, and the
+        state groups' traffic. Added to the counters when the chunk is
+        HARVESTED (``_harvest``), with its routing."""
+        eng = self.engine
         decoding = [e for e in self._active.values()
                     if e.joined <= self._chunk]
-        self._C["busy_slot_steps"].inc(len(decoding) * eng.sync_every)
-        self._C["decode_steps"].inc(eng.sync_every)
         ps = eng.page_size
         full = banded = fetched = held = 0.0
         for kv in self._kv_groups:
@@ -971,36 +1127,21 @@ class ServingFrontend:
         # moved whole, in and out, by every step
         state = self._state_bytes_per_slot * len(decoding)
         held += state
-        self._C["state_bytes_moved"].inc(2 * state * eng.sync_every)
-        self._C["kv_bytes_attended"].inc(full + banded)
-        self._C["kv_full_bytes_attended"].inc(full)
-        self._C["kv_window_bytes_attended"].inc(banded)
-        self._C["kv_bytes_fetched"].inc(fetched)
-        self._C["kv_bytes_held_steps"].inc(held * eng.sync_every)
-        self._C["context_token_steps"].inc(eng.sync_every * sum(
-            e.s0 + (self._chunk - e.joined) * eng.sync_every + 1
-            for e in decoding))
-        t0 = self.clock()
-        if eng.draft_len:
-            # speculative chunk: the payload is (target predictions,
-            # per-slot per-round acceptance counts) — the harvest emits
-            # toks[r, slot, :counts[r, slot]]
-            (eng.cache, eng.draft_cache, self._tok, self._done,
-             self._n_left, toks, counts) = eng._spec_step_fn()(
-                eng.cache, eng.draft_cache, eng.variables,
-                eng.draft_variables, self._tok, self._done, self._n_left)
-            self._inflight = _Chunk((toks, counts), self._chunk, t0)
-        else:
-            (eng.cache, self._tok, self._done, self._n_left, self._samp_i,
-             toks, routed) = eng._step_fn()(
-                eng.cache, eng.variables, self._tok, self._done,
-                self._n_left, self._req_keys, self._samp_i)
-            self._inflight = _Chunk(toks, self._chunk, t0, routed)
-        self.peak_slots = max(self.peak_slots, len(self._active))
-        self._occ.set(len(self._active))
+        return {
+            "decode_steps": eng.sync_every,
+            "busy_slot_steps": len(decoding) * eng.sync_every,
+            "state_bytes_moved": 2 * state * eng.sync_every,
+            "kv_bytes_attended": full + banded,
+            "kv_full_bytes_attended": full,
+            "kv_window_bytes_attended": banded,
+            "kv_bytes_fetched": fetched,
+            "kv_bytes_held_steps": held * eng.sync_every,
+            "context_token_steps": eng.sync_every * sum(
+                e.s0 + (self._chunk - e.joined) * eng.sync_every + 1
+                for e in decoding)}
 
     def _tokens_attended(self, entry: _Entry, window: Optional[int]) -> int:
-        """Context tokens the chunk being dispatched attends for one
+        """Context tokens the chunk just launched attends for one
         decoding slot in a layer of the given ``window`` (``None``:
         full), summed over its LIVE steps: step ``j`` of a slot that has
         run ``ran`` steps since it joined reads the K/V of ``s0 + ran +
@@ -1019,7 +1160,7 @@ class ServingFrontend:
 
     def _pages_moved(self, entry: _Entry, kv: "_KvGroup") -> int:
         """Pages the decode kernel fetches for one decoding slot in a
-        layer of group ``kv`` over the chunk being dispatched, summed
+        layer of group ``kv`` over the chunk just launched, summed
         over ALL its steps: the kernel moves whole page blocks
         (``ops._page_walk.pages_fetched``), and a frozen step still
         runs the forward at the slot's last length. Over
@@ -1066,16 +1207,22 @@ class ServingFrontend:
             # preemption flush harvests mid-chunk and only its labeled
             # histogram keeps that wall time
             self._per_run["pump.dispatch_ready_ms"].append(chunk_ms)
-        if not isinstance(chunk.routed, tuple):
-            # the chunk's own account of its routing, ready with its tokens
-            # (three numbers a step, or the four of a layer that holds a
-            # share of its experts: zip stops at the vector's end)
-            routed = dict(zip(SHARE_ROUTING_STATS,
-                              np.asarray(chunk.routed).sum(axis=0).tolist()))
-            for name, n in routed.items():
+        with self._phase("harvest.account"):
+            # the ONE moment a chunk is counted: what it did by its own
+            # account (_chunk_account) and what its routing did, so any
+            # two snapshots of the counters hold the same whole chunks
+            account = chunk.account
+            if not isinstance(chunk.routed, tuple):
+                # ready with the tokens (three numbers a step, or the
+                # four of a layer that holds a share of its experts: zip
+                # stops at the vector's end)
+                routed = dict(zip(
+                    SHARE_ROUTING_STATS,
+                    np.asarray(chunk.routed).sum(axis=0).tolist()))
+                account = dict(account, **routed, expert_bytes_read=(
+                    routed["experts_hit"] * eng.cfg.routed_expert_bytes))
+            for name, n in account.items():
                 self._C[name].inc(n)
-            self._C["expert_bytes_read"].inc(
-                routed["experts_hit"] * eng.cfg.routed_expert_bytes)
         eos = eng.eos_token_id
         spec = isinstance(toks_np, tuple)
         if spec:
@@ -1171,31 +1318,34 @@ class ServingFrontend:
         the partial tail frees; without a prefix cache everything
         frees."""
         eng = self.engine
-        if eng.prefix is None:
-            eng.cache = eng._free_jit(eng.cache, jnp.int32(slot))
-            if eng.draft_len:
-                # the draft pool mirrors the target pool slot-for-slot
-                eng.draft_cache = eng._draft_free_jit(eng.draft_cache,
-                                                      jnp.int32(slot))
-            return
-        if entry.prefilling:
-            # a mid-prefill release (cancel/shutdown): only the chunks
-            # already fed are written — their full pages are cacheable
-            written = entry.pf_pos
-            seq = entry.prompt[:written]
-        else:
-            # written K/V = prompt + every token fed while alive (all but
-            # the final sampled token); only full pages are shareable
-            written = entry.s0 + len(entry.seg_tokens) - 1
-            seq = np.concatenate(
-                [entry.prompt, np.asarray(entry.seg_tokens[:-1],
-                                          np.int32)])
-        # the read waits for whatever produces ``eng.cache`` — with a chunk
-        # in flight, for that whole chunk
-        row = self._await(eng.cache["block_tables"][slot])
-        keep = eng.prefix.release_and_insert(seq, written, entry.nodes, row)
-        eng.cache = eng._release_jit(eng.cache, jnp.int32(slot),
-                                     jnp.asarray(keep))
+        with self._phase("retire.release"):
+            if eng.prefix is None:
+                eng.cache = eng._free_jit(eng.cache, jnp.int32(slot))
+                if eng.draft_len:
+                    # the draft pool mirrors the target pool slot-for-slot
+                    eng.draft_cache = eng._draft_free_jit(
+                        eng.draft_cache, jnp.int32(slot))
+                return
+            if entry.prefilling:
+                # a mid-prefill release (cancel/shutdown): only the chunks
+                # already fed are written — their full pages are cacheable
+                written = entry.pf_pos
+                seq = entry.prompt[:written]
+            else:
+                # written K/V = prompt + every token fed while alive (all
+                # but the final sampled token); only full pages are
+                # shareable
+                written = entry.s0 + len(entry.seg_tokens) - 1
+                seq = np.concatenate(
+                    [entry.prompt, np.asarray(entry.seg_tokens[:-1],
+                                              np.int32)])
+            # the read waits for whatever produces ``eng.cache`` — with a
+            # chunk in flight, for that whole chunk
+            row = self._await_pool(eng.cache["block_tables"][slot])
+            keep = eng.prefix.release_and_insert(seq, written, entry.nodes,
+                                                 row)
+            eng.cache = eng._release_jit(eng.cache, jnp.int32(slot),
+                                         jnp.asarray(keep))
 
     def _observe_lifecycle(self, idx) -> dict:
         life = self.tracer.lifecycle(idx)
@@ -1231,25 +1381,44 @@ class ServingFrontend:
         misses = sum(1 for _, m in self._slo_window if m)
         self._slo_burn.set(misses / len(self._slo_window))
 
-    def _retire(self, slot: int, *, cancelled: bool = False) -> None:
-        eng = self.engine
-        entry = self._active.pop(slot)
+    def _account_finish(self, entry: _Entry, *, slot: Optional[int],
+                        cancelled: bool) -> np.ndarray:
+        """The account of EVERY handle the frontend finishes, once,
+        wherever it is finished (a decoding slot, a slot mid-prefill, or
+        the queue: ``slot`` None): ``retired`` counts it, the tracer's
+        ``retire`` instant and the ring's ``retire`` / ``cancel`` event
+        name it. Returns the output; the caller hands it to the handle
+        once the slot's pages are released."""
         output = np.asarray(entry.prev + entry.seg_tokens, np.int32)
+        new_tokens = int(output.shape[0])
+        self._finished += 1
         self._C["retired"].inc()
-        n_seg = len(entry.seg_tokens)
-        self.tracer.end(entry.idx, "decode", new_tokens=n_seg)
         self.tracer.event(entry.idx, "retire", slot=slot,
-                          new_tokens=int(output.shape[0]),
-                          cancelled=cancelled)
-        eng.events.emit("cancel" if cancelled else "retire",
-                        request=entry.idx, slot=slot,
-                        new_tokens=int(output.shape[0]))
-        life = self._observe_lifecycle(entry.idx)
-        if not cancelled:
-            self._observe_slo(entry, life, self.clock())
-        self._release_pages(slot, entry)
-        self._pool_dirty = True
-        entry.handle._finish(output)
+                          new_tokens=new_tokens, cancelled=cancelled)
+        where = {"queued": True} if slot is None else {"slot": slot}
+        self.engine.events.emit("cancel" if cancelled else "retire",
+                                request=entry.idx, new_tokens=new_tokens,
+                                **where)
+        return output
+
+    def _retire(self, slot: int, *, cancelled: bool = False) -> None:
+        with self._phase("retire"):
+            entry = self._active.pop(slot)
+            if entry.prefilling:
+                # cancelled mid-prefill: no decode state exists
+                self.tracer.end(entry.idx, "prefill")
+            else:
+                self.tracer.end(entry.idx, "decode",
+                                new_tokens=len(entry.seg_tokens))
+            output = self._account_finish(entry, slot=slot,
+                                          cancelled=cancelled)
+            if not entry.prefilling:
+                life = self._observe_lifecycle(entry.idx)
+                if not cancelled:
+                    self._observe_slo(entry, life, self.clock())
+            self._release_pages(slot, entry)
+            self._pool_dirty = True
+            entry.handle._finish(output)
 
     def _preempt(self, slot: int) -> None:
         """Stop the victim at this (flushed) sync boundary, spill its
@@ -1413,11 +1582,7 @@ class ServingFrontend:
         if target <= m0:
             return nodes[:target]
         h = target - m0
-        # the pool read below syncs the stream — stamp the in-flight
-        # chunk first (same discipline as the admission's free read)
-        if self._inflight is not None:
-            self._materialize(self._inflight)
-        free = int(self._await(kv_pool.free_page_count(eng.cache)))
+        free = int(self._await_pool(kv_pool.free_page_count(eng.cache)))
         need_after = kv_pool.pages_for(s0 + entry.seg_new, ps) - target
         if free < h + need_after:
             # the tier swap: in a thrashing pool the stack is never
@@ -1478,6 +1643,39 @@ class ServingFrontend:
 
     # --- admission ----------------------------------------------------------
 
+    def _room_for(self, need: int, idx) -> int:
+        """Free pages for an admission that needs ``need``: the stack's
+        count, and where that is short the radix tree's LRU pages evicted
+        (demoted where there is a host tier) and, where the host's own
+        count says pages leaked, a defrag. Returns the free count."""
+        eng = self.engine
+        free = int(self._await_pool(kv_pool.free_page_count(eng.cache)))
+        if free < need and eng.prefix is not None:
+            victims: List[tuple] = []
+            sink = ((lambda path, page: victims.append((path, page)))
+                    if eng.host_tier is not None else None)
+            pages = eng.prefix.evict(need - free, sink=sink)
+            if victims:
+                # demote BEFORE the stack push: the gather is queued on
+                # the device stream ahead of any program that could
+                # re-allocate (and overwrite) the evicted pages
+                self._demote(victims)
+            if pages:
+                row = np.zeros((eng.cache["block_tables"].shape[1],),
+                               np.int32)
+                row[:len(pages)] = pages
+                eng.cache = eng._evict_jit(eng.cache, jnp.asarray(row),
+                                           jnp.int32(len(pages)))
+                self._C["evicted_pages"].inc(len(pages))
+                eng.events.emit("evict", request=idx, pages=len(pages))
+                free += len(pages)
+        if free < need and eng._leak_suspected(free, self._active):
+            eng._defrag_now()
+            self._C["defrag_runs"].inc()
+            eng.events.emit("defrag", request=idx)
+            free = int(self._await_pool(kv_pool.free_page_count(eng.cache)))
+        return free
+
     def _try_admit(self, entry: _Entry, slot: int, now: float) -> bool:
         """Admit ``entry`` into vacant ``slot`` if the pool can hold it
         (evicting/defragging as needed); False defers it (head-of-line:
@@ -1496,47 +1694,23 @@ class ServingFrontend:
         # prefix match BEFORE the page check: matched pages are shared,
         # not allocated, so they shrink the demand. Acquire immediately —
         # eviction below must see them pinned, not as LRU victims
-        nodes = eng.prefix.match(prompt) if eng.prefix is not None else []
-        if eng.host_tier is not None:
-            # tiered pool: extend the tree match with host-resident
-            # pages (promote instead of re-prefill); applies the match
-            # floor itself, so the plain floor below is the tier-off path
-            nodes = self._try_promote(entry, nodes)
-        elif not entry.resume:
-            nodes = nodes[:_bucket_match_pages(len(nodes))]
-        if nodes:
-            eng.prefix.acquire(nodes)
+        with self._phase("admission.match"):
+            nodes = (eng.prefix.match(prompt) if eng.prefix is not None
+                     else [])
+            if eng.host_tier is not None:
+                # tiered pool: extend the tree match with host-resident
+                # pages (promote instead of re-prefill); applies the
+                # match floor itself, so the plain floor below is the
+                # tier-off path
+                nodes = self._try_promote(entry, nodes)
+            elif not entry.resume:
+                nodes = nodes[:_bucket_match_pages(len(nodes))]
+            if nodes:
+                eng.prefix.acquire(nodes)
         m = len(nodes)
         need = need_total - m
-        # the pool read below waits for everything queued on the stream —
-        # including the in-flight chunk; stamp its completion FIRST so
-        # decode_step_ms never charges admission work to the chunk
-        if self._inflight is not None:
-            self._materialize(self._inflight)
-        free = int(self._await(kv_pool.free_page_count(eng.cache)))
-        if free < need and eng.prefix is not None:
-            victims: List[tuple] = []
-            sink = ((lambda path, page: victims.append((path, page)))
-                    if eng.host_tier is not None else None)
-            pages = eng.prefix.evict(need - free, sink=sink)
-            if victims:
-                # demote BEFORE the stack push: the gather is queued on
-                # the device stream ahead of any program that could
-                # re-allocate (and overwrite) the evicted pages
-                self._demote(victims)
-            if pages:
-                row = np.zeros((max_pages,), np.int32)
-                row[:len(pages)] = pages
-                eng.cache = eng._evict_jit(eng.cache, jnp.asarray(row),
-                                           jnp.int32(len(pages)))
-                self._C["evicted_pages"].inc(len(pages))
-                eng.events.emit("evict", request=idx, pages=len(pages))
-                free += len(pages)
-        if free < need and eng._leak_suspected(free, self._active):
-            eng._defrag_now()
-            self._C["defrag_runs"].inc()
-            eng.events.emit("defrag", request=idx)
-            free = int(self._await(kv_pool.free_page_count(eng.cache)))
+        with self._phase("admission.pool"):
+            free = self._room_for(need, idx)
         if free < need:
             if nodes:
                 eng.prefix.release(nodes)
@@ -1544,21 +1718,23 @@ class ServingFrontend:
             eng.events.emit("defer", request=idx, need_pages=need,
                             free_pages=free)
             return False
-        if entry.resume:
-            tr.end(idx, "preempted")
-            tr.event(idx, "resume", slot=slot, cached_pages=m,
-                     resumed_at=entry.generated)
-            self._C["resumes"].inc()
-            eng.events.emit("resume", request=idx, slot=slot,
-                            cached_pages=m)
-        t_admit = tr.event(idx, "admit", slot=slot, free_pages=free,
-                           cached_pages=m).t_start
-        if entry.t_admit is None:
-            # first admission only, as lifecycle() anchors queue_wait_ms
-            entry.t_admit = t_admit
-            self._C["queue_wait_seconds"].inc(
-                max(0.0, t_admit - entry.t_enqueue))
-        req_key = jax.random.fold_in(eng.rng, idx)
+        with self._phase("admission.launch"):
+            if entry.resume:
+                tr.end(idx, "preempted")
+                tr.event(idx, "resume", slot=slot, cached_pages=m,
+                         resumed_at=entry.generated)
+                self._C["resumes"].inc()
+                eng.events.emit("resume", request=idx, slot=slot,
+                                cached_pages=m)
+            t_admit = tr.event(idx, "admit", slot=slot, free_pages=free,
+                               cached_pages=m).t_start
+            if entry.t_admit is None:
+                # first admission only, as lifecycle() anchors
+                # queue_wait_ms
+                entry.t_admit = t_admit
+                self._C["queue_wait_seconds"].inc(
+                    max(0.0, t_admit - entry.t_enqueue))
+            req_key = jax.random.fold_in(eng.rng, idx)
         samp0 = len(entry.prev)          # resume continues the key stream
         # chunked prefill (docs/frontend.md): instead of one monolithic
         # contiguous prefill, allocate the pages now and feed the
@@ -1571,96 +1747,102 @@ class ServingFrontend:
                 and s0 + eng.prefill_chunk - 1 <= max_pages * ps):
             tr.begin(idx, "prefill", cached_tokens=m * ps,
                      computed_tokens=s0 - m * ps, chunked=True)
-            if m == 0:
-                eng.cache = eng._chunk_alloc_jit(
-                    eng.cache, jnp.int32(slot), jnp.int32(need))
-            else:
-                self._C["prefix_hits"].inc()
-                row = np.zeros((max_pages,), np.int32)
-                row[:m] = [n.page for n in nodes]
-                eng.cache = eng._chunk_alloc_shared_jit(
-                    eng.cache, jnp.int32(slot), jnp.asarray(row),
-                    jnp.int32(m), jnp.int32(need))
-            self._C["admitted"].inc()
-            self._C["chunked_prefills"].inc()
-            self._C["prefill_tokens_total"].inc(s0)
-            self._C["prefill_tokens_computed"].inc(s0 - m * ps)
-            eng.events.emit("admit", request=idx, slot=slot,
-                            prompt_tokens=s0, cached_tokens=m * ps,
-                            priority=entry.priority, chunked=True)
-            entry.nodes = nodes
-            entry.n_private = need
-            entry.win_dropped = 0
-            entry.seg_tokens = []
-            entry.prefilling = True
-            entry.pf_pos = m * ps
-            entry.pf_key = req_key
-            entry.pf_samp0 = samp0
-            # no harvestable decode tokens until the prefill finishes
-            entry.joined = self._chunk + (1 << 30)
-            self._active[slot] = entry
-            self._pool_dirty = True
+            with self._phase("admission.launch"):
+                if m == 0:
+                    eng.cache = eng._chunk_alloc_jit(
+                        eng.cache, jnp.int32(slot), jnp.int32(need))
+                else:
+                    self._C["prefix_hits"].inc()
+                    row = np.zeros((max_pages,), np.int32)
+                    row[:m] = [n.page for n in nodes]
+                    eng.cache = eng._chunk_alloc_shared_jit(
+                        eng.cache, jnp.int32(slot), jnp.asarray(row),
+                        jnp.int32(m), jnp.int32(need))
+            with self._phase("admission.join"):
+                self._C["admitted"].inc()
+                self._C["chunked_prefills"].inc()
+                self._C["prefill_tokens_total"].inc(s0)
+                self._C["prefill_tokens_computed"].inc(s0 - m * ps)
+                eng.events.emit("admit", request=idx, slot=slot,
+                                prompt_tokens=s0, cached_tokens=m * ps,
+                                priority=entry.priority, chunked=True)
+                entry.nodes = nodes
+                entry.n_private = need
+                entry.win_dropped = 0
+                entry.seg_tokens = []
+                entry.prefilling = True
+                entry.pf_pos = m * ps
+                entry.pf_key = req_key
+                entry.pf_samp0 = samp0
+                # no harvestable decode tokens until the prefill finishes
+                entry.joined = self._chunk + (1 << 30)
+                self._active[slot] = entry
+                self._pool_dirty = True
             self._feed_chunk(slot, entry)    # first chunk rides now
             return True
         # prefill span: covers the admission program AND the first-token
         # sync — its end IS the first token's arrival
         with tr.span(idx, "prefill", cached_tokens=m * ps,
                      computed_tokens=s0 - m * ps):
-            if m == 0:
-                admit_fn, bucket = self.admission_program(s0)
-                ids = np.zeros((1, bucket), np.int32)
-                ids[0, :s0] = prompt
-                if eng.draft_len:
-                    # speculative admission prefills the draft pool too
-                    eng.cache, eng.draft_cache, tok0 = admit_fn(
-                        eng.cache, eng.draft_cache, eng.variables,
-                        eng.draft_variables, jnp.asarray(ids),
-                        jnp.int32(s0), jnp.int32(slot), jnp.int32(need),
-                        req_key, jnp.int32(samp0))
+            with self._phase("admission.launch"):
+                if m == 0:
+                    admit_fn, bucket = self.admission_program(s0)
+                    ids = np.zeros((1, bucket), np.int32)
+                    ids[0, :s0] = prompt
+                    if eng.draft_len:
+                        # speculative admission prefills the draft pool
+                        # too
+                        eng.cache, eng.draft_cache, tok0 = admit_fn(
+                            eng.cache, eng.draft_cache, eng.variables,
+                            eng.draft_variables, jnp.asarray(ids),
+                            jnp.int32(s0), jnp.int32(slot),
+                            jnp.int32(need), req_key, jnp.int32(samp0))
+                    else:
+                        eng.cache, tok0 = admit_fn(
+                            eng.cache, eng.variables, jnp.asarray(ids),
+                            jnp.int32(s0), jnp.int32(slot),
+                            jnp.int32(need), req_key, jnp.int32(samp0))
                 else:
-                    eng.cache, tok0 = admit_fn(
+                    self._C["prefix_hits"].inc()
+                    t_start = m * ps
+                    tail_bucket = min(round_up(s0 - t_start, ps),
+                                      cfg.max_position_embeddings - t_start)
+                    ids = np.zeros((1, tail_bucket), np.int32)
+                    ids[0, :s0 - t_start] = prompt[t_start:]
+                    row = np.zeros((max_pages,), np.int32)
+                    row[:m] = [n.page for n in nodes]
+                    eng.cache, tok0 = eng._admit_shared_fn(
+                        t_start, tail_bucket)(
                         eng.cache, eng.variables, jnp.asarray(ids),
-                        jnp.int32(s0), jnp.int32(slot), jnp.int32(need),
-                        req_key, jnp.int32(samp0))
-            else:
-                self._C["prefix_hits"].inc()
-                t_start = m * ps
-                tail_bucket = min(round_up(s0 - t_start, ps),
-                                  cfg.max_position_embeddings - t_start)
-                ids = np.zeros((1, tail_bucket), np.int32)
-                ids[0, :s0 - t_start] = prompt[t_start:]
-                row = np.zeros((max_pages,), np.int32)
-                row[:m] = [n.page for n in nodes]
-                eng.cache, tok0 = eng._admit_shared_fn(
-                    t_start, tail_bucket)(
-                    eng.cache, eng.variables, jnp.asarray(ids),
-                    jnp.int32(s0), jnp.int32(slot), jnp.asarray(row),
-                    jnp.int32(need), req_key, jnp.int32(samp0))
+                        jnp.int32(s0), jnp.int32(slot), jnp.asarray(row),
+                        jnp.int32(need), req_key, jnp.int32(samp0))
             tok0 = self._await_first_token(tok0)
-        self._first_token(entry, slot)
-        tr.begin(idx, "decode", slot=slot)
-        self._C["admitted"].inc()
-        self._C["prefill_tokens_total"].inc(s0)
-        self._C["prefill_tokens_computed"].inc(s0 - m * ps)
-        eng.events.emit("admit", request=idx, slot=slot, prompt_tokens=s0,
-                        cached_tokens=m * ps, priority=entry.priority)
-        entry.nodes = nodes
-        entry.n_private = need
-        entry.win_dropped = 0            # fresh row: nothing dropped yet
-        entry.seg_tokens = [tok0]
-        entry.joined = self._chunk + 1
-        self._active[slot] = entry
-        entry.handle._push(tok0)
-        self._pool_dirty = True
-        if ((eng.eos_token_id is not None and tok0 == eng.eos_token_id)
-                or entry.seg_new == 1):
-            self._retire(slot)
-            return True
-        self._tok = self._tok.at[slot].set(tok0)
-        self._done = self._done.at[slot].set(False)
-        self._n_left = self._n_left.at[slot].set(entry.seg_new - 1)
-        self._samp_i = self._samp_i.at[slot].set(samp0 + 1)
-        self._req_keys = self._req_keys.at[slot].set(req_key)
+        with self._phase("admission.join"):
+            self._first_token(entry, slot)
+            tr.begin(idx, "decode", slot=slot)
+            self._C["admitted"].inc()
+            self._C["prefill_tokens_total"].inc(s0)
+            self._C["prefill_tokens_computed"].inc(s0 - m * ps)
+            eng.events.emit("admit", request=idx, slot=slot,
+                            prompt_tokens=s0, cached_tokens=m * ps,
+                            priority=entry.priority)
+            entry.nodes = nodes
+            entry.n_private = need
+            entry.win_dropped = 0        # fresh row: nothing dropped yet
+            entry.seg_tokens = [tok0]
+            entry.joined = self._chunk + 1
+            self._active[slot] = entry
+            entry.handle._push(tok0)
+            self._pool_dirty = True
+            if ((eng.eos_token_id is not None and tok0 == eng.eos_token_id)
+                    or entry.seg_new == 1):
+                self._retire(slot)
+                return True
+            self._tok = self._tok.at[slot].set(tok0)
+            self._done = self._done.at[slot].set(False)
+            self._n_left = self._n_left.at[slot].set(entry.seg_new - 1)
+            self._samp_i = self._samp_i.at[slot].set(samp0 + 1)
+            self._req_keys = self._req_keys.at[slot].set(req_key)
         return True
 
     def _advance_prefills(self) -> bool:
@@ -1676,7 +1858,10 @@ class ServingFrontend:
             if entry is None or not entry.prefilling:
                 continue
             if entry.handle.cancelled:
-                self._abort_prefill(slot, entry)
+                # no decode state exists: the pages go back (full fed
+                # pages still cacheable) and the handle finishes with
+                # the earlier segments' tokens
+                self._retire(slot, cancelled=True)
                 continue
             self._feed_chunk(slot, entry)
             advanced = True
@@ -1685,23 +1870,26 @@ class ServingFrontend:
     def _feed_chunk(self, slot: int, entry: _Entry) -> None:
         eng = self.engine
         C = eng.prefill_chunk
-        t, s0 = entry.pf_pos, entry.s0
-        valid = min(C, s0 - t)
-        ids = np.zeros((1, C), np.int32)     # final chunk zero-pads
-        ids[0, :valid] = entry.prompt[t:t + valid]
-        eng.cache, tok = eng._prefill_chunk_fn()(
-            eng.cache, eng.variables, jnp.asarray(ids), jnp.int32(slot),
-            jnp.int32(valid), entry.pf_key, jnp.int32(entry.pf_samp0))
-        entry.pf_pos = t + valid
-        self._C["prefill_chunks"].inc()
-        if entry.pf_pos >= s0:
+        with self._phase("admission.feed"):
+            t, s0 = entry.pf_pos, entry.s0
+            valid = min(C, s0 - t)
+            ids = np.zeros((1, C), np.int32)     # final chunk zero-pads
+            ids[0, :valid] = entry.prompt[t:t + valid]
+            eng.cache, tok = eng._prefill_chunk_fn()(
+                eng.cache, eng.variables, jnp.asarray(ids),
+                jnp.int32(slot), jnp.int32(valid), entry.pf_key,
+                jnp.int32(entry.pf_samp0))
+            entry.pf_pos = t + valid
+            self._C["prefill_chunks"].inc()
+            if entry.pf_pos < s0:
+                return
             # the first-token sync below waits on the whole stream —
             # stamp the in-flight decode chunk's completion first so
             # decode_step_ms never charges prefill work to it
             if self._inflight is not None:
                 self._materialize(self._inflight)
-            self._finish_prefill(slot, entry,
-                                 self._await_first_token(tok))
+            tok0 = self._await_first_token(tok)
+        self._finish_prefill(slot, entry, tok0)
 
     def _first_token(self, entry: _Entry, slot: int) -> None:
         """The ``first_token`` instant and what hangs on it, exactly once
@@ -1729,39 +1917,24 @@ class ServingFrontend:
         eng = self.engine
         tr = self.tracer
         idx = entry.idx
-        entry.prefilling = False
-        tr.end(idx, "prefill")
-        self._first_token(entry, slot)
-        tr.begin(idx, "decode", slot=slot)
-        entry.seg_tokens = [tok0]
-        entry.joined = self._chunk + 1
-        entry.handle._push(tok0)
-        self._pool_dirty = True
-        if ((eng.eos_token_id is not None and tok0 == eng.eos_token_id)
-                or entry.seg_new == 1):
-            self._retire(slot)
-            return
-        self._tok = self._tok.at[slot].set(tok0)
-        self._done = self._done.at[slot].set(False)
-        self._n_left = self._n_left.at[slot].set(entry.seg_new - 1)
-        self._samp_i = self._samp_i.at[slot].set(entry.pf_samp0 + 1)
-        self._req_keys = self._req_keys.at[slot].set(entry.pf_key)
-
-    def _abort_prefill(self, slot: int, entry: _Entry) -> None:
-        """Cancellation mid-prefill: no decode state exists — release
-        the pages (full fed pages still cacheable) and finish the handle
-        with the earlier segments' tokens."""
-        eng = self.engine
-        self._active.pop(slot)
-        self._C["retired"].inc()
-        self.tracer.end(entry.idx, "prefill")
-        self.tracer.event(entry.idx, "retire", slot=slot,
-                          new_tokens=len(entry.prev), cancelled=True)
-        eng.events.emit("cancel", request=entry.idx, slot=slot,
-                        new_tokens=len(entry.prev))
-        self._release_pages(slot, entry)
-        self._pool_dirty = True
-        entry.handle._finish(np.asarray(entry.prev, np.int32))
+        with self._phase("admission.join"):
+            entry.prefilling = False
+            tr.end(idx, "prefill")
+            self._first_token(entry, slot)
+            tr.begin(idx, "decode", slot=slot)
+            entry.seg_tokens = [tok0]
+            entry.joined = self._chunk + 1
+            entry.handle._push(tok0)
+            self._pool_dirty = True
+            if ((eng.eos_token_id is not None and tok0 == eng.eos_token_id)
+                    or entry.seg_new == 1):
+                self._retire(slot)
+                return
+            self._tok = self._tok.at[slot].set(tok0)
+            self._done = self._done.at[slot].set(False)
+            self._n_left = self._n_left.at[slot].set(entry.seg_new - 1)
+            self._samp_i = self._samp_i.at[slot].set(entry.pf_samp0 + 1)
+            self._req_keys = self._req_keys.at[slot].set(entry.pf_key)
 
     def _admission(self) -> int:
         """Fill vacant slots from the policy-ordered pending queue;
@@ -1788,10 +1961,14 @@ class ServingFrontend:
         while self._pending:
             entry = self._pending[0]
             if entry.handle.cancelled:
+                # cancelled in the queue, before its first admission or
+                # while it waits for a slot again after a preemption:
+                # finished and counted like any other
                 self._pending.pop(0)
-                eng.events.emit("cancel", request=entry.idx, queued=True)
+                if entry.resume:
+                    self.tracer.end(entry.idx, "preempted")
                 entry.handle._finish(
-                    np.asarray(entry.prev, np.int32))
+                    self._account_finish(entry, slot=None, cancelled=True))
                 continue
             free_slots = [s for s in range(eng.num_slots)
                           if s not in self._active]
@@ -1800,7 +1977,9 @@ class ServingFrontend:
                     preempts_left -= 1
                     continue
                 break
-            if self._try_admit(entry, free_slots[0], now):
+            with self._phase("admission.request"):
+                done = self._try_admit(entry, free_slots[0], now)
+            if done:
                 self._pending.pop(0)
                 admitted += 1
                 continue
@@ -1944,4 +2123,7 @@ class ServingFrontend:
              "pages_held": (paged[g].ring * eng.num_slots if paged[g].ring
                             else kv_pool.num_pages_of(eng.cache) - 1)}
             for g in eng.groups]
+        # the longest iterations of this frontend's life, longest first:
+        # what an untraced run can say of a stall
+        stats["pump.slowest"] = self._slowest_records()
         return stats

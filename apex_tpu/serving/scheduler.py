@@ -95,17 +95,23 @@ _RUN_COUNTERS = ("admitted", "retired", "decode_steps", "busy_slot_steps",
                  "spec_rounds", "spec_tokens", "chunked_prefills",
                  "prefill_chunks",
                  # the pump's own account, as counters of host seconds
-                 # (docs/frontend.md "Measuring the pump"), the two
-                 # halves of TTFT summed over requests, and the K/V bytes
-                 # the decode steps' attention must read and the bytes
-                 # the kernel's page blocks move for them
+                 # (docs/frontend.md "Measuring the pump": the iterations'
+                 # host work and its four top-level phases, the waits, the
+                 # bubbles), the two halves of TTFT summed over requests,
+                 # and the K/V bytes the decode steps' attention must read
+                 # and the bytes the kernel's page blocks move for them.
+                 # Every counter that describes a decode chunk (the steps,
+                 # the bytes, the routing below) is added when the chunk is
+                 # HARVESTED: they all count the same completed chunks
                  "pump_iterations", "pump_host_seconds",
+                 "pump_dispatch_seconds", "pump_harvest_seconds",
+                 "pump_housekeeping_seconds",
                  "pump_admission_seconds", "pump_blocked_seconds",
                  "pump_bubble_seconds", "queue_wait_seconds",
                  "first_token_wait_seconds", "kv_bytes_attended",
                  "kv_bytes_fetched",
                  # kv_bytes_attended split by kind of layer (full /
-                 # windowed: kv_pool.layer_groups), and per dispatch the
+                 # windowed: kv_pool.layer_groups), and per chunk the
                  # bytes of the pages the decoding slots own in all groups
                  # x sync_every beside the sum of their context lengths x
                  # sync_every: their ratio is what a live token costs
@@ -120,11 +126,15 @@ _RUN_COUNTERS = ("admitted", "retired", "decode_steps", "busy_slot_steps",
                  "expert_bytes_read",
                  # a chip that holds a SHARE of a layer's experts counts the
                  # four above over the held ones, and here the routed pairs
-                 # whose expert lies on another chip; and per dispatch the
+                 # whose expert lies on another chip; and per chunk the
                  # bytes the state groups' layers (kv_pool.layer_groups: one
                  # recurrent state a slot) read and write for the decoding
                  # slots x sync_every
-                 "expert_pairs_elsewhere", "state_bytes_moved")
+                 "expert_pairs_elsewhere", "state_bytes_moved",
+                 # seconds the process spent in Python's collector while
+                 # the background pump ran (any thread's collection holds
+                 # the interpreter, the pump's included)
+                 "gc_pause_seconds")
 
 #: per-request latency histograms (``serving.<name>``, log-bucketed ms)
 _RUN_HISTOGRAMS = ("ttft_ms", "tpot_ms", "queue_wait_ms", "decode_step_ms")

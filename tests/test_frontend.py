@@ -935,3 +935,357 @@ def test_kv_bytes_fetched_counts_whole_page_blocks(rng):
     assert d["kv_bytes_fetched"] == d["busy_slot_steps"] * 16 * \
         kv_pool.page_bytes(cfg, fe.engine.page_size)
     assert 0 < d["kv_bytes_attended"] < d["kv_bytes_fetched"]
+
+
+# --------------------------------------------------------------------------
+# the two-level account, counted at one moment (PR 39)
+# --------------------------------------------------------------------------
+
+PER_CHUNK = ("decode_steps", "busy_slot_steps", "kv_bytes_attended",
+             "kv_full_bytes_attended", "kv_window_bytes_attended",
+             "kv_bytes_fetched", "kv_bytes_held_steps",
+             "context_token_steps", "state_bytes_moved",
+             "expert_pairs_routed", "experts_hit", "expert_load_max",
+             "expert_bytes_read")
+
+
+def test_a_parent_phase_holds_its_children_and_no_wait(rng):
+    """A phase's seconds include its children's and are net of every
+    ``wait_device`` under it, at either level; the second level feeds no
+    counter of its own."""
+    cfg, fe, clk = _clocked_frontend()
+    before = fe.counter_deltas()
+    with fe._phase("admission"):
+        clk.jump(1.0)
+        with fe._phase("admission.launch"):
+            clk.jump(2.0)
+            assert int(fe._await(_SlowValue(7, clk, 5.0))) == 7
+        with fe._phase("wait_device"):
+            clk.jump(7.0)
+        clk.jump(0.5)
+    with fe._phase("retire"):            # a child with no parent open
+        clk.jump(3.0)
+    after = fe.counter_deltas()
+    moved = {k: after[k] - before[k] for k in after if after[k] != before[k]}
+    assert set(moved) == {"pump_admission_seconds", "pump_blocked_seconds"}
+    # the clock ticks a millisecond a read: eight reads inside the parent
+    assert moved["pump_admission_seconds"] == pytest.approx(3.5, abs=0.01)
+    assert moved["pump_blocked_seconds"] == pytest.approx(12.0, abs=0.01)
+    assert fe._iter_s["admission"] == pytest.approx(
+        moved["pump_admission_seconds"])
+    assert fe._iter_s["wait_device"] == pytest.approx(
+        moved["pump_blocked_seconds"])
+
+
+def test_the_four_host_phases_are_the_iterations_host_work(rng):
+    """dispatch + harvest + housekeeping + admission, each net of its
+    waits, are the iterations' host seconds but for what lies between
+    phases (a clock tick here and there)."""
+    cfg, fe, clk = _clocked_frontend(prefix_cache=True)
+    for i, r in enumerate(_requests(rng, cfg, [(9, 6), (17, 5), (9, 4)])):
+        fe.submit(r, request_id=i)
+    fe.drain()
+    d = fe.counter_deltas()
+    phases = sum(d[f"pump_{p}_seconds"] for p in (
+        "dispatch", "harvest", "housekeeping", "admission"))
+    assert all(d[f"pump_{p}_seconds"] > 0.0 for p in (
+        "dispatch", "harvest", "housekeeping", "admission"))
+    between = d["pump_host_seconds"] - phases
+    # between phases the pump reads its clock a few times an iteration
+    assert 0.0 <= between <= 0.02 * d["pump_iterations"]
+
+
+@pytest.fixture(scope="module")
+def routed_tiny():
+    """The tiny routed model of tests/test_glm4_moe_lite.py: a decode
+    chunk hands its routing back with its tokens."""
+    from apex_tpu.models.glm4_moe_lite import (Glm4MoeLiteModel,
+                                               glm4_moe_lite_tiny_config)
+    from benchmark.harness import weights
+
+    cfg = glm4_moe_lite_tiny_config()
+    model = Glm4MoeLiteModel(cfg)
+    like = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                          jax.ShapeDtypeStruct((1, 8), jnp.int32))
+    return cfg, model, {"params": weights.make_like(like["params"],
+                                                    20261001)}
+
+
+def _chunk_counts(cfg, chunk):
+    """What harvesting ``chunk`` adds to the per-chunk counters."""
+    from apex_tpu.serving.scheduler import SHARE_ROUTING_STATS
+
+    counts = dict(chunk.account)
+    if not isinstance(chunk.routed, tuple):
+        routed = dict(zip(SHARE_ROUTING_STATS,
+                          np.asarray(chunk.routed).sum(axis=0).tolist()))
+        counts.update(routed, expert_bytes_read=(
+            routed["experts_hit"] * cfg.routed_expert_bytes))
+    return counts
+
+
+@pytest.mark.parametrize("case", ["steady", "preempt_flush",
+                                  "shutdown_cancel"])
+def test_every_per_chunk_counter_counts_the_harvested_chunks(routed_tiny,
+                                                             case):
+    """One moment for the whole account: after a dispatch and before its
+    harvest NO per-chunk counter has moved, after the harvest all have,
+    and between ANY two snapshots every one of them holds exactly the
+    chunks harvested in between, so every ratio of two of them
+    (``experts_hit / decode_steps``, ``busy_slot_steps / decode_steps``,
+    ``kv_bytes_attended / decode_steps``) is exact. A preemption flush
+    harvests and counts; a chunk in flight at ``shutdown(mode="cancel")``
+    counts if and only if it is harvested."""
+    cfg, model, variables = routed_tiny
+    engine = PagedDecodeEngine(model, variables, num_slots=2, page_size=8,
+                               num_pages=24, sync_every=2,
+                               prefix_cache=True)
+    fe = ServingFrontend(engine, policy=PriorityDeadlinePolicy(
+        preempt_on_priority=True))
+    harvested = []                       # (chunk, phase) in harvest order
+    harvest = fe._harvest
+
+    def spy(chunk, *, phase="steady"):
+        harvested.append((chunk, phase))
+        return harvest(chunk, phase=phase)
+
+    fe._harvest = spy
+    local = np.random.default_rng(5)
+    for i, n in enumerate((9, 12)):
+        fe.submit(Request(prompt=local.integers(4, cfg.vocab_size, n
+                                                ).astype(np.int32),
+                          max_new_tokens=9, priority=0), request_id=i)
+    snapshots = []                       # (chunks harvested, counters)
+
+    def snap():
+        d = fe.counter_deltas()
+        snapshots.append((len(harvested), {k: d[k] for k in PER_CHUNK}))
+
+    snap()
+    fe.pump()                            # admits both
+    fe.pump()                            # dispatches chunk 1
+    assert fe._inflight is not None and not harvested
+    snap()
+    assert not any(snapshots[-1][1].values()), \
+        "a per-chunk counter moved between a dispatch and its harvest"
+    fe.pump()                            # harvests 1, dispatches 2
+    snap()
+    first = snapshots[-1][1]
+    assert first["decode_steps"] == engine.sync_every
+    assert first["busy_slot_steps"] == 2 * engine.sync_every
+    assert all(first[k] > 0 for k in PER_CHUNK if k not in (
+        "kv_window_bytes_attended", "state_bytes_moved"))
+    if case == "preempt_flush":
+        fe.submit(Request(prompt=local.integers(4, cfg.vocab_size, 10
+                                                ).astype(np.int32),
+                          max_new_tokens=3, priority=9), request_id=2)
+        fe.pump()
+        snap()
+        assert fe.counter_deltas()["preemptions"] == 1
+        assert "preempt" in [phase for _, phase in harvested]
+    if case == "shutdown_cancel":
+        assert fe._inflight is not None
+        fe.shutdown(mode="cancel")
+        snap()
+    else:
+        alive = True
+        while alive:
+            alive = fe.pump()
+            snap()
+    # between any two snapshots: exactly the chunks harvested in between
+    for i, (n0, c0) in enumerate(snapshots):
+        for n1, c1 in snapshots[i + 1:]:
+            want = dict.fromkeys(PER_CHUNK, 0.0)
+            for chunk, _ in harvested[n0:n1]:
+                for name, n in _chunk_counts(cfg, chunk).items():
+                    want[name] += n
+            assert {k: c1[k] - c0[k] for k in PER_CHUNK} == \
+                pytest.approx(want)
+    total = snapshots[-1][1]
+    assert total["decode_steps"] == engine.sync_every * len(harvested)
+    assert total["experts_hit"] / total["decode_steps"] == pytest.approx(
+        sum(_chunk_counts(cfg, c)["experts_hit"] for c, _ in harvested)
+        / (engine.sync_every * len(harvested)))
+
+
+def test_a_retirement_stamps_the_chunk_in_flight_before_its_read(rng):
+    """With the prefix cache on a retirement reads the slot's block table,
+    which waits for the chunk in flight: that chunk is stamped before the
+    read, so its ``decode_step_ms`` holds no later host work and the NEXT
+    dispatch records the bubble the drained device sat through."""
+    cfg, fe, clk = _clocked_frontend(prefix_cache=True)
+    stamped = []
+    release = fe._release_pages
+    await_ = fe._await
+
+    def releasing(slot, entry):
+        inflight = fe._inflight
+        reads = []
+
+        def reading(value):
+            if inflight is not None:
+                reads.append(inflight.t_done)
+            return await_(value)
+
+        fe._await = reading
+        try:
+            release(slot, entry)
+        finally:
+            fe._await = await_
+        if inflight is not None:
+            stamped.append((reads, inflight.t_done, clk.t))
+
+    fe._release_pages = releasing
+    # the first retires while the second still decodes: a chunk in flight
+    for i, r in enumerate(_requests(rng, cfg, [(9, 3), (9, 12)])):
+        fe.submit(r, request_id=i)
+    retired_then_bubble = []
+    alive = True
+    while alive:
+        before = fe.counter_deltas()
+        bubbles = len(fe._per_run["pump.bubble_ms"])
+        alive = fe.pump()
+        after = fe.counter_deltas()
+        retired_then_bubble.append((
+            after["retired"] - before["retired"],
+            len(fe._per_run["pump.bubble_ms"]) - bubbles,
+            fe._inflight is not None))
+    assert stamped, "no retirement found a chunk in flight"
+    for reads, t_done, t_end in stamped:
+        assert reads and all(t is not None for t in reads)
+        assert t_done <= t_end
+    # an iteration that retired with a chunk in flight: the next dispatch
+    # knows when the device went idle
+    for (retired, _, inflight), (_, bubble, dispatched) in zip(
+            retired_then_bubble, retired_then_bubble[1:]):
+        if retired and inflight and dispatched:
+            assert bubble == 1
+
+
+def _scripted_pumps(fe, clk, stalls):
+    """Idle pump iterations whose housekeeping takes ``stalls[i]``."""
+    script = iter(stalls)
+    fe._backpressure_spill = lambda: clk.jump(next(script))
+    for _ in stalls:
+        fe.pump()
+
+
+def test_the_eight_longest_iterations_are_kept_with_their_split(rng):
+    cfg, fe, clk = _clocked_frontend()
+    stalls = [0.010 * ((7 * i) % 20 + 1) for i in range(20)]    # 10-200 ms
+    _scripted_pumps(fe, clk, stalls)
+    slowest = fe.stats()["pump.slowest"]
+    assert len(slowest) == 8
+    order = sorted(range(20), key=lambda i: -stalls[i])[:8]
+    assert [r["iteration"] for r in slowest] == [i + 1 for i in order]
+    for r, i in zip(slowest, order):
+        assert r["host_ms"]["housekeeping"] == pytest.approx(
+            stalls[i] * 1e3, abs=5.0)
+        assert r["wall_ms"] == pytest.approx(stalls[i] * 1e3, abs=15.0)
+        assert r["wall_ms"] >= sum(r["host_ms"].values()) + r["wait_ms"]
+        assert set(r["host_ms"]) == {"dispatch", "harvest", "housekeeping",
+                                     "admission"}
+        assert (r["admitted"], r["retired"], r["wait_ms"]) == (0, 0, 0.0)
+        assert r["compiles"] >= 0 and r["gc_ms"] == 0.0
+    walls = [r["wall_ms"] for r in slowest]
+    assert walls == sorted(walls, reverse=True)
+
+
+def test_the_slowest_iterations_name_what_they_admitted_and_retired(rng):
+    cfg, fe, clk = _clocked_frontend()
+    for i, r in enumerate(_requests(rng, cfg, [(9, 3), (9, 3)])):
+        fe.submit(r, request_id=i)
+    fe.drain()
+    slowest = fe.stats()["pump.slowest"]
+    assert sum(r["admitted"] for r in slowest) == 2
+    assert sum(r["retired"] for r in slowest) == 2
+    assert any(r["wait_ms"] > 0.0 for r in slowest)
+    assert any(r["host_ms"]["admission"] > 0.0 for r in slowest)
+
+
+@pytest.mark.parametrize("end", ["shutdown", "death"])
+def test_the_pumps_end_puts_its_slowest_iterations_in_the_event_ring(rng,
+                                                                     end):
+    """Once, at shutdown or at the pump's death: the postmortem dump (and
+    through it the router's flight bundle) then carries them."""
+    cfg, model, v = _model()
+    if end == "death":
+        fe = _killed_frontend(model, v, at=2, start=False)
+    else:
+        fe = ServingFrontend(PagedDecodeEngine(model, v, num_slots=2,
+                                               page_size=8))
+    [req] = _requests(rng, cfg, [(9, 6)])
+    fe.submit(req, request_id=0)
+    if end == "death":
+        with pytest.raises(Exception):
+            fe.drain()
+    else:
+        fe.drain()
+    fe.shutdown()
+    fe.shutdown()
+    found = [e for e in fe.engine.events.tail()
+             if e["kind"] == "pump_slowest"]
+    assert len(found) == 1
+    assert found[0]["iterations"] == fe.stats()["pump.slowest"]
+    assert found[0]["iterations"][0]["wall_ms"] > 0.0
+
+
+def test_the_collector_is_timed_while_the_background_pump_runs(rng):
+    import gc
+
+    cfg, model, v = _model()
+    fe = ServingFrontend(PagedDecodeEngine(model, v, num_slots=2,
+                                           page_size=8))
+    hooks = len(gc.callbacks)
+    fe.start()
+    try:
+        for _ in range(100):
+            if len(gc.callbacks) > hooks:
+                break
+            time.sleep(0.01)
+        assert len(gc.callbacks) == hooks + 1
+        gc.collect()
+        assert fe._gc_s > 0.0
+        assert fe.counter_deltas()["gc_pause_seconds"] == pytest.approx(
+            fe._gc_s)
+    finally:
+        fe.stop()
+    assert len(gc.callbacks) == hooks
+
+
+def test_a_cancel_while_waiting_to_resume_is_a_retirement(rng):
+    """A request preempted, then cancelled while it waits for a slot
+    again, was admitted and decoded: the frontend finishes its handle in
+    the queue, and ``retired`` and the ``cancel`` event count it, once
+    (the retirement that counted nowhere in
+    ``test_concurrent_submit_cancel_stress``)."""
+    cfg, model, v = _model()
+    engine = PagedDecodeEngine(model, v, num_slots=1, page_size=8,
+                               prefix_cache=True)
+    fe = ServingFrontend(engine, policy=PriorityDeadlinePolicy(
+        preempt_on_priority=True))
+    low, hi, never = _requests(rng, cfg, [(24, 16), (16, 4), (9, 4)])
+    low.priority, hi.priority, never.priority = 0, 5, 0
+    h_low = fe.submit(low, request_id=0)
+    for _ in range(4):
+        fe.pump()
+    h_hi = fe.submit(hi, request_id=1)
+    while not fe.counter_deltas()["preemptions"]:
+        fe.pump()
+    assert not h_low.done and fe.queue_depth == 1
+    h_low.cancel()                       # waits for a slot again: queued
+    h_never = fe.submit(never, request_id=2)
+    h_never.cancel()                     # cancelled before any admission
+    fe.drain()
+    assert h_hi.result(timeout=0).shape[0] == 4
+    got = h_low.result(timeout=0)
+    assert 0 < got.shape[0] < 16 and h_never.result(timeout=0).shape[0] == 0
+    stats = fe.stats()
+    assert stats["retired"] == 3
+    cancels = [e for e in engine.events.tail() if e["kind"] == "cancel"]
+    assert [(e["request"], e.get("queued"), e["new_tokens"])
+            for e in cancels] == [(0, True, got.shape[0]), (2, True, 0)]
+    assert fe.tracer.lifecycle(0)["preemptions"] == 1
+    usable = engine.cache["free_stack"].shape[0] - 1
+    assert int(free_page_count(engine.cache)) == \
+        usable - len(engine.prefix)

@@ -348,3 +348,64 @@ def test_a_share_of_the_experts_counts_what_it_holds_under_these_names():
     assert "gated_delta_step" in _dispatch.KERNEL_LABELS
     # the chunked rule is plain XLA in this PR: no label of its own yet
     assert "gated_delta_chunk" not in _dispatch.KERNEL_LABELS
+
+
+# -- the pump's spans and phase counters (PR 39) --------------------------------
+
+PUMP_SPANS = ("dispatch", "harvest", "housekeeping", "admission",
+              "wait_device", "dispatch.launch", "dispatch.account",
+              "harvest.account", "retire", "retire.release",
+              "admission.request", "admission.match", "admission.pool",
+              "admission.launch", "admission.join", "admission.feed")
+
+
+@pytest.mark.parametrize("span", PUMP_SPANS)
+def test_the_pumps_spans_keep_the_names_the_idle_metrics_read(span):
+    """``idle_admission_share.serve`` and ``idle_harvest_share.serve`` (and
+    the ``breakdown`` of every traced serving run) find the pump's host
+    spans by ``pump:<name>``: ``^pump:admission`` and
+    ``^pump:(harvest|retire)``; ``trace_reduce.load`` keeps the prefix
+    ``pump:``. A rename would move idle time between the two in silence."""
+    import inspect
+    import re
+
+    from apex_tpu.serving import frontend
+
+    assert frontend.PUMP_SPANS == PUMP_SPANS
+    source = inspect.getsource(frontend.ServingFrontend)
+    assert f'_phase("{span}")' in source
+    assert '"pump:" + name' in inspect.getsource(
+        frontend.ServingFrontend._phase)
+    # each span is read by at most one of the two shares: its own stem's
+    stem = span.split(".")[0]
+    assert bool(re.search(r"^pump:admission", "pump:" + span)) == \
+        (stem == "admission")
+    assert bool(re.search(r"^pump:(harvest|retire)", "pump:" + span)) == \
+        (stem in ("harvest", "retire"))
+
+
+@pytest.mark.parametrize("phase", ["dispatch", "harvest", "housekeeping",
+                                   "admission"])
+def test_every_top_level_phase_feeds_the_counter_its_metric_reads(phase):
+    """``pump_<phase>_ms.serve`` reads ``pump_<phase>_seconds`` over
+    ``pump_iterations``; the phase's exit is where it is fed."""
+    import json
+    import os
+
+    from apex_tpu.serving import frontend
+    from apex_tpu.serving.scheduler import _RUN_COUNTERS
+
+    counter = f"pump_{phase}_seconds"
+    assert frontend._PHASE_COUNTERS[phase] == counter
+    assert frontend._PHASE_COUNTERS["wait_device"] == "pump_blocked_seconds"
+    assert {counter, "pump_iterations", "pump_host_seconds"} \
+        <= set(_RUN_COUNTERS)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           f"pump_{phase}_ms.serve.json"),
+              encoding="utf-8") as f:
+        spec = json.load(f)
+    assert spec["reader"] == "counter_ratio"
+    assert spec["args"] == {"numerator": counter,
+                            "denominator": "pump_iterations",
+                            "scale": 1000.0}
