@@ -43,8 +43,9 @@ is position-contiguous per device); the models raise on that combination.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Optional
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -162,12 +163,24 @@ def layer_cache(cache, i: int, table=None):
     return lc
 
 
+@dataclasses.dataclass(frozen=True)
+class StateTensor:
+    """One tensor a layer keeps per sequence, as a config's ``layer_states``
+    states it (``serving/kv_pool.layer_groups`` reads it): ``(slots,) +
+    shape`` in the engine's cache, ``(batch,) + shape`` in a contiguous
+    one."""
+
+    name: str
+    shape: Tuple[int, ...]
+    dtype: Any
+
+
 def layer_state(cache, i: int):
     """Per-layer view for a block that keeps a STATE and no token
-    (``serving/kv_pool.layer_groups``: a linear-attention layer): its
-    tensors, a row a sequence (a slot, in the engine's cache), and neither
-    table nor lengths: what it keeps does not depend on where a sequence
-    stands."""
+    (``serving/kv_pool.layer_groups``: a linear-attention or a gated conv
+    layer): its tensors, a row a sequence (a slot, in the engine's cache),
+    and neither table nor lengths: what it keeps does not depend on where a
+    sequence stands."""
     return dict(cache["layers"][i])
 
 

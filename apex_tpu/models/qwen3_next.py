@@ -61,10 +61,11 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.amp.policy import resolve_compute_dtype
-from apex_tpu.models.generation import (advance_cache, cached_attention,
-                                        check_chunk_bounds, is_paged,
-                                        is_static_prefill, layer_cache,
-                                        layer_state, update_layer_cache,
+from apex_tpu.models.generation import (StateTensor, advance_cache,
+                                        cached_attention, check_chunk_bounds,
+                                        is_paged, is_static_prefill,
+                                        layer_cache, layer_state,
+                                        update_layer_cache,
                                         update_paged_layer_cache)
 from apex_tpu.models.glm4_moe_lite import Embedding
 from apex_tpu.normalization import FusedRMSNorm
@@ -76,16 +77,6 @@ from apex_tpu.transformer.functional.fused_rope import (
 from apex_tpu.transformer.moe.dropless import DroplessMoEMLP, Linear
 
 LINEAR, FULL = "linear_attention", "full_attention"
-
-
-@dataclasses.dataclass(frozen=True)
-class StateTensor:
-    """One tensor a layer keeps per sequence: ``(slots,) + shape`` in the
-    engine's cache, ``(batch,) + shape`` in a contiguous one."""
-
-    name: str
-    shape: Tuple[int, ...]
-    dtype: Any
 
 
 @dataclasses.dataclass(frozen=True)

@@ -85,21 +85,23 @@ mesh (``ROADMAP.md``).
 
 A THIRD KIND OF GROUP STORES NO TOKEN AT ALL: a STATE group
 (``models/qwen3_next.py``: three ``linear_attention`` layers to one
-``full_attention``). A config states ``layer_states`` (one entry a layer:
-``None``, or the tensors the layer keeps per SEQUENCE, each a name, a shape
-and a dtype), and such a layer's dict holds exactly those tensors, ``(num_
-slots,) + shape`` (a gated delta-rule layer: its float32 recurrent state and
-the last inputs of its short convolution), whatever the sequences' lengths.
-A state group is sized by ``num_slots`` alone (:func:`state_bytes`, beside
-:func:`page_bytes`), and is no part of the free stack, the block table,
-:func:`alloc_slot` / :func:`release_slot`, :func:`defrag_map` or
+``full_attention``; ``models/lfm2_moe.py``: three gated ``conv`` layers to
+one). A config states ``layer_states`` (one entry a layer: ``None``, or the
+tensors the layer keeps per SEQUENCE, ``models.generation.StateTensor``
+each: a name, a shape and a dtype), and such a layer's dict holds exactly
+those tensors, ``(num_slots,) + shape`` (a gated delta-rule layer: its
+float32 recurrent state and the last inputs of its short convolution; a
+gated conv layer: its two-token window alone), whatever the sequences'
+lengths. A state group is sized by ``num_slots`` alone (:func:`state_bytes`,
+beside :func:`page_bytes`), and is no part of the free stack, the block
+table, :func:`alloc_slot` / :func:`release_slot`, :func:`defrag_map` or
 :func:`gather_pages`: slot ``b``'s row is the state of whatever request
 holds slot ``b``. An admission OVERWRITES the row whole with the state its
 prompt ends in (:func:`prefill_into_pages`), so retirement need not clear
 it; a decode step updates it in place (``ops.gated_delta_step`` aliases it,
-and the chunk's scan carries it without a copy). What a state cannot do:
-be shared by prefix (the state at a prefix's end is not kept), be rolled
-back past a rejected draft block, be handed from chunk to chunk of a
+and the chunk's scan carries every state row without a copy). What a state
+cannot do: be shared by prefix (the state at a prefix's end is not kept),
+be rolled back past a rejected draft block, be handed from chunk to chunk of a
 chunked prefill through the paged ``s > 1`` path, go to the host tier,
 shard over a tensor-parallel mesh, or stand beside quantized pages: the
 engine refuses each by name (:class:`StateGroupUnsupported`;
@@ -217,14 +219,14 @@ class RingGroupUnsupported(ValueError):
 
 class StateGroupUnsupported(ValueError):
     """``state-group-unsupported``: a pool with a state group (layers that
-    keep one recurrent state a slot and no token) was asked for what a
-    state cannot give."""
+    keep one state a slot and no token) was asked for what a state cannot
+    give."""
 
     def __init__(self, what: str, why: str = ""):
         super().__init__(
             f"state-group-unsupported: {what} does not compose with a "
-            "model whose linear-attention layers keep one recurrent state "
-            "a slot and no token (kv_pool.layer_groups)"
+            "model whose recurrent layers keep one state a slot and no "
+            "token (kv_pool.layer_groups)"
             + (f": {why}" if why else ""))
 
 

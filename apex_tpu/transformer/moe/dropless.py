@@ -251,6 +251,8 @@ class DroplessMoEMLP(nn.Module):
     factor), SwiGLU experts of width ``ffn_hidden_size`` stacked ``(E, in,
     out)``, and ``shared_experts`` always-on experts fused into one SwiGLU
     of their summed width, times ``sigmoid(w_g . x)`` where ``shared_gate``.
+    ``norm_eps``: what the sigmoid router adds to the chosen weights' sum
+    before it renormalises them.
     ``held`` (None: all) and ``first``: the share of the experts this chip
     holds (module docstring): the stack is ``held`` experts, the router all
     ``num_experts`` outputs."""
@@ -262,6 +264,7 @@ class DroplessMoEMLP(nn.Module):
     shared_experts: int = 0
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    norm_eps: float = 1e-20
     params_dtype: jnp.dtype = jnp.float32
     router: str = "sigmoid_bias"
     held: Optional[int] = None
@@ -278,7 +281,8 @@ class DroplessMoEMLP(nn.Module):
                              f"'sigmoid_bias', 'softmax')")
         return SigmoidBiasTopKRouter(
             self.num_experts, self.k,
-            routed_scaling_factor=self.routed_scaling_factor, **common)
+            routed_scaling_factor=self.routed_scaling_factor,
+            norm_eps=self.norm_eps, **common)
 
     @nn.compact
     def __call__(self, x):

@@ -80,7 +80,8 @@ class SigmoidBiasTopKRouter(nn.Module):
     ``s + b`` are chosen, ``b`` the learned ``e_score_correction_bias``
     that balances load without an auxiliary loss; the chosen experts'
     weights are ``s`` WITHOUT ``b``, renormalized to sum 1 where
-    ``norm_topk_prob``, times ``routed_scaling_factor``.
+    ``norm_topk_prob`` (over their sum plus ``norm_eps``: GLM's 1e-20 by
+    default, LFM2's 1e-6), times ``routed_scaling_factor``.
 
     Returns ``(idx, weights)``: ``(tokens, k)`` int32 expert ids and their
     fp32 weights. No capacity, no dropping: what consumes them decides how
@@ -92,6 +93,7 @@ class SigmoidBiasTopKRouter(nn.Module):
     k: int
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    norm_eps: float = 1e-20
     params_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
@@ -105,7 +107,8 @@ class SigmoidBiasTopKRouter(nn.Module):
         _, idx = lax.top_k(scores + b.astype(jnp.float32), self.k)
         weights = jnp.take_along_axis(scores, idx, axis=-1)
         if self.norm_topk_prob:
-            weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+            weights = weights / (weights.sum(-1, keepdims=True)
+                                 + self.norm_eps)
         return idx, weights * self.routed_scaling_factor
 
 

@@ -589,6 +589,99 @@ def test_a_state_group_beside_pages_compiles_for_the_chip(program, mesh):
     assert peak < tpu_aot.HBM_BUDGET
 
 
+def _lfm2_cell():
+    """``lfm2-24b-a2b.chat-closed128`` at its widths, three of its kinds of
+    layer (the dense conv layer, a routed attention layer, a routed conv
+    layer): 128 slots, 64 experts of 1536, 8 kv heads of 64 behind
+    512-page tables over the 2 GiB pool's 32,769 pages, and for each conv
+    layer a window of 2 x 2048 a slot."""
+    from apex_tpu.models.lfm2_moe import (CONV, FULL, Lfm2MoeConfig,
+                                          Lfm2MoeModel)
+
+    cfg = Lfm2MoeConfig(layer_types=(CONV, FULL, CONV), num_dense_layers=1,
+                        max_position_embeddings=8192)
+    return Lfm2MoeModel(cfg), dict(slots=128, num_pages=32769, max_pages=512)
+
+
+@pytest.mark.parametrize("program", ["step", "admit"])
+def test_a_window_group_beside_pages_compiles_for_the_chip(program, mesh):
+    """The decode chunk and an admission of a model whose conv layers keep
+    a two-token window a slot (a STATE group of windows alone), compiled for
+    the described v5e: the convolution is XLA's in both (no Mosaic call
+    but the norms', the attention layer's and the page write), the step's one
+    ``paged_attention`` at two 64-wide heads a pool row, the admission's
+    (4096 tokens) ``flash_fwd``; no ``transpose`` has the windows' shape
+    and the step relayouts each window at most three times, and hands it
+    back in the buffer it came in."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    import tpu_aot
+    from apex_tpu.serving import kv_pool
+    from apex_tpu.serving.scheduler import PagedDecodeEngine
+
+    model, pool = _lfm2_cell()
+    slots = pool["slots"]
+    engine = PagedDecodeEngine(model, variables=None, num_slots=2,
+                               page_size=16, num_pages=3,
+                               max_pages_per_seq=pool["max_pages"],
+                               sync_every=4)
+    cache = jax.eval_shape(lambda: kv_pool.init_paged_cache(
+        model.config, slots, num_pages=pool["num_pages"], page_size=16,
+        max_pages_per_seq=pool["max_pages"]))
+    i32 = jnp.int32
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), i32)))
+    sds = jax.ShapeDtypeStruct
+    if program == "step":
+        fn = engine._step_fn()
+        rest = [sds((slots,), i32), sds((slots,), jnp.bool_),
+                sds((slots,), i32), sds((slots, 2), jnp.uint32),
+                sds((slots,), i32)]
+    else:
+        fn = engine._admit_fn(4096)
+        rest = [sds((1, 4096), i32), sds((), i32), sds((), i32),
+                sds((), i32), sds((2,), jnp.uint32)]
+    compiled = tpu_aot.compile_replicated(mesh, fn,
+                                          [cache, variables] + rest, (0,))
+    txt = compiled.as_text()
+
+    window = (slots, 2, 2048)
+    assert [lc["conv_state"].shape for lc in (cache["layers"][0],
+                                              cache["layers"][2])] == [
+        window] * 2
+    assert sorted(cache["layers"][1]) == ["k_pages", "v_pages"]
+    assert cache["layers"][1]["k_pages"].shape == (pool["num_pages"], 4, 16,
+                                                   128)
+    labels = [label for label in re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*\n"kernel":"(\w+)"', txt)
+        if label != "layer_norm_fwd"]           # the norms' own kernel
+    if program == "step":
+        assert sorted(labels) == ["paged_attention", "paged_write"]
+        # the donated cache, windows included, comes back in place
+        held = sum(x.size * x.dtype.itemsize
+                   for x in jax.tree.leaves(cache))
+        assert compiled.memory_analysis().alias_size_in_bytes >= held
+    else:
+        assert sorted(labels) == ["flash_fwd", "paged_write"]
+    shape = re.escape(",".join(map(str, window)))
+    ops = [m.group(1) for m in re.finditer(
+        rf"= \w+\[{shape}\]\S* (copy|transpose)\(", txt)]
+    # no transpose of a window anywhere; in the step XLA holds each window
+    # in VMEM (memory space S(1)) major axis first: one relayout of its
+    # 1 MiB at the program's entry, one at its exit and one a step in the
+    # scan's body, a few microseconds of a step of tens of milliseconds
+    assert "transpose" not in ops
+    assert ops.count("copy") <= (3 * 2 if program == "step" else 0), ops
+    # the whole program fits the chip beside what it is handed
+    ma = compiled.memory_analysis()
+    peak = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert peak < tpu_aot.HBM_BUDGET
+
+
 @pytest.mark.parametrize("cell", [_mellum_cell, _qwen3_next_cell])
 def test_full_layers_of_a_step_share_one_walk(cell, mesh):
     """The two claimed cells run two full-attention layers a decode step

@@ -111,6 +111,13 @@ def _cases():
                 _sds((64, 32, 128, 128)), _sds((64, 16, 128)),
                 _sds((64, 16, 128)), _sds((64, 32, 128)), _sds((64, 32)),
                 _sds((64, 32))]),
+        # the conv cell's attention layers: 128 slots, 32 query heads over
+        # 8 kv heads of 64 held two a 128-lane row, 512-entry tables over a
+        # 2 GiB pool
+        "paged_attention@lfm2-24b-a2b.chat-closed128": (paged_attention, [
+            _sds((128, 32, 1, 64), bf16), _sds((32769, 4, 16, 128), bf16),
+            _sds((32769, 4, 16, 128), bf16), _sds((128, 512), i32),
+            _sds((128,), i32)]),
         "paged_latent_attention": (
             functools.partial(paged_latent_attention, value_width=128), [
                 _sds((2, 4, 1, 256), bf16), _sds((9, 1, 16, 256), bf16),
@@ -174,7 +181,8 @@ def test_the_cases_cover_the_closed_set():
     "paged_window_attention@mellum2-12b-a2.5b.ide-closed48",
     "paged_attention@mellum2-12b-a2.5b.ide-closed48",
     "paged_attention@qwen3-next-80b-a3b.longchat-closed64",
-    "gated_delta_step@qwen3-next-80b-a3b.longchat-closed64"))
+    "gated_delta_step@qwen3-next-80b-a3b.longchat-closed64",
+    "paged_attention@lfm2-24b-a2b.chat-closed128"))
 def test_label_reaches_the_lowered_program(case):
     """``metadata={"kernel": label}`` lands on the Mosaic custom call as
     ``kernel_metadata``; the benchmark's pattern finds it there."""
