@@ -2127,6 +2127,9 @@ class ServingFrontend:
         # (dropless.lay_out_experts), with the bytes of zeros the layout
         # added: static, so a reading and not a rate
         stats["expert_served_width"] = eng.expert_layout
+        # and the rows their products are handed a layer, in the decode
+        # chunk and each admit program (dropless.served_rows): static too
+        stats["expert_served_rows"] = eng.expert_rows()
         # the longest iterations of this frontend's life, longest first:
         # what an untraced run can say of a stall
         stats["pump.slowest"] = self._slowest_records()

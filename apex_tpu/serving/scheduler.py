@@ -82,7 +82,7 @@ from apex_tpu.serving.host_tier import HostPageTier
 from apex_tpu.serving.prefix_cache import PrefixCache
 from apex_tpu.transformer.moe.dropless import (ROUTING_COLLECTION,
                                                SHARE_ROUTING_STATS,
-                                               lay_out_experts)
+                                               expert_rows, lay_out_experts)
 
 #: run() counters in the instrument registry (``serving.<name>``); the
 #: per-run stats dict is the DELTA of these across the run — the registry
@@ -690,6 +690,28 @@ class PagedDecodeEngine:
         (:func:`~apex_tpu.transformer.moe.dropless.lay_out_experts`, in
         place); returns ``{layer: widths}``."""
         return lay_out_experts(variables)
+
+    def expert_rows(self) -> dict:
+        """``{"decode": (rows, served, tile), "admit": {bucket: (rows,
+        served, tile)}}``: the rows a layer's grouped products route in a
+        decode step (a token a slot; ``draft_len + 1`` with a draft) and in
+        each admit program compiled so far, the rows they are handed
+        (:func:`~apex_tpu.transformer.moe.dropless.served_rows`) and the
+        row tile that count gets; empty for a model with no routed
+        experts."""
+        k = getattr(self.cfg, "num_experts_per_tok", None)
+        if not k:
+            return {}
+        # the router's experts, where a configuration holds a share of them
+        router = getattr(self.cfg, "num_experts", None)
+        decode = expert_rows(self.variables, k, {
+            "decode": self.num_slots * (self.draft_len + 1)}, router)
+        if not decode:
+            return {}
+        return {"decode": decode["decode"],
+                "admit": expert_rows(self.variables, k,
+                                     {b: b for b in sorted(self._admit_jit)},
+                                     router)}
 
     def _make_cache(self, num_slots, num_pages, page_size,
                     max_pages_per_seq, config=None):

@@ -65,6 +65,49 @@ engine lays out the tree it is handed, in place and one matrix at a time
 layout"); training, lock-step ``generate`` and every other caller keep the
 published layout. A counter of expert bytes (``routed_expert_bytes``)
 counts the published bytes: what the routing must read, not the zeros.
+
+THE SERVED ROWS. XLA takes the row tile by the same rule, from the row
+count (``ragged_dot_tiling`` of ``(rows, 2048) x (64, 2048, 1536)``, the
+same versions):
+
+=====  ====  =====  ====  ======  ====
+rows   tile  rows   tile  rows    tile
+=====  ====  =====  ====  ======  ====
+128    128   768    256   4224    128
+384    128   1024   512   8448    256
+512    512   1152   128   16384   512
+520    8     2048   512   16512   128
+576    64    4096   512
+640    128
+=====  ====  =====  ====  ======  ====
+
+A product of ``K`` in and ``N`` out on a row tile ``tm`` then makes ``v =
+groups hit + ceil(rows / tm) - 1`` (group, row tile) visits, each a pass of
+``tm`` rows through the MXU and a read of the expert's ``K x N`` weights,
+and rows in no group cost nothing. LFM2's decode step routes 128 slots x
+top 4 = 512 rows, ~8 a group, so each of its 64 groups ran a whole 512-row
+tile: 1.2 ms a product (the ledger's PR 41 line) where 640 rows on 128-row
+tiles take 0.73 (timed alone on the chip, PERF.md section 6). Timed alone
+at every (rows, stack) the routed cells compile, at tm 128, 256 and 512, a
+visit costs its MXU pass AND its weights' read, not the larger of them:
+``t = v (tm 2 K N / MXU_FLOPS_PER_S + K N itemsize / HBM_BYTES_PER_S + (K /
+t(K)) (N / t(N)) GRID_STEP_S)`` ranks the three tiles as the chip does at
+all 19 shapes (the pick at most 1.5 % over the fastest), where the larger
+of the first two terms picked tiles up to 18 % slower. It ranks tiles and
+does not predict a time: on the four routed cells' decode products it
+reads 1.2-1.5 x high (ms a product, model / measured: GLM 0.79 / 0.56,
+Qwen3-Next 0.45 / 0.31, Mellum 0.85 / 0.70, LFM2 1.71 / 1.20 at 512 rows
+and 0.97 / 0.67 at 640), where the larger of the two terms read within
+10 % of the first four. So :func:`served_rows` hands the products, from
+the shapes alone, the row count of ``rows`` and the smallest odd
+multiples of 128, 256 and 512 not under it that this model times least,
+the rows past ``rows`` zeros in no group (a share of the experts is
+priced on the rows its groups can expect): LFM2's 512 decode rows are
+handed 640; GLM's 128, Mellum's 384 and
+Qwen3-Next's 640 tile 128 high already and are handed as they are; an
+admission's rows go to 128-row tiles up to ~4k rows over a 64 x 2048 x 1536
+stack, 256 up to ~16k, and keep 512 above. The K and N tiles do not change,
+so no sum changes its order.
 """
 
 from __future__ import annotations
@@ -98,11 +141,13 @@ STACK = ("gate_proj", "up_proj", "down_proj")
 GRID_STEP_S = 0.23e-6
 #: the v5e's HBM bandwidth, bytes a second
 HBM_BYTES_PER_S = 819e9
+#: the v5e's MXU peak in bfloat16, FLOP a second
+MXU_FLOPS_PER_S = 197e12
 
 
 def _tile(width: int) -> int:
-    """The tile XLA's ragged-dot kernel takes on an axis of ``width``: the
-    largest power of two up to 512 that divides it."""
+    """The tile XLA's ragged-dot kernel takes on an axis of ``width`` (the
+    rows too): the largest power of two up to 512 that divides it."""
     return min(width & -width, 512)
 
 
@@ -119,6 +164,45 @@ def served_width(d: int, m: int, itemsize: int = 2) -> int:
 
     widths = {m} | {-(-m // a) * a for a in (128, 256, 512)}
     return min(sorted(widths), key=seconds)
+
+
+def served_rows(rows: int, experts: int, d: int, w: int,
+                itemsize: int = 2, grouped: Optional[int] = None) -> int:
+    """The row count ``R >= rows`` the grouped products of ``rows`` routed
+    rows over ``experts`` experts of ``d`` in and out and ``w`` hidden are
+    handed (the rows past ``rows`` lie in no group): of ``rows`` and, for
+    ``tm`` 128, 256 and 512, the smallest odd multiple of ``tm`` not under
+    ``rows`` (whose row tile is ``tm``), the one whose three products take
+    the least modelled time (module docstring); ``rows`` where it ties.
+    ``grouped`` (None: ``rows``): the rows the groups can expect, where the
+    stack is a share of the router's experts.
+
+    A product of ``K`` in and ``N`` out on a row tile ``tm`` visits ``v =
+    min(experts, grouped) + ceil(grouped / tm) - 1`` (group, row tile)
+    pairs and takes ``v (tm 2 K N / MXU_FLOPS_PER_S + K N itemsize /
+    HBM_BYTES_PER_S + (K / t(K)) (N / t(N)) GRID_STEP_S)``; a tile under
+    128 rows is charged the MXU's full 128-row pass."""
+    grouped = rows if grouped is None else grouped
+
+    def seconds(served):
+        tm = _tile(served)
+        visits = min(experts, grouped) + -(-grouped // tm) - 1
+        total = 0.0
+        for k, n in ((d, w), (d, w), (w, d)):
+            mxu = max(tm, 128) * 2 * k * n / MXU_FLOPS_PER_S
+            hbm = k * n * itemsize / HBM_BYTES_PER_S
+            steps = (k // _tile(k)) * (n // _tile(n))
+            total += visits * (mxu + hbm + steps * GRID_STEP_S)
+        return total
+
+    padded = sorted({(-(-rows // tm) | 1) * tm for tm in (128, 256, 512)})
+    return min([rows] + padded, key=seconds)
+
+
+def _grouped(rows: int, held: int, num_experts: Optional[int]) -> int:
+    """The rows a stack of ``held`` of the router's ``num_experts`` experts
+    can expect in its groups (None: the stack is all of them)."""
+    return rows if num_experts is None else -(-rows * held // num_experts)
 
 
 def _stacks(tree, path=()):
@@ -171,6 +255,29 @@ def expert_widths(variables) -> dict:
     return out
 
 
+def expert_rows(variables, k: int, tokens: Mapping,
+                num_experts: Optional[int] = None) -> dict:
+    """``{name: (rows, served, tile)}`` for each ``name: T`` of ``tokens``:
+    the ``T * k`` rows a call over ``T`` tokens routes, the row count
+    :func:`served_rows` hands its grouped products at the shapes and dtype
+    of ``variables``' expert stacks (a share of the router's
+    ``num_experts``, as :func:`grouped_experts` is told), and the row tile
+    XLA takes from that count. Empty where ``variables`` hold no stack."""
+    variables = variables or {}
+    stacks = [s for c in ("params", SERVED_COLLECTION)
+              for _, s in _stacks(variables.get(c))]
+    if not stacks:
+        return {}
+    e, d, w = stacks[0]["gate_proj"].shape
+    itemsize = jnp.dtype(stacks[0]["gate_proj"].dtype).itemsize
+    out = {}
+    for name, t in tokens.items():
+        served = served_rows(t * k, e, d, w, itemsize,
+                             grouped=_grouped(t * k, e, num_experts))
+        out[name] = (t * k, served, _tile(served))
+    return out
+
+
 def lay_out_experts(variables) -> dict:
     """Hold every expert stack of ``variables`` whose :func:`served_width`
     is wider than published zero-padded to it, IN PLACE: the stack leaves
@@ -206,7 +313,8 @@ def lay_out_experts(variables) -> dict:
     return widths
 
 
-def grouped_experts(x, idx, weights, gate, up, down, *, first=None):
+def grouped_experts(x, idx, weights, gate, up, down, *, first=None,
+                    num_experts=None):
     """``sum_i weights[t, i] * SwiGLU_{idx[t, i]}(x[t])`` for every row.
 
     ``x``: (T, d); ``idx``/``weights``: (T, k); ``gate``/``up``:
@@ -214,11 +322,15 @@ def grouped_experts(x, idx, weights, gate, up, down, *, first=None):
     with ``sizes`` the rows each expert got.
 
     ``first`` (an int; None: the stack is all the experts): the stack holds
-    experts ``first .. first + E - 1`` of those ``idx`` names. A pair whose
-    expert is not among them counts in no group, so it costs no row of the
-    products, and adds nothing to ``y``."""
+    experts ``first .. first + E - 1`` of the ``num_experts`` those ``idx``
+    name. A pair whose expert is not among them counts in no group, so it
+    costs no row of the products, and adds nothing to ``y``.
+
+    The products see the ``T*k`` rows followed by zero rows, in no group,
+    up to :func:`served_rows` (a share expecting ``E / num_experts`` of the
+    rows in groups): XLA's row tile follows the count."""
     t, k = idx.shape
-    held = gate.shape[0]
+    held, d, w = gate.shape
     flat = idx.reshape(-1)
     if first is not None:
         flat = flat - first
@@ -227,10 +339,13 @@ def grouped_experts(x, idx, weights, gate, up, down, *, first=None):
     order = jnp.argsort(flat, stable=True)               # pairs by expert
     rows = x[order // k]                                 # (T*k, d)
     sizes = jnp.bincount(flat, length=held).astype(jnp.int32)
+    served = served_rows(t * k, held, d, w, jnp.dtype(gate.dtype).itemsize,
+                         grouped=_grouped(t * k, held, num_experts))
+    rows = jnp.pad(rows, ((0, served - t * k), (0, 0)))
     with jax.named_scope("moe_experts"):
         mid = jax.nn.silu(lax.ragged_dot(rows, gate, sizes)) \
             * lax.ragged_dot(rows, up, sizes)
-        out = lax.ragged_dot(mid, down, sizes)           # (T*k, d)
+        out = lax.ragged_dot(mid, down, sizes)[:t * k]   # (T*k, d)
     if first is not None:
         # the rows past the last group are no product's: whatever the
         # kernel left there must not meet even a weight of 0
@@ -298,7 +413,8 @@ class DroplessMoEMLP(nn.Module):
                                      self.params_dtype, name="experts")()
         y, sizes = grouped_experts(xt, idx, weights, gate.astype(x.dtype),
                                    up.astype(x.dtype), down.astype(x.dtype),
-                                   first=self.first if share else None)
+                                   first=self.first if share else None,
+                                   num_experts=e if share else None)
         stats = [jnp.int32(idx.size), (sizes > 0).sum().astype(jnp.int32),
                  sizes.max()]
         if share:

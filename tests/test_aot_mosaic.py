@@ -41,6 +41,7 @@ CASE_NAMES = [
     "paged_latent_attention_glm_cell",  # the latent cell: 20 heads x one 640-lane entry
     "moe_grouped_experts_glm_decode",   # ragged_dot -> XLA's %ragged-dot-* Mosaic calls
     "moe_grouped_experts_mellum_decode",  # the served width: 512-wide tiles
+    "moe_grouped_experts_lfm2_decode",  # the served rows: 128-row tiles
     "gpt2s_prefix_cached_admit",      # prefix cache: tail-only admission
     "gpt2s_paged_spec_verify",        # s=4 query block: spec verify step
     "gpt2s_chunked_prefill_step",     # chunked prefill through the s>1 path
@@ -162,6 +163,32 @@ def test_mellum_experts_are_served_at_a_width_xla_tiles_512_wide(mesh,
     assert served == 1024
     assert sorted(tilings) == [("128", "256", "512")] * 2 + [
         ("128", "512", "256")]
+
+
+@pytest.mark.parametrize("name,tilings", [
+    # 512 rows, ~8 a group, handed 640: a 128-row tile and not 512
+    ("moe_grouped_experts_lfm2_decode", ["128,512,512"] * 3),
+    # 128 and 384 rows tile 128 high already, and are handed as they are
+    ("moe_grouped_experts_glm_decode", ["128,512,512"] * 3),
+    ("moe_grouped_experts_mellum_decode",
+     ["128,256,512"] * 2 + ["128,512,256"]),
+])
+def test_the_products_take_their_row_tile_from_the_rows_served(
+        mesh, cases, name, tilings):
+    """XLA's ragged dot takes its row tile from the row count (the largest
+    power of two up to 512 that divides it), so the three products of a
+    decode step's experts are handed ``dropless.served_rows`` rows: each
+    is still a ``ragged-dot-none`` call (the benchmark's ``moe_experts_*``
+    find them by that name), on a row tile of 128."""
+    import re
+
+    import tpu_aot
+
+    fn, structs, _ = cases[name]
+    txt = tpu_aot.compile_replicated(mesh, fn, structs).as_text()
+    got = re.findall(r'ragged_dot_tiling="([\d,]+)"\}, '
+                     r'metadata=\{op_name="ragged-dot-none"\}', txt)
+    assert sorted(got) == sorted(tilings)
 
 
 @pytest.mark.slow

@@ -467,6 +467,22 @@ def test_engine_run_serves_what_the_reference_puts_first():
         4 * 3 * 3 * counted["decode_steps"]     # top 4 x slots x 3 layers
 
 
+def test_the_stats_name_the_rows_the_products_are_handed():
+    """``stats()["expert_served_rows"]``: for the decode step and each admit
+    program built so far, the rows a layer routes (tokens x top 4), the
+    rows ``dropless.served_rows`` hands its products at the tiny stack's
+    shapes (8 experts, 64 x 32, float32) and the row tile of that count. A
+    decode step's 12 rows (a 4-row tile) are handed 128, the 116 past them
+    in no group."""
+    _, model, variables = _built()
+    engine = _engine(model, variables)
+    for bucket in (8, 40):
+        engine._admit_fn(bucket)
+    rows = ServingFrontend(engine).stats()["expert_served_rows"]
+    assert rows == {"decode": (12, 128, 128),
+                    "admit": {8: (32, 32, 32), 40: (160, 256, 256)}}
+
+
 # -- the router ---------------------------------------------------------------------
 
 def _route(eps=None, seed=0):

@@ -381,6 +381,96 @@ def test_grouped_experts_on_zero_padded_stacks_equal_the_published(
                                rtol=1e-6, atol=1e-6)
 
 
+#: the rows a layer's products route in each routed cell's decode step and
+#: admit programs (tokens x k) and the stack they meet: (experts, d, w) and
+#: the router's experts where the stack is a share of them
+_GLM = (64, 2048, 1536, None)     # and lfm2-24b-a2b's
+_MELLUM = (64, 2304, 1024, None)  # served at 1024
+_QWEN = (128, 2048, 512, 512)     # 128 of 512 held: a quarter of the rows
+
+
+@pytest.mark.parametrize("rows,stack,served", [
+    (32 * 4, _GLM, 128),          # glm-4.7-flash decode: tiles 128 already
+    (48 * 8, _MELLUM, 384),       # mellum2 decode: 128
+    (64 * 10, _QWEN, 640),        # qwen3-next decode: 128
+    (128 * 4, _GLM, 640),         # lfm2 decode: 512 -> 128 high
+    (65 * 8, _MELLUM, 640),       # 65 slots: an 8-row tile -> 128
+    (65 * 4, _GLM, 384),          # 65 slots of top 4: a 4-row tile -> 128
+    (256 * 4, _GLM, 1152),        # lfm2 admit 256 .. 4096 tokens: 512 ->
+    (512 * 4, _GLM, 2176),        # 128 up to ~4k rows, 256 up to ~16k
+    (1024 * 4, _GLM, 4224),       # (and glm admit 1k .. 4k tokens)
+    (2048 * 4, _GLM, 8448),
+    (4096 * 4, _GLM, 16640),
+    (8192 * 4, _GLM, 32768),      # glm admit 8k, 16k tokens: 512, kept
+    (16384 * 4, _GLM, 65536),
+    (512 * 8, _MELLUM, 4224),     # mellum2 admit 512 .. 16k tokens
+    (4096 * 8, _MELLUM, 32768),
+    (8192 * 8, _MELLUM, 65536),
+    (16384 * 8, _MELLUM, 131072),
+    (1024 * 10, _QWEN, 10368),    # qwen3-next admit 1k .. 16k tokens,
+    (4096 * 10, _QWEN, 41088),    # priced on the held quarter
+    (8192 * 10, _QWEN, 82176),
+    (16384 * 10, _QWEN, 164096),
+    (8 * 4, _GLM, 32),            # 8 slots: a tile under the MXU's 128 rows
+    (8, (8, 64, 128, None), 8),   # that no padding makes fuller
+])
+def test_served_rows_at_the_routed_cells(rows, stack, served):
+    from apex_tpu.transformer.moe.dropless import (_grouped, _tile,
+                                                   served_rows)
+
+    e, d, w, router = stack
+    got = served_rows(rows, e, d, w, grouped=_grouped(rows, e, router))
+    assert got == served
+    # the count, or an odd multiple of a row tile of 128 or more
+    assert got == rows or (_tile(got) >= 128 and (got // _tile(got)) % 2)
+
+
+@pytest.mark.parametrize("case", ["plain", "idle", "share"])
+def test_padded_rows_change_no_product_and_no_stat(rng, monkeypatch, case):
+    """The products handed zero rows in no group (an odd multiple of 128
+    past the routed rows) give what the unpadded ones give, bit for bit,
+    and the layer sows the same routing stats: the padding is no pair."""
+    from apex_tpu.transformer.moe import dropless
+
+    e, d, m, k, t = 8, 64, 48, 2, 24
+    first = 4 if case == "share" else None
+    x = np.asarray(rng.standard_normal((t, d)), np.float32)
+    idx = rng.integers(0, e + (8 if first else 0), (t, k))
+    if case == "idle":
+        x[16:] = 0.0                  # idle slots route too
+        idx[:] = idx % 5              # experts 5 .. 7 get no row
+    x, idx = jnp.asarray(x), jnp.asarray(idx, jnp.int32)
+    weights = jnp.asarray(rng.random((t, k)), jnp.float32)
+    gate, up = (jnp.asarray(rng.standard_normal((e, d, m)) * 0.1,
+                            jnp.float32) for _ in range(2))
+    down = jnp.asarray(rng.standard_normal((e, m, d)) * 0.1, jnp.float32)
+    layer = dropless.DroplessMoEMLP(
+        hidden_size=d, ffn_hidden_size=m, num_experts=e + (8 if first else 0),
+        k=k, held=e if first else None, first=first or 0)
+    variables = layer.init(jax.random.PRNGKey(0), x)
+    variables["params"]["experts"] = {"gate_proj": gate, "up_proj": up,
+                                      "down_proj": down}
+
+    def run(pad):
+        monkeypatch.setattr(dropless, "served_rows",
+                            lambda rows, *a, **kw: (-(-rows // 128) | 1)
+                            * 128 if pad else rows)
+        y, sizes = dropless.grouped_experts(
+            x, idx, weights, gate, up, down, first=first,
+            num_experts=e + 8 if first else None)
+        _, sown = layer.apply(variables, x, mutable=["routing"])
+        return y, sizes, sown["routing"]["stats"]
+
+    want, got = run(False), run(True)
+    assert t * k < 128
+    if case == "idle":
+        assert int((want[1] == 0).sum()) == 3
+    if case == "share":
+        assert int(want[1].sum()) < t * k
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_the_layout_moves_only_the_stacks_it_widens():
     """``lay_out_experts`` in place: a stack served wider leaves ``params``
     for ``served_experts`` zero-padded, one served as published stays; a

@@ -342,6 +342,15 @@ def kernel_cases():
             _sds((64, 2304, w), bf16), _sds((64, 2304, w), bf16),
             _sds((64, w, 2304), bf16)])
 
+    # -- the routed experts of lfm2-24b-a2b.chat-closed128's decode step:
+    # 128 slots x top 4 = 512 rows over GLM's 64 x 2048 x 1536 stack, ~8 a
+    # group; handed 640 rows (dropless.served_rows), so the row tile is 128
+    # and not the 512 that 512 rows would get
+    yield ("moe_grouped_experts_lfm2_decode", grouped_experts,
+           [_sds((128, 2048), bf16), _sds((128, 4), i32), _sds((128, 4), f32),
+            _sds((64, 2048, 1536), bf16), _sds((64, 2048, 1536), bf16),
+            _sds((64, 1536, 2048), bf16)])
+
     # -- the s>1 query-block generalization (ISSUE 13): the speculative
     # verify step reads a 4-token block (draft_len 3 + 1 pending) per
     # slot through the SAME kernel — the per-row causal band
