@@ -27,7 +27,16 @@ contiguous latent buffer in the expanded form with a position mask.
 
 Scores, softmax, norms, RoPE angles and the router are float32; weights and
 matmuls are ``config.dtype``. RoPE pairs dims ``(i, i + d/2)`` (rotate-half,
-the repo's ``fused_rope`` convention). Multi-token prediction
+the repo's ``fused_rope`` convention).
+
+:class:`LatentAttention` also serves Ling's MLA layers
+(``models/bailing_hybrid.py``), whose config states what GLM's has no field
+for (read as off): ``q_lora_rank`` None (one ``q_proj`` of the hidden
+state, no low-rank query), ``rope_interleave`` (the rotated dims come in
+adjacent pairs, ``(2i, 2i + 1)``, and are gathered into halves before
+rotate-half, DeepSeek-V3's ``rope_interleave``) and ``q_head_norm`` with
+``head_gate`` (an RMSNorm of each head's whole query, and each head's
+context times ``sigmoid(w_h . x)`` before ``o_proj``). Multi-token prediction
 (``num_nextn_predict_layers``) is not held: it adds nothing to the forward
 pass and needs speculation over a latent pool (ROADMAP.md).
 """
@@ -127,11 +136,23 @@ def _rope(x, pos, theta: float):
     return out.astype(x.dtype)
 
 
+def _halves(x):
+    """Adjacent pairs ``(2i, 2i + 1)`` of the last axis gathered into
+    halves ``(i, i + d/2)``: interleaved RoPE as rotate-half (the rotated
+    output stays in halves, for queries and keys alike)."""
+    d = x.shape[-1]
+    return x.reshape(x.shape[:-1] + (d // 2, 2)).swapaxes(-1, -2).reshape(
+        x.shape)
+
+
 class LatentAttention(nn.Module):
     """MLA: see the module docstring. ``pos`` is (b, s) absolute positions;
-    ``cache`` a per-layer view (``generation.layer_cache``) or None."""
+    ``cache`` a per-layer view (``generation.layer_cache``) or None.
+    ``config``: a :class:`Glm4MoeLiteConfig`, or any config with its
+    attention fields and Ling's three options
+    (``bailing_hybrid.BailingHybridConfig``)."""
 
-    config: Glm4MoeLiteConfig
+    config: Any
 
     @nn.compact
     def __call__(self, x, pos, cache=None):
@@ -141,18 +162,30 @@ class LatentAttention(nn.Module):
                              cfg.qk_rope_head_dim, cfg.v_head_dim)
         rank, pd = cfg.kv_lora_rank, cfg.param_dtype
         scale = 1.0 / (cfg.head_dim ** 0.5)
+        ling = {name: getattr(cfg, name, False)
+                for name in ("rope_interleave", "q_head_norm", "head_gate")}
+        pairs = _halves if ling["rope_interleave"] else (lambda t: t)
 
-        c_q = FusedRMSNorm(cfg.q_lora_rank, eps=cfg.rms_eps, param_dtype=pd,
-                           name="q_a_norm")(
-            Linear(cfg.q_lora_rank, e, pd, name="q_a_proj")(x)).astype(x.dtype)
-        q = Linear(h * cfg.head_dim, cfg.q_lora_rank, pd,
-                   name="q_b_proj")(c_q).reshape(b, s, h, cfg.head_dim)
-        q_nope, q_rope = q[..., :nope], _rope(q[..., nope:], pos,
+        if cfg.q_lora_rank is None:
+            q = Linear(h * cfg.head_dim, e, pd, name="q_proj")(x)
+        else:
+            c_q = FusedRMSNorm(cfg.q_lora_rank, eps=cfg.rms_eps,
+                               param_dtype=pd, name="q_a_norm")(
+                Linear(cfg.q_lora_rank, e, pd, name="q_a_proj")(x)
+            ).astype(x.dtype)
+            q = Linear(h * cfg.head_dim, cfg.q_lora_rank, pd,
+                       name="q_b_proj")(c_q)
+        q = q.reshape(b, s, h, cfg.head_dim)
+        if ling["q_head_norm"]:
+            q = FusedRMSNorm(cfg.head_dim, eps=cfg.rms_eps, param_dtype=pd,
+                             name="q_norm")(q).astype(x.dtype)
+        q_nope, q_rope = q[..., :nope], _rope(pairs(q[..., nope:]), pos,
                                               cfg.rope_theta)
         ckv = Linear(rank + rope, e, pd, name="kv_a_proj")(x)
         c_kv = FusedRMSNorm(rank, eps=cfg.rms_eps, param_dtype=pd,
                             name="kv_a_norm")(ckv[..., :rank]).astype(x.dtype)
-        k_rope = _rope(ckv[..., rank:], pos, cfg.rope_theta)  # (b, s, rope)
+        k_rope = _rope(pairs(ckv[..., rank:]), pos,
+                       cfg.rope_theta)                        # (b, s, rope)
         w_ukv = KvUp(h * (nope + vd), rank, pd, name="kv_b_proj")() \
             .astype(x.dtype).reshape(h, nope + vd, rank)
 
@@ -208,7 +241,12 @@ class LatentAttention(nn.Module):
                 ctx = _masked_attention_core(
                     q_full, k, _pad_last(v, k.shape[-1]),
                     mask[None, None, None], scale=scale)[..., :vd]
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, h * vd)
+        ctx = ctx.transpose(0, 2, 1, 3)                      # (b, s, h, vd)
+        if ling["head_gate"]:
+            ctx = ctx * jax.nn.sigmoid(Linear(h, e, pd, name="g_proj")(x)
+                                       .astype(jnp.float32))[..., None] \
+                .astype(ctx.dtype)
+        ctx = ctx.reshape(b, s, h * vd)
         out = Linear(e, h * vd, pd, name="o_proj")(ctx.astype(x.dtype))
         return out if cache is None else (out, cache)
 

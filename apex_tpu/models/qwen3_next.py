@@ -190,9 +190,31 @@ def _rotate(x, cos, sin):
         x.astype(jnp.float32), cos, sin).astype(x.dtype)
 
 
-def _l2norm(x, eps: float = 1e-6):
+def l2norm(x, eps: float = 1e-6):
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + eps)
+
+
+def causal_conv_silu(mixed, weight, state=None, lengths=None):
+    """SiLU of a causal depthwise convolution over a chunk of ``s`` tokens a
+    row, in float32: ``mixed`` ``(b, s, channels)``, ``weight`` ``(channels,
+    taps)``, ``state`` the last ``taps - 1`` inputs before the chunk ``(b,
+    taps - 1, channels)`` (None: a sequence's start, zeros). Returns the
+    output and the window's last ``taps - 1`` TRUE inputs (``lengths``:
+    each row's true length; None: all ``s``), in ``mixed``'s dtype."""
+    b, s, channels = mixed.shape
+    taps = weight.shape[1]
+    tail = jnp.zeros((b, taps - 1, channels), mixed.dtype) \
+        if state is None else state.astype(mixed.dtype)
+    window = jnp.concatenate([tail, mixed], axis=1)
+    conv = sum(window[:, j:j + s].astype(jnp.float32)
+               * weight[:, j].astype(jnp.float32) for j in range(taps))
+    if lengths is None:
+        tail = window[:, s:]
+    else:
+        tail = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
+            w, n, taps - 1, axis=0))(window, lengths)
+    return jax.nn.silu(conv), tail
 
 
 class GatedDeltaNet(nn.Module):
@@ -221,18 +243,13 @@ class GatedDeltaNet(nn.Module):
         norm_w = self.param("norm_weight", nn.initializers.ones, (dv,), pd)
 
         mixed, z = qkvz[..., :cfg.conv_dim], qkvz[..., cfg.conv_dim:]
-        # the convolution's window: the last ``taps - 1`` inputs before this
-        # call (zeros at a sequence's start), then the call's own
-        tail = jnp.zeros((b, taps - 1, cfg.conv_dim), mixed.dtype) \
-            if state is None else state["conv_state"].astype(mixed.dtype)
-        window = jnp.concatenate([tail, mixed], axis=1)
-        conv = sum(window[:, j:j + s].astype(f32)
-                   * conv_w[:, j].astype(f32) for j in range(taps))
-        conv = jax.nn.silu(conv)
-        q = _l2norm(conv[..., :cfg.key_dim].reshape(b, s, hk, dk)) \
+        conv, tail = causal_conv_silu(
+            mixed, conv_w, None if state is None else state["conv_state"],
+            lengths)
+        q = l2norm(conv[..., :cfg.key_dim].reshape(b, s, hk, dk)) \
             * dk ** -0.5
-        k = _l2norm(conv[..., cfg.key_dim:2 * cfg.key_dim]
-                    .reshape(b, s, hk, dk))
+        k = l2norm(conv[..., cfg.key_dim:2 * cfg.key_dim]
+                   .reshape(b, s, hk, dk))
         v = conv[..., 2 * cfg.key_dim:].reshape(b, s, hv, dv)
         beta = jax.nn.sigmoid(ba[..., :hv])
         g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
@@ -253,12 +270,6 @@ class GatedDeltaNet(nn.Module):
             o.reshape(b, s, hv * dv).astype(x.dtype))
         if state is None:
             return out
-        # the window's last ``taps - 1`` TRUE inputs
-        if lengths is None:
-            tail = window[:, s:]
-        else:
-            tail = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
-                w, n, taps - 1, axis=0))(window, lengths)
         return out, {"delta_state": s1,
                      "conv_state": tail.astype(state["conv_state"].dtype)}
 

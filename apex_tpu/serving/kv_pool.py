@@ -105,7 +105,10 @@ be rolled back past a rejected draft block, be handed from chunk to chunk of a
 chunked prefill through the paged ``s > 1`` path, go to the host tier,
 shard over a tensor-parallel mesh, or stand beside quantized pages: the
 engine refuses each by name (:class:`StateGroupUnsupported`;
-``ROADMAP.md``).
+``ROADMAP.md``). The layers beside a state group that store tokens store
+them in the model-wide layout, per-head pages or LATENT pages alike
+(``models/bailing_hybrid.py``: Kimi delta attention layers beside one MLA
+layer's latent entries).
 
 A POOL ROW IS 128 LANES WHERE IT CAN BE. A per-head pool whose head width
 divides 128 holds ``pack = 128 // width`` heads side by side in one row

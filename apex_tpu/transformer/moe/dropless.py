@@ -367,7 +367,8 @@ class DroplessMoEMLP(nn.Module):
     out)``, and ``shared_experts`` always-on experts fused into one SwiGLU
     of their summed width, times ``sigmoid(w_g . x)`` where ``shared_gate``.
     ``norm_eps``: what the sigmoid router adds to the chosen weights' sum
-    before it renormalises them.
+    before it renormalises them; ``n_group`` / ``topk_group``: its groups
+    of experts (:func:`~apex_tpu.transformer.moe.router.group_limited`).
     ``held`` (None: all) and ``first``: the share of the experts this chip
     holds (module docstring): the stack is ``held`` experts, the router all
     ``num_experts`` outputs."""
@@ -385,11 +386,16 @@ class DroplessMoEMLP(nn.Module):
     held: Optional[int] = None
     first: int = 0
     shared_gate: bool = False
+    n_group: int = 1
+    topk_group: int = 1
 
     def _router(self):
         common = dict(norm_topk_prob=self.norm_topk_prob,
                       params_dtype=self.params_dtype, name="router")
         if self.router == "softmax":
+            if self.n_group > 1:
+                raise ValueError("groups of experts are the sigmoid "
+                                 "router's (noaux_tc), not the softmax's")
             return SoftmaxTopKRouter(self.num_experts, self.k, **common)
         if self.router != "sigmoid_bias":
             raise ValueError(f"unknown router {self.router!r} (has "
@@ -397,7 +403,8 @@ class DroplessMoEMLP(nn.Module):
         return SigmoidBiasTopKRouter(
             self.num_experts, self.k,
             routed_scaling_factor=self.routed_scaling_factor,
-            norm_eps=self.norm_eps, **common)
+            norm_eps=self.norm_eps, n_group=self.n_group,
+            topk_group=self.topk_group, **common)
 
     @nn.compact
     def __call__(self, x):

@@ -74,11 +74,28 @@ class TopKRouter(nn.Module):
         return probs, logits
 
 
+def group_limited(choice: jnp.ndarray, n_group: int,
+                  topk_group: int) -> jnp.ndarray:
+    """``choice`` (tokens, E) with every expert outside the ``topk_group``
+    best of ``n_group`` groups of consecutive experts set to ``-inf``; a
+    group scores the sum of its two largest entries."""
+    t, e = choice.shape
+    if e % n_group:
+        raise ValueError(f"{e} experts do not split into {n_group} groups")
+    grouped = choice.reshape(t, n_group, e // n_group)
+    _, best = lax.top_k(lax.top_k(grouped, 2)[0].sum(-1), topk_group)
+    kept = jax.nn.one_hot(best, n_group, dtype=jnp.bool_).any(1)
+    return jnp.where(kept[..., None], grouped, -jnp.inf).reshape(t, e)
+
+
 class SigmoidBiasTopKRouter(nn.Module):
-    """Sigmoid scores with a selection bias (DeepSeek-V3's ``noaux_tc``
-    with one group): ``s = sigmoid(x W^T)`` in fp32; the ``k`` largest of
-    ``s + b`` are chosen, ``b`` the learned ``e_score_correction_bias``
-    that balances load without an auxiliary loss; the chosen experts'
+    """Sigmoid scores with a selection bias (DeepSeek-V3's ``noaux_tc``):
+    ``s = sigmoid(x W^T)`` in fp32; the ``k`` largest of ``s + b`` are
+    chosen, ``b`` the learned ``e_score_correction_bias`` that balances
+    load without an auxiliary loss. With ``n_group`` groups of consecutive
+    experts (:func:`group_limited`) the ``k`` are chosen inside the
+    ``topk_group`` best groups alone; the defaults (1, 1) add nothing to
+    the program. The chosen experts'
     weights are ``s`` WITHOUT ``b``, renormalized to sum 1 where
     ``norm_topk_prob`` (over their sum plus ``norm_eps``: GLM's 1e-20 by
     default, LFM2's 1e-6), times ``routed_scaling_factor``.
@@ -94,6 +111,8 @@ class SigmoidBiasTopKRouter(nn.Module):
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
     norm_eps: float = 1e-20
+    n_group: int = 1
+    topk_group: int = 1
     params_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
@@ -104,7 +123,10 @@ class SigmoidBiasTopKRouter(nn.Module):
                        (self.num_experts,), self.params_dtype)
         scores = jax.nn.sigmoid(
             x.astype(jnp.float32) @ w.astype(jnp.float32).T)
-        _, idx = lax.top_k(scores + b.astype(jnp.float32), self.k)
+        choice = scores + b.astype(jnp.float32)
+        if self.n_group > 1:
+            choice = group_limited(choice, self.n_group, self.topk_group)
+        _, idx = lax.top_k(choice, self.k)
         weights = jnp.take_along_axis(scores, idx, axis=-1)
         if self.norm_topk_prob:
             weights = weights / (weights.sum(-1, keepdims=True)

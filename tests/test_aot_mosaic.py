@@ -709,6 +709,89 @@ def test_a_window_group_beside_pages_compiles_for_the_chip(program, mesh):
     assert peak < tpu_aot.HBM_BUDGET
 
 
+def _ling_cell():
+    """``ling-3.0-flash.reason-closed128`` at its widths, two of its kinds
+    of layer (published layers 4 and 5: a routed KDA layer and the routed
+    MLA layer): 128 slots, 128 of 512 experts held in groups, a quarter of
+    the vocabulary, the latent pool's 58,255 pages behind 512-page tables,
+    and for the KDA layer a float32 state of 32 x 128 x 128 a slot."""
+    from apex_tpu.models.bailing_hybrid import (BailingHybridConfig,
+                                                BailingHybridModel)
+
+    cfg = BailingHybridConfig(num_layers=2, first_layer=4,
+                              first_k_dense_replace=0, experts_held=128,
+                              vocab_size=39296, max_position_embeddings=8192)
+    return BailingHybridModel(cfg), dict(slots=128, num_pages=58255,
+                                         max_pages=512)
+
+
+def test_latent_pages_beside_a_state_group_compile_for_the_chip(mesh):
+    """The decode chunk of a model whose KDA layers keep a state a slot
+    beside an MLA layer's LATENT pages, compiled for the described v5e: its
+    ``gated_delta_step`` takes its decay a channel and aliases its state,
+    the MLA layer reads its pages through ``paged_latent_attention``; no
+    ``copy`` or ``transpose`` has the state's or the pool's shape, both
+    enter row-major, and the program fits the chip beside what it is
+    handed. (A 4096-token admission of the whole cut compiles to 14.17 GB
+    of peak; PERF.md section 6.)"""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    import tpu_aot
+    from apex_tpu.serving import kv_pool
+    from apex_tpu.serving.scheduler import PagedDecodeEngine
+
+    model, pool = _ling_cell()
+    slots = pool["slots"]
+    assert model.config.layer_types == ("kda", "mla")
+    engine = PagedDecodeEngine(model, variables=None, num_slots=2,
+                               page_size=16, num_pages=3,
+                               max_pages_per_seq=pool["max_pages"],
+                               sync_every=4)
+    cache = jax.eval_shape(lambda: kv_pool.init_paged_cache(
+        model.config, slots, num_pages=pool["num_pages"], page_size=16,
+        max_pages_per_seq=pool["max_pages"]))
+    i32 = jnp.int32
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), i32)))
+    sds = jax.ShapeDtypeStruct
+    rest = [sds((slots,), i32), sds((slots,), jnp.bool_),
+            sds((slots,), i32), sds((slots, 2), jnp.uint32),
+            sds((slots,), i32)]
+    compiled = tpu_aot.compile_replicated(mesh, engine._step_fn(),
+                                          [cache, variables] + rest, (0,))
+    txt = compiled.as_text()
+
+    state = (slots, 32, 128, 128)
+    latent = (pool["num_pages"], 1, 16, 640)
+    assert cache["layers"][0]["delta_state"].shape == state
+    assert cache["layers"][1]["latent_pages"].shape == latent
+    calls = re.findall(r'custom_call_target="tpu_custom_call"([^\n]*)\n'
+                       r'"kernel":"(\w+)"', txt)
+    labels = [label for _, label in calls]
+    steps = [attrs for attrs, label in calls if label == "gated_delta_step"]
+    assert len(steps) == 1
+    assert "output_to_operand_aliasing" in steps[0]
+    assert labels.count("paged_latent_attention") == 1
+    assert labels.count("paged_write") == 1
+    for shape in (state, latent):
+        shape = re.escape(",".join(map(str, shape)))
+        moved = [ln.strip()[:200] for ln in txt.splitlines()
+                 if re.search(rf"= \w+\[{shape}\]\S* (copy|transpose)\(",
+                              ln)]
+        assert not moved, moved[:2]
+        entry = next(ln for ln in txt.splitlines()
+                     if "entry_computation_layout" in ln)
+        assert set(re.findall(rf"\w+\[{shape}\]\{{([\d,]+)", entry)) == {
+            "3,2,1,0"}
+    ma = compiled.memory_analysis()
+    peak = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert peak < tpu_aot.HBM_BUDGET
+
+
 @pytest.mark.parametrize("cell", [_mellum_cell, _qwen3_next_cell])
 def test_full_layers_of_a_step_share_one_walk(cell, mesh):
     """The two claimed cells run two full-attention layers a decode step

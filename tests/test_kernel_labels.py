@@ -111,6 +111,20 @@ def _cases():
                 _sds((64, 32, 128, 128)), _sds((64, 16, 128)),
                 _sds((64, 16, 128)), _sds((64, 32, 128)), _sds((64, 32)),
                 _sds((64, 32))]),
+        # the per-channel cell's KDA layers: 128 slots, 32 heads of 128 x
+        # 128, the decay a column of 128 a head
+        "gated_delta_step@ling-3.0-flash.reason-closed128": (
+            gated_delta_step, [
+                _sds((128, 32, 128, 128)), _sds((128, 32, 128)),
+                _sds((128, 32, 128)), _sds((128, 32, 128)),
+                _sds((128, 32, 128)), _sds((128, 32))]),
+        # its MLA layer: 32 heads, one 640-lane entry a token (512 of them
+        # the values), 512-page tables over the 1 GiB pool's 58,255 pages
+        "paged_latent_attention@ling-3.0-flash.reason-closed128": (
+            functools.partial(paged_latent_attention, value_width=512), [
+                _sds((128, 32, 1, 640), bf16),
+                _sds((58255, 1, 16, 640), bf16), _sds((128, 512), i32),
+                _sds((128,), i32)]),
         # the conv cell's attention layers: 128 slots, 32 query heads over
         # 8 kv heads of 64 held two a 128-lane row, 512-entry tables over a
         # 2 GiB pool
@@ -182,7 +196,9 @@ def test_the_cases_cover_the_closed_set():
     "paged_attention@mellum2-12b-a2.5b.ide-closed48",
     "paged_attention@qwen3-next-80b-a3b.longchat-closed64",
     "gated_delta_step@qwen3-next-80b-a3b.longchat-closed64",
-    "paged_attention@lfm2-24b-a2b.chat-closed128"))
+    "paged_attention@lfm2-24b-a2b.chat-closed128",
+    "gated_delta_step@ling-3.0-flash.reason-closed128",
+    "paged_latent_attention@ling-3.0-flash.reason-closed128"))
 def test_label_reaches_the_lowered_program(case):
     """``metadata={"kernel": label}`` lands on the Mosaic custom call as
     ``kernel_metadata``; the benchmark's pattern finds it there."""

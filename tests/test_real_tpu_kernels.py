@@ -559,6 +559,79 @@ def test_gated_delta_chunk_matches_the_recurrence_on_chip(precision, tol,
         < tol * float(jnp.abs(want_s).max())
 
 
+def _channel_decay(rng, b, s, h, dk):
+    """Kimi's safe gate: ``-5 sigmoid(z)`` a key channel, ``z`` around -6
+    as the Ling cell seeds it, with a tenth of the channels at the bound."""
+    z = rng.standard_normal((b, s, h, dk)) - 6.0
+    z[..., ::10] = 20.0
+    return jnp.asarray(-5.0 / (1.0 + np.exp(-z)), jnp.float32)
+
+
+def test_gated_delta_step_takes_a_decay_a_channel_on_chip(tpu, rng):
+    """The decode step's kernel at Ling's shapes (32 heads of 128 x 128,
+    one key head a value head), its decay a column of 128 a head, through a
+    scan that carries the state as the decode chunk does."""
+    from apex_tpu.ops.gated_delta import (gated_delta_reference,
+                                          gated_delta_step)
+
+    q, k, v, _, beta, state = _delta_inputs(rng, 8, 4, 32, 32, 128, 128)
+    g = _channel_decay(rng, 8, 4, 32, 128)
+    with jax.default_matmul_precision("highest"):
+        want_o, want_s = gated_delta_reference(q, k, v, g, beta, state)
+
+    def chunk(state):
+        def one(c, t):
+            o, c = gated_delta_step(c, q[:, t], k[:, t], v[:, t], g[:, t],
+                                    beta[:, t])
+            return c, o
+        return jax.lax.scan(one, state, jnp.arange(4))
+    got_s, got_o = jax.jit(chunk, donate_argnums=(0,))(state + 0.0)
+    np.testing.assert_allclose(np.asarray(got_o).swapaxes(0, 1),
+                               np.asarray(want_o), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got_s), np.asarray(want_s),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("precision,tol", [("highest", 5e-5),
+                                           (None, 2e-2)])
+def test_gated_delta_chunk_takes_a_decay_a_channel_on_chip(precision, tol,
+                                                           tpu, rng):
+    """The chunked rule with a decay a key channel over 1100 tokens, the
+    second row 700 true tokens long, a tenth of the channels at Kimi's
+    bound of -5 a token: the sub-chunks' factors stay inside float32 on the
+    chip's own exponential and products. At full precision it read 2.6e-5
+    of the outputs' scale (a v5e), where the scalar rule reads under 2e-5:
+    a sub-chunk's two factors span up to exp(+-75), and each product of
+    them rounds once more."""
+    from apex_tpu.ops.gated_delta import (gated_delta_chunk,
+                                          gated_delta_reference)
+
+    q, k, v, _, beta, state = _delta_inputs(rng, 2, 1100, 32, 32, 128, 128)
+    g = _channel_decay(rng, 2, 1100, 32, 128)
+    lengths = jnp.asarray([1100, 700])
+    live = (jnp.arange(1100)[None] < lengths[:, None])[..., None]
+    with jax.default_matmul_precision("highest"):
+        want_o, want_s = jax.jit(gated_delta_reference)(
+            q, k, v, jnp.where(live[..., None], g, 0.0),
+            jnp.where(live, beta, 0.0), state)
+
+    def run():
+        return jax.jit(lambda *a: gated_delta_chunk(
+            *a, initial_state=state, lengths=lengths))(q, k, v, g, beta)
+    if precision:
+        with jax.default_matmul_precision(precision):
+            got_o, got_s = run()
+    else:
+        got_o, got_s = run()
+    assert np.isfinite(np.asarray(got_o)).all()
+    scale = float(jnp.abs(want_o).max())
+    assert float(jnp.abs(got_o[0] - want_o[0]).max()) < tol * scale
+    assert float(jnp.abs(got_o[1, :700] - want_o[1, :700]).max()) \
+        < tol * scale
+    assert float(jnp.abs(got_s - want_s).max()) \
+        < tol * float(jnp.abs(want_s).max())
+
+
 @pytest.mark.parametrize("s", [1, 4])
 def test_paged_attention_head256_rep8_matches_reference_on_chip(s, tpu, rng):
     """Qwen3-Next's full layers: 16 query heads over 2 key/value heads of
